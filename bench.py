@@ -8,27 +8,24 @@ CPU reference engine, and prints ONE JSON line:
      "vs_baseline": tpu_events_per_sec / baseline_events_per_sec, ...}
 
 ``vs_baseline`` divides by the honest thread-per-core C++ DES
-(detail.cpp_thread_per_core, SURVEY §7.3.5) run on the same achieved
-config when it builds; else by the interpreted Python oracle
-(detail.python_oracle). ``detail.baseline_kind`` says which.
+(detail.cpp_thread_per_core, SURVEY §7.3.5) run on the same config; a
+machine that cannot build it cannot run this benchmark.
 
-Robustness contract (round-1/2 postmortems):
-* ALWAYS exactly one JSON line on stdout.
-* The accelerator is probed in a subprocess with a deadline
-  (shadow1_tpu.platform) before any in-process backend init.
-* The timed loop runs in CHUNKS of <=50 windows via ckpt.run_chunked — no
-  single device program runs for minutes (the round-2 fault was one
-  monolithic 2000-window XLA program; 50-window programs complete in
-  seconds on this chip).
-* On a runtime fault the run retries at half scale, and finally on the
-  forced-CPU platform — a measurement is always produced; compile time is
-  reported separately from timed walls. A run that lands on the CPU
-  fallback emits ``value: null`` + ``invalid`` in the headline fields (the
-  fallback numbers stay under ``detail``): a CPU wall is not a TPU datum.
+Contract:
+* It measures the accelerator or nothing. jax picks the platform; when that
+  platform is the CPU, or on any exception, the error is printed and the
+  exit code is non-zero — there is no smaller retry and no CPU row.
+* Every row names where it ran (``platform``/``device_kind``/``n_devices``,
+  shadow1_tpu.platform.describe).
+* The timed loop runs in CHUNKS of <=50 windows via ckpt.run_chunked;
+  compile time is reported separately from timed walls.
+* The answer is checked, not only timed: the C++ comparator's event count
+  for its window slice must equal the engine's at the same window
+  (``detail.cpp_events_match``), else the run fails.
 
 The Python oracle is measured on a smaller host count (the eager oracle is
-O(events) Python; PHOLD cost/event is scale-stable) — see
-``detail.python_oracle`` for its exact config.
+O(events) Python; PHOLD cost/event is scale-stable) and reported for scale
+only — see ``detail.python_oracle`` for its exact config.
 """
 
 from __future__ import annotations
@@ -49,28 +46,19 @@ CHUNK = 50
 CPU_HOSTS = 1024
 CPU_WINDOWS = 2
 
-# CPU-fallback sizing: a run that lands on the CPU backend publishes
-# ``value: null`` + ``invalid`` anyway (a CPU wall is not a TPU datum), so
-# burning minutes on it buys nothing — BENCH_r05 spent 269 s producing an
-# invalid row at 8192 hosts × 250 windows. Smoke scale keeps the row (the
-# fallback numbers remain under ``detail`` for debugging) at seconds of
-# wall.
-SMOKE_HOSTS = 2048
-SMOKE_WINDOWS = 60
+# The C++ comparator runs this many windows (PHOLD is stationary, so a
+# slice gives a stable events/sec); a whole number of CHUNKs, so the engine
+# has a chunk boundary at the same window to compare event counts at.
+CPP_WINDOWS = 2 * CHUNK
 
 
 # Fleet sweep row (bench.py --fleet): E phold seed variants answered as one
 # vmapped program vs E sequential solo runs — the sweep-throughput claim
-# (ROADMAP item 1; ISSUE 8 acceptance: fleet wall < 0.5x sequential wall on
-# this container). The fleet's win is FIXED-COST amortization: one
+# (ROADMAP Reach 1). The fleet's win is FIXED-COST amortization: one
 # compile/launch bill for all E lanes instead of 16 (a solo engine
 # re-traces per seed — the key is a closed-over constant). Sized to the
-# regime where that fixed cost matters (small planes, many windows): at
-# large H on the CPU fallback the vectorized run's 16x flops swamp the
-# saving (measured: H=256 ratio 0.81, H=32 ratio ~0.39). The run-only
-# ratio is reported alongside, unspun: on CPU it is > 1; on a TPU the
-# per-round launch overhead is the fixed cost the same mechanism
-# amortizes (the paper's round economics).
+# regime where that fixed cost matters (small planes, many windows). The
+# run-only ratio is reported alongside, unspun.
 FLEET_E = 16
 FLEET_HOSTS = 32
 FLEET_WINDOWS = 200
@@ -97,10 +85,11 @@ def _params():
 
 
 def run_tpu(n_hosts: int, windows: int) -> dict:
+    """Time the batched engine; also returns ``events_at_cpp_windows``, its
+    event count at the CPP_WINDOWS chunk boundary."""
     import jax
 
     from shadow1_tpu import ckpt
-    from shadow1_tpu.consts import SEC
     from shadow1_tpu.core.engine import Engine
 
     eng = Engine(_experiment(n_hosts, windows), _params())
@@ -115,13 +104,16 @@ def run_tpu(n_hosts: int, windows: int) -> dict:
 
     chunk_walls: list[float] = []
     last = time.perf_counter()
+    events_at = None  # device scalar, read after the timed loop
 
     def on_chunk(st, done):
-        nonlocal last
+        nonlocal last, events_at
         jax.block_until_ready(st)
         now = time.perf_counter()
         chunk_walls.append(now - last)
         last = now
+        if done == CPP_WINDOWS:
+            events_at = st.metrics.events
 
     t0 = time.perf_counter()
     st = ckpt.run_chunked(eng, n_windows=windows, chunk=CHUNK, on_chunk=on_chunk)
@@ -140,8 +132,8 @@ def run_tpu(n_hosts: int, windows: int) -> dict:
         "ev_overflow": m["ev_overflow"],
         "ob_overflow": m["ob_overflow"],
         "rounds_per_window": m["rounds"] / max(m["windows"], 1),
+        "events_at_cpp_windows": int(events_at),
         "backend": jax.default_backend(),
-        "device": str(jax.devices()[0]),
         "n_hosts": n_hosts,
         "windows": windows,
     }
@@ -163,51 +155,38 @@ def run_cpu_oracle() -> dict:
     }
 
 
-def run_cpp_baseline(n_hosts: int, tpu_windows: int) -> dict | None:
+def run_cpp_baseline(n_hosts: int) -> dict:
     """The honest thread-per-core baseline (SURVEY §7.3.5): the C++
-    multi-core DES on the SAME achieved experiment config (counters
-    bit-match the oracle and the TPU engine — tests/test_native_
-    comparator.py). PHOLD is stationary, so 1/5 of the windows gives a
-    stable events/sec. Reported as the best of (one thread per available
-    core, 16 shards) — on a single-core box extra shards still help via
-    smaller, cache-resident heaps, and the baseline should be the CPU's
-    best foot. Each variant fails independently (a timeout in one must not
-    discard the other)."""
+    multi-core DES on the SAME experiment config for CPP_WINDOWS windows
+    (counters bit-match the oracle and the TPU engine — tests/test_native_
+    comparator.py, and main() re-checks the event count on every run).
+    Reported as the best of (one thread per available core, 16 shards) —
+    on a single-core box extra shards still help via smaller,
+    cache-resident heaps, and the baseline should be the CPU's best foot."""
     import os
 
-    try:
-        from shadow1_tpu import native
+    from shadow1_tpu import native
 
-        native.ensure_built()
-    except Exception as e:  # noqa: BLE001 — no toolchain -> no baseline
-        return {"kind": "cpp_thread_per_core", "error": repr(e)[:300]}
-    windows = max(tpu_windows // 5, 10)
     variants = []
     for nt in dict.fromkeys((os.cpu_count() or 1, 16)):
-        try:
-            r = native.run_phold(
-                n_hosts=n_hosts, seed=1234, n_windows=windows,
-                window_ns=WINDOW_MS * 10**6, mean_delay_ns=MEAN_DELAY_MS * 1e6,
-                init_events=INIT_EVENTS, ev_cap=_params().ev_cap,
-                outbox_cap=_params().outbox_cap, n_threads=nt,
-            )
-            variants.append(
-                {"n_threads": nt, "events": r["events"], "wall_s": r["wall_s"],
-                 "events_per_sec": r["events_per_sec"]}
-            )
-        except Exception as e:  # noqa: BLE001 — per-variant best effort
-            variants.append({"n_threads": nt, "error": repr(e)[:300]})
-    ok = [v for v in variants if "events_per_sec" in v]
-    out = {
+        r = native.run_phold(
+            n_hosts=n_hosts, seed=1234, n_windows=CPP_WINDOWS,
+            window_ns=WINDOW_MS * 10**6, mean_delay_ns=MEAN_DELAY_MS * 1e6,
+            init_events=INIT_EVENTS, ev_cap=_params().ev_cap,
+            outbox_cap=_params().outbox_cap, n_threads=nt,
+        )
+        variants.append(
+            {"n_threads": nt, "events": r["events"], "wall_s": r["wall_s"],
+             "events_per_sec": r["events_per_sec"]}
+        )
+    return {
         "kind": "cpp_thread_per_core",
         "n_hosts": n_hosts,
-        "windows": windows,
+        "windows": CPP_WINDOWS,
         "cpu_cores": os.cpu_count(),
         "variants": variants,
+        "best": max(variants, key=lambda v: v["events_per_sec"]),
     }
-    if ok:
-        out["best"] = max(ok, key=lambda v: v["events_per_sec"])
-    return out
 
 
 def _fleet_experiments(n_hosts: int, windows: int) -> list:
@@ -295,163 +274,91 @@ def run_fleet_bench(n_hosts: int = FLEET_HOSTS,
         "speedup_run_only": seq_run_wall / fleet_run_wall,
         "fleet_vs_sequential_wall_ratio": fleet_total / seq_total,
         "backend": jax.default_backend(),
-        "device": str(jax.devices()[0]),
     }
 
 
+def _round(d: dict) -> dict:
+    return {k: (round(v, 4) if isinstance(v, float) else v)
+            for k, v in d.items()}
+
+
+def _device() -> dict:
+    """describe(), refusing the CPU: this file's metrics are the
+    accelerator's, and a CPU wall under their name is not a measurement."""
+    import shadow1_tpu  # noqa: F401  (x64 on, before jax arrays exist)
+    from shadow1_tpu.platform import describe
+
+    dev = describe()
+    if dev["platform"] == "cpu":
+        raise SystemExit(
+            "bench.py: jax came up on the cpu platform "
+            f"({dev['n_devices']} device(s)); this benchmark measures the "
+            "accelerator and does not fall back. Run it on a machine with a "
+            "chip (and without JAX_PLATFORMS=cpu).")
+    return dev
+
+
 def fleet_main() -> None:
-    """bench.py --fleet → one fleet_e16 JSON row (BENCH_r06)."""
-    result = None
-    try:
-        import shadow1_tpu  # noqa: F401
-        from shadow1_tpu.platform import ensure_live_platform
-
-        ensure_live_platform(min_devices=1)
-        detail = run_fleet_bench()
-        result = {
-            "metric": "fleet_e16_events_per_sec",
-            "value": round(detail["fleet"]["events_per_sec"], 1),
-            "unit": "events/s (aggregate across 16 experiments)",
-            # The sweep-throughput claim: the whole fleet's wall as a
-            # fraction of 16 sequential solo runs (< 0.5 = acceptance).
-            "fleet_vs_sequential_wall_ratio": round(
-                detail["fleet_vs_sequential_wall_ratio"], 3),
-            "detail": {
-                k: ({kk: (round(vv, 4) if isinstance(vv, float) else vv)
-                     for kk, vv in v.items()} if isinstance(v, dict)
-                    else (round(v, 4) if isinstance(v, float) else v))
-                for k, v in detail.items()
-            },
-        }
-    except Exception as e:  # noqa: BLE001 — the JSON line must always print
-        import traceback
-
-        result = {
-            "metric": "fleet_e16_events_per_sec",
-            "value": None,
-            "unit": "events/s",
-            "error": repr(e),
-            "detail": {"traceback": traceback.format_exc()[-2000:]},
-        }
-    print(json.dumps(result))
-
-
-def _run_cpu_subprocess(n_hosts: int, windows: int) -> dict:
-    """Last-resort rung: re-exec this script with the CPU platform forced
-    BEFORE backend init (an in-process ``jax.config.update`` after a TPU
-    attempt is a no-op — the backend is cached)."""
-    import subprocess
-    import sys
-
-    out = subprocess.run(
-        [sys.executable, __file__, "--cpu-child", str(n_hosts), str(windows)],
-        capture_output=True, text=True, timeout=1200,
-    )
-    if out.returncode != 0:
-        raise RuntimeError(f"cpu-child rc={out.returncode}: {out.stderr[-500:]}")
-    return json.loads(out.stdout.strip().splitlines()[-1])
-
-
-def _cpu_child(n_hosts: int, windows: int) -> None:
-    import shadow1_tpu  # noqa: F401
-    from shadow1_tpu.platform import force_cpu
-
-    force_cpu()
-    print(json.dumps(run_tpu(n_hosts, windows)))
+    """bench.py --fleet → one fleet_e16 JSON row."""
+    dev = _device()
+    detail = run_fleet_bench()
+    if not detail["events_match"]:
+        raise SystemExit(f"bench.py --fleet: fleet and sequential event "
+                         f"counts differ: {json.dumps(detail)}")
+    print(json.dumps({
+        "metric": "fleet_e16_events_per_sec",
+        "value": round(detail["fleet"]["events_per_sec"], 1),
+        "unit": "events/s (aggregate across 16 experiments)",
+        **dev,
+        # The sweep-throughput claim: the whole fleet's wall as a fraction
+        # of 16 sequential solo runs.
+        "fleet_vs_sequential_wall_ratio": round(
+            detail["fleet_vs_sequential_wall_ratio"], 3),
+        "detail": {k: (_round(v) if isinstance(v, dict) else v)
+                   for k, v in _round(detail).items()},
+    }))
 
 
 def main() -> None:
-    result = None
-    try:
-        import shadow1_tpu  # noqa: F401  (x64 on, before jax arrays exist)
-        from shadow1_tpu.platform import ensure_live_platform, probe_default_backend
-
-        backend = ensure_live_platform(min_devices=1)
-        probe = probe_default_backend()
-
-        if backend == "cpu":
-            # Probe already forced CPU: the row will be invalid whatever its
-            # size, so run the smoke-scale config and keep the minutes.
-            ladder = ((SMOKE_HOSTS, SMOKE_WINDOWS, False),)
-        else:
-            ladder = (
-                (N_HOSTS, SIM_WINDOWS, False),
-                (N_HOSTS // 2, SIM_WINDOWS // 2, False),
-                (SMOKE_HOSTS, SMOKE_WINDOWS, True),
-            )
-        attempts = []
-        tpu = None
-        for n_hosts, windows, cpu_sub in ladder:
-            try:
-                if cpu_sub:
-                    tpu = _run_cpu_subprocess(n_hosts, windows)
-                else:
-                    tpu = run_tpu(n_hosts, windows)
-                break
-            except Exception as e:  # noqa: BLE001 — fall down the ladder
-                attempts.append(
-                    {"n_hosts": n_hosts, "windows": windows,
-                     "cpu_subprocess": cpu_sub, "error": repr(e)[:300]}
-                )
-        if tpu is None:
-            raise RuntimeError(f"all bench attempts failed: {attempts}")
-
-        cpu = run_cpu_oracle()
-        cpp = run_cpp_baseline(tpu["n_hosts"], tpu["windows"])
-        # vs_baseline is against the HONEST thread-per-core C++ DES when it
-        # built and ran; the interpreted Python oracle otherwise (labeled).
-        if cpp and "best" in cpp:
-            base_eps = cpp["best"]["events_per_sec"]
-            base_kind = "cpp_thread_per_core"
-        else:
-            base_eps = cpu["events_per_sec"]
-            base_kind = "python_oracle"
-        # A TPU benchmark that landed on the CPU fallback is NOT a perf
-        # datum: the headline fields must not publish a number whose
-        # denominator is a different machine class. The measured fallback
-        # row stays in detail for debugging.
-        on_accel = tpu.get("backend") not in ("", None, "cpu")
-        result = {
-            "metric": "phold_events_per_sec",
-            "value": round(tpu["events_per_sec"], 1) if on_accel else None,
-            "unit": "events/s",
-            "vs_baseline": (
-                round(tpu["events_per_sec"] / base_eps, 3) if on_accel else None
-            ),
-            **({} if on_accel else {"invalid": "no accelerator: run fell back "
-                                               "to the cpu backend"}),
-            "detail": {
-                **{k: (round(v, 4) if isinstance(v, float) else v) for k, v in tpu.items()},
-                "baseline_kind": base_kind,
-                "cpp_thread_per_core": cpp,
-                "python_oracle": {
-                    k: (round(v, 4) if isinstance(v, float) else v) for k, v in cpu.items()
-                },
-                "failed_attempts": attempts,
-            },
-        }
-        if probe.get("error"):
-            result["detail"]["backend_probe_error"] = probe["error"]
-    except Exception as e:  # noqa: BLE001 — the JSON line must always print
-        import traceback
-
-        result = {
-            "metric": "phold_events_per_sec",
-            "value": None,
-            "unit": "events/s",
-            "vs_baseline": None,
-            "error": repr(e),
-            "detail": {"traceback": traceback.format_exc()[-2000:]},
-        }
-    print(json.dumps(result))
+    dev = _device()
+    tpu = run_tpu(N_HOSTS, SIM_WINDOWS)
+    cpp = run_cpp_baseline(N_HOSTS)
+    cpu = run_cpu_oracle()
+    base = cpp["best"]
+    detail = {
+        **_round(tpu),
+        "baseline_kind": "cpp_thread_per_core",
+        "cpp_thread_per_core": cpp,
+        "cpp_events_match": all(v["events"] == tpu["events_at_cpp_windows"]
+                                for v in cpp["variants"]),
+        "python_oracle": _round(cpu),
+    }
+    # A timing of a wrong answer is not a result: no row is printed.
+    if not detail["cpp_events_match"]:
+        raise SystemExit(
+            f"bench.py: after {CPP_WINDOWS} windows the engine and the C++ "
+            f"comparator disagree on the event count: {json.dumps(detail)}")
+    if tpu["ev_overflow"] or tpu["ob_overflow"]:
+        raise SystemExit(
+            f"bench.py: overflow counters non-zero, the caps dropped "
+            f"events: {json.dumps(detail)}")
+    print(json.dumps({
+        "metric": "phold_events_per_sec",
+        "value": round(tpu["events_per_sec"], 1),
+        "unit": "events/s",
+        "vs_baseline": round(tpu["events_per_sec"] / base["events_per_sec"],
+                             3),
+        **dev,
+        "detail": detail,
+    }))
 
 
 if __name__ == "__main__":
     import sys
 
-    if len(sys.argv) == 4 and sys.argv[1] == "--cpu-child":
-        _cpu_child(int(sys.argv[2]), int(sys.argv[3]))
-    elif len(sys.argv) == 2 and sys.argv[1] == "--fleet":
+    if sys.argv[1:] == ["--fleet"]:
         fleet_main()
+    elif sys.argv[1:]:
+        raise SystemExit("usage: python bench.py [--fleet]")
     else:
         main()

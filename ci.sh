@@ -48,14 +48,15 @@
 #
 # Tests force the CPU platform with 8 virtual devices (tests/conftest.py),
 # so CI needs no accelerator; the TPU-hardware path is covered separately
-# by tests/test_backend_parity.py, which skips cleanly when absent.
+# by chip_smoke.py (fails without a chip) and tests/test_backend_parity.py
+# (slow tier; skips where the default platform is the CPU).
 set -euo pipefail
 cd "$(dirname "$0")"
 
 tier="${1:-fast}"
 case "$tier" in
   smoke)
-    python -m pytest tests/test_config.py tests/test_events.py tests/test_rng.py tests/test_ckpt_obs.py tests/test_telemetry.py tests/test_tune.py tests/test_digest.py tests/test_txn.py tests/test_fleet.py tests/test_fleet_recover.py tests/test_preempt.py tests/test_perfobs.py tests/test_serve.py tests/test_probes.py tests/test_pcap.py tests/test_links.py -q -m "not slow" -k "not tgen"
+    python -m pytest tests/test_bringup.py tests/test_config.py tests/test_events.py tests/test_rng.py tests/test_ckpt_obs.py tests/test_telemetry.py tests/test_tune.py tests/test_digest.py tests/test_txn.py tests/test_fleet.py tests/test_fleet_recover.py tests/test_preempt.py tests/test_perfobs.py tests/test_serve.py tests/test_probes.py tests/test_pcap.py tests/test_links.py -q -m "not slow" -k "not tgen"
     echo "== paritytrace bisect smoke (rung-1, injected corruption) =="
     # CPU platform like the pytest tiers (conftest forces it there; the
     # tool inherits the env) — the smoke must not depend on an accelerator.

@@ -28,6 +28,8 @@ invariant: same seed ⇒ identical event streams on both engines and across
 shardings.
 """
 
+import os
+
 import jax
 
 # Simulation time is int64 nanoseconds (the reference's SimulationTime is
@@ -35,10 +37,15 @@ import jax
 # explicitly dtyped (f32) so this does not silently promote compute to f64.
 jax.config.update("jax_enable_x64", True)
 
-# Persistent compilation cache: engine round bodies are large programs
-# (minutes to compile); caching makes repeat CLI/bench/test invocations
-# start in seconds.
-jax.config.update("jax_compilation_cache_dir", "/tmp/shadow1_tpu_jax_cache")
+# Persistent compilation cache: engine round bodies are large programs and
+# compile is the dominant cost of most runs. Where JAX_COMPILATION_CACHE_DIR
+# is set, jax reads it and nothing is set here. Otherwise the cache lives at
+# one fixed path inside the checkout — the path is part of the cache key, so
+# a directory that moves never hits.
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    jax.config.update("jax_compilation_cache_dir", os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache"))
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 
 __version__ = "0.1.0"

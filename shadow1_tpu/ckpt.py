@@ -95,9 +95,15 @@ def _integrity_digest(leaves) -> int:
     position and xor-reduced; leaf hashes then fold in order with the byte
     length, so any single flipped bit, swapped word, or truncated tail
     changes the digest. numpy-only — the supervisor verifies checkpoints
-    host-side without touching an accelerator."""
-    from shadow1_tpu.core.digest import _mix_int
+    host-side without touching an accelerator, so nothing under core/ may
+    be imported here (its modules create jax arrays at import, which
+    initialises a backend and takes the chip the child needs)."""
     from shadow1_tpu.rng import _mix_np
+
+    def _mix_int(z: int) -> int:
+        # core.digest._mix_int's value, via the numpy twin on one word.
+        with np.errstate(over="ignore"):
+            return int(_mix_np(np.uint64(z)))
 
     z = _ISEED
     for i, a in enumerate(leaves):

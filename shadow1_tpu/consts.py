@@ -33,8 +33,8 @@ EXIT_PREEMPTED = 5   # SIGTERM/SIGINT drain: the in-flight chunk was
 EXIT_HUNG = 6        # supervisor abort: the child's progress sidecar went
                      # stale past --watchdog-s twice consecutively with no
                      # forward progress — a deterministic wedge, not a
-                     # transient device fault (see the no-kill probe
-                     # playbook: tools/faultprobe)
+                     # transient device fault (bisect with
+                     # tools/faultprobe)
 EXIT_MEMORY = 7      # memory plane (shadow1_tpu/mem.py): the pre-flight
                      # byte budget rejected an oversubscribed config
                      # (MemoryBudgetError, per-plane attribution + paste-

@@ -903,26 +903,18 @@ def _model_module(name: str):
 
 
 def _resolve_kernel_impls(params: EngineParams, n_hosts: int) -> EngineParams:
-    """Downgrade pop_impl/push_impl='pallas' to 'xla' when the gridless fused
-    kernels cannot hold the plane set in VMEM at this (cap, n_hosts) — the
-    kernels would otherwise raise mid-trace (core/popk.py _check_vmem).
-    Logged, not silent: the selection is a measured perf knob."""
-    if "pallas" not in (params.pop_impl, params.push_impl):
-        return params
-    from shadow1_tpu.core import popk
+    """Check an explicit pop_impl/push_impl='pallas' at construction: when
+    the gridless fused kernels cannot hold the plane set in VMEM at this
+    (cap, n_hosts), popk.preflight's ValueError propagates — the selection
+    was asked for by name, so running something else under it would
+    mislabel the result. (The default is 'xla'; nothing selects pallas on
+    its own.)"""
+    if "pallas" in (params.pop_impl, params.push_impl):
+        from shadow1_tpu.core import popk
 
-    try:
         popk.preflight(params.ev_cap, params.outbox_cap, n_hosts,
                        pop_pallas=params.pop_impl == "pallas",
                        push_pallas=params.push_impl == "pallas")
-    except ValueError as e:
-        import warnings
-
-        warnings.warn(f"pallas kernels unavailable at this shape ({e}); "
-                      "falling back to pop_impl=push_impl='xla'")
-        import dataclasses
-
-        params = dataclasses.replace(params, pop_impl="xla", push_impl="xla")
     return params
 
 

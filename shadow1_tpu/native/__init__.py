@@ -16,6 +16,7 @@ entitles bench.py to use its wall clock as ``vs_baseline``.
 
 from __future__ import annotations
 
+import hashlib
 import json
 import os
 import pathlib
@@ -45,24 +46,54 @@ def _dump_table() -> None:
         f.write(np.uint64(rng._LN2_Q32).tobytes())
 
 
-def ensure_built(force: bool = False) -> pathlib.Path:
-    src = _DIR / "phold_comparator.cpp"
-    rng_src = _REPO / "shadow1_tpu" / "rng.py"
+def _digest(source: pathlib.Path) -> str:
+    return hashlib.sha256(source.read_bytes()).hexdigest()
+
+
+def _stamp(artifact: pathlib.Path) -> pathlib.Path:
+    return artifact.with_name(artifact.name + ".src.sha256")
+
+
+def _current(artifact: pathlib.Path, digest: str) -> bool:
+    """Was ``artifact`` built from the source bytes that hash to ``digest``?
+    The hash of the source an artifact was built from sits beside it:
+    ``build/`` is git-ignored and travels with copies of the tree, so an
+    mtime says nothing about which source a binary found there came from."""
+    stamp = _stamp(artifact)
+    return (artifact.exists() and stamp.exists()
+            and stamp.read_text().strip() == digest)
+
+
+def _ensure(binary: pathlib.Path, src_name: str, force: bool) -> pathlib.Path:
+    """Build ``binary`` from ``src_name`` (and the Q32 table from rng.py)
+    unless each already matches its source's content. A stale table would
+    make the comparator silently non-identical to the jnp/numpy engines."""
     _BUILD.mkdir(parents=True, exist_ok=True)
-    # Re-dump when rng.py is newer than the table: a stale table would make
-    # the comparator silently non-identical to the jnp/numpy engines.
-    if force or not _TABLE.exists() or _TABLE.stat().st_mtime < rng_src.stat().st_mtime:
+    tbl_src = _digest(_REPO / "shadow1_tpu" / "rng.py")
+    if force or not _current(_TABLE, tbl_src):
         _dump_table()
-    if not force and _BIN.exists() and _BIN.stat().st_mtime >= src.stat().st_mtime:
-        return _BIN
-    cmd = ["g++", "-O2", "-std=c++17", "-pthread", "-o", str(_BIN), str(src)]
+        _stamp(_TABLE).write_text(tbl_src)
+    src = _DIR / src_name
+    want = _digest(src)
+    if not force and _current(binary, want):
+        return binary
+    # Compile beside the target and rename: a build cut short never leaves
+    # a half-written binary under a stamp that vouches for it.
+    tmp = binary.with_name(binary.name + ".tmp")
+    cmd = ["g++", "-O2", "-std=c++17", "-pthread", "-o", str(tmp), str(src)]
     try:
         out = subprocess.run(cmd, capture_output=True, text=True, timeout=300)
     except (FileNotFoundError, subprocess.TimeoutExpired) as e:
         raise NativeUnavailable(f"g++ unavailable: {e!r}") from e
     if out.returncode != 0:
         raise NativeUnavailable(f"g++ failed: {out.stderr[-800:]}")
-    return _BIN
+    os.replace(tmp, binary)
+    _stamp(binary).write_text(want)
+    return binary
+
+
+def ensure_built(force: bool = False) -> pathlib.Path:
+    return _ensure(_BIN, "phold_comparator.cpp", force)
 
 
 def run_phold(
@@ -98,21 +129,7 @@ _NET_BIN = _BUILD / "net_comparator"
 
 
 def ensure_net_built(force: bool = False) -> pathlib.Path:
-    src = _DIR / "net_comparator.cpp"
-    rng_src = _REPO / "shadow1_tpu" / "rng.py"
-    _BUILD.mkdir(parents=True, exist_ok=True)
-    if force or not _TABLE.exists() or _TABLE.stat().st_mtime < rng_src.stat().st_mtime:
-        _dump_table()
-    if not force and _NET_BIN.exists() and _NET_BIN.stat().st_mtime >= src.stat().st_mtime:
-        return _NET_BIN
-    cmd = ["g++", "-O2", "-std=c++17", "-pthread", "-o", str(_NET_BIN), str(src)]
-    try:
-        out = subprocess.run(cmd, capture_output=True, text=True, timeout=300)
-    except (FileNotFoundError, subprocess.TimeoutExpired) as e:
-        raise NativeUnavailable(f"g++ unavailable: {e!r}") from e
-    if out.returncode != 0:
-        raise NativeUnavailable(f"g++ failed: {out.stderr[-800:]}")
-    return _NET_BIN
+    return _ensure(_NET_BIN, "net_comparator.cpp", force)
 
 
 _NET_MAGIC = 0x53484457434D5032

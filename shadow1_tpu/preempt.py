@@ -76,7 +76,7 @@ def run_injection_hooks(sim_ns: int) -> None:
     * ``SHADOW1_OBS_SIGTERM_SELF_AT_NS`` — deliver SIGTERM to ourselves
       (the deterministic twin of a real preemption notice);
     * ``SHADOW1_OBS_HANG_AT_NS`` (+ ``SHADOW1_OBS_HANG_ONCE_FLAG``) — stop
-      updating the progress sidecar while staying alive (the dead-tunnel
+      updating the progress sidecar while staying alive (the alive-but-stuck
       shape the watchdog must detect); the flag file makes it fire once so
       a respawn proceeds.
 

@@ -441,9 +441,15 @@ class ServeDaemon:
                 self.ledger_dict, port=self.metrics_port,
                 prefix="shadow1_serve", specs=SERVE_SPECS).start()
         self._recover()
+        from shadow1_tpu.platform import describe
+
+        # describe() brings the backend up here, before the first job: a
+        # daemon that cannot reach its device fails at start, not at the
+        # first tenant's batch, and the start event says where jobs run.
         self._event("start", pid=os.getpid(), spool=self.spool.root,
                     metrics_port=(self._metrics_srv.port
-                                  if self._metrics_srv else None))
+                                  if self._metrics_srv else None),
+                    **describe())
         return self
 
     def _start_socket(self) -> None:
@@ -1528,9 +1534,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     import shadow1_tpu  # noqa: F401  (x64 before jax arrays)
-    from shadow1_tpu.platform import ensure_live_platform
 
-    ensure_live_platform(min_devices=1)
     try:
         daemon = ServeDaemon(
             args.spool, metrics_port=args.metrics_port,

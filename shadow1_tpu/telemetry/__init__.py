@@ -22,6 +22,7 @@ from shadow1_tpu.telemetry.profiler import (  # noqa: F401
     PH_DRAIN,
     PH_INIT,
     PH_RUN_CHUNK,
+    CompileMeter,
     PhaseProfiler,
     device_trace,
     maybe_span,

@@ -113,6 +113,45 @@ def maybe_span(profiler: PhaseProfiler | None, name: str, **args):
     return profiler.span(name, **args)
 
 
+class CompileMeter:
+    """What jax spent building programs in this process, from jax's own
+    monitoring events: trace + lowering + backend compile seconds (a
+    persistent-cache hit counts its retrieval time there) and the
+    persistent cache's hit/miss tally. Lets a result row report compile
+    apart from run whether the run was one device execution or many
+    chunks, and say whether the compile was warm."""
+
+    def __init__(self):
+        import jax
+
+        self.seconds = 0.0
+        self.cache_hits = 0
+        self.cache_misses = 0
+        jax.monitoring.register_event_duration_secs_listener(self._duration)
+        jax.monitoring.register_event_listener(self._event)
+
+    def _duration(self, event: str, secs: float, **_kw) -> None:
+        if event.startswith("/jax/core/compile/"):
+            self.seconds += secs
+
+    def _event(self, event: str, **_kw) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            self.cache_hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.cache_misses += 1
+
+    def finish(self) -> dict:
+        """Stop listening (jax's listener lists are process-global) and
+        return the tally."""
+        import jax
+
+        jax.monitoring.unregister_event_duration_listener(self._duration)
+        jax.monitoring.unregister_event_listener(self._event)
+        return {"seconds": round(self.seconds, 3),
+                "cache_hits": self.cache_hits,
+                "cache_misses": self.cache_misses}
+
+
 @contextlib.contextmanager
 def device_trace(log_dir: str, profiler: PhaseProfiler | None = None,
                  perfetto: bool = True):

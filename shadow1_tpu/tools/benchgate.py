@@ -281,9 +281,6 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     import shadow1_tpu  # noqa: F401  (x64 before jax arrays)
-    from shadow1_tpu.platform import ensure_live_platform
-
-    ensure_live_platform(min_devices=1)
     import jax
 
     backend = jax.default_backend()

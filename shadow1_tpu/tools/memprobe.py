@@ -277,9 +277,6 @@ def main(argv=None) -> int:
 
     import shadow1_tpu  # noqa: F401  (x64 before jax arrays)
     from shadow1_tpu import mem
-    from shadow1_tpu.platform import ensure_live_platform
-
-    ensure_live_platform(min_devices=1)
 
     def say(msg):
         if not args.json_only:

@@ -62,7 +62,7 @@ DEFAULT_CONFIGS = ("smoke", "configs/rung1_filexfer.yaml")
 def _sub_jaxprs(v):
     """Yield every Jaxpr nested in an eqn param value (pjit/cond/while/scan
     bodies, custom-call jaxprs, lists thereof)."""
-    from jax import core as jcore
+    from jax.extend import core as jcore
 
     if isinstance(v, jcore.ClosedJaxpr):
         yield v.jaxpr
@@ -85,20 +85,17 @@ def iter_eqns(jaxpr):
 def _source_label(eqn) -> str:
     """``file.function`` of the deepest user frame that created the eqn —
     the round-5 census's grouping (dense.get_col, events.push_local, ...)."""
-    try:
-        from jax._src import source_info_util
+    from jax._src import source_info_util
 
-        frame = source_info_util.user_frame(eqn.source_info)
-        if frame is None:
-            return "(no source)"
-        base = os.path.basename(frame.file_name)
-        if base.endswith(".py"):
-            base = base[:-3]
-        if base == "__init__":
-            base = os.path.basename(os.path.dirname(frame.file_name))
-        return f"{base}.{frame.function_name}"
-    except Exception:
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
+    if frame is None:
         return "(no source)"
+    base = os.path.basename(frame.file_name)
+    if base.endswith(".py"):
+        base = base[:-3]
+    if base == "__init__":
+        base = os.path.basename(os.path.dirname(frame.file_name))
+    return f"{base}.{frame.function_name}"
 
 
 def count_eqns(fn, *args, sources: bool = False):

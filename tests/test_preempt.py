@@ -307,7 +307,7 @@ def test_cli_sigterm_drain_preempted_then_resume(tmp_path):
 
 def test_cli_watchdog_kills_and_recovers_hung_child(tmp_path):
     """The stale-progress acceptance: a child whose sidecar stops ticking
-    (the dead-tunnel shape) is killed within the watchdog deadline,
+    (alive but stuck) is killed within the watchdog deadline,
     classified 'hung' (not crashed), respawned, and the finished run
     bit-matches an uninterrupted one."""
     from shadow1_tpu.config.experiment import load_experiment
