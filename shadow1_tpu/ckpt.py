@@ -306,8 +306,12 @@ def run_chunked(engine, st=None, n_windows: int | None = None,
     each (for checkpoints/heartbeats). One compiled program is reused for
     every full chunk. Returns the final state.
 
-    ``profiler`` (telemetry.PhaseProfiler) records one ``run-chunk`` span
-    per chunk — the dominant phase every trace wants resolved.
+    Every chunk is spanned (telemetry/profiler.py): ``run-chunk`` ⊃
+    ``dispatch`` (the run call returning; + ``sync`` under a profiler),
+    then ``commit`` (guard), ``on-chunk``, ``retune``, each with the chunk's
+    first window as ``done``. The spans are ``shadow1:`` annotations in any
+    ``jax.profiler`` capture; ``profiler`` (telemetry.PhaseProfiler) also
+    records them for ``--trace`` and makes ``run-chunk`` cover execution.
 
     ``retune(engine, st) -> (engine, st)`` is the between-chunk adaptation
     hook (tune/autocap.CapController): it may hand back a DIFFERENT engine
@@ -335,7 +339,16 @@ def run_chunked(engine, st=None, n_windows: int | None = None,
     snapshot when the run carries a checkpoint path) and raises
     preempt.PreemptedExit — checked only at chunk boundaries, never inside
     a window (a window is the atomic unit of the determinism contract)."""
-    from shadow1_tpu.telemetry import PH_INIT, PH_RUN_CHUNK, maybe_span
+    from shadow1_tpu.telemetry import (
+        PH_COMMIT,
+        PH_DISPATCH,
+        PH_INIT,
+        PH_ON_CHUNK,
+        PH_RETUNE,
+        PH_RUN_CHUNK,
+        PH_SYNC,
+        maybe_span,
+    )
 
     if st is None:
         with maybe_span(profiler, PH_INIT):
@@ -348,21 +361,28 @@ def run_chunked(engine, st=None, n_windows: int | None = None,
     done = 0
     while done < total:
         step = min(chunk, total - done)
+        # What every span of this chunk carries: its first window and size.
+        ids = {"done": done, "windows": step}
         # Rollback point: jax states are immutable and run() never donates,
         # so holding the reference is free until the commit drops it.
         st0 = st if guard is not None else None
-        with maybe_span(profiler, PH_RUN_CHUNK, windows=step, done=done):
+        with maybe_span(profiler, PH_RUN_CHUNK, **ids):
             # Under a guard the sharded engine's eager x2x safety net
             # stands down (guard.run_guarded passes check_x2x=False) — the
             # commit below owns the overflow response.
-            st = (guard.run_guarded(engine, st, step) if guard is not None
-                  else engine.run(st, n_windows=step))
+            with maybe_span(profiler, PH_DISPATCH, **ids):
+                st = (guard.run_guarded(engine, st, step)
+                      if guard is not None
+                      else engine.run(st, n_windows=step))
             if profiler is not None:
-                # Only when tracing: make the span cover execution, not just
-                # async dispatch. Chunk boundary — never inside a window.
-                jax.block_until_ready(st)
+                # Only under a PhaseProfiler: make the span cover execution,
+                # not just async dispatch. Chunk boundary — never inside a
+                # window.
+                with maybe_span(profiler, PH_SYNC, **ids):
+                    jax.block_until_ready(st)
         if guard is not None:
-            engine, st = guard.commit(engine, st0, st, done, step)
+            with maybe_span(profiler, PH_COMMIT, **ids):
+                engine, st = guard.commit(engine, st0, st, done, step)
         done += step
         if selfcheck:
             from shadow1_tpu.txn import check_boundary_identity
@@ -377,7 +397,8 @@ def run_chunked(engine, st=None, n_windows: int | None = None,
         # never honored without its snapshot.
         draining = drain is not None and drain.requested and done < total
         if on_chunk is not None:
-            on_chunk(st, done)
+            with maybe_span(profiler, PH_ON_CHUNK, **ids):
+                on_chunk(st, done)
         if draining:
             from shadow1_tpu.preempt import PreemptedExit
 
@@ -385,7 +406,8 @@ def run_chunked(engine, st=None, n_windows: int | None = None,
                 st=st, signame=drain.signame, done_windows=done,
                 win_start=int(np.asarray(st.win_start).max()))
         if retune is not None and done < total:
-            engine, st = retune(engine, st)
+            with maybe_span(profiler, PH_RETUNE, **ids):
+                engine, st = retune(engine, st)
             if guard is not None:
                 guard.engine = engine
     return st

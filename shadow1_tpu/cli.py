@@ -10,7 +10,9 @@ config values.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
+import os
 import sys
 import time
 
@@ -431,6 +433,30 @@ def _supervise(child_argv, ckpt_path, config_path,
     return rc
 
 
+@contextlib.contextmanager
+def _run_traced(args, engine=None):
+    """The run under ``--trace PATH`` / ``--profile DIR``: yields the
+    PhaseProfiler to hand to the chunk runner (None where neither flag is
+    set). ``--profile`` scopes a ``telemetry.device_trace`` over the body;
+    with ``engine`` it leaves ``DIR/phases.json`` (device time by window
+    phase, joined against that engine's program) beside
+    ``DIR/phases.trace.json``. Files are written on a clean exit only."""
+    if not (args.trace or args.profile):
+        yield None
+        return
+    from shadow1_tpu.telemetry import PhaseProfiler, device_trace
+
+    phases = PhaseProfiler()
+    with (device_trace(args.profile, phases, engine=engine) if args.profile
+          else contextlib.nullcontext()):
+        yield phases
+    if args.trace:
+        phases.write(args.trace)
+    if args.profile:
+        os.makedirs(args.profile, exist_ok=True)
+        phases.write(os.path.join(args.profile, "phases.trace.json"))
+
+
 def _fleet_main(args, params, plan, log, t0, capacity_exit,
                 preempted_exit, memory_exit=None, sub_batch=None,
                 auto_caps=False, pre_downshift_retry=False) -> int:
@@ -458,8 +484,11 @@ def _fleet_main(args, params, plan, log, t0, capacity_exit,
     from shadow1_tpu.txn import CapacityExceededError
 
     if sub_batch and sub_batch < len(plan.exps):
-        return _fleet_subbatched(args, params, plan, log, t0, capacity_exit,
-                                 preempted_exit, memory_exit, sub_batch)
+        # Spans only: phases.json is one engine's, and each batch has its own.
+        with _run_traced(args) as phases:
+            return _fleet_subbatched(args, params, plan, log, t0,
+                                     capacity_exit, preempted_exit,
+                                     memory_exit, sub_batch, profiler=phases)
     # Resume resolution FIRST: a lineage generation carries the surviving
     # lane ids (``lanes`` manifest meta) when the sweep had already
     # quarantined or finalized lanes — the engine must be built for
@@ -561,32 +590,34 @@ def _fleet_main(args, params, plan, log, t0, capacity_exit,
     try:
         if _os.environ.get("SHADOW1_MEM_INJECT_OOM") == "run":
             raise RuntimeError("RESOURCE_EXHAUSTED: injected (test hook)")
-        st, hb = run_fleet(
-            eng, st, n_windows=args.windows,
-            every_windows=args.heartbeat or (ring_w or None),
-            stream=None if (args.heartbeat or ring_w
-                            or params.link_telem) else False,
-            ckpt_path=args.ckpt, ckpt_every_s=args.ckpt_every_s,
-            emit_heartbeat=bool(args.heartbeat),
-            emit_ring=bool(ring_w or params.link_telem),
-            selfcheck=bool(params.selfcheck),
-            labels=labels,
-            ckpt_keep=args.ckpt_keep,
-            drain=drain,
-            auto_caps=auto_caps,
-            quarantine_base=qbase,
-            recovery_seed=({"quarantined":
-                            (resolved.meta or {}).get("quarantined", []),
-                            "finished":
-                            (resolved.meta or {}).get("finished", [])}
-                           if resolved is not None and st is not None
-                           else None),
-            # Quarantine / early-finalize records print to stdout the
-            # moment the lane leaves the fleet — its fleet_exp would
-            # otherwise never appear.
-            emit_record=lambda rec: print(json.dumps(rec), flush=True),
-        )
-        jax.block_until_ready(st)
+        with _run_traced(args, eng) as phases:
+            st, hb = run_fleet(
+                eng, st, n_windows=args.windows,
+                every_windows=args.heartbeat or (ring_w or None),
+                stream=None if (args.heartbeat or ring_w
+                                or params.link_telem) else False,
+                ckpt_path=args.ckpt, ckpt_every_s=args.ckpt_every_s,
+                emit_heartbeat=bool(args.heartbeat),
+                emit_ring=bool(ring_w or params.link_telem),
+                selfcheck=bool(params.selfcheck),
+                labels=labels,
+                ckpt_keep=args.ckpt_keep,
+                drain=drain,
+                auto_caps=auto_caps,
+                quarantine_base=qbase,
+                recovery_seed=({"quarantined":
+                                (resolved.meta or {}).get("quarantined", []),
+                                "finished":
+                                (resolved.meta or {}).get("finished", [])}
+                               if resolved is not None and st is not None
+                               else None),
+                # Quarantine / early-finalize records print to stdout the
+                # moment the lane leaves the fleet — its fleet_exp would
+                # otherwise never appear.
+                emit_record=lambda rec: print(json.dumps(rec), flush=True),
+                profiler=phases,
+            )
+            jax.block_until_ready(st)
     except CapacityExceededError as e:
         return capacity_exit(e)
     except PreemptedExit as e:
@@ -616,7 +647,8 @@ def _fleet_main(args, params, plan, log, t0, capacity_exit,
 
 
 def _fleet_subbatched(args, params, plan, log, t0, capacity_exit,
-                      preempted_exit, memory_exit, sub: int) -> int:
+                      preempted_exit, memory_exit, sub: int,
+                      profiler=None) -> int:
     """Memory-downshifted fleet: the sweep's E lanes run as SEQUENTIAL
     sub-batches of ≤ ``sub`` lanes, each its own vmapped FleetEngine run
     (cli --on-oom downshift; mem.downshift sized ``sub`` so one batch fits
@@ -750,6 +782,7 @@ def _fleet_subbatched(args, params, plan, log, t0, capacity_exit,
                 emit_record=lambda rec: print(json.dumps(rec), flush=True),
                 resume_meta={"batch": bi, "batch_summaries": summaries},
                 recovery_seed=recovery_seed,
+                profiler=profiler,
             )
             jax.block_until_ready(st)
         except CapacityExceededError as e:
@@ -887,13 +920,22 @@ def main(argv=None) -> int:
                     help="write final per-host tracker records (JSON lines)")
     ap.add_argument("--profile", default=None, metavar="DIR",
                     help="capture a jax.profiler trace of the run into DIR "
-                         "(open with TensorBoard; reference: heartbeat/"
-                         "tracker profiling hooks, SURVEY §5); also writes "
-                         "DIR/phases.trace.json (the --trace phase spans)")
+                         "(open with TensorBoard). The capture holds the "
+                         "program's own spans as 'shadow1:<name>' on the "
+                         "device trace's clock. Also writes "
+                         "DIR/phases.trace.json (the --trace spans) and "
+                         "DIR/phases.json: device seconds by window phase "
+                         "(prepare / rounds/pop / rounds/h_<kind> / deliver "
+                         "/ telem), joined from the compiled program's text "
+                         "because a TPU trace does not carry scopes. Works "
+                         "under --fleet")
     ap.add_argument("--trace", default=None, metavar="PATH",
                     help="write a Chrome trace-event JSON of the host-side "
-                         "phases (compile/init/run-chunk/drain/checkpoint) "
-                         "to PATH — load in Perfetto or chrome://tracing")
+                         "spans (init, compile, run-chunk > dispatch + sync, "
+                         "commit, on-chunk > drain + checkpoint, retune; "
+                         "each with its chunk's first window as 'done') to "
+                         "PATH, on a clock of its own — load in Perfetto or "
+                         "chrome://tracing. Works under --fleet")
     ap.add_argument("--auto-caps", action="store_true",
                     help="occupancy-driven capacity autotuning: at chunk "
                          "boundaries, grow ev_cap before overflow and shrink "
@@ -1110,14 +1152,12 @@ def main(argv=None) -> int:
                  "engine (tpu or sharded)")
     if args.fleet:
         bad = [f for f, v in (("--tracker", args.tracker),
-                              ("--summary", args.summary),
-                              ("--profile", args.profile),
-                              ("--trace", args.trace)) if v]
+                              ("--summary", args.summary)) if v]
         if bad:
             ap.error(f"--fleet does not support {', '.join(bad)}: "
-                     f"per-experiment tracker/summary/phase traces are a "
-                     f"follow-up; use the fleet_exp records and "
-                     f"--metrics-ring (per-experiment rows)")
+                     f"per-experiment tracker/summary are a follow-up; use "
+                     f"the fleet_exp records and --metrics-ring "
+                     f"(per-experiment rows)")
         from shadow1_tpu.fleet.expand import FleetConfigError
 
         def _fleet_config_exit(e: FleetConfigError) -> int:
@@ -1498,15 +1538,6 @@ def main(argv=None) -> int:
                     # whole supervised run, not N more on top of the
                     # snapshot.
                     args.windows = max(args.windows - done, 0)
-        import contextlib
-
-        prof = (jax.profiler.trace(args.profile) if args.profile
-                else contextlib.nullcontext())
-        phases = None
-        if args.trace or args.profile:
-            from shadow1_tpu.telemetry import PhaseProfiler
-
-            phases = PhaseProfiler()
         ring_w = params.metrics_ring
         if auto_caps:
             from shadow1_tpu.tune import CapController
@@ -1525,7 +1556,7 @@ def main(argv=None) -> int:
         from shadow1_tpu.preempt import DrainHandler, PreemptedExit
 
         try:
-            with prof:
+            with _run_traced(args, eng) as phases:
                 if os.environ.get("SHADOW1_MEM_INJECT_OOM") == "run":
                     raise RuntimeError(
                         "RESOURCE_EXHAUSTED: injected (test hook)")
@@ -1580,12 +1611,6 @@ def main(argv=None) -> int:
             if mem.is_oom(e):
                 return _memory_exit_runtime(e)
             raise
-        if phases is not None:
-            if args.trace:
-                phases.write(args.trace)
-            if args.profile:
-                os.makedirs(args.profile, exist_ok=True)
-                phases.write(os.path.join(args.profile, "phases.trace.json"))
         if args.save_state:
             from shadow1_tpu.ckpt import save_state
 

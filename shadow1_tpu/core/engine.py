@@ -566,8 +566,8 @@ def window_phases(ctx: Ctx, handlers: dict, exchange=None, pre_window=None,
     destination scatter + outbox clear — sub-annotated in deliver_window),
     ``telem`` (occupancy gauges, window counters, the telemetry-ring row).
     Each stage is wrapped in ``jax.named_scope("phase:<name>")`` by
-    window_step, so device traces (``jax.profiler`` via
-    telemetry/profiler.device_trace) carry the phases as spans, and
+    window_step: the scopes reach every instruction of the compiled program
+    (telemetry/phases.py joins a device trace's ops to them), and
     ``tools/opcensus.py`` censuses each stage's jaxpr separately."""
     from shadow1_tpu.core.events import push_impl_ctx, rebase
 
@@ -753,8 +753,8 @@ def window_step(st: SimState, ctx: Ctx, handlers: dict, exchange=None,
     Structured as the composition of the ``window_phases`` stage list, each
     under a ``jax.named_scope("phase:<name>")`` — the performance
     attribution plane's decomposition (tools/phaseprobe.py times the stages
-    individually; tools/opcensus.py censuses their jaxprs; device traces
-    carry them as spans)."""
+    individually; tools/opcensus.py censuses their jaxprs; telemetry/
+    phases.py reads them off the compiled program for a device trace)."""
     fr = window_frame(st, ctx)
     for name, fn in window_phases(ctx, handlers, exchange, pre_window,
                                   make_handlers, telem_reduce, probe_reduce,
@@ -1012,6 +1012,17 @@ class Engine:
             st = self.init_state()
         n = n_windows if n_windows is not None else self.n_windows
         return self._run_jit(st, jnp.asarray(n, jnp.int32))
+
+    def hlo_text(self, st: SimState | None = None, n_windows: int = 0) -> str:
+        """The optimized HLO text of the one window program ``run`` drives
+        (``n_windows`` is a traced argument: any count is the same program),
+        for ``telemetry.phases.phase_table``. Tracing consumers only, after
+        the run, never on the hot path: it lowers and compiles again (the
+        persistent cache serves it where it holds the program)."""
+        if st is None:
+            st = jax.eval_shape(self.init_state)
+        return self._run_jit.lower(
+            st, jnp.asarray(n_windows, jnp.int32)).compile().as_text()
 
     @staticmethod
     def metrics_dict(st: SimState) -> dict[str, int]:

@@ -463,6 +463,15 @@ class FleetEngine:
         n = n_windows if n_windows is not None else self.n_windows
         return self._run_jit(st, jnp.asarray(n, jnp.int32), self._variants)
 
+    def hlo_text(self, st: SimState | None = None, n_windows: int = 0) -> str:
+        """The optimized HLO text of the fleet's one window program, as
+        ``Engine.hlo_text`` (the phases sit under ``vmap(phase:...)``)."""
+        if st is None:
+            st = jax.eval_shape(self.init_state)
+        return self._run_jit.lower(
+            st, jnp.asarray(n_windows, jnp.int32),
+            self._variants).compile().as_text()
+
     @staticmethod
     def _signature(variants: dict, has: dict) -> tuple:
         leaves, treedef = jax.tree_util.tree_flatten(variants)

@@ -229,7 +229,7 @@ def run_fleet(engine, st=None, n_windows=None, every_windows=None,
               emit_heartbeat=True, emit_ring=True, selfcheck=False,
               labels=None, ckpt_keep=3, drain=None, auto_caps=False,
               quarantine_base=None, emit_record=None, resume_meta=None,
-              recovery_seed=None):
+              recovery_seed=None, profiler=None):
     """Run the fleet in chunks. Returns (final_state, FleetHeartbeat).
 
     Mirrors ``obs.run_with_heartbeat`` (compile excluded from the first
@@ -260,7 +260,15 @@ def run_fleet(engine, st=None, n_windows=None, every_windows=None,
     generation's meta) pre-populates the ledger so a respawned process's
     final summary still reports lanes that left the fleet before the
     crash. The heartbeat's ``engine`` / ``labels`` / ``recovery``
-    attributes expose the live fleet shape."""
+    attributes expose the live fleet shape.
+
+    The loop is spanned like ``ckpt.run_chunked``, under the same names
+    (telemetry/profiler.py): ``init``, ``compile``, then per chunk
+    ``run-chunk`` ⊃ ``dispatch`` (+ ``sync`` under a profiler), ``commit``
+    (guard), ``drain`` (the per-experiment metrics fetch), ``on-chunk`` (the
+    heartbeat), ``retune``, ``checkpoint`` — ``shadow1:`` annotations in any
+    ``jax.profiler`` capture, and Chrome-trace events of ``profiler``
+    (telemetry.PhaseProfiler — CLI ``--fleet --trace``)."""
     import jax
 
     from shadow1_tpu import ckpt as _ckpt
@@ -271,6 +279,19 @@ def run_fleet(engine, st=None, n_windows=None, every_windows=None,
     )
     from shadow1_tpu.lineage import Lineage, write_json_atomic
     from shadow1_tpu.preempt import PreemptedExit, run_injection_hooks
+    from shadow1_tpu.telemetry import (
+        PH_CHECKPOINT,
+        PH_COMMIT,
+        PH_COMPILE,
+        PH_DISPATCH,
+        PH_DRAIN,
+        PH_INIT,
+        PH_ON_CHUNK,
+        PH_RETUNE,
+        PH_RUN_CHUNK,
+        PH_SYNC,
+        maybe_span,
+    )
     from shadow1_tpu.txn import (
         CapacityExceededError,
         OverflowGuard,
@@ -283,13 +304,15 @@ def run_fleet(engine, st=None, n_windows=None, every_windows=None,
     if every_windows is None:
         every_windows = max(total // 10, 1)
     if st is None:
-        st = engine.init_state()
+        with maybe_span(profiler, PH_INIT):
+            st = engine.init_state()
     labels = ([dict(l) for l in labels] if labels else
               [{"exp": i + engine.exp_base, "seed": int(e.seed)}
                for i, e in enumerate(engine.exps)])
     engine.exp_ids = [l.get("exp", i) for i, l in enumerate(labels)]
     try:
-        jax.block_until_ready(engine.run(st, n_windows=0))
+        with maybe_span(profiler, PH_COMPILE):
+            jax.block_until_ready(engine.run(st, n_windows=0))
     except Exception as e:
         from shadow1_tpu import mem
 
@@ -497,13 +520,22 @@ def run_fleet(engine, st=None, n_windows=None, every_windows=None,
         # so holding the reference is free until the commit drops it.
         st0 = st if (guard is not None or quarantine) else None
         w0 = int(np.asarray(st.win_start).max()) // engine.window
-        st_new = (OverflowGuard.run_guarded(engine, st, step)
-                  if guard is not None
-                  else engine.run(st, n_windows=step))
+        # What every span of this chunk carries: its first window and size.
+        ids = {"done": done, "windows": step}
+        with maybe_span(profiler, PH_RUN_CHUNK, **ids):
+            with maybe_span(profiler, PH_DISPATCH, **ids):
+                st_new = (OverflowGuard.run_guarded(engine, st, step)
+                          if guard is not None
+                          else engine.run(st, n_windows=step))
+            if profiler is not None:
+                # Only under a PhaseProfiler: the span covers execution.
+                with maybe_span(profiler, PH_SYNC, **ids):
+                    jax.block_until_ready(st_new)
         if guard is not None:
             try:
-                engine, st_new = guard.commit(engine, st0, st_new, done,
-                                              step)
+                with maybe_span(profiler, PH_COMMIT, **ids):
+                    engine, st_new = guard.commit(engine, st0, st_new, done,
+                                                  step)
             except CapacityExceededError as err:
                 if not (quarantine and err.lanes):
                     raise
@@ -515,7 +547,8 @@ def run_fleet(engine, st=None, n_windows=None, every_windows=None,
                                  retries_discarded=True)
                 continue
             hb.engine = engine
-        per_exp = engine.metrics_per_exp(st_new)
+        with maybe_span(profiler, PH_DRAIN, **ids):
+            per_exp = engine.metrics_per_exp(st_new)
         if halt:
             try:
                 _check_halt(engine, labels, per_exp, prev_per_exp, done,
@@ -552,7 +585,8 @@ def run_fleet(engine, st=None, n_windows=None, every_windows=None,
         # section and the per-lane retry table read these.
         _drain_retry_records()
         prev_per_exp = per_exp
-        hb(st, done, per_exp=per_exp)
+        with maybe_span(profiler, PH_ON_CHUNK, **ids):
+            hb(st, done, per_exp=per_exp)
         sim_ns = int(np.asarray(st.win_start).max())
         # Fault/preemption/hang injection (preempt.run_injection_hooks) —
         # the same chunk-boundary contract as obs.run_with_heartbeat, so
@@ -580,7 +614,8 @@ def run_fleet(engine, st=None, n_windows=None, every_windows=None,
                 st = _repack(keep, st)
         # ---- between-chunk retune (fleet --auto-caps) ------------------
         if controller is not None and done < total and engine.n_exp > 0:
-            new_engine, st = controller(engine, st)
+            with maybe_span(profiler, PH_RETUNE, **ids):
+                new_engine, st = controller(engine, st)
             if new_engine is not engine:
                 engine = new_engine
                 hb.engine = engine
@@ -602,7 +637,8 @@ def run_fleet(engine, st=None, n_windows=None, every_windows=None,
                 meta["finished"] = [r["exp"] for r in recovery["finished"]]
             if resume_meta:
                 meta.update(resume_meta)
-            last_seq[0] = lineage.save(st, meta)
+            with maybe_span(profiler, PH_CHECKPOINT, **ids):
+                last_seq[0] = lineage.save(st, meta)
             last_save = now
             saved = True
         if ckpt_path:

@@ -523,6 +523,14 @@ class ShardedEngine:
             )
         return out
 
+    def hlo_text(self, st: SimState | None = None, n_windows: int = 0) -> str:
+        """``Engine.hlo_text`` for the program ``run`` drives now: the one
+        compiled for the exchange bucket's current cap (per device: SPMD)."""
+        if st is None:
+            st = jax.eval_shape(self.init_state)
+        return self._get_run(self._x2x_cap).lower(
+            st, jnp.asarray(n_windows, jnp.int32)).compile().as_text()
+
     def grow_x2x(self) -> bool:
         """Escalate the exchange bucket to its guaranteed-fit cap (the
         overflow-retry hook, txn.OverflowGuard._grow). The bucket is not a

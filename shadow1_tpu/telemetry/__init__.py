@@ -1,12 +1,15 @@
 """Telemetry plane: on-device metrics ring, phase profiler, metrics registry.
 
-Three coordinated observability pieces (see docs/OBSERVABILITY.md):
+Four coordinated observability pieces (see docs/OBSERVABILITY.md):
 
 * ``telemetry.ring`` — per-window counter deltas recorded on device inside
   the jitted window loop, drained at chunk boundaries (the true time series
   the chunk-averaged heartbeat cannot provide);
-* ``telemetry.profiler`` — host-side phase spans exported as Chrome
-  trace-event JSON (Perfetto-viewable);
+* ``telemetry.profiler`` — host-side phase spans: ``shadow1:`` annotations
+  in any ``jax.profiler`` capture, and Chrome trace-event JSON
+  (Perfetto-viewable) under a PhaseProfiler;
+* ``telemetry.phases`` — the window phase of every traced device op, joined
+  from the compiled program's text (the TPU trace does not carry scopes);
 * ``telemetry.registry`` — the one named-counter namespace shared by the
   tpu, sharded and cpu engines, with Prometheus text exposition and the
   JSONL record schema.
@@ -16,12 +19,18 @@ it lazily from host-only paths.
 """
 
 from shadow1_tpu.telemetry.profiler import (  # noqa: F401
+    ANNOTATION_PREFIX,
     PH_CHECKPOINT,
+    PH_COMMIT,
     PH_COMPILE,
     PH_DEVICE_TRACE,
+    PH_DISPATCH,
     PH_DRAIN,
     PH_INIT,
+    PH_ON_CHUNK,
+    PH_RETUNE,
     PH_RUN_CHUNK,
+    PH_SYNC,
     CompileMeter,
     PhaseProfiler,
     device_trace,

@@ -1,17 +1,26 @@
-"""Host-side phase profiler — Chrome trace-event export.
+"""Host-side phase profiler — one set of spans, two carriers.
 
 The reference's heartbeat rows carry wall time next to sim time so the
 sim/wall ratio and its phases are derivable from the log (SURVEY §5); the
-batched rebuild's phases are coarser — compile, init, run-chunk, drain,
-checkpoint — and the question a perf PR actually asks is "where did the
-wall clock go between heartbeats?". This profiler answers it with near-zero
-overhead: a ``with profiler.span("run-chunk"):`` records one complete
-("ph": "X") trace event; ``write(path)`` emits Chrome trace-event JSON
-that chrome://tracing and Perfetto (https://ui.perfetto.dev) load directly.
+batched rebuild's phases are coarser — compile, init, run-chunk (⊃ dispatch,
+sync), commit, on-chunk (⊃ drain, checkpoint), retune — and the question a
+perf PR actually asks is "where did the wall clock go between heartbeats?".
 
-Not a replacement for ``--profile`` (the jax/XLA op-level profiler): this
-is the cheap always-on layer above it, one event per phase rather than per
-op, safe to leave enabled on production runs.
+Every ``maybe_span(profiler, name)`` call site of the program is carried two
+ways:
+
+* as a ``jax.profiler.TraceAnnotation("shadow1:" + name)``, always — so any
+  ``jax.profiler`` capture (``--profile DIR``, a benchmark's traced run)
+  holds the program's spans on the *device trace's clock*, beside the device
+  ops, whether or not a PhaseProfiler is attached. With no profiler session
+  open an annotation is a flag check;
+* as one complete (``"ph": "X"``) Chrome trace event of the attached
+  ``PhaseProfiler`` (``--trace PATH``), on a ``time.perf_counter`` clock of
+  its own: ``write(path)`` emits JSON that chrome://tracing and Perfetto
+  (https://ui.perfetto.dev) load directly.
+
+Spans of one chunk share the arguments ``done`` (the chunk's first window)
+and ``windows``; a span's parent is the span that contains it on its thread.
 """
 
 from __future__ import annotations
@@ -27,10 +36,24 @@ import time
 PH_COMPILE = "compile"
 PH_INIT = "init"
 PH_RUN_CHUNK = "run-chunk"
+PH_DISPATCH = "dispatch"    # engine.run / guard.run_guarded returning
+PH_SYNC = "sync"            # block_until_ready, only under a PhaseProfiler
+PH_COMMIT = "commit"        # txn.OverflowGuard.commit
+PH_ON_CHUNK = "on-chunk"    # the chunk-boundary hook (heartbeat, snapshot)
+PH_RETUNE = "retune"        # between-chunk cap adaptation
 PH_DRAIN = "drain"
 PH_CHECKPOINT = "checkpoint"
 # Device-trace span (the jax.profiler capture window — see device_trace).
 PH_DEVICE_TRACE = "device-trace"
+# Every span of the program is a TraceAnnotation under this prefix.
+ANNOTATION_PREFIX = "shadow1:"
+
+
+def annotation(name: str, **args):
+    """The span ``name`` on the profiler's clock alone."""
+    import jax
+
+    return jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + name, **args)
 
 
 class PhaseProfiler:
@@ -50,7 +73,8 @@ class PhaseProfiler:
         """Time a phase: ``with prof.span("run-chunk", windows=128): ...``"""
         t_start = self._now_us()
         try:
-            yield self
+            with annotation(name, **args):
+                yield self
         finally:
             t_end = self._now_us()
             ev = {
@@ -65,21 +89,6 @@ class PhaseProfiler:
                 ev["args"] = args
             with self._lock:
                 self.events.append(ev)
-
-    def instant(self, name: str, **args) -> None:
-        """Mark a point in time (``"ph": "i"`` instant event)."""
-        ev = {
-            "name": name,
-            "ph": "i",
-            "s": "p",  # process-scoped instant
-            "ts": round(self._now_us(), 1),
-            "pid": os.getpid(),
-            "tid": threading.get_ident() & 0xFFFFFFFF,
-        }
-        if args:
-            ev["args"] = args
-        with self._lock:
-            self.events.append(ev)
 
     def chrome_trace(self) -> dict:
         """The Chrome trace-event JSON object (dict form)."""
@@ -107,9 +116,11 @@ class PhaseProfiler:
 
 
 def maybe_span(profiler: PhaseProfiler | None, name: str, **args):
-    """``profiler.span(...)`` or a nullcontext — call sites stay branchless."""
+    """``profiler.span(...)``, or the bare annotation where no PhaseProfiler
+    is attached — call sites stay branchless, and every span is in any
+    ``jax.profiler`` capture either way."""
     if profiler is None:
-        return contextlib.nullcontext()
+        return annotation(name, **args)
     return profiler.span(name, **args)
 
 
@@ -154,41 +165,61 @@ class CompileMeter:
 
 @contextlib.contextmanager
 def device_trace(log_dir: str, profiler: PhaseProfiler | None = None,
-                 perfetto: bool = True):
-    """The op-level zoom under the host-side phase spans: a ``jax.profiler``
-    device trace scoped over the with-body, written to ``log_dir``.
+                 engine=None, st=None):
+    """A ``jax.profiler`` capture scoped over the with-body, written to
+    ``log_dir`` (the TensorBoard profile plugin reads that directory), with
+    the program's own spans in it (``shadow1:<name>``, module docstring) and
+    a ``device-trace`` span marking the capture window.
 
-    The engine's window program is annotated with
-    ``jax.named_scope("phase:...")`` spans (core/engine.window_phases:
-    prepare / rounds (pop, h_<kind>) / route / exchange / deliver / telem
-    — plus ``phase:tcp_flush`` inside the TCP send path), so the captured
-    trace shows exactly which window phase each device op belongs to.
-    With ``perfetto=True`` jax also writes a ``*.perfetto-trace`` file
-    under ``log_dir/plugins/profile/<run>/`` that https://ui.perfetto.dev
-    loads directly (the TensorBoard profile plugin reads the same
-    directory). A ``device-trace`` host span marks the capture window in
-    the PhaseProfiler's own Chrome trace so the two zoom levels line up.
+    The TPU trace does **not** carry the window program's
+    ``jax.named_scope("phase:...")`` scopes: it names a device op by its HLO
+    text. With ``engine`` (an ``Engine`` or ``FleetEngine``; ``st`` gives
+    the state's shapes, default the initial state's) the phase of every
+    captured op is joined on exit from the compiled program's text
+    (telemetry/phases.py) and written to ``log_dir/phases.json``: device
+    seconds by phase path, the fixed roll-up, busy time, ``unknown_ops``
+    (0 when the captured program is ``engine``'s). That costs one more
+    lowering and compile (a persistent-cache load where the cache holds the
+    program), after the body, off its clock, and only where the capture
+    holds device ops.
 
     Degrades gracefully: if the installed jax cannot start a profiler
     session (no profiler support, or a session already active), the body
     still runs and a warning names the reason — attribution tools must
-    never fail a run over a missing trace backend."""
+    never fail a run over a missing trace backend, nor over a join that
+    fails after it (a warning, no ``phases.json``). A capture with no
+    device op (the CPU backend) writes no ``phases.json``."""
     import jax
 
     started = False
     try:
         try:
-            jax.profiler.start_trace(log_dir,
-                                     create_perfetto_trace=perfetto)
+            jax.profiler.start_trace(log_dir)
             started = True
         except Exception as e:  # profiler backend unavailable — not fatal
             import warnings
 
-            warnings.warn(f"jax device trace unavailable ({e}); phases "
-                          "still carry jax.named_scope annotations but no "
-                          "device trace was captured")
+            warnings.warn(f"jax device trace unavailable ({e}); the body "
+                          "runs untraced")
         with maybe_span(profiler, PH_DEVICE_TRACE, log_dir=log_dir):
             yield
     finally:
         if started:
             jax.profiler.stop_trace()
+    if started and engine is not None:
+        from shadow1_tpu.telemetry import phases
+
+        try:
+            table = phases.attribute_capture(
+                log_dir, lambda: engine.hlo_text(st))
+        except Exception as e:  # the run is done: keep it and its capture
+            import warnings
+
+            warnings.warn(f"no phases.json: the join of the capture with "
+                          f"the compiled program failed ({e!r})")
+            table = None
+        if table is not None:
+            path = os.path.join(log_dir, "phases.json")
+            with open(path + ".tmp", "w") as f:
+                json.dump(table, f)
+            os.replace(path + ".tmp", path)

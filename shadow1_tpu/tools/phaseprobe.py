@@ -31,11 +31,9 @@ timings × measured rounds/window): ``rounds.pop_est`` (the pop chain — the
 rest of ``rounds`` is the handler passes) and ``deliver.route_est`` (the
 latency/loss routing — the rest of ``deliver`` is the destination scatter).
 
-``--device-trace DIR`` additionally captures a ``jax.profiler`` device
-trace of one straight chunk through telemetry/profiler.device_trace: the
-engine's ``jax.named_scope("phase:...")`` annotations make the window
-phases appear as spans in Perfetto (https://ui.perfetto.dev) next to the
-host-side phase spans (``DIR/phases.trace.json``).
+Device time by phase of a real run comes from the trace itself:
+``python -m shadow1_tpu cfg.yaml --profile DIR`` leaves ``DIR/phases.json``
+(telemetry/phases.py). This probe times the stages one by one instead.
 
 Prints one JSON line per phase plus a final summary line on stdout (the
 bench.py contract) and an aligned human table on stderr; ``--md`` emits
@@ -276,11 +274,6 @@ def main(argv=None) -> int:
     ap.add_argument("--min-coverage", type=float, default=0.0,
                     help="exit 1 when Σ phases / total falls below this "
                          "(ci.sh passes 0.9 — the acceptance bound)")
-    ap.add_argument("--device-trace", default=None, metavar="DIR",
-                    help="capture a jax.profiler device trace of one "
-                         "straight chunk (phases appear as named_scope "
-                         "spans in Perfetto); also writes "
-                         "DIR/phases.trace.json host spans")
     ap.add_argument("--md", action="store_true",
                     help="print the attribution table as markdown "
                          "(the docs/PERF.md format)")
@@ -295,18 +288,6 @@ def main(argv=None) -> int:
                       reps=args.reps)
     att = {"probe": "phaseprobe", "config": label,
            "backend": jax.default_backend(), **att}
-    if args.device_trace:
-        from shadow1_tpu.telemetry import PhaseProfiler, device_trace
-
-        prof = PhaseProfiler()
-        st0 = eng.run(eng.init_state(), n_windows=args.warmup)
-        jax.block_until_ready(st0)
-        with device_trace(args.device_trace, profiler=prof):
-            jax.block_until_ready(eng.run(st0, n_windows=args.windows))
-        import os
-
-        prof.write(os.path.join(args.device_trace, "phases.trace.json"))
-        att["device_trace"] = args.device_trace
     print(_table(label, att, md=args.md), file=sys.stderr, flush=True)
     print(json.dumps(att))
     if args.min_coverage and (att["coverage"] or 0) < args.min_coverage:
