@@ -6,7 +6,14 @@ write and 371 ms for a 131k-element batch scatter — the entire per-window
 cost of round 2's engine. Every hot-path "write one slot per host" in this
 package therefore goes through these helpers, which express the update as a
 one-hot mask + ``where`` (dense, fuses into one cheap elementwise kernel)
-instead of a scatter. Reads keep ``take_along_axis`` (gathers are fast).
+instead of a scatter.
+
+Reads of one slot per host go the same way: on the v5e XLA runs a
+``take_along_axis`` gather one element at a time, 12–13.5 ns an element,
+while ``extract_col(read_sel(col, C), arr)`` streams the plane once at HBM
+speed and wins while the slot axis is under ~2,500 tall (configs reach
+1,024). The rule: reads and writes of one slot per host are both one-hot
+passes; ``get_col`` is the gather that remains (PERF.md §6, PR 26).
 
 Layout contract (round-4 rewrite): the HOST axis is the LAST (minor/lane)
 axis of every per-host state tensor, the slot/capacity axis is second-to-
@@ -65,6 +72,14 @@ def add_col(arr, col, val, mask=None):
     return arr + jnp.where(sel, val, jnp.zeros((), arr.dtype))
 
 
+def read_sel(col, cap: int) -> jnp.ndarray:
+    """The read one-hot, bool [C, H]: True at (clip(col[h]), h).
+    ``extract_col(read_sel(col, C), arr)`` equals ``get_col(arr, col)`` bit
+    for bit without the gather; build it once per column vector and hand it
+    to ``extract_col`` for every plane read at that column."""
+    return onehot_col(jnp.clip(col, 0, cap - 1), cap)
+
+
 def get_col(arr, col):
     """Gather ``arr[..., col[h], h]`` → [*L, H] (col clipped into range)."""
     c = jnp.clip(col, 0, arr.shape[-2] - 1)
@@ -78,10 +93,12 @@ def extract_col(sel, arr):
 
     ``sel`` [C, H] must be at most one-hot per host (the pop-min and
     message-boundary invariants — see core/events.py); hosts with no True
-    read 0. Masked-sum reduction over the sublane axis, in the array's own
-    dtype (jnp.sum would promote i32 → i64 and silently break the u32
-    wrapping-arithmetic contract)."""
+    read 0 (False). Masked reduction over the sublane axis: ``any`` for
+    bool, else a sum in the array's own dtype (jnp.sum would promote
+    i32 → i64 and silently break the u32 wrapping-arithmetic contract)."""
     s = _expand(sel, arr.ndim)
+    if arr.dtype == jnp.bool_:
+        return (arr & s).any(axis=-2)
     return jnp.where(s, arr, 0).sum(axis=-2, dtype=arr.dtype)
 
 

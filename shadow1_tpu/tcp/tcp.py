@@ -73,6 +73,7 @@ from shadow1_tpu.core.dense import (
     get_col,
     last_true,
     onehot_col,
+    read_sel,
     set_col,
 )
 from shadow1_tpu.core.events import tb_join, tb_split
@@ -265,7 +266,8 @@ def _tcp_flush(st, ctx, mask, sock, now):
     allow; schedule K_TX_RESUME to continue if still pending.
 
     Bit-exact vectorization of the former per-segment loop (round-4 op-count
-    trim): socket state is gathered ONCE, the burst recurrence (sequence
+    trim): socket state is read ONCE (one-hot passes through one read_sel,
+    not gathers — core/dense.py), the burst recurrence (sequence
     advance, window/outbox budget, message-boundary truncation, NIC clock,
     RED coins) runs as cheap [H]-vector arithmetic per lane, and every heavy
     tensor write — the outbox append, the TCP field writes, the timer-event
@@ -279,14 +281,14 @@ def _tcp_flush(st, ctx, mask, sock, now):
     B = pr.send_burst
     H = ctx.n_hosts
     tcp = st.model.tcp
-    sock_safe = jnp.where(mask, sock, 0)
+    # One read one-hot [S, H] for all ~24 reads of this flush.
+    rsel = read_sel(jnp.where(mask, sock, 0), tcp["st"].shape[0])
 
     def g(f):
-        return get_col(tcp[f], sock_safe)
+        return extract_col(rsel, tcp[f])
 
     def g64(f):
-        return tb_join(get_col(tcp[f + "_hi"], sock_safe),
-                       get_col(tcp[f + "_lo"], sock_safe))
+        return tb_join(g(f + "_hi"), g(f + "_lo"))
 
     state = g("st")
     sendable = mask & _state_in(state, _SENDABLE)
@@ -448,7 +450,7 @@ def _tcp_flush(st, ctx, mask, sock, now):
     rthi, rtlo = tb_split(now64 + rto)
     d["rtx_t_hi"] = set_col(d["rtx_t_hi"], sock, rthi, mask & arm_any)
     d["rtx_t_lo"] = set_col(d["rtx_t_lo"], sock, rtlo, mask & arm_any)
-    timer_armed0 = get_col(tcp["timer_armed"], sock_safe)
+    timer_armed0 = g("timer_armed")
     need_ev = arm_any & ~timer_armed0
     d["timer_armed"] = set_col(d["timer_armed"], sock, True, mask & need_ev)
 
@@ -474,7 +476,7 @@ def _tcp_flush(st, ctx, mask, sock, now):
     pending = (nxt - total_end) < 0
     wnd_ok = (nxt - snd_una) < limit
     blocked_outbox = outbox_space(st.outbox) <= 0
-    txr0 = get_col(st.model.tcp["txr"], sock_safe)
+    txr0 = extract_col(rsel, st.model.tcp["txr"])
     more = sendable & pending & wnd_ok & (txr0 == 0)
     # Outbox-blocked sends resume at the next window start (after drain);
     # burst-limited sends resume immediately (same timestamp, next round).

@@ -1,0 +1,137 @@
+"""core/dense's one-hot read (``read_sel`` + ``extract_col``) against the
+gather it replaces.
+
+Values: the dense read must equal ``take_along_axis`` at the clipped column
+— and ``get_col``, which still is that gather — bit for bit, in every dtype
+the state planes use (bool, i32, u32 with the top bit set, i64), at every
+rank callers pass ([C,H], [L,C,H], [L1,L2,C,H]), for columns below 0 and at
+or above C, eagerly, under ``jit`` and under ``vmap`` (what the fleet engine
+compiles).
+
+Shape of the program: in the ``rounds`` phase of a TCP model, solo and under
+``vmap`` over two lanes, no ``gather`` equation comes from ``_tcp_flush``.
+On the v5e such a gather is an element-serial kCustom fusion, 12–13.5 ns an
+element (PERF.md §6, PR 26).
+"""
+
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from shadow1_tpu.core.dense import extract_col, get_col, read_sel
+
+C, H = 5, 7
+RANKS = {"CH": (), "LCH": (3,), "LLCH": (2, 3)}
+DTYPES = ("bool", "int32", "uint32", "int64")
+# Every host's column in range / some below 0 and some at or above C.
+COLS = {
+    "inrange": np.array([0, 4, 2, 1, 3, 0, 4], np.int32),
+    "clipped": np.array([-1, 5, 2, -7, 99, 0, 4], np.int32),
+}
+MODES = ("eager", "jit", "vmap")
+
+
+def _plane(dtype: str, lead: tuple) -> np.ndarray:
+    rng = np.random.default_rng(len(lead) * 10 + len(dtype))
+    shape = lead + (C, H)
+    if dtype == "bool":
+        return rng.integers(0, 2, shape).astype(bool)
+    if dtype == "uint32":  # values >= 2**31: the wrapping-sum contract
+        return rng.integers(2**31, 2**32, shape, dtype=np.uint64).astype(
+            np.uint32)
+    if dtype == "int64":   # beyond 32 bits, both signs
+        return rng.integers(-2**62, 2**62, shape, dtype=np.int64)
+    return rng.integers(-2**31, 2**31, shape, dtype=np.int64).astype(np.int32)
+
+
+def dense_read(arr, col):
+    return extract_col(read_sel(col, arr.shape[-2]), arr)
+
+
+def _gather_ref(arr: np.ndarray, col: np.ndarray) -> np.ndarray:
+    """The read as a gather: take_along_axis at the clipped column."""
+    c = np.clip(col, 0, arr.shape[-2] - 1)
+    idx = np.broadcast_to(c, arr.shape[:-2] + (1, c.shape[0]))
+    return np.take_along_axis(arr, idx, axis=-2).squeeze(-2)
+
+
+@pytest.mark.parametrize("mode", MODES)
+@pytest.mark.parametrize("cols", COLS)
+@pytest.mark.parametrize("rank", RANKS)
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_dense_read_equals_gather(dtype, rank, cols, mode):
+    arr = _plane(dtype, RANKS[rank])
+    col = COLS[cols]
+    if mode == "vmap":
+        # Two lanes with different planes and columns, as the fleet stacks
+        # its experiments.
+        arrs = np.stack([arr, arr[..., ::-1, :]])
+        colv = np.stack([col, col[::-1]])
+        got = jax.vmap(dense_read)(jnp.asarray(arrs), jnp.asarray(colv))
+        want = np.stack([_gather_ref(a, c) for a, c in zip(arrs, colv)])
+    else:
+        f = jax.jit(dense_read) if mode == "jit" else dense_read
+        got = f(jnp.asarray(arr), jnp.asarray(col))
+        want = _gather_ref(arr, col)
+        np.testing.assert_array_equal(
+            np.asarray(get_col(jnp.asarray(arr), jnp.asarray(col))), want)
+    assert got.dtype == arr.dtype
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_one_sel_serves_many_planes(dtype):
+    """_tcp_flush builds read_sel once and extracts every plane through it:
+    the same values as a get_col per plane, [S,H] and [MQ,S,H]."""
+    col = jnp.asarray(COLS["clipped"])
+    sel = read_sel(col, C)
+    for lead in RANKS.values():
+        arr = jnp.asarray(_plane(dtype, lead))
+        np.testing.assert_array_equal(np.asarray(extract_col(sel, arr)),
+                                      np.asarray(get_col(arr, col)))
+
+
+# ---------------------------------------------------------------------------
+# static guard: no gather from _tcp_flush in the TCP round
+# ---------------------------------------------------------------------------
+
+def _functions(eqn) -> set[str]:
+    tb = eqn.source_info.traceback
+    return set() if tb is None else {f.function_name for f in tb.frames}
+
+
+@pytest.fixture(scope="module")
+def tcp_rounds():
+    """(rounds-phase fn, its frame) for rung1_filexfer — the jaxpr
+    tools/opcensus.py traces."""
+    from shadow1_tpu.core.engine import window_frame, window_phases
+    from shadow1_tpu.tools.phaseprobe import build_engine
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    eng, _ = build_engine(os.path.join(root, "configs", "rung1_filexfer.yaml"))
+    phases = dict(window_phases(eng.ctx, eng._handlers, None, eng._pre_window,
+                                eng._model.make_handlers, None))
+    return phases["rounds"], window_frame(eng.init_state(), eng.ctx)
+
+
+@pytest.mark.parametrize("lanes", [0, 2], ids=["solo", "vmap2"])
+def test_tcp_flush_has_no_gather(tcp_rounds, lanes):
+    from shadow1_tpu.tools.opcensus import iter_eqns
+
+    fn, fr = tcp_rounds
+    if lanes:
+        fn = jax.vmap(fn)
+        fr = jax.tree_util.tree_map(lambda x: jnp.stack([x] * lanes), fr)
+    flush = [(e.primitive.name, fns)
+             for e in iter_eqns(jax.make_jaxpr(fn)(fr).jaxpr)
+             if "_tcp_flush" in (fns := _functions(e))]
+    # The guard can see the flush: its reads are there, as one-hot reduces.
+    assert len(flush) > 1000
+    assert any("extract_col" in fns for _, fns in flush)
+    n_gather = sum(prim == "gather" for prim, _ in flush)
+    assert n_gather == 0, f"{n_gather} gather eqns traced from _tcp_flush"
