@@ -20,10 +20,19 @@ events, and the traced round body instantiates the TCP send path once
 instead of K times (the SIMD analogue of the reference queueing work items
 rather than deep call chains).
 
+Phase scopes (under the engine's ``phase:h_app`` / ``phase:h_deliver``, read
+by telemetry/phases.py): ``phase:btc_dial`` (dialing a neighbor, binding an
+accepted conn), ``phase:btc_create`` (a tx's origin), ``phase:btc_msg`` (the
+admission check and ``tcp_send`` of OP_TX_MSG), ``phase:btc_notify`` (a
+received INV / GETDATA / TX and the announcements it queues).
+
 model_cfg:
   peers      i32 [H, K] neighbor ids, -1 = unused slot (edges must be
              symmetric: n in peers[h] ⇔ h in peers[n])
-  tx_origin  i32 [T] origin host per transaction
+  tx_origin  i32 [T] origin host per transaction — the one key the
+             config generator draws from the seed (apps.LANE_TABLES): a
+             traced per-lane table under the fleet engine, so it is only
+             ever compared with ``ctx.hosts``, never read as a Python int
   tx_time    i64 [T] creation time per transaction (leave ≥ a few RTT after
              connect_time so the conn mesh is up)
   tx_size    int, payload bytes of a transaction (default 400)
@@ -68,9 +77,9 @@ def _meta(cmd, txid):
 def init(ctx, evbuf, tcpd):
     cfg = ctx.model_cfg
     peers = jnp.asarray(cfg["peers"], jnp.int32).T        # [K, H] host-minor
-    tx_origin = np.asarray(cfg["tx_origin"], np.int64)    # [T] (host-side)
-    tx_time = np.asarray(cfg["tx_time"], np.int64)
-    n_tx = len(tx_origin)
+    tx_origin = jnp.asarray(cfg["tx_origin"], jnp.int32)  # [T], maybe traced
+    tx_time = np.asarray(cfg["tx_time"], np.int64)        # [T] (host-side)
+    n_tx = tx_origin.shape[0]
     assert n_tx <= TXID_MASK
     k_max, h = peers.shape
     app = {
@@ -98,7 +107,7 @@ def init(ctx, evbuf, tcpd):
         n_over = n_over + over.sum(dtype=jnp.int64)
     # Seed tx-creation wakeups, one masked push per transaction.
     for t in range(n_tx):
-        mask = ctx.hosts == int(tx_origin[t])
+        mask = ctx.hosts == tx_origin[t]
         p = jnp.zeros((NP, ctx.n_hosts), jnp.int32)
         p = p.at[0].set(OP_TX_CREATE).at[1].set(t)
         evbuf, over = push_local(
@@ -158,7 +167,8 @@ def on_wakeup(st, ctx, ev, mask):
         st = st._replace(model=st.model._replace(app=napp))
         return T.tcp_connect(st, ctx, conn, sock, peer, zero, ev.time)
 
-    st = jax.lax.cond(conn.any(), _op_conn, lambda s: s, st)
+    with jax.named_scope("phase:btc_dial"):
+        st = jax.lax.cond(conn.any(), _op_conn, lambda s: s, st)
 
     # OP_TX_CREATE: origin marks the tx seen and queues the announcements
     # (a few hundred per run — cond-gated).
@@ -172,31 +182,34 @@ def on_wakeup(st, ctx, ev, mask):
         none = jnp.full(ctx.n_hosts, -1, jnp.int32)
         return _announce(st, ctx, new, txid, none, ev.time)
 
-    st = jax.lax.cond(create.any(), _op_create, lambda s: s, st)
+    with jax.named_scope("phase:btc_create"):
+        st = jax.lax.cond(create.any(), _op_create, lambda s: s, st)
 
     # OP_TX_MSG: the single transport-send site. Admission: the message must
     # fit the send buffer and a boundary slot must be free, else retry at the
     # next window start — a congested conn defers gossip instead of losing
     # its framing (same shape as tor.py's OP_TX_CELL).
-    tx = mask & (op == OP_TX_MSG)
-    sock, meta, nbytes = ev.p[1], ev.p[2], ev.p[3]
-    tcp = st.model.tcp
-    sk = jnp.where(tx, sock, 0)
-    snd_una = get_col(tcp["snd_una"], sk)
-    app_end = get_col(tcp["app_end"], sk)
-    buffered = (app_end - snd_una) - (snd_una == 0).astype(jnp.int32)
-    fits = (ctx.params.sndbuf - buffered) >= nbytes
-    mq_ok = ~get_col(tcp["mq_valid"], sk).all(axis=0)
-    can = tx & fits & mq_ok
-    retry = tx & ~can
-    st, _acc = T.tcp_send(st, ctx, can, sock, nbytes, meta, ev.time)
-    napp = dict(st.model.app)
-    napp["msg_retries"] = napp["msg_retries"] + retry.astype(jnp.int64)
-    st = st._replace(model=st.model._replace(app=napp))
-    t_retry = (ev.time // ctx.window + 1) * ctx.window
-    return push_local_event(
-        st, ctx, retry, t_retry, K_APP, p0=OP_TX_MSG, p1=sock, p2=meta, p3=nbytes
-    )
+    with jax.named_scope("phase:btc_msg"):
+        tx = mask & (op == OP_TX_MSG)
+        sock, meta, nbytes = ev.p[1], ev.p[2], ev.p[3]
+        tcp = st.model.tcp
+        sk = jnp.where(tx, sock, 0)
+        snd_una = get_col(tcp["snd_una"], sk)
+        app_end = get_col(tcp["app_end"], sk)
+        buffered = (app_end - snd_una) - (snd_una == 0).astype(jnp.int32)
+        fits = (ctx.params.sndbuf - buffered) >= nbytes
+        mq_ok = ~get_col(tcp["mq_valid"], sk).all(axis=0)
+        can = tx & fits & mq_ok
+        retry = tx & ~can
+        st, _acc = T.tcp_send(st, ctx, can, sock, nbytes, meta, ev.time)
+        napp = dict(st.model.app)
+        napp["msg_retries"] = napp["msg_retries"] + retry.astype(jnp.int64)
+        st = st._replace(model=st.model._replace(app=napp))
+        t_retry = (ev.time // ctx.window + 1) * ctx.window
+        return push_local_event(
+            st, ctx, retry, t_retry, K_APP,
+            p0=OP_TX_MSG, p1=sock, p2=meta, p3=nbytes
+        )
 
 
 def on_notify(st, ctx, nf: T.Notif, now, mask):
@@ -219,10 +232,16 @@ def on_notify(st, ctx, nf: T.Notif, now, mask):
             )
         return st._replace(model=st.model._replace(app=app))
 
-    st = jax.lax.cond(acc.any(), _accepted, lambda s: s, st)
+    with jax.named_scope("phase:btc_dial"):
+        st = jax.lax.cond(acc.any(), _accepted, lambda s: s, st)
+    with jax.named_scope("phase:btc_notify"):
+        return _on_msg(st, ctx, nf, now, mask, tx_size, inv_size)
 
-    # Protocol messages (one boundary per host-round at most).
-    msg = mask & ((f & N_MSG) != 0)
+
+def _on_msg(st, ctx, nf, now, mask, tx_size, inv_size):
+    """Protocol messages (one boundary per host-round at most)."""
+    sock = nf.sock
+    msg = mask & ((nf.flags & N_MSG) != 0)
     cmd = nf.meta >> TXID_BITS
     txid = nf.meta & TXID_MASK
     app = st.model.app
@@ -263,6 +282,9 @@ def summary(app) -> dict:
         "tx_rx": app["tx_rx"],
         "reach": seen.sum(axis=1),            # nodes reached per tx
         "msg_retries": app["msg_retries"],
+        # Run totals (0-dim: they ride heartbeat rows' ``model`` block,
+        # telemetry/registry.MODEL_TOTALS).
         "total_seen": seen.sum(),
         "total_tx_rx": app["tx_rx"].sum(),
+        "total_msg_retries": app["msg_retries"].sum(),
     }

@@ -267,7 +267,12 @@ def _gen_bitcoin_cfg(model_cfg: dict, h: int, seed: int) -> None:
         count = int(tspec.get("count", 50))
         start = parse_time_ns(tspec.get("start", "1 s"))
         interval = parse_time_ns(tspec.get("interval", "200 ms"))
-        rs = np.random.RandomState(seed ^ 0xB17C01)  # config-gen only
+        # Config-gen only. A seed under 2**32 seeds the generator as it
+        # always has; a wider one (a study's pool, the benchmark's seeds)
+        # goes in whole as two 32-bit words, so no two seeds share a stream.
+        s = int(seed) ^ 0xB17C01
+        rs = np.random.RandomState(
+            s if s < 1 << 32 else [s & 0xFFFFFFFF, s >> 32])
         model_cfg["tx_origin"] = rs.randint(0, h, count).astype(np.int64)
         model_cfg["tx_time"] = (start + np.arange(count) * interval).astype(np.int64)
 

@@ -41,7 +41,8 @@ from shadow1_tpu.core.events import I32_FREE
 # Ctx fields indexed by LOCAL host lane (everything else — vertex tables,
 # host_vertex (global-id-indexed), scalars, static flags — stays as is).
 _CTX_HOST_FIELDS = (
-    "hosts", "bw_up", "bw_dn", "fault_down", "fault_up", "cpu_cost",
+    "hosts", "bw_up", "bw_dn", "ser_up", "ser_dn", "fault_down", "fault_up",
+    "cpu_cost",
     "tx_qlen_ns", "rx_qlen_ns", "aqm_min_ns", "aqm_span_ns", "aqm_pmax_thr",
 )
 

@@ -192,6 +192,11 @@ class Ctx:
     loss_ramp: Any = None          # (src, dst, t0, t1, thr) [R] or None
     init_model: Any = None         # post-init model pytree (restart target)
     cpu_cost: jax.Array = None     # i64 [H] virtual CPU ns per event
+    # ns per wire byte, 8e9 // bw, where EVERY link's bits/s divides 8e9
+    # (then serialization is bytes × this, exactly, and no 64-bit division
+    # is traced: net/nic.ser_delay); None as soon as one link's does not.
+    ser_up: Any = None             # i64 [H] (numpy until traced)
+    ser_dn: Any = None             # i64 [H]
     tx_qlen_ns: jax.Array = None   # i64 [H] uplink queue bound (ns of backlog)
     rx_qlen_ns: jax.Array = None   # i64 [H]
     aqm_min_ns: jax.Array = None   # i64 [H] RED min threshold (backlog ns)
@@ -801,6 +806,18 @@ def aqm_tables_np(exp) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     return min_ns.astype(np.int64), span_ns.astype(np.int64), pmax_thr
 
 
+def ser_tables_np(exp):
+    """(ns per wire byte up, down), i64 [H] each, when 8e9 is a whole
+    multiple of every host's uplink AND downlink bits/s; else (None, None)
+    and serialization keeps its per-packet division (net/nic.ser_delay)."""
+    from shadow1_tpu.consts import SEC
+
+    bw = [np.asarray(b, np.int64) for b in (exp.bw_up, exp.bw_dn)]
+    if any(((8 * SEC) % b).any() for b in bw):
+        return None, None
+    return tuple((8 * SEC) // b for b in bw)
+
+
 def fidelity_ctx_kwargs(exp) -> dict:
     """The Ctx fidelity fields + static has_* flags from a CompiledExperiment
     (shared by Engine and ShardedEngine; everything numpy → device const).
@@ -815,6 +832,7 @@ def fidelity_ctx_kwargs(exp) -> dict:
     )
 
     aqm_min_ns, aqm_span_ns, aqm_pmax_thr = aqm_tables_np(exp)
+    ser_up, ser_dn = ser_tables_np(exp)
     fault_down, fault_up = host_interval_tensors(exp)
     lf = link_tables(exp)
     rt = ramp_tables(exp)
@@ -827,6 +845,10 @@ def fidelity_ctx_kwargs(exp) -> dict:
         loss_ramp=(tuple(jnp.asarray(a) for a in rt)
                    if rt is not None else None),
         cpu_cost=jnp.asarray(exp.cpu_ns_per_event, jnp.int64),
+        # Host-side (numpy): a model without a NIC never touches them, and
+        # then they take no device memory (phold65k: 2 x 512 KiB).
+        ser_up=ser_up,
+        ser_dn=ser_dn,
         tx_qlen_ns=jnp.asarray(qlen_ns_np(exp.tx_qlen_bytes, exp.bw_up)),
         rx_qlen_ns=jnp.asarray(qlen_ns_np(exp.rx_qlen_bytes, exp.bw_dn)),
         aqm_min_ns=jnp.asarray(aqm_min_ns),
@@ -1030,3 +1052,10 @@ class Engine:
 
     def model_summary(self, st: SimState) -> dict[str, Any]:
         return jax.tree.map(np.asarray, self._model.summary(st.model, self.ctx))
+
+    def model_totals(self, st: SimState) -> dict[str, int]:
+        """The model summary's run totals (its 0-dim entries), fetched
+        without its per-host tables: a heartbeat row's ``model`` block."""
+        return {k: int(v) for k, v in
+                self._model.summary(st.model, self.ctx).items()
+                if jnp.ndim(v) == 0}

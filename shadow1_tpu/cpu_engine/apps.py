@@ -248,6 +248,7 @@ class CpuBitcoin:
             "msg_retries": self.msg_retries,
             "total_seen": int(self.seen.sum()),
             "total_tx_rx": int(self.tx_rx.sum()),
+            "total_msg_retries": int(self.msg_retries.sum()),
         }
 
 

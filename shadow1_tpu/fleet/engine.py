@@ -11,9 +11,9 @@ exact single-device ``window_step``:
 * every ``SimState`` leaf grows a leading ``[E, ...]`` axis (event
   buffers ``[E, C, H]``, metrics ``[E]``, telemetry rings ``[E, W, F]``);
 * the per-experiment *variants* — RNG key, loss thresholds, fault tables,
-  ``max_rounds`` — ride a batched pytree zipped with the state, so lane e
-  executes with exactly the constants a solo run of experiment e would
-  close over;
+  ``max_rounds``, the app's seed-drawn ``model_cfg`` tables — ride a
+  batched pytree zipped with the state, so lane e executes with exactly
+  the constants a solo run of experiment e would close over;
 * everything trace-structural is shared: one compiled program, one launch
   per chunk, per-window cost = the max lane's round count.
 
@@ -64,7 +64,12 @@ from shadow1_tpu.core.engine import (
 )
 from shadow1_tpu.core.events import evbuf_init
 from shadow1_tpu.core.outbox import outbox_init
-from shadow1_tpu.fleet.expand import FleetConfigError, check_uniform
+from shadow1_tpu.fleet.expand import (
+    FleetConfigError,
+    check_uniform,
+    lane_table_keys,
+    same_shape_class,
+)
 
 
 def slice_experiment(st: SimState, e: int) -> SimState:
@@ -370,6 +375,11 @@ class FleetEngine:
             variants["loss_ramp"] = tuple(jnp.asarray(a) for a in rt)
         if len(set(self.max_rounds)) > 1:
             variants["max_rounds"] = jnp.asarray(self.max_rounds, jnp.int32)
+        tables = lane_table_keys(self.exp)
+        if tables:
+            variants["model_tables"] = {
+                k: jnp.stack([jnp.asarray(e.model_cfg[k]) for e in exps])
+                for k in tables}
         has = {
             "stop": bool(down.min() < NO_STOP),
             "restart": bool((up < NO_STOP).any()),
@@ -387,6 +397,8 @@ class FleetEngine:
         return dataclasses.replace(
             self._base_ctx,
             params=params,
+            model_cfg={**self._base_ctx.model_cfg,
+                       **var.get("model_tables", {})},
             key=var["key"],
             loss_thr_vv=var["loss_thr_vv"],
             fault_down=var["fault_down"],
@@ -491,7 +503,8 @@ class FleetEngine:
         already-compiled engine — no re-jit, no re-trace.
 
         The new set may differ in exactly the fleet-variable knobs (seed,
-        loss, fault schedules, legacy stop_time, per-lane max_rounds):
+        loss, fault schedules, legacy stop_time, per-lane max_rounds, the
+        app's lane tables — ``expand.shape_class`` is the rule):
         those ride the variant pytree, which ``run`` takes as a traced
         argument. Everything the base ctx closes over (topology, window,
         caps, model config) must be identical — the serve-plane engine
@@ -509,21 +522,17 @@ class FleetEngine:
             exp.validate()
         check_uniform(exps, [self.params] * len(exps))
         # The compiled program closed over the OLD exps' shared constants
-        # (topology tables, horizon, model config): every field outside
-        # the fleet-variable set must compare EQUAL to the compiled one,
-        # not just within the new set — the serve cache's fingerprint
-        # guarantees this, but a direct caller gets the same wall.
-        from shadow1_tpu.fleet.expand import _VARIABLE_EXP, _np_equal
-
-        for f in (fld.name for fld in dataclasses.fields(type(self.exp))):
-            if f in _VARIABLE_EXP:
-                continue
-            if not _np_equal(getattr(self.exp, f), getattr(exps[0], f)):
-                raise FleetConfigError(
-                    f"rebind: {f!r} differs from the compiled engine's — "
-                    f"it is closed over as a device constant (or picks "
-                    f"shapes); a different shape class needs a fresh "
-                    f"engine", kind="shape", knob=f)
+        # (topology tables, horizon, model config): the new set must be of
+        # the compiled set's shape class, not just uniform within itself —
+        # the serve cache's fingerprint guarantees this, but a direct
+        # caller gets the same wall.
+        f = same_shape_class(self.exp, exps[0])
+        if f is not None:
+            raise FleetConfigError(
+                f"rebind: {f!r} differs from the compiled engine's — "
+                f"it is closed over as a device constant (or picks "
+                f"shapes); a different shape class needs a fresh "
+                f"engine", kind="shape", knob=f)
         new_mr = [int(m) for m in
                   (max_rounds or [self.params.max_rounds] * self.n_exp)]
         if len(set(new_mr)) == 1 and new_mr[0] != self.params.max_rounds:
@@ -620,6 +629,16 @@ class FleetEngine:
 
         kind = np.asarray(st.evbuf.kind)          # [E, C, H]
         return ~(kind != K_NONE).any(axis=(-2, -1))
+
+    def model_totals(self, st: SimState) -> list[dict[str, int]]:
+        """Per lane, the model summary's run totals (its 0-dim entries),
+        fetched without the per-host tables or a lane slice: the
+        heartbeat's ``fleet.model_per_exp``."""
+        summ = jax.vmap(lambda m: self._model.summary(m, self._base_ctx))(
+            st.model)
+        tot = {k: np.asarray(v) for k, v in summ.items() if v.ndim == 1}
+        return [{k: int(v[e]) for k, v in tot.items()}
+                for e in range(self.n_exp)]
 
     def model_summary(self, st: SimState, e: int) -> dict[str, Any]:
         lane = slice_experiment(st, e)

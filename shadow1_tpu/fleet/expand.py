@@ -11,8 +11,10 @@ and validates the *fleet contract* (docs/SEMANTICS.md §"Fleet contract"):
   streams), path loss probabilities (``network.single_vertex.loss`` /
   anything that changes only ``loss_vv``), the whole ``faults:`` section
   (host churn / link outages / loss ramps — tables pad to a common shape
-  with inert entries), the legacy per-group ``stop_time`` churn, and
-  ``engine.max_rounds`` (a traced scalar in the round loop);
+  with inert entries), the legacy per-group ``stop_time`` churn,
+  ``engine.max_rounds`` (a traced scalar in the round loop), and the
+  app's seed-drawn lane tables (``apps.LANE_TABLES``: bitcoin's
+  ``tx_origin``) — one rule, ``shape_class`` below;
 * **must be shape-uniform** — host count, topology latencies (the
   conservative window derives from them), ``stop_time`` horizon
   (``general.stop_time``), every capacity knob and every other
@@ -49,6 +51,7 @@ import dataclasses
 
 import numpy as np
 
+from shadow1_tpu.apps import LANE_TABLES
 from shadow1_tpu.config.experiment import _reject_unknown, build_experiment
 from shadow1_tpu.consts import EngineParams
 
@@ -220,6 +223,34 @@ _VARIABLE_EXP = ("seed", "loss_vv", "faults", "stop_time", "dns")
 _SHAPE_EXP = ("n_hosts", "lat_vv", "jitter_vv", "host_vertex", "end_time")
 
 
+def lane_table_keys(exp) -> tuple[str, ...]:
+    """The ``model_cfg`` keys of ``exp``'s app that ride per lane."""
+    return LANE_TABLES.get(exp.model_cfg.get("app"), ())
+
+
+def shape_class(exp) -> dict:
+    """THE fleet-variable rule, stated once: everything of a compiled
+    experiment that one compiled fleet program closes over or takes its
+    shapes from, by field, shape fields first. Two experiments may share a
+    fleet (``check_uniform``), a compiled engine (``FleetEngine.rebind``)
+    or an engine-cache entry (``serve/cache.shape_class_key``) iff their
+    shape classes compare equal. Left out: the ``_VARIABLE_EXP`` fields,
+    and the VALUES of the app's lane tables (``apps.LANE_TABLES``) — of
+    those only shape and dtype stay, every other ``model_cfg`` key whole."""
+    tables = lane_table_keys(exp)
+    out = {}
+    names = [f.name for f in dataclasses.fields(type(exp))]
+    for f in (*_SHAPE_EXP, *(n for n in names if n not in _SHAPE_EXP)):
+        if f in _VARIABLE_EXP:
+            continue
+        v = getattr(exp, f)
+        if f == "model_cfg" and tables:
+            v = {k: (("lane table", np.shape(x), str(np.asarray(x).dtype))
+                     if k in tables else x) for k, x in v.items()}
+        out[f] = v
+    return out
+
+
 def _np_equal(a, b) -> bool:
     if isinstance(a, np.ndarray) or isinstance(b, np.ndarray):
         return (np.asarray(a).shape == np.asarray(b).shape
@@ -227,6 +258,13 @@ def _np_equal(a, b) -> bool:
     if isinstance(a, dict) and isinstance(b, dict):
         return set(a) == set(b) and all(_np_equal(a[k], b[k]) for k in a)
     return a == b
+
+
+def same_shape_class(a, b) -> str | None:
+    """None when ``a`` and ``b`` share a shape class, else the first field
+    of ``shape_class`` in which they differ."""
+    ca, cb = shape_class(a), shape_class(b)
+    return next((f for f in ca if not _np_equal(ca[f], cb[f])), None)
 
 
 def check_uniform(exps: list, params_list: list[EngineParams],
@@ -243,23 +281,22 @@ def check_uniform(exps: list, params_list: list[EngineParams],
             f"the whole fleet runs one engine", kind="shape",
             knob="scheduler")
     for i, exp in enumerate(exps[1:], start=1):
-        for f in _SHAPE_EXP:
-            if not _np_equal(getattr(base, f), getattr(exp, f)):
-                raise FleetConfigError(
-                    f"sweep experiment {i} changes {f!r} — that changes "
-                    f"plane shapes (or the conservative window) mid-fleet; "
-                    f"fleet experiments must share one topology shape "
-                    f"class (docs/SEMANTICS.md §'Fleet contract')",
-                    kind="shape", knob=f)
-        for f in (fld.name for fld in dataclasses.fields(type(base))):
-            if f in _VARIABLE_EXP or f in _SHAPE_EXP:
-                continue
-            if not _np_equal(getattr(base, f), getattr(exp, f)):
-                raise FleetConfigError(
-                    f"sweep experiment {i} varies {f!r}, which is outside "
-                    f"the fleet-variable set (seed / loss / faults / "
-                    f"stop_time / engine.max_rounds)", kind="uniform",
-                    knob=f)
+        f = same_shape_class(base, exp)
+        if f in _SHAPE_EXP:
+            raise FleetConfigError(
+                f"sweep experiment {i} changes {f!r} — that changes "
+                f"plane shapes (or the conservative window) mid-fleet; "
+                f"fleet experiments must share one topology shape "
+                f"class (docs/SEMANTICS.md §'Fleet contract')",
+                kind="shape", knob=f)
+        if f is not None:
+            tables = ", ".join(lane_table_keys(base))
+            raise FleetConfigError(
+                f"sweep experiment {i} varies {f!r}, which is outside "
+                f"the fleet-variable set (seed / loss / faults / "
+                f"stop_time / engine.max_rounds"
+                + (f" / model_cfg {tables}" if tables else "") + ")",
+                kind="uniform", knob=f)
     p0 = params_list[0]
     for i, p in enumerate(params_list[1:], start=1):
         for f in (fld.name for fld in dataclasses.fields(EngineParams)):

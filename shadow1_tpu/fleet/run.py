@@ -143,6 +143,8 @@ class FleetHeartbeat:
                 "exps": [l.get("exp") for l in self.labels]
                 if self.labels else list(range(self.engine.n_exp)),
                 "events_per_exp": ev_per_exp,
+                # Each lane's model run totals (registry.MODEL_TOTALS).
+                "model_per_exp": self.engine.model_totals(st),
             },
         }
         drops = {f: delta.pop(f, 0) for f in DROP_FIELDS}
@@ -195,13 +197,15 @@ def _check_halt(engine, plan_labels, per_exp, prev_per_exp, done, step):
 
 
 def lane_record(engine, st, i: int, label: dict, windows: int,
-                m: dict | None = None) -> dict:
+                m: dict | None = None, model: dict | None = None) -> dict:
     """One ``fleet_exp`` final record for lane ``i`` of a fleet state —
     the unit final_records() assembles and the early-finalize path emits
-    immediately (docs/OBSERVABILITY.md §"Fleet records"). ``m`` reuses an
-    already-fetched per-experiment metrics dict."""
+    immediately (docs/OBSERVABILITY.md §"Fleet records"). ``m`` / ``model``
+    reuse an already-fetched per-experiment metrics / model-totals dict."""
     if m is None:
         m = engine.metrics_per_exp(st)[i]
+    if model is None:
+        model = engine.model_totals(st)[i]
     params = engine.params
     drops = {f: int(m.get(f, 0)) for f in DROP_FIELDS}
     rec = {
@@ -214,6 +218,7 @@ def lane_record(engine, st, i: int, label: dict, windows: int,
         "caps": {"ev_cap": params.ev_cap, "outbox_cap": params.outbox_cap,
                  "compact_cap": params.compact_cap},
         "metrics": m,
+        "model": model,
         "drops": {"total": sum(drops.values()), **drops},
     }
     restarts = int(m.get("host_restarts", 0))
@@ -664,6 +669,7 @@ def final_records(engine, st, labels, n_windows, wall, resumed=False,
     early-finished lanes into the summary — their own records were
     emitted when they left the fleet."""
     per_exp = engine.metrics_per_exp(st) if engine.n_exp else []
+    totals = engine.model_totals(st) if engine.n_exp else []
     sim_s = n_windows * engine.window / 1e9
     recs = []
     ev_run_total = 0
@@ -671,7 +677,8 @@ def final_records(engine, st, labels, n_windows, wall, resumed=False,
         label = labels[e] if labels else {"exp": e}
         ev0 = metrics0[e].get("events", 0) if metrics0 else 0
         ev_run_total += m["events"] - ev0
-        recs.append(lane_record(engine, st, e, label, n_windows, m=m))
+        recs.append(lane_record(engine, st, e, label, n_windows, m=m,
+                                model=totals[e]))
     agg = engine.metrics_dict(st) if engine.n_exp else {}
     params = engine.params
     summary = {

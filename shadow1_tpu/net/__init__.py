@@ -27,13 +27,19 @@ from shadow1_tpu.consts import (
     K_TCP_TIMER,
     K_TX_RESUME,
     N_DGRAM,
-    SEC,
     WIRE_OVERHEAD,
 )
 from shadow1_tpu.core.dense import payload
 from shadow1_tpu.core.events import I64_MAX, push_local, tb_split
 from shadow1_tpu.core.outbox import outbox_append
-from shadow1_tpu.net.nic import NicState, ctx_aqm, nic_init, rx_stamp, tx_stamp
+from shadow1_tpu.net.nic import (
+    NicState,
+    ctx_aqm,
+    nic_init,
+    rx_stamp,
+    ser_delay,
+    tx_stamp,
+)
 from shadow1_tpu.tcp import tcp as T
 
 
@@ -91,7 +97,7 @@ def udp_send(st, ctx, mask, dst_host, dst_sock, length, meta, meta2, now):
     nic, depart, sent, red = tx_stamp(
         st.model.nic, mask, wire, now, ctx.bw_up,
         ctx.tx_qlen_ns if ctx.has_tx_qlen else None,
-        aqm=ctx_aqm(ctx),
+        aqm=ctx_aqm(ctx), ser=ctx.ser_up,
     )
     k = jnp.full(ctx.n_hosts, K_PKT, jnp.int32)
     outbox, ok = outbox_append(st.outbox, sent, dst_host, k, depart, p)
@@ -177,8 +183,8 @@ def make_pre_window(ctx):
         valid = t_s < I64_MAX
         plen = jnp.take_along_axis(buf.p[4], idx_s, axis=0)
         wire = jnp.where(valid, plen.astype(jnp.int64) + WIRE_OVERHEAD, 0)
-        bw = ctx.bw_dn[None, :]
-        ser = jnp.where(valid, (wire * (8 * SEC) + bw - 1) // bw, 0)
+        ser = jnp.where(
+            valid, ser_delay(wire, ctx.bw_dn[None, :], ctx.ser_dn), 0)
         # Max-plus prefix: each packet is the affine map x ↦ max(x+p, q)
         # with p = ser, q = arr + ser; invalid slots are the identity.
         pq = (ser, jnp.where(valid, t_s + ser, neg))
@@ -224,7 +230,7 @@ def make_handlers(ctx):
         wire = jnp.asarray(ev.p[4], jnp.int64) + WIRE_OVERHEAD
         nic, ready, okq = rx_stamp(
             st.model.nic, m, wire, ev.time, ctx.bw_dn,
-            ctx.rx_qlen_ns if ctx.has_rx_qlen else None,
+            ctx.rx_qlen_ns if ctx.has_rx_qlen else None, ser=ctx.ser_dn,
         )
         st = st._replace(model=st.model._replace(nic=nic))
         k = jnp.full(ctx.n_hosts, K_PKT_DELIVER, jnp.int32)

@@ -44,14 +44,22 @@ def ctx_aqm(ctx):
 _RED_CERTAIN = np.uint64(1) << np.uint64(32)  # threshold meaning "always"
 
 
-def ser_delay(wire_bytes, bw_bits):
-    """ceil(8e9 · bytes / bw) ns — identical integer math in both engines."""
+def ser_delay(wire_bytes, bw_bits, ns_per_byte=None):
+    """ceil(8e9 · bytes / bw) ns — identical integer math in both engines.
+
+    ``ns_per_byte`` (``Ctx.ser_up`` / ``ser_dn``) is ``8e9 // bw`` where
+    every link's bits/s divides 8e9, else None. Then the ceiling is exactly
+    ``bytes × ns_per_byte`` and no division is traced: the TPU has no 64-bit
+    divider, each ``//`` by a per-host rate is ~2,000 emulated instructions,
+    and they were 57 % of the TCP window program (PERF.md §6, PR 28)."""
     w = jnp.asarray(wire_bytes, jnp.int64)
+    if ns_per_byte is not None:
+        return w * ns_per_byte
     return (w * (8 * SEC) + bw_bits - 1) // bw_bits
 
 
 def tx_stamp(nic: NicState, mask, wire_bytes, now, bw_up, qlen_ns=None,
-             aqm=None):
+             aqm=None, ser=None):
     """Reserve the uplink: returns (nic', depart_time[H], ok[H], red[H]).
 
     Two drop gates, in order (both off by default):
@@ -86,7 +94,7 @@ def tx_stamp(nic: NicState, mask, wire_bytes, now, bw_up, qlen_ns=None,
     if qlen_ns is not None:
         mask = mask & ((nic.tx_free - jnp.asarray(now, jnp.int64)) <= qlen_ns)
     depart = jnp.maximum(now, nic.tx_free)
-    busy = depart + ser_delay(wire_bytes, bw_up)
+    busy = depart + ser_delay(wire_bytes, bw_up, ser)
     w = jnp.asarray(wire_bytes, jnp.int64)
     return (
         nic._replace(
@@ -99,13 +107,14 @@ def tx_stamp(nic: NicState, mask, wire_bytes, now, bw_up, qlen_ns=None,
     )
 
 
-def rx_stamp(nic: NicState, mask, wire_bytes, now, bw_dn, qlen_ns=None):
+def rx_stamp(nic: NicState, mask, wire_bytes, now, bw_dn, qlen_ns=None,
+             ser=None):
     """Reserve the downlink: returns (nic', ready_time[H], ok[H]) — the time
     the packet clears the receive queue; drop-tail like tx_stamp."""
     if qlen_ns is not None:
         mask = mask & ((nic.rx_free - jnp.asarray(now, jnp.int64)) <= qlen_ns)
     ready = jnp.maximum(now, nic.rx_free)
-    busy = ready + ser_delay(wire_bytes, bw_dn)
+    busy = ready + ser_delay(wire_bytes, bw_dn, ser)
     w = jnp.asarray(wire_bytes, jnp.int64)
     return (
         nic._replace(

@@ -112,6 +112,11 @@ class Heartbeat:
             if d_windows else None,
             "delta": delta,
         }
+        # The model's run totals (bitcoin: total_seen / total_tx_rx /
+        # total_msg_retries; registry.MODEL_TOTALS), absolutes like ``fill``.
+        totals = getattr(self.engine, "model_totals", None)
+        if totals is not None:
+            rec["model"] = totals(st)
         # Drop accounting: the nine ways an event/packet can be discarded,
         # grouped under one structured block (with chunk deltas) instead of
         # scattered through ``delta`` — the shape heartbeat_report's

@@ -5,11 +5,12 @@ compile once vs 25.3 s paid per-seed across 16 sequential solos). A
 long-lived daemon amortizes it by keeping compiled ``FleetEngine``
 programs hot, keyed by everything that affects the TRACE:
 
-* the **shape class** of the experiment — every ``CompiledExperiment``
-  field outside the fleet-variable set (host count, topology latency /
-  jitter / bandwidth tables, model + model_cfg, fidelity knobs, horizon):
-  exactly the fields ``fleet.expand.check_uniform`` pins, because they
-  either pick tensor shapes or are closed over as device constants;
+* the **shape class** of the experiment — ``fleet.expand.shape_class``:
+  every ``CompiledExperiment`` field outside the fleet-variable set (host
+  count, topology latency / jitter / bandwidth tables, model + model_cfg
+  less the values of the app's lane tables, fidelity knobs, horizon):
+  what ``check_uniform`` pins, because it either picks tensor shapes or
+  is closed over as a device constant;
 * the **EngineParams** (caps, ring width, policies, kernel impls) — a
   frozen dataclass, hashable as-is;
 * the **lane count** E (state shapes carry the leading [E] axis);
@@ -32,13 +33,12 @@ counters feed the daemon's Prometheus ledger (SERVE_SPECS).
 
 from __future__ import annotations
 
-import dataclasses
 import hashlib
 from collections import OrderedDict
 
 import numpy as np
 
-from shadow1_tpu.fleet.expand import _VARIABLE_EXP, FleetConfigError
+from shadow1_tpu.fleet.expand import FleetConfigError, shape_class
 
 
 def _fold(h, x) -> None:
@@ -61,14 +61,10 @@ def shape_class_key(exp, params, n_exp: int, backend: str = "cpu") -> tuple:
     """The engine-cache key for a batch of ``n_exp`` lanes of ``exp``'s
     shape class under ``params``. Two batches with equal keys differ at
     most in the fleet-variable knobs (seed / loss / faults / stop_time /
-    per-lane max_rounds), which ride the variant pytree — never the
-    compiled program."""
+    per-lane max_rounds / the app's lane tables), which ride the variant
+    pytree — never the compiled program."""
     h = hashlib.sha256()
-    for f in dataclasses.fields(type(exp)):
-        if f.name in _VARIABLE_EXP:
-            continue
-        h.update(f.name.encode())
-        _fold(h, getattr(exp, f.name))
+    _fold(h, shape_class(exp))
     return (h.hexdigest(), params, int(n_exp), backend)
 
 

@@ -246,7 +246,7 @@ class ShardedEngine:
         cols_g = dict(
             hosts=gctx.hosts, bw_up=gctx.bw_up, bw_dn=gctx.bw_dn,
             fault_down=gctx.fault_down, fault_up=gctx.fault_up,
-            cpu_cost=gctx.cpu_cost,
+            cpu_cost=gctx.cpu_cost, ser_up=gctx.ser_up, ser_dn=gctx.ser_dn,
             tx_qlen_ns=gctx.tx_qlen_ns, rx_qlen_ns=gctx.rx_qlen_ns,
             aqm_min_ns=gctx.aqm_min_ns, aqm_span_ns=gctx.aqm_span_ns,
             aqm_pmax_thr=gctx.aqm_pmax_thr,
@@ -287,6 +287,8 @@ class ShardedEngine:
                 loss_ramp=loss_ramp,
                 init_model=imodel,
                 cpu_cost=cols["cpu_cost"],
+                ser_up=cols["ser_up"],
+                ser_dn=cols["ser_dn"],
                 tx_qlen_ns=cols["tx_qlen_ns"],
                 rx_qlen_ns=cols["rx_qlen_ns"],
                 aqm_min_ns=cols["aqm_min_ns"],
@@ -451,7 +453,7 @@ class ShardedEngine:
         def run(st: SimState, n_windows) -> SimState:
             specs = self._state_specs(st)
             col_specs = {
-                k: P(*([None] * (v.ndim - 1)), axis)
+                k: None if v is None else P(*([None] * (v.ndim - 1)), axis)
                 for k, v in cols_g.items()
             }
             imodel_specs = jax.tree.map(self._spec_for, init_model_g)

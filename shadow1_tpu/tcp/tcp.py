@@ -205,7 +205,7 @@ def _emit(st, ctx, r: Sock, mask, flags, seq, length, mend, mmeta, now):
     nic, depart, sent, red = tx_stamp(
         st.model.nic, mask, wire, now, ctx.bw_up,
         ctx.tx_qlen_ns if ctx.has_tx_qlen else None,
-        aqm=ctx_aqm(ctx),
+        aqm=ctx_aqm(ctx), ser=ctx.ser_up,
     )
     k = jnp.full(ctx.n_hosts, K_PKT, jnp.int32)
     # A queue-dropped segment (tail or RED) behaves exactly like path loss:
@@ -360,7 +360,8 @@ def _tcp_flush(st, ctx, mask, sock, now):
         # semantics have exactly one source of truth (net/nic.py).
         wire = length.astype(jnp.int64) + WIRE_OVERHEAD
         nic_run, depart, sent, red = tx_stamp(
-            nic_run, can, wire, now64, ctx.bw_up, qlen, aqm=aqm
+            nic_run, can, wire, now64, ctx.bw_up, qlen, aqm=aqm,
+            ser=ctx.ser_up,
         )
         n_tx_drop = n_tx_drop + (can & ~sent & ~red).sum(dtype=jnp.int64)
         n_red = n_red + red.sum(dtype=jnp.int64)

@@ -229,6 +229,26 @@ SERVE_SPECS: dict[str, tuple[str, str]] = {
     "top_edge_drops": (GAUGE, "drops on the lossiest edge seen (link records)"),
 }
 
+# Model run totals: the 0-dim entries of a model's ``summary``, lifetime
+# absolutes. They ride a heartbeat row's ``model`` block (per lane under
+# ``fleet.model_per_exp``) and the CLI's final ``summary``; they are NOT
+# ``Metrics`` fields (a new field would change every model's program).
+MODEL_TOTALS: dict[str, str] = {
+    "total_hops": "phold: events handled, all hosts",
+    "total_rx": "dgram: datagrams received",
+    "total_rx_bytes": "filexfer, tgen: payload bytes received",
+    "total_flows_done": "filexfer: flows completed",
+    "total_streams_served": "tgen: streams a server finished sending",
+    "total_streams_done": "tgen, tor: streams a client completed",
+    "total_cells_rx": "tor: cells received at an endpoint",
+    "total_cells_fwd": "tor: cells a relay forwarded",
+    "total_ct_overflow": "tor: circuits refused, relay circuit table full",
+    "total_seen": "bitcoin: (tx, node) first sights, origins included",
+    "total_tx_rx": "bitcoin: TX payloads received",
+    "total_msg_retries": "bitcoin: protocol sends deferred a window "
+                         "(send buffer or boundary FIFO full)",
+}
+
 # The drop/overflow counter group: every way a modeled event or packet can
 # be discarded, with the human-readable reason. Heartbeat records and the
 # CLI's final JSON group these under one structured ``drops`` block (and
