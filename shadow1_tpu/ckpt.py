@@ -74,7 +74,11 @@ import jax
 #      bit-identically with no baseline bookkeeping. A telemetry-off
 #      state keeps the v11 leaf layout; the bump makes an on/off mismatch
 #      fail as a version error.
-CKPT_FORMAT = 12
+#  13: Metrics gains deliver_ranks (the window-end merge's fill-loop trips
+#      times its rank block, core/events.deliver_batch): one more i64 leaf
+#      in every snapshot. A running sum like the other counters, so a
+#      resumed run continues it bit-identically.
+CKPT_FORMAT = 13
 
 
 class CorruptCheckpointError(ValueError):

@@ -108,6 +108,10 @@ class Metrics(NamedTuple):
     fires_timer: jnp.ndarray
     fires_txr: jnp.ndarray
     fires_app: jnp.ndarray
+    # Arriving ranks the window-end merge swept (events.deliver_batch: its
+    # fill loop's trips * RB, summed over windows) — against windows * ev_cap
+    # it says what a fill by slot would sweep. Batch-engine-only like fires_*.
+    deliver_ranks: jnp.ndarray
     # Fault plane (shadow1_tpu/fault/): deterministic link outages and host
     # restarts (docs/SEMANTICS.md §"Fault plane").
     link_down_pkts: jnp.ndarray  # packets dropped: link outage window
@@ -441,8 +445,8 @@ def deliver_flat(evbuf, ctx: Ctx, fp: FlatPackets):
     ctx.hosts[0]); packets for other blocks are masked out; packets whose
     arrival falls inside a down interval of the destination are dropped
     here (churn — counted, never delivered, so a dead host's buffers stay
-    clean). Returns (evbuf, n_delivered, n_overflow, n_down) counting only
-    this block's packets."""
+    clean). Returns (evbuf, n_delivered, n_overflow, n_down, n_ranks)
+    counting only this block's packets; n_ranks is deliver_batch's."""
     base = ctx.hosts[0].astype(fp.dst.dtype)
     local = fp.dst - base
     mine = fp.keep & (local >= 0) & (local < ctx.n_hosts)
@@ -456,10 +460,10 @@ def deliver_flat(evbuf, ctx: Ctx, fp: FlatPackets):
         )
         n_down = to_down.sum(dtype=jnp.int64)
         mine = mine & ~to_down
-    evbuf, n_over = deliver_batch(
+    evbuf, n_over, n_ranks = deliver_batch(
         evbuf, local, fp.arrival, fp.tb, fp.kind, fp.p, mine
     )
-    return evbuf, mine.sum(dtype=jnp.int64) - n_over, n_over, n_down
+    return evbuf, mine.sum(dtype=jnp.int64) - n_over, n_over, n_down, n_ranks
 
 
 def deliver_window(st: SimState, ctx: Ctx, exchange=None) -> SimState:
@@ -488,7 +492,8 @@ def deliver_window(st: SimState, ctx: Ctx, exchange=None) -> SimState:
         with jax.named_scope("phase:exchange"):
             fp, n_x2x, x2x_hw = exchange(fp)
     with jax.named_scope("phase:deliver"):
-        evbuf, n_deliv, n_over, n_down = deliver_flat(st.evbuf, ctx, fp)
+        evbuf, n_deliv, n_over, n_down, n_ranks = deliver_flat(
+            st.evbuf, ctx, fp)
     m = st.metrics
     return st._replace(
         evbuf=evbuf,
@@ -505,6 +510,7 @@ def deliver_window(st: SimState, ctx: Ctx, exchange=None) -> SimState:
             down_pkts=m.down_pkts + n_down,
             link_down_pkts=m.link_down_pkts + n_linkdown,
             outbox_hosts=m.outbox_hosts + ob_hosts,
+            deliver_ranks=m.deliver_ranks + n_ranks,
         ),
     )
 

@@ -325,19 +325,28 @@ def evbuf_fill(buf: EventBuf) -> jnp.ndarray:
     return (buf.kind != K_NONE).sum(axis=0, dtype=jnp.int32).max().astype(jnp.int64)
 
 
-def deliver_batch(buf: EventBuf, dst, time, tb, kind, p, mask) -> tuple[EventBuf, jnp.ndarray]:
+# Arriving ranks deliver_batch places per pass of its fill loop. A pass costs
+# RB * H gathered packet rows plus one fused select over the event planes, so
+# a larger block trades plane passes for rows fetched past the busiest host's
+# last packet (dense PHOLD on the v5e: 54.0 / 54.9 / 61.0 / 79.4 ms a merge
+# at 2 / 4 / 8 / 16; PERF.md §6, PR 29).
+RB = 4
+
+
+def deliver_batch(
+    buf: EventBuf, dst, time, tb, kind, p, mask
+) -> tuple[EventBuf, jnp.ndarray, jnp.ndarray]:
     """Merge N externally-created events into their hosts' buffers.
 
     The tensor analogue of the reference's locked cross-thread event push
-    (src/main/utility/async-priority-queue.c), restructured gather-style for
-    TPU: sort packets by destination (masked ones to the end), then each
-    host's r-th free slot *gathers* the r-th packet of its segment
-    (seg_start[h] + r). All reads are sorted gathers; the only writes are
-    dense ``where``s. Packet r per host is the r-th in flat source order,
-    and free slots fill in ascending slot index. Slot ASSIGNMENT is an
-    engine-internal layout choice; pop order is decided purely by the
-    (time, tb) keys, so it is engine- and layout-independent.
-    Returns (buf, n_overflow). ``p`` is [NP, N].
+    (src/main/utility/async-priority-queue.c). Packets are sorted by
+    destination (masked ones to the end); packet r of a host — the r-th in
+    flat source order — goes to that host's r-th free slot in ascending
+    slot index, and when free slots run out the highest ranks drop. Slot
+    ASSIGNMENT is an engine-internal layout choice; pop order is decided
+    purely by the (time, tb) keys, so it is engine- and layout-independent.
+    Returns (buf, n_overflow, n_ranks). ``p`` is [NP, N]; ``n_ranks`` is
+    the fill loop's trips * RB (``Metrics.deliver_ranks``).
 
     Runs at window granularity only, so it writes the authoritative i64
     time plane and leaves t32 stale — the window-start ``rebase`` repairs
@@ -353,12 +362,18 @@ def deliver_batch(buf: EventBuf, dst, time, tb, kind, p, mask) -> tuple[EventBuf
     TPU tuning: the sort key packs (dst, flat index) into one integer so an
     *unstable* single-key sort is deterministic (keys are distinct and the
     packing preserves source order within a destination); segment bounds
-    come from one H+1-point searchsorted; the 15 payload rows (time split
-    into i32 halves, the pre-split tb planes, kind, p) ride one stacked
-    gather instead of four. This runs once per window, so its cumsum over
-    the slot axis is off the round path.
+    come from one H+1-point searchsorted. The chip runs a gather one index
+    at a time (7-11 ns an index, 20-25 ns a 15-field row: PERF.md §6,
+    PR 29), so the fill fetches by ARRIVING RANK, not by slot: a
+    ``while_loop`` over blocks of RB ranks, each trip gathering the
+    block's RB * H packet rows (time split into i32 halves, the pre-split
+    tb planes, kind, p: one stacked gather) and writing them with dense
+    compare-selects against each slot's free rank. The trip count is data
+    — ceil(busiest host's placed packets / RB): zero in a window that sent
+    nothing — where a gather per slot costs C * H indices whatever
+    arrived. Under ``vmap`` the loop runs to the busiest lane's count.
     """
-    cap, n_hosts = buf.kind.shape
+    n_hosts = buf.kind.shape[1]
     n = dst.shape[0]
     nb = max((n - 1).bit_length(), 1)
     wide = (n_hosts + 1) << nb > 2**31 - 1
@@ -366,14 +381,17 @@ def deliver_batch(buf: EventBuf, dst, time, tb, kind, p, mask) -> tuple[EventBuf
     key = (jnp.where(mask, dst, n_hosts).astype(kdt) << nb) | jnp.arange(n, dtype=kdt)
     (key_s,) = jax.lax.sort((key,), is_stable=False)
     dst_s = (key_s >> nb).astype(jnp.int32)
+    idx_s = (key_s & ((1 << nb) - 1)).astype(jnp.int32)      # [N] flat idx
     hs = jnp.arange(n_hosts + 1, dtype=jnp.int32)
-    seg = jnp.searchsorted(dst_s, hs, side="left")
-    n_in = (seg[1:] - seg[:-1]).astype(jnp.int32)            # [H]
+    seg = jnp.searchsorted(dst_s, hs, side="left").astype(jnp.int32)
+    first = seg[:-1]                                         # [H] rank 0's
+    n_in = seg[1:] - first                                   # [H]
     free = buf.kind == K_NONE                                # [C, H]
     free_rank = (jnp.cumsum(free, axis=0) - free).astype(jnp.int32)
-    take = free & (free_rank < n_in[None, :])                # slot receives one
-    src = jnp.minimum(seg[:-1][None, :] + free_rank, n - 1)
-    oidx = (key_s & ((1 << nb) - 1)).astype(jnp.int32)[src]  # [C, H] flat idx
+    # The arriving rank each slot receives, -1 where it receives none.
+    slot_rank = jnp.where(free & (free_rank < n_in[None, :]), free_rank, -1)
+    n_take = jnp.minimum(n_in, free.sum(axis=0, dtype=jnp.int32))
+    trips = (n_take.max() + (RB - 1)) // RB
     thi, tlo = tb_split(jnp.asarray(time, jnp.int64))
     bhi, blo = tb_split(jnp.asarray(tb, jnp.int64))
     stacked = jnp.concatenate(
@@ -382,18 +400,29 @@ def deliver_batch(buf: EventBuf, dst, time, tb, kind, p, mask) -> tuple[EventBuf
             p,
         ]
     )                                                        # [5+NP, N] i32
-    g = stacked[:, oidx]                                     # [5+NP, C, H]
+
+    def fill(carry):
+        i, heads, pay = carry
+        r = i * RB + jnp.arange(RB, dtype=jnp.int32)
+        src = jnp.minimum(first[None, :] + r[:, None], n - 1)
+        g = stacked[:, idx_s[src]]                           # [5+NP, RB, H]
+        for j in range(RB):
+            sel = slot_rank == r[j]
+            heads = [jnp.where(sel, g[k, j][None, :], x)
+                     for k, x in enumerate(heads)]
+            pay = jnp.where(sel[None], g[5:, j][:, None, :], pay)
+        return i + 1, heads, pay
+
+    heads = [buf.time_hi, buf.time_lo, buf.tb_hi, buf.tb_lo, buf.kind]
+    _, heads, pay = jax.lax.while_loop(
+        lambda carry: carry[0] < trips, fill,
+        (jnp.zeros((), jnp.int32), heads, buf.p))
     buf = buf._replace(
-        time_hi=jnp.where(take, g[0], buf.time_hi),
-        time_lo=jnp.where(take, g[1], buf.time_lo),
-        tb_hi=jnp.where(take, g[2], buf.tb_hi),
-        tb_lo=jnp.where(take, g[3], buf.tb_lo),
-        kind=jnp.where(take, g[4], buf.kind),
-        p=jnp.where(take[None], g[5:], buf.p),
+        time_hi=heads[0], time_lo=heads[1], tb_hi=heads[2], tb_lo=heads[3],
+        kind=heads[4], p=pay,
     )
-    free_cnt = free.sum(axis=0, dtype=jnp.int32)
-    n_over = mask.sum() - jnp.minimum(n_in, free_cnt).sum()
-    return buf, n_over
+    n_over = mask.sum() - n_take.sum()
+    return buf, n_over, (trips * RB).astype(jnp.int64)
 
 
 def _lo(x):

@@ -35,9 +35,9 @@ source-shard order, so each destination sees its packets in shard-major ×
 host-major = global host-major order — exactly the single-device flatten
 order — and all event/tie-break keys are computed from global host ids, so the
 delivered event streams are identical for any device count. The
-``rounds``/``round_cap_hits`` metrics are the one exception (each shard
-counts its own inner rounds; they are summed), so they are performance
-counters, not semantic invariants.
+``rounds``/``round_cap_hits``/``deliver_ranks`` metrics are the one exception
+(each shard counts its own inner rounds and its own merge's trips; they are
+summed), so they are performance counters, not semantic invariants.
 """
 
 from __future__ import annotations

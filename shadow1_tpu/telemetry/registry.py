@@ -67,6 +67,8 @@ METRIC_SPECS: dict[str, tuple[str, str]] = {
     "fires_timer": (COUNTER, "rounds where the K_TCP_TIMER pass fired"),
     "fires_txr": (COUNTER, "rounds where the K_TX_RESUME pass fired"),
     "fires_app": (COUNTER, "rounds where the K_APP pass fired"),
+    "deliver_ranks": (COUNTER, "arriving ranks swept by the window-end merge "
+                               "(deliver_batch's trips * RB; batch engines)"),
     "link_down_pkts": (COUNTER, "packets dropped: link outage window (fault plane)"),
     "host_restarts": (COUNTER, "host restart resets applied (fault plane churn)"),
     # Wasted-work accounting (performance attribution plane): per-window

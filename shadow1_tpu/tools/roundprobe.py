@@ -361,7 +361,7 @@ def main() -> int:
             m = jnp.ones(H, bool)
 
             def step(buf):
-                buf2, _over = ev.deliver_batch(buf, dst, t, tb, k, pay, m)
+                buf2, _over, _ranks = ev.deliver_batch(buf, dst, t, tb, k, pay, m)
                 # hold occupancy: keep the timing honest across iters
                 return buf2._replace(kind=buf.kind, time_hi=buf.time_hi,
                                      time_lo=buf.time_lo, t32=buf.t32)

@@ -1,10 +1,13 @@
 """Unit tests for the batched event-buffer primitives."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from shadow1_tpu.consts import NP, K_PHOLD
 from shadow1_tpu.core.events import (
+    RB,
     deliver_batch,
     evbuf_init,
     pop_until,
@@ -64,7 +67,7 @@ def test_deliver_batch_ranks_and_overflow():
     kind = jnp.full(n, K_PHOLD, jnp.int32)
     p = jnp.zeros((NP, n), jnp.int32)
     mask = jnp.ones(n, bool)
-    buf, n_over = deliver_batch(buf, dst, time, tb, kind, p, mask)
+    buf, n_over, _ = deliver_batch(buf, dst, time, tb, kind, p, mask)
     assert int(n_over) == 1
     counts = np.asarray((buf.kind != 0).sum(axis=0))
     assert counts.tolist() == [1, 2, 1]
@@ -237,3 +240,169 @@ def test_pallas_preflight_fallback_shapes():
     with pytest.raises(ValueError, match="outbox_cap=4096"):
         popk._check_vmem(4096, 50_000, planes=popk.OBOX_PLANES,
                          knob="outbox_cap")
+
+
+# ---------------------------------------------------------------------------
+# deliver_batch against a numpy oracle of the layout rule
+# ---------------------------------------------------------------------------
+
+def _deliver_oracle(kind0, dst, mask):
+    """The layout rule alone: packet r of a host, in flat source order,
+    goes to that host's r-th free slot in ascending slot index; when free
+    slots run out the highest ranks drop. Returns (slot -> flat packet
+    index or -1 as [C, H], n_overflow, the busiest host's placed count)."""
+    cap, n_hosts = kind0.shape
+    placed = np.full((cap, n_hosts), -1)
+    n_over = busiest = 0
+    by_host: dict[int, list[int]] = {}
+    for i in np.flatnonzero(mask):
+        by_host.setdefault(int(dst[i]), []).append(int(i))
+    for h, pkts in by_host.items():
+        slots = np.flatnonzero(kind0[:, h] == 0)
+        for c, i in zip(slots, pkts):
+            placed[c, h] = i
+        n_over += max(len(pkts) - len(slots), 0)
+        busiest = max(busiest, min(len(pkts), len(slots)))
+    return placed, n_over, busiest
+
+
+def _deliver_case(name):
+    """The lanes of one named case, each (occupied [C, H] bool, dst [N],
+    mask [N]): one, but for ``lanes``, which has three of different
+    in-degree."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    cap, n_hosts = 12, 7
+    occ = rng.random((cap, n_hosts)) < 0.4
+    if name == "empty":           # nothing sent: zero trips
+        return [(occ, np.zeros(16, np.int64), np.zeros(16, bool))]
+    if name == "all_masked":      # destinations present, none valid
+        return [(occ, rng.integers(0, n_hosts, 16), np.zeros(16, bool))]
+    if name == "full_host":       # host 3 has no free slot, host 4 one
+        occ[:, 3] = True
+        occ[:, 4] = True
+        occ[5, 4] = False
+        dst = np.r_[np.full(4, 3), np.full(3, 4), rng.integers(0, n_hosts, 9)]
+        return [(occ, dst, np.ones(16, bool))]
+    if name == "over_rb":         # in-degree > RB and > the free slots
+        occ[:, 2] = np.arange(cap) % 2 == 0          # 6 free slots
+        dst = np.r_[np.full(2 * RB + 3, 2), rng.integers(0, n_hosts, 20)]
+        return [(occ, dst, rng.random(len(dst)) < 0.9)]
+    if name == "wide":            # (H + 1) << nb > 2**31: the int64 keys
+        n = 2**14 + 5
+        hosts = 2**17
+        occ = rng.random((3, hosts)) < 0.5
+        dst = rng.integers(0, hosts, n)
+        dst[:9] = hosts - 1       # the last host over RB and over its slots
+        dst[9:20] = 0
+        return [(occ, dst, rng.random(n) < 0.8)]
+    assert name == "lanes"    # in-degree 0, 3 and 2 * RB + 1 at host 1
+    lanes = []
+    for deg in (0, 3, 2 * RB + 1):
+        dst = np.r_[np.full(deg, 1), 2 + np.arange(2 * RB + 9 - deg) % 5]
+        mask = np.arange(len(dst)) < (deg + 8 if deg else 0)
+        occ = rng.random((cap, n_hosts)) < 0.3
+        occ[:, 1] = False
+        lanes.append((occ, dst, mask))
+    return lanes
+
+
+def _deliver_inputs(occ, dst, mask, seed=0):
+    rng = np.random.default_rng(seed)
+    cap, n_hosts = occ.shape
+    n = len(dst)
+    buf = evbuf_init(n_hosts, cap)
+    buf = buf._replace(
+        kind=jnp.asarray(np.where(occ, K_PHOLD, 0), jnp.int32),
+        time_hi=jnp.asarray(rng.integers(0, 99, occ.shape), jnp.int32),
+        time_lo=jnp.asarray(rng.integers(0, 99, occ.shape), jnp.int32),
+        tb_hi=jnp.asarray(rng.integers(0, 99, occ.shape), jnp.int32),
+        tb_lo=jnp.asarray(rng.integers(0, 99, occ.shape), jnp.int32),
+        p=jnp.asarray(rng.integers(0, 99, (NP,) + occ.shape), jnp.int32),
+    )
+    args = (jnp.asarray(dst, jnp.int32),
+            jnp.asarray(rng.integers(0, 2**40, n), jnp.int64),
+            jnp.asarray(rng.integers(0, 2**61, n), jnp.int64),
+            jnp.asarray(rng.integers(1, 5, n), jnp.int32),
+            jnp.asarray(rng.integers(0, 1000, (NP, n)), jnp.int32),
+            jnp.asarray(mask))
+    return buf, args
+
+
+def _check_deliver(buf0, args, out, occ, dst, mask):
+    """``out`` = deliver_batch(buf0, *args) holds the oracle's layout in
+    every plane, its overflow count, and trips * RB ranks."""
+    buf, n_over, n_ranks = out
+    placed, want_over, busiest = _deliver_oracle(np.where(occ, 1, 0), dst, mask)
+    _, time, tb, kind, p, _ = (np.asarray(a) for a in args)
+    got = placed >= 0
+    src = np.where(got, placed, 0)
+    thi, tlo = (np.asarray(x) for x in tb_split(jnp.asarray(time)))
+    bhi, blo = (np.asarray(x) for x in tb_split(jnp.asarray(tb)))
+    for plane, rows in (("time_hi", thi), ("time_lo", tlo), ("tb_hi", bhi),
+                        ("tb_lo", blo), ("kind", kind)):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(buf, plane)),
+            np.where(got, rows[src], np.asarray(getattr(buf0, plane))), plane)
+    np.testing.assert_array_equal(
+        np.asarray(buf.p), np.where(got[None], p[:, src], np.asarray(buf0.p)))
+    for leaf in ("t32", "self_ctr", "epoch", "n_elig", "u32"):
+        np.testing.assert_array_equal(np.asarray(getattr(buf, leaf)),
+                                      np.asarray(getattr(buf0, leaf)), leaf)
+    assert int(n_over) == want_over
+    assert int(n_ranks) == -(-busiest // RB) * RB
+
+
+@pytest.mark.parametrize(
+    "case", ["empty", "all_masked", "full_host", "over_rb", "wide", "lanes"])
+def test_deliver_batch_layout_rule(case):
+    lanes = _deliver_case(case)
+    if case == "wide":
+        occ, dst, _ = lanes[0]
+        nb = max((len(dst) - 1).bit_length(), 1)
+        assert (occ.shape[1] + 1) << nb > 2**31
+    ins = [_deliver_inputs(*lane, seed=i) for i, lane in enumerate(lanes)]
+    if len(lanes) == 1:
+        outs = [jax.jit(deliver_batch)(ins[0][0], *ins[0][1])]
+    else:
+        stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *ins)
+        batched = jax.jit(jax.vmap(deliver_batch))(stacked[0], *stacked[1])
+        outs = [jax.tree_util.tree_map(lambda x: x[i], batched)
+                for i in range(len(lanes))]
+    for lane, (buf0, args), out in zip(lanes, ins, outs):
+        _check_deliver(buf0, args, out, *lane)
+    ranks = [int(out[2]) for out in outs]
+    if case in ("empty", "all_masked"):
+        assert ranks == [0]                  # zero trips
+    if case == "lanes":
+        assert ranks == [0, RB, 3 * RB]      # each lane its own trip count
+
+
+# ---------------------------------------------------------------------------
+# static guard: deliver_batch gathers by arriving rank, never by slot
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("lanes", [0, 2], ids=["solo", "vmap2"])
+def test_deliver_batch_gathers_no_slot_wide_index(lanes):
+    """Every ``gather`` deliver_batch traces fetches at most RB * H indices
+    (a block of ranks) or searchsorted's H + 1 — never one per event slot
+    (C * H), which the chip walks one index at a time (PERF.md §6, PR 29)."""
+    from shadow1_tpu.tools.opcensus import iter_eqns
+
+    cap, n_hosts, n = 56, 4096, 32 * 4096
+    buf = jax.eval_shape(lambda: evbuf_init(n_hosts, cap))
+    sd = jax.ShapeDtypeStruct
+    args = (sd((n,), jnp.int32), sd((n,), jnp.int64), sd((n,), jnp.int64),
+            sd((n,), jnp.int32), sd((NP, n), jnp.int32), sd((n,), jnp.bool_))
+    fn, batch = deliver_batch, 1
+    if lanes:
+        fn, batch = jax.vmap(deliver_batch), lanes
+        buf, args = jax.tree_util.tree_map(
+            lambda x: sd((lanes,) + x.shape, x.dtype), (buf, args))
+    gathers = [e for e in iter_eqns(jax.make_jaxpr(fn)(buf, *args).jaxpr)
+               if e.primitive.name == "gather"]
+    # The guard can see the fill: its index and row gathers are there.
+    assert len(gathers) >= 2
+    for e in gathers:
+        n_idx = int(np.prod(e.invars[1].aval.shape[:-1])) // batch
+        assert n_idx <= max(RB * n_hosts, n_hosts + 1), (n_idx, e)
+    assert RB * n_hosts < cap * n_hosts

@@ -202,7 +202,7 @@ def test_event_core_fuzz_vs_heap_model(impl):
             p = rng.integers(0, 100, (NP, n))
             mask = rng.random(n) < 0.9
             n_over_m = model.deliver(dst, t, tb, kind, p, mask)
-            buf, n_over = ev.deliver_batch(
+            buf, n_over, _ = ev.deliver_batch(
                 buf, jnp.asarray(dst, jnp.int32), jnp.asarray(t, jnp.int64),
                 jnp.asarray(tb, jnp.int64), jnp.asarray(kind, jnp.int32),
                 jnp.asarray(p, jnp.int32), jnp.asarray(mask),

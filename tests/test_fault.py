@@ -396,7 +396,13 @@ def test_supervise_survives_crash_and_corrupt_checkpoint(tmp_path):
     with open(cfg, "rb") as f:
         fp = hashlib.sha256(f.read()).hexdigest()
     body = bytearray(open(ref_npz, "rb").read())
-    body[len(body) // 2] ^= 0x40
+    # A run of bytes, not one: the reader takes a member's sizes from the
+    # central directory, so a flip inside a local header's zip64 record
+    # (where the midpoint fell once the snapshot gained the deliver_ranks
+    # leaf) changes nothing it reads, and an intact snapshot is rightly not
+    # discarded. 128 bytes span a whole header (61) and reach into data.
+    mid = len(body) // 2
+    body[mid:mid + 128] = bytes(b ^ 0x40 for b in body[mid:mid + 128])
     with open(ck, "wb") as f:
         f.write(bytes(body))
     with open(ck + ".meta", "w") as f:

@@ -71,9 +71,10 @@ def test_work_schema_and_ckpt_format():
     assert RING_FIELDS == RING_COUNTERS + RING_WORK + RING_GAUGES + \
         RING_DIGESTS
     # Widened ring row + new Metrics leaves = snapshot layout change
-    # (v10); the flow-probe ring leaf bumped it again (v11), and the
-    # link-telemetry accumulator leaf once more (v12).
-    assert CKPT_FORMAT == 12
+    # (v10); the flow-probe ring leaf bumped it again (v11), the
+    # link-telemetry accumulator leaf once more (v12), and the
+    # deliver_ranks Metrics leaf again (v13).
+    assert CKPT_FORMAT == 13
 
 
 def test_stale_ckpt_format_rejected(tmp_path):
