@@ -22,8 +22,6 @@ a parent that has touched jax holds the chip its children need:
          planes), event count checked against the C++ PHOLD comparator.
   leg 4  the serve daemon answers two same-shape requests; the second is an
          engine-cache hit; SIGTERM drains it (exit 8, its clean-drain code).
-  leg 5  the opt-in Pallas pop / pop+push kernels compile for this chip and
-         leave a PHOLD run (1,024 hosts) bit-equal to the default XLA path.
 
 Why leg 1 is rung 1 and not the 1,000-host Tor rung: compile. XLA:TPU needs
 minutes for the TCP round body at ANY width, and far longer for rung 3 (Tor
@@ -359,47 +357,6 @@ def leg4_serve(config: str = SERVE_CONFIG) -> None:
            cache=[f.get("cache") for f in finals])
 
 
-_PALLAS_CHILD = """
-import json
-import numpy as np
-import shadow1_tpu
-from shadow1_tpu.config.compiled import single_vertex_experiment
-from shadow1_tpu.consts import MS, EngineParams
-from shadow1_tpu.core.engine import Engine
-from shadow1_tpu.platform import describe
-
-# tests/test_phold_parity.py's pallas tests (8 hosts, interpret mode) at a
-# lane-aligned width that passes popk.preflight on the chip.
-exp = single_vertex_experiment(
-    n_hosts=1024, seed=7, end_time=300 * MS, latency_ns=10 * MS,
-    model="phold", model_cfg={"mean_delay_ns": 20 * MS, "init_events": 2})
-
-def go(**impls):
-    eng = Engine(exp, EngineParams(ev_cap=32, outbox_cap=16, **impls))
-    st = eng.run()
-    return (Engine.metrics_dict(st),
-            np.asarray(eng.model_summary(st)["hops"]).tolist())
-
-xla = go()
-pallas = go(pop_impl="pallas", push_impl="pallas")
-print(json.dumps({**describe(), "events": xla[0]["events"],
-                  "metrics_equal": xla[0] == pallas[0],
-                  "hops_equal": xla[1] == pallas[1]}))
-"""
-
-
-def leg5_pallas() -> None:
-    leg = "leg5"
-    rc, out, err, wall = run(leg, [PY, "-c", _PALLAS_CHILD], timeout_s=300)
-    check(rc == 0, leg, f"exit {rc}: {err[-1500:]}")
-    row = last_json(out, leg)
-    require_tpu(row, leg)
-    check(row["events"] > 0, leg, "PHOLD did nothing")
-    check(row["metrics_equal"] and row["hops_equal"], leg,
-          f"pallas pop+push != xla: {row}")
-    report(leg, row, wall, events=row["events"], pallas_equals_xla=True)
-
-
 def main() -> int:
     shutil.rmtree(OUT, ignore_errors=True)
     os.makedirs(OUT)
@@ -409,7 +366,6 @@ def main() -> int:
         leg2_supervised(ref)
         leg3_phold()
         leg4_serve()
-        leg5_pallas()
         # One process for each chip: this parent must never touch jax.
         check("jax" not in sys.modules, "parent", "chip_smoke.py imported jax")
     except SmokeFailure as e:

@@ -79,11 +79,11 @@ def _resolve_ckpt_lineage(args, log, what="checkpoint"):
 
 def _supervise(child_argv, ckpt_path, config_path,
                watchdog_s: float = 0.0) -> int:
-    """Parent side of ``--ckpt`` fault tolerance (the ladder's recipe,
-    bench_ladder.py): run the CLI in a child process; when it dies with a
-    checkpoint showing forward progress, respawn a fresh child that resumes
-    from the snapshot — a wedged-runtime fault never survives into the next
-    attempt because the next attempt is a new process.
+    """Parent side of ``--ckpt`` fault tolerance: run the CLI in a child
+    process; when it dies with a checkpoint showing forward progress,
+    respawn a fresh child that resumes from the snapshot — a wedged-runtime
+    fault never survives into the next attempt because the next attempt is
+    a new process.
 
     Failure handling beyond the bare respawn loop:
 

@@ -446,7 +446,7 @@ def build_experiment(doc: dict, base_dir: str = ".") -> tuple[CompiledExperiment
         "section (host[:sock] targets) or the --watch flag"
     )
     # Coerce by the DECLARED field type (a quoted "256" in YAML must still
-    # become an int; only genuinely-str fields like pop_extract stay str).
+    # become an int; only genuinely-str fields like on_overflow stay str).
     params = EngineParams(**{
         k: str(v) if fields[k].type in (str, "str") else int(v)
         for k, v in eng.items()
@@ -603,19 +603,3 @@ def load_experiment(path: str):
     with open(path) as f:
         doc = yaml.safe_load(f)
     return build_experiment(doc, base_dir=os.path.dirname(os.path.abspath(path)))
-
-
-def apply_engine_overrides(params: EngineParams, spec: str | None) -> EngineParams:
-    """Apply a ``k=v,k=v`` override list to an EngineParams (bench/CLI A/B
-    without config-file edits; e.g. ``compact_cap=384,pop_extract=gather``).
-    Values coerce to the field's current type (str fields stay str)."""
-    if not spec:
-        return params
-    repl = {}
-    fields = {f.name: f for f in dataclasses.fields(EngineParams)}
-    for item in spec.split(","):
-        k, sep, v = item.partition("=")
-        k = k.strip()
-        assert sep and k in fields, f"bad engine override {item!r}"
-        repl[k] = v.strip() if fields[k].type in (str, "str") else int(v)
-    return dataclasses.replace(params, **repl)

@@ -297,22 +297,6 @@ class EngineParams:
     # / window boundary (cpu oracle); violation raises SelfCheckError
     # naming the non-closing counters. 0 (default) = off.
     selfcheck: int = 0
-    # Pop-min result extraction: "sum" (masked-sum over the one-hot — the
-    # round-4 default) or "gather" (index via min-over-iota, then
-    # take_along_axis — the round-3 style on the round-4 layout). Bit-exact
-    # either way (the one-hot is exact); a perf A/B knob for the round-path
-    # regression hunt (docs/PERF.md round-5).
-    pop_extract: str = "sum"
-    # Pop-min implementation: "xla" (the masked-reduction chain in
-    # core/events.py) or "pallas" (the fused single-pass VMEM kernel in
-    # core/popk.py — one HBM read/write per plane instead of ~12 full-plane
-    # passes). Bit-exact either way (tests/test_events.py); a perf knob
-    # pending on-chip A/B (docs/PERF.md round-5).
-    pop_impl: str = "xla"
-    # Push implementation, same contract: "xla" (first-free + one-hot
-    # wheres) or "pallas" (core/popk.py fused single-pass kernel). Scoped
-    # into the handler layers at trace time via events.push_impl_ctx.
-    push_impl: str = "xla"
 
     # --- TCP constants (reference: src/main/host/descriptor/tcp.c) ---
     mss: int = 1460               # bytes per segment
@@ -326,7 +310,6 @@ class EngineParams:
 
     def __post_init__(self):
         assert self.sockets_per_host <= 256, "sock ids are packed into 8 bits"
-        assert self.pop_extract in ("sum", "gather"), self.pop_extract
         assert self.metrics_ring >= 0, self.metrics_ring
         assert self.state_digest in (0, 1), self.state_digest
         assert self.link_telem in (0, 1), self.link_telem
@@ -343,14 +326,6 @@ class EngineParams:
         assert self.on_lane_fail in ("halt", "quarantine"), self.on_lane_fail
         assert self.lane_finalize in (0, 1), self.lane_finalize
         assert self.selfcheck in (0, 1), self.selfcheck
-        assert self.pop_impl in ("xla", "pallas"), self.pop_impl
-        assert self.push_impl in ("xla", "pallas"), self.push_impl
-        # The fused pop kernel extracts via the one-hot masked sum only; a
-        # silent no-op pop_extract would corrupt exactly the A/B this knob
-        # exists for.
-        assert not (self.pop_impl == "pallas" and self.pop_extract != "sum"), (
-            "pop_impl='pallas' implies pop_extract='sum'"
-        )
 
 
 # App notification flags (per-round, host-level — set by the transport layer,

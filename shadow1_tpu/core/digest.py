@@ -7,7 +7,7 @@ the contract *continuously* observable: one integer digest word per engine
 subsystem per conservative window, computed INSIDE the jitted window loop
 (window granularity, never the round path) and recorded as telemetry-ring
 columns. Any two runs of the same config — tpu↔cpu, sharded↔single,
-pallas↔xla, resume↔straight-through — must carry identical digest streams;
+resume↔straight-through — must carry identical digest streams;
 the first differing (window, subsystem) pinpoints a violation that an
 end-of-run assert could only report as "some key mismatched after millions
 of windows" (``tools/paritytrace.py`` automates the bisection).

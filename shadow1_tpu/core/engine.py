@@ -301,13 +301,7 @@ def run_round(st: SimState, ctx: Ctx, handlers: dict, win_end) -> SimState:
     and advance counters only where masked, so an all-false pass is a
     no-op by construction and skipping it is exact)."""
     with jax.named_scope("phase:pop"):
-        if ctx.params.pop_impl == "pallas":
-            from shadow1_tpu.core.popk import pop_until_fused
-
-            evbuf, ev = pop_until_fused(st.evbuf, win_end)
-        else:
-            evbuf, ev = pop_until(st.evbuf, win_end,
-                                  extract=ctx.params.pop_extract)
+        evbuf, ev = pop_until(st.evbuf, win_end)
     st = st._replace(evbuf=evbuf)
     m = st.metrics
     n_down = jnp.zeros((), jnp.int64)
@@ -580,7 +574,7 @@ def window_phases(ctx: Ctx, handlers: dict, exchange=None, pre_window=None,
     window_step: the scopes reach every instruction of the compiled program
     (telemetry/phases.py joins a device trace's ops to them), and
     ``tools/opcensus.py`` censuses each stage's jaxpr separately."""
-    from shadow1_tpu.core.events import push_impl_ctx, rebase
+    from shadow1_tpu.core.events import rebase
 
     digest_on = bool(ctx.params.state_digest)
 
@@ -651,19 +645,15 @@ def window_phases(ctx: Ctx, handlers: dict, exchange=None, pre_window=None,
     def ph_rounds(fr: WindowFrame) -> WindowFrame:
         st = fr.st
         ccap = ctx.params.compact_cap
-        # push_impl scopes over the round tracing: every handler-layer
-        # push_local/push_back below dispatches to the selected
-        # implementation (trace-time — see events.push_impl_ctx).
-        with push_impl_ctx(ctx.params.push_impl):
-            if ccap and ccap < ctx.n_hosts and make_handlers is not None:
-                from shadow1_tpu.core.compact import compact_window_rounds
+        if ccap and ccap < ctx.n_hosts and make_handlers is not None:
+            from shadow1_tpu.core.compact import compact_window_rounds
 
-                st, cap_hit = compact_window_rounds(
-                    st, ctx, handlers, make_handlers, run_rounds,
-                    fr.win_end, ccap
-                )
-            else:
-                st, cap_hit = run_rounds(st, ctx, handlers, fr.win_end)
+            st, cap_hit = compact_window_rounds(
+                st, ctx, handlers, make_handlers, run_rounds,
+                fr.win_end, ccap
+            )
+        else:
+            st, cap_hit = run_rounds(st, ctx, handlers, fr.win_end)
         return fr._replace(st=st, cap_hit=cap_hit)
 
     def ph_deliver(fr: WindowFrame) -> WindowFrame:
@@ -930,22 +920,6 @@ def _model_module(name: str):
     raise ValueError(f"unknown model {name!r}")
 
 
-def _resolve_kernel_impls(params: EngineParams, n_hosts: int) -> EngineParams:
-    """Check an explicit pop_impl/push_impl='pallas' at construction: when
-    the gridless fused kernels cannot hold the plane set in VMEM at this
-    (cap, n_hosts), popk.preflight's ValueError propagates — the selection
-    was asked for by name, so running something else under it would
-    mislabel the result. (The default is 'xla'; nothing selects pallas on
-    its own.)"""
-    if "pallas" in (params.pop_impl, params.push_impl):
-        from shadow1_tpu.core import popk
-
-        popk.preflight(params.ev_cap, params.outbox_cap, n_hosts,
-                       pop_pallas=params.pop_impl == "pallas",
-                       push_pallas=params.push_impl == "pallas")
-    return params
-
-
 class Engine:
     """Batched engine for one CompiledExperiment.
 
@@ -962,7 +936,6 @@ class Engine:
         from shadow1_tpu.telemetry.links import check_link_params
 
         check_link_params(self.params, np.asarray(exp.lat_vv).shape[0])
-        self.params = _resolve_kernel_impls(self.params, exp.n_hosts)
         self.window = exp.window
         self.n_windows = int(-(-exp.end_time // self.window))
         self.ctx = build_base_ctx(exp, self.params, window=self.window)

@@ -44,7 +44,6 @@ scatters, no per-slot argmin/cumsum in the round path (core/dense.py).
 
 from __future__ import annotations
 
-import contextlib
 from typing import NamedTuple
 
 import jax
@@ -52,23 +51,6 @@ import jax.numpy as jnp
 
 from shadow1_tpu.consts import K_NONE, NP
 from shadow1_tpu.core.dense import extract_col, first_true
-
-# Trace-time push-implementation selector (EngineParams.push_impl). Handlers
-# throughout the model layers call push_local/push_back directly, so the
-# engine scopes this around its window-step tracing instead of threading an
-# argument through every handler signature. Tracing is single-threaded
-# Python, so a plain module global scoped by the context manager is exact.
-_PUSH_IMPL = "xla"
-
-
-@contextlib.contextmanager
-def push_impl_ctx(impl: str):
-    global _PUSH_IMPL
-    prev, _PUSH_IMPL = _PUSH_IMPL, impl
-    try:
-        yield
-    finally:
-        _PUSH_IMPL = prev
 
 I64_MAX = jnp.iinfo(jnp.int64).max
 I32_MAX = jnp.iinfo(jnp.int32).max
@@ -112,10 +94,9 @@ def _t32_of(time, epoch) -> jnp.ndarray:
 
 
 class EventBuf(NamedTuple):
-    """Every [C, H] plane is i32 — the chip has no native i64, and this is
-    also the precondition for the Pallas fused-pop kernel (core/popk.py).
-    Absolute event times live as a tb_split-encoded (hi, lo) pair,
-    reassembled only at window granularity (rebase, pre_window)."""
+    """Every [C, H] plane is i32 — the chip has no native i64. Absolute
+    event times live as a tb_split-encoded (hi, lo) pair, reassembled only
+    at window granularity (rebase, pre_window)."""
 
     time_hi: jnp.ndarray   # i32 [C, H] absolute time, high word
     time_lo: jnp.ndarray   # i32 [C, H] absolute time, low word (sign-flip)
@@ -192,10 +173,6 @@ def push_local(buf: EventBuf, mask, time, kind, p) -> tuple[EventBuf, jnp.ndarra
     Returns (buf, overflow_mask). Overflowing events are dropped and must be
     surfaced as a metric — capacity is an experiment knob (SURVEY §7.3.2).
     """
-    if _PUSH_IMPL == "pallas":
-        from shadow1_tpu.core.popk import push_local_fused
-
-        return push_local_fused(buf, mask, time, kind, p)
     has_free, first = first_true(buf.kind == K_NONE)
     ok = mask & has_free
     w = first & ok[None, :]
@@ -224,10 +201,6 @@ def push_back(buf: EventBuf, mask, time, tb, kind, p) -> tuple[EventBuf, jnp.nda
     past the window boundary (docs/SEMANTICS.md §cpu): the event re-enters
     at (eff_time, original tb), so its order among same-time events is
     preserved. Does not advance self_ctr."""
-    if _PUSH_IMPL == "pallas":
-        from shadow1_tpu.core.popk import push_back_fused
-
-        return push_back_fused(buf, mask, time, tb, kind, p)
     has_free, first = first_true(buf.kind == K_NONE)
     ok = mask & has_free
     w = first & ok[None, :]
@@ -255,20 +228,15 @@ def until32(buf: EventBuf, until) -> jnp.ndarray:
     return jnp.clip(until - buf.epoch, 0, I32_HORIZON).astype(jnp.int32)
 
 
-def pop_until(buf: EventBuf, until, extract: str = "sum") -> tuple[EventBuf, Popped]:
+def pop_until(buf: EventBuf, until) -> tuple[EventBuf, Popped]:
     """Per-host pop of the minimum-(time, tb) event with time < until.
 
     A 3-step lexicographic masked min over the slot (sublane) axis — t32,
     then tb_hi among time-ties, then tb_lo — ending in an equality one-hot;
     exact because (time, tb) is unique per host (module docstring). All
     i32: the only i64 work is the [H]-vector reconstruction of the popped
-    absolute time/tb.
-
-    ``extract`` selects how kind/payload leave the buffer — "sum" (masked
-    sum over the one-hot) or "gather" (one-hot → index → take_along_axis).
-    Both are exact; which is faster is a backend/layout question
-    (EngineParams.pop_extract, docs/PERF.md round-5)."""
-    assert extract in ("sum", "gather"), f"bad pop_extract {extract!r}"
+    absolute time/tb. Kind and payload leave the buffer as a masked sum
+    over the one-hot."""
     u32 = until32(buf, until)
     elig = (buf.kind != K_NONE) & (buf.t32 < u32)
     t_masked = jnp.where(elig, buf.t32, I32_FREE)
@@ -281,15 +249,8 @@ def pop_until(buf: EventBuf, until, extract: str = "sum") -> tuple[EventBuf, Pop
     lo_masked = jnp.where(tie2, buf.tb_lo, I32_MAX)
     min_lo = lo_masked.min(axis=0)
     sel = tie2 & (lo_masked == min_lo[None, :])    # one-hot per active host
-    if extract == "gather":
-        from shadow1_tpu.core.dense import first_true_idx, get_col
-
-        _, slot = first_true_idx(sel)
-        kind = jnp.where(mask, get_col(buf.kind, slot), K_NONE)
-        pay = jnp.where(mask[None, :], get_col(buf.p, slot), 0)
-    else:
-        kind = extract_col(sel, buf.kind)
-        pay = extract_col(sel, buf.p)
+    kind = extract_col(sel, buf.kind)
+    pay = extract_col(sel, buf.p)
     ev = Popped(
         mask=mask,
         time=jnp.where(mask, buf.epoch + min_t.astype(jnp.int64), 0),

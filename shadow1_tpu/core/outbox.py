@@ -73,16 +73,8 @@ def outbox_append(ob: Outbox, mask, dst, kind, depart, p) -> tuple[Outbox, jnp.n
 
     Callers that cannot tolerate drops (TCP) must check ``outbox_space``
     first and defer to the next window instead (K_TX_RESUME). Dense one-hot
-    write — no scatter (core/dense.py). ``p`` is [NP, H]. Dispatches to the
-    fused Pallas kernel under EngineParams.push_impl="pallas"
-    (events.push_impl_ctx scope, core/popk.py).
+    write — no scatter (core/dense.py). ``p`` is [NP, H].
     """
-    from shadow1_tpu.core.events import _PUSH_IMPL
-
-    if _PUSH_IMPL == "pallas":
-        from shadow1_tpu.core.popk import outbox_append_fused
-
-        return outbox_append_fused(ob, mask, dst, kind, depart, p)
     cap = ob.dst.shape[0]
     ok = mask & (ob.cnt < cap)
     dhi, dlo = tb_split(jnp.asarray(depart, jnp.int64))

@@ -337,14 +337,6 @@ class FleetEngine:
         # pytree is the transaction unit, caps stay fleet-uniform, and
         # tune/resize.py migrates the batched planes per lane (PR 13,
         # docs/SEMANTICS.md §"Fleet recovery contract").
-        repl = {}
-        if "pallas" in (params.pop_impl, params.push_impl):
-            import warnings
-
-            warnings.warn("fleet mode runs the XLA pop/push kernels "
-                          "(pallas fused kernels are not vmapped); "
-                          "falling back to pop_impl=push_impl='xla'")
-            repl.update(pop_impl="xla", push_impl="xla")
         if params.compact_cap:
             import warnings
 
@@ -353,8 +345,8 @@ class FleetEngine:
                           "execute per window, negating the win; running "
                           "full-width (bit-identical by the compaction "
                           "contract)")
-            repl.update(compact_cap=0)
-        return dataclasses.replace(params, **repl) if repl else params
+            return dataclasses.replace(params, compact_cap=0)
+        return params
 
     def _build_variants(self) -> tuple[dict, dict]:
         exps = self.exps

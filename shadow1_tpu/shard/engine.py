@@ -96,9 +96,6 @@ class ShardedEngine:
                 f"n_hosts={exp.n_hosts} not divisible by {self.n_dev} devices"
             )
         self.h_local = exp.n_hosts // self.n_dev
-        from shadow1_tpu.core.engine import _resolve_kernel_impls
-
-        self.params = _resolve_kernel_impls(self.params, self.h_local)
         self.axis = axis
         self.mesh = jax.make_mesh((self.n_dev,), (axis,), devices=devices)
         self.window = exp.window

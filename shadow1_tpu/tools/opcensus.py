@@ -167,7 +167,7 @@ def census(eng, sources: bool = False, fusion: bool = False,
         window_frame,
         window_phases,
     )
-    from shadow1_tpu.core.events import pop_until, push_impl_ctx
+    from shadow1_tpu.core.events import pop_until
 
     ctx, handlers = eng.ctx, eng._handlers
     st = eng.init_state()
@@ -195,25 +195,14 @@ def census(eng, sources: bool = False, fusion: bool = False,
         if fusion:
             fus[name] = count_fusions(fn, fr)
 
-    def in_push_scope(f):
-        def g(*a):
-            with push_impl_ctx(ctx.params.push_impl):
-                return f(*a)
-
-        return g
-
     for kind, hfn in sorted(handlers.items()):
         name = f"h_{KIND_NAMES.get(kind, kind)}"
-        eqns[name], by = count_eqns(in_push_scope(hfn), st, ev,
-                                    sources=sources)
+        eqns[name], by = count_eqns(hfn, st, ev, sources=sources)
         if sources:
             srcs[name] = by
-    eqns["pop"], _ = count_eqns(
-        lambda b: pop_until(b, win_end, extract=ctx.params.pop_extract),
-        st.evbuf,
-    )
+    eqns["pop"], _ = count_eqns(lambda b: pop_until(b, win_end), st.evbuf)
     eqns["round"], _ = count_eqns(
-        in_push_scope(lambda s: run_round(s, ctx, handlers, win_end)), st,
+        lambda s: run_round(s, ctx, handlers, win_end), st,
     )
     out: dict = {"eqns": eqns}
     if sources:

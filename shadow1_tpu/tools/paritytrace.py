@@ -1,7 +1,7 @@
 """paritytrace — first-divergence bisection between two engine configurations.
 
 The determinism contract says any two executions of the same experiment —
-CPU oracle vs TPU engine, sharded vs single-device, pallas vs xla kernels,
+CPU oracle vs TPU engine, sharded vs single-device,
 checkpoint-resume vs straight-through — produce bit-identical results. When
 the contract breaks, the end-of-run parity asserts report one mismatched
 counter after millions of windows with zero localization. This tool runs
@@ -19,14 +19,13 @@ Side specs (A / B):
     cpu                the sequential oracle
     tpu                single-device batched engine
     sharded[:D]        host-axis sharded over D devices (default: all)
-    +pallas            fused pop/push kernels (e.g. tpu+pallas)
     +resume            checkpoint/restore roundtrip at every chunk boundary
+                       (e.g. tpu+resume)
 
 Examples:
 
     paritytrace cfg.yaml tpu cpu                 # engine vs oracle
     paritytrace cfg.yaml tpu sharded:2           # sharding determinism
-    paritytrace cfg.yaml tpu tpu+pallas          # kernel A/B
     paritytrace cfg.yaml tpu tpu+resume          # snapshot fidelity
 
 ``--inject W[:SUBSYS[:SIDE]]`` corrupts one side's state at the window-W
@@ -184,16 +183,12 @@ class BatchSide(Side):
         mods = set(mods.split("+")) if mods else set()
         self.resume = "resume" in mods
         mods.discard("resume")
-        kw = {}
-        if "pallas" in mods:
-            kw.update(pop_impl="pallas", push_impl="pallas")
-            mods.discard("pallas")
         if mods:
             raise ValueError(f"unknown side modifiers {sorted(mods)!r}")
         # The ring is the digest transport: depth == lockstep chunk so every
         # window drains before it can be overwritten.
         self.params = dataclasses.replace(
-            params, state_digest=1, metrics_ring=chunk, **kw
+            params, state_digest=1, metrics_ring=chunk
         )
         name, _, ndev = kind.partition(":")
         if name == "tpu":
@@ -498,7 +493,7 @@ def main(argv=None) -> int:
         description="lockstep digest comparison + first-divergence bisection",
     )
     ap.add_argument("config", help="YAML experiment file")
-    ap.add_argument("side_a", help="cpu | tpu | sharded[:D] (+pallas/+resume)")
+    ap.add_argument("side_a", help="cpu | tpu | sharded[:D] (+resume)")
     ap.add_argument("side_b", help="same grammar as side A")
     ap.add_argument("--windows", type=int, default=None,
                     help="compare this many windows (default: the full run)")

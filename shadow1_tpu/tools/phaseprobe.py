@@ -114,7 +114,7 @@ def capture_frames(eng, st0, n_windows: int):
 
 def _time_reps(f, arg, reps: int) -> float:
     """min wall of ``jax.block_until_ready(f(arg))`` over ``reps`` (after a
-    compile warmup) — the roundprobe discipline."""
+    compile warmup)."""
     import jax
 
     jax.block_until_ready(f(arg))
@@ -160,7 +160,10 @@ def attribution(eng, n_windows: int = 16, warmup: int = 8, reps: int = 3,
     def straight(st):
         return eng.run(st, n_windows=n_windows)
 
-    total_s = _time_reps(straight, st0, reps)
+    # Every share and the coverage divide by this one reading, and a stall
+    # in it moves them all (a stalled phase sample moves one row), so it is
+    # the minimum over four times the phases' repetitions.
+    total_s = _time_reps(straight, st0, 4 * reps)
     st1 = eng.run(st0, n_windows=n_windows)
     jax.block_until_ready(st1)
     m1 = Engine.metrics_dict(st1)
@@ -204,8 +207,7 @@ def attribution(eng, n_windows: int = 16, warmup: int = 8, reps: int = 3,
         from shadow1_tpu.core.events import pop_until
 
         def pop_fn(fr):
-            buf, ev = pop_until(fr.st.evbuf, fr.win_end,
-                                extract=eng.ctx.params.pop_extract)
+            buf, ev = pop_until(fr.st.evbuf, fr.win_end)
             return fr._replace(st=fr.st._replace(evbuf=buf))
 
         pop_wall = _time_reps(_scan_phase(pop_fn), stacked["rounds"], reps)

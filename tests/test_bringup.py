@@ -4,7 +4,8 @@ on the chip can be believed.
 * nothing switches platform on its own — the chip smoke and the benchmark
   FAIL on a CPU, naming it — and every row says where it ran;
 * the compile cache can be placed from outside;
-* an explicit Pallas selection that cannot be honoured is an error;
+* an engine refuses, by name and before it builds anything, parameters it
+  cannot run;
 * a comparator binary is trusted for its source's content, not its mtime;
 * a parent that only verifies a checkpoint never initialises a backend.
 """
@@ -86,23 +87,28 @@ def test_compile_cache_dir_placeable_from_outside():
         r.stdout, r.stderr[-500:])
 
 
-def test_explicit_pallas_that_cannot_fit_is_an_error(monkeypatch):
-    """pop_impl='pallas' asked for by name at a shape the gridless kernels
-    cannot hold in VMEM: Engine construction raises (it used to warn and
-    run the XLA path under the Pallas label). preflight only checks on a
-    TPU, so the backend name is faked."""
+def test_engine_constructors_refuse_params_they_cannot_run():
+    """Digest words and probe samples are columns of the telemetry ring, so
+    either without a ring is a ValueError from every batched engine's
+    constructor; the sharded engine also refuses a host count its devices
+    do not divide."""
     from shadow1_tpu.consts import MS, EngineParams
-    from shadow1_tpu.core import popk
     from shadow1_tpu.core.engine import Engine
+    from shadow1_tpu.fleet.engine import FleetEngine
     from shadow1_tpu.shard.engine import ShardedEngine
     from tests.test_phold_parity import make_exp
 
-    monkeypatch.setattr(popk.jax, "default_backend", lambda: "tpu")
-    exp = make_exp(n_hosts=1024, end=10 * MS)
-    params = EngineParams(ev_cap=4096, outbox_cap=16, pop_impl="pallas")
-    for eng_cls in (Engine, ShardedEngine):
-        with pytest.raises(ValueError, match="VMEM"):
-            eng_cls(exp, params)
+    exp = make_exp(n_hosts=16, end=10 * MS)
+    builders = (lambda p: Engine(exp, p), lambda p: ShardedEngine(exp, p),
+                lambda p: FleetEngine([exp, exp], p))
+    for params, msg in (
+            (EngineParams(state_digest=1), "state_digest=1 requires metrics_ring"),
+            (EngineParams(probes=((0, -1),)), "probes require metrics_ring")):
+        for build in builders:
+            with pytest.raises(ValueError, match=msg):
+                build(params)
+    with pytest.raises(ValueError, match="n_hosts=12 not divisible by 8"):
+        ShardedEngine(make_exp(n_hosts=12, end=10 * MS), EngineParams())
 
 
 def test_dryrun_multichip_says_how_to_ask(monkeypatch):

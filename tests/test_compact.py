@@ -9,6 +9,8 @@ fallback) and on the lossy-TCP net model (the sparse workload the knob
 exists for).
 """
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -152,3 +154,30 @@ def test_sharded_compact_parity():
     params = dataclasses.replace(params, compact_cap=64)
     m1, s1, m8, s8 = run_pair(exp, params)
     assert_same(m1, s1, m8, s8, ["rx_bytes"])
+
+
+def test_compacted_run_keeps_n_elig_equal_to_plane_scan():
+    """The maintained eligibility counters survive the gather into the
+    bucket and the scatter back: after a run whose windows took both the
+    compacted and the full-width branch, and whose round cap left events
+    eligible (so the counters are not all zero), ``n_elig`` equals a scan
+    of the planes against ``u32`` and the plain engine's counters."""
+    from shadow1_tpu.telemetry.ring import drain_ring
+
+    exp, cap, n_win = _phold_exp(), 12, 40
+    base = EngineParams(ev_cap=64, outbox_cap=64, max_rounds=1,
+                        metrics_ring=n_win)
+    eng = Engine(exp, dataclasses.replace(base, compact_cap=cap))
+    st = eng.run(n_windows=n_win)
+    active = [r["active_hosts"] for r in drain_ring(st, eng.window)
+              if r["type"] == "ring"]
+    assert len(active) == n_win
+    assert min(active) <= cap < max(active), active   # both branches ran
+    buf = st.evbuf
+    scan = ((np.asarray(buf.kind) != 0)
+            & (np.asarray(buf.t32) < int(buf.u32))).sum(axis=0)
+    assert scan.sum() > 0 and Engine.metrics_dict(st)["round_cap_hits"] > 0
+    np.testing.assert_array_equal(np.asarray(buf.n_elig), scan)
+    plain = Engine(exp, base).run(n_windows=n_win)
+    np.testing.assert_array_equal(np.asarray(buf.n_elig),
+                                  np.asarray(plain.evbuf.n_elig))

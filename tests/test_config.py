@@ -122,9 +122,46 @@ def test_unknown_keys_fail_fast():
         with pytest.raises(AssertionError, match="unknown"):
             build_experiment(doc)
     # The engine section already rejected typos; keep that contract pinned.
-    with pytest.raises(AssertionError, match="unknown engine params"):
-        build_experiment(_phold_doc(engine={"scheduler": "tpu",
-                                            "ev_capp": 64}))
+    # So does a knob that no longer exists (pop_impl went with the Pallas
+    # kernels in PR 30): the error names the key.
+    for eng in ({"ev_capp": 64}, {"pop_impl": "pallas"}):
+        with pytest.raises(AssertionError,
+                           match=f"unknown engine params.*{list(eng)[0]}"):
+            build_experiment(_phold_doc(engine={"scheduler": "tpu", **eng}))
+
+
+def test_engine_section_coerces_by_declared_type():
+    """``engine:`` values take their EngineParams field's declared type: a
+    quoted number becomes an int, the str knobs stay str and are held to
+    their own value lists, and the probe watchlist is not settable there."""
+    _, params, _ = build_experiment(_phold_doc(engine={
+        "ev_cap": "256", "outbox_cap": 8, "on_overflow": "retry"}))
+    assert params.ev_cap == 256 and isinstance(params.ev_cap, int)
+    assert params.outbox_cap == 8 and params.on_overflow == "retry"
+    with pytest.raises(AssertionError, match="sometimes"):
+        build_experiment(_phold_doc(engine={"on_overflow": "sometimes"}))
+    with pytest.raises(ValueError):
+        build_experiment(_phold_doc(engine={"ev_cap": "many"}))
+    with pytest.raises(AssertionError, match="engine.probes is not settable"):
+        build_experiment(_phold_doc(engine={"probes": [[0, -1]]}))
+
+
+def test_engine_params_hold_each_knob_to_its_values():
+    """EngineParams refuses, at construction, a value outside a knob's own
+    list — whoever builds it (YAML, CLI override, a test): a bad value never
+    reaches a trace."""
+    from shadow1_tpu.consts import EngineParams
+
+    EngineParams(sockets_per_host=256, probes=((3, -1), (0, 15)))
+    for bad in ({"sockets_per_host": 257}, {"metrics_ring": -1},
+                {"state_digest": 2}, {"link_telem": 2}, {"auto_caps": -1},
+                {"on_overflow": "sometimes"}, {"on_lane_fail": "retry"},
+                {"lane_finalize": 2}, {"selfcheck": 2},
+                {"probes": [(0, 0)]},            # a list, not a tuple
+                {"probes": ((0, 16),)},          # sock past sockets_per_host
+                {"probes": ((-1, 0),)}, {"probes": (("h", 0),)}):
+        with pytest.raises(AssertionError):
+            EngineParams(**bad)
 
 
 def test_stagger_start_times():

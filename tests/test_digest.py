@@ -2,8 +2,8 @@
 
 The digest contract (ISSUE 3 acceptance, docs/SEMANTICS.md §"State
 digest"): per-window subsystem digest words are bit-identical across the
-CPU oracle, the single-chip engine, the sharded engine, the pallas/xla
-kernel variants and a checkpoint-resumed run; they are invariant under
+CPU oracle, the single-chip engine, the sharded engine and a
+checkpoint-resumed run; they are invariant under
 slot-layout permutation (identity lives in (time, tb) keys, never slot
 indices); and a single flipped bit in any digested subsystem changes that
 subsystem's word in that window. ``tools/paritytrace.py`` turns the stream
@@ -253,17 +253,6 @@ def test_digest_stream_sharded_vs_single():
                          ring_digests(st8, sh.window), "sharded")
 
 
-def test_digest_stream_pallas_vs_xla():
-    exp = phold_exp(n_hosts=8, end=100 * MS)
-    a = Engine(exp, dataclasses.replace(DIGEST_PARAMS, ev_cap=32,
-                                        outbox_cap=32))
-    b = Engine(exp, dataclasses.replace(DIGEST_PARAMS, ev_cap=32,
-                                        outbox_cap=32, pop_impl="pallas",
-                                        push_impl="pallas"))
-    assert_streams_equal(ring_digests(a.run(), a.window),
-                         ring_digests(b.run(), b.window), "pallas")
-
-
 def test_digest_stream_resume_vs_straight(tmp_path):
     """No digest state rides snapshots — the words are pure functions of
     engine state, so a save/load roundtrip continues the stream exactly."""
@@ -341,6 +330,21 @@ def test_paritytrace_localizes_injected_corruption(tmp_path, capsys,
     assert out["first_divergence"]["subsystems"] == [subsys]
     recs = [json.loads(x) for x in dump.read_text().splitlines()]
     assert any(r.get("type") == "plane_diff" for r in recs)
+
+
+def test_paritytrace_side_grammar_names_what_it_rejects():
+    """A side spec is ``cpu`` or ``tpu | sharded[:D]`` with ``+resume``:
+    anything else (the ``+pallas`` modifier went with the kernels in PR 30)
+    is refused by name before an engine is built."""
+    from shadow1_tpu.tools.paritytrace import make_side
+
+    exp = phold_exp(n_hosts=8, end=20 * MS)
+    for spec, msg in (("tpu+pallas", r"unknown side modifiers \['pallas'\]"),
+                      ("tpu+resume+fast", r"unknown side modifiers \['fast'\]"),
+                      ("gpu", "unknown side kind 'gpu'"),
+                      ("cpu+resume", "cpu oracle takes no modifiers")):
+        with pytest.raises(ValueError, match=msg):
+            make_side(spec, exp, DIGEST_PARAMS, 4)
 
 
 def test_paritytrace_resume_side_identical(tmp_path, capsys):

@@ -106,25 +106,19 @@ def test_past_due_events_keep_exact_time_and_order():
     epoch: their reconstructed pop times must stay exact and their (time,
     tb) order must survive — t32 goes negative rather than clamping to 0
     (core/events.py I32_PASTDUE; round-5 review finding)."""
-    from shadow1_tpu.core.popk import pop_until_fused
-
     buf = evbuf_init(1, 4)
     one = jnp.ones(1, bool)
     k = jnp.full(1, K_PHOLD, jnp.int32)
     # Three events, all before the NEXT window's start (past-due there).
     for t in (300, 100, 200):
         buf, _ = push_local(buf, one, jnp.full(1, t, jnp.int64), k, ZP(1))
-    for fused in (False, True):
-        b = rebase(buf, 1000, 2000)  # epoch has moved past all three
-        seen = []
-        for _ in range(3):
-            if fused:
-                b, ev2 = pop_until_fused(b, jnp.int64(2000))
-            else:
-                b, ev2 = pop_until(b, jnp.int64(2000))
-            assert bool(ev2.mask[0])
-            seen.append(int(ev2.time[0]))
-        assert seen == [100, 200, 300], (fused, seen)
+    b = rebase(buf, 1000, 2000)  # epoch has moved past all three
+    seen = []
+    for _ in range(3):
+        b, ev2 = pop_until(b, jnp.int64(2000))
+        assert bool(ev2.mask[0])
+        seen.append(int(ev2.time[0]))
+    assert seen == [100, 200, 300], seen
 
 
 def test_tb_split_join_order():
@@ -143,55 +137,6 @@ def test_tb_split_join_order():
     pairs = list(zip(np.asarray(hi).tolist(), np.asarray(lo).tolist()))
     order = sorted(range(len(vals)), key=lambda i: pairs[i])
     assert order == sorted(range(len(vals)), key=lambda i: int(vals[i]))
-
-
-def test_pop_fused_pallas_matches_xla():
-    """The Pallas fused pop kernel (core/popk.py, interpret mode on CPU) is
-    bit-identical to the XLA reduction chain — buffer planes and every
-    Popped field, across a drain of a randomly seeded buffer with time and
-    tie-break collisions."""
-    from shadow1_tpu.core.popk import pop_until_fused
-
-    rng = np.random.default_rng(11)
-    h, c = 7, 12
-    buf = evbuf_init(h, c)
-    k = jnp.full(h, K_PHOLD, jnp.int32)
-    for _ in range(c - 2):
-        m = jnp.asarray(rng.random(h) < 0.85)
-        # Narrow time range to force same-time ties (tb must break them).
-        t = jnp.asarray(rng.integers(1, 6, h), jnp.int64)
-        p = jnp.asarray(rng.integers(0, 99, (NP, h)), jnp.int32)
-        buf, _ = push_local(buf, m, t, k, p)
-    a, b = buf, buf
-    for _ in range(c):
-        a, ea = pop_until(a, jnp.int64(10**9))
-        b, eb = pop_until_fused(b, jnp.int64(10**9), interpret=True)
-        for fa, fb in zip(ea, eb):
-            np.testing.assert_array_equal(np.asarray(fa), np.asarray(fb))
-    for fa, fb in zip(a, b):
-        np.testing.assert_array_equal(np.asarray(fa), np.asarray(fb))
-
-
-def test_pop_extract_gather_matches_sum():
-    """The two pop_until extraction modes are bit-identical (perf A/B knob,
-    EngineParams.pop_extract)."""
-    rng = np.random.default_rng(3)
-    h, c = 5, 8
-    buf = evbuf_init(h, c)
-    k = jnp.full(h, K_PHOLD, jnp.int32)
-    for _ in range(c - 1):
-        m = jnp.asarray(rng.random(h) < 0.8)
-        t = jnp.asarray(rng.integers(1, 1000, h), jnp.int64)
-        p = jnp.asarray(rng.integers(0, 99, (NP, h)), jnp.int32)
-        buf, _ = push_local(buf, m, t, k, p)
-    a, b = buf, buf
-    for _ in range(c):
-        a, ea = pop_until(a, jnp.int64(10**9), extract="sum")
-        b, eb = pop_until(b, jnp.int64(10**9), extract="gather")
-        for fa, fb in zip(ea, eb):
-            np.testing.assert_array_equal(np.asarray(fa), np.asarray(fb))
-    for fa, fb in zip(a, b):
-        np.testing.assert_array_equal(np.asarray(fa), np.asarray(fb))
 
 
 def test_payload_matches_at_chain():
@@ -215,31 +160,6 @@ def test_payload_matches_at_chain():
     assert p.dtype == jnp.int32 and p.shape == (NP, h)
     with pytest.raises(ValueError, match="rows > NP"):
         payload(h, *([jnp.int32(0)] * (NP + 1)))
-
-
-def test_pallas_preflight_fallback_shapes():
-    """popk.preflight accepts in-VMEM shapes and rejects over-VMEM ones on
-    TPU; off-TPU (this suite) it must be a no-op so interpret-mode tests
-    keep exercising the kernels at any shape."""
-    import jax
-
-    from shadow1_tpu.core import popk
-
-    # Off-TPU the preflight never raises (interpret mode has no VMEM). On a
-    # TPU-attached run of this suite the same call MUST raise.
-    if jax.default_backend() == "tpu":
-        with np.testing.assert_raises(ValueError):
-            popk.preflight(4096, 4096, 100_000,
-                           pop_pallas=True, push_pallas=True)
-    else:
-        popk.preflight(4096, 4096, 100_000, pop_pallas=True, push_pallas=True)
-    # The underlying check itself rejects over-VMEM and accepts small.
-    popk._check_vmem(64, 1000, planes=popk.POP_PLANES)
-    import pytest
-
-    with pytest.raises(ValueError, match="outbox_cap=4096"):
-        popk._check_vmem(4096, 50_000, planes=popk.OBOX_PLANES,
-                         knob="outbox_cap")
 
 
 # ---------------------------------------------------------------------------
@@ -406,3 +326,82 @@ def test_deliver_batch_gathers_no_slot_wide_index(lanes):
         n_idx = int(np.prod(e.invars[1].aval.shape[:-1])) // batch
         assert n_idx <= max(RB * n_hosts, n_hosts + 1), (n_idx, e)
     assert RB * n_hosts < cap * n_hosts
+
+
+# ---------------------------------------------------------------------------
+# the outbox against per-host Python lists
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("lanes", [0, 2], ids=["solo", "vmap2"])
+def test_outbox_append_matches_list_model(lanes):
+    """outbox_append under random masks that run past ``outbox_cap``, with
+    outbox_space before and outbox_clear between, against one Python list a
+    host: the ok mask, ``cnt``, the lifetime ``pkt_ctr`` (a clear does not
+    reset it), and every stored row — the departure time through its
+    (hi, lo) split, ``ctr`` as the counter's low word. Under ``vmap2`` two
+    lanes fill at different rates, so one is full where the other is not."""
+    from shadow1_tpu.core.outbox import (
+        outbox_append,
+        outbox_clear,
+        outbox_init,
+        outbox_space,
+    )
+
+    h, cap, n = 5, 4, max(lanes, 1)
+    # Lifetime counters that start near the i32 edges: ctr keeps the low word.
+    ctr0 = np.array([2**32 - 3, 0, 7, 2**31 - 2, 1], np.int64)
+    ob = outbox_init(h, cap)._replace(pkt_ctr=jnp.asarray(ctr0))
+    append, space, clear = outbox_append, outbox_space, outbox_clear
+    if lanes:
+        ob = jax.tree_util.tree_map(lambda x: jnp.stack([x] * n), ob)
+        append, space, clear = map(jax.vmap, (append, space, clear))
+    append, space, clear = map(jax.jit, (append, space, clear))
+
+    def lane(x, i):
+        return jax.tree_util.tree_map(lambda a: np.asarray(a[i]), x) \
+            if lanes else jax.tree_util.tree_map(np.asarray, x)
+
+    rngs = [np.random.default_rng(30 + i) for i in range(n)]
+    rows = [[[] for _ in range(h)] for _ in range(n)]
+    sent = [ctr0.copy() for _ in range(n)]
+    full_seen = 0
+    for step in range(40):
+        free = space(ob)
+        args, want_ok = [], []
+        for i, rng in enumerate(rngs):
+            mask = rng.random(h) < (0.9, 0.5)[i]
+            dst = rng.integers(0, h, h).astype(np.int32)
+            kind = rng.integers(1, 5, h).astype(np.int32)
+            depart = rng.integers(0, 2**40, h)      # past one i32 word
+            p = rng.integers(0, 100, (NP, h)).astype(np.int32)
+            args.append((mask, dst, kind, depart, p))
+            np.testing.assert_array_equal(
+                lane(free, i), [cap - len(r) for r in rows[i]])
+            ok = []
+            for j in range(h):
+                ok.append(bool(mask[j]) and len(rows[i][j]) < cap)
+                full_seen += bool(mask[j]) and not ok[-1]
+                if ok[-1]:
+                    rows[i][j].append((
+                        dst[j], kind[j], depart[j],
+                        np.int64(sent[i][j]).astype(np.int32),
+                        p[:, j].tolist()))
+                    sent[i][j] += 1
+            want_ok.append(ok)
+        cols = [jnp.asarray(np.stack(c) if lanes else c[0])
+                for c in zip(*args)]
+        ob, ok = append(ob, *cols)
+        for i in range(n):
+            o = lane(ob, i)
+            assert lane(ok, i).tolist() == want_ok[i], (step, i)
+            assert o.cnt.tolist() == [len(r) for r in rows[i]], (step, i)
+            assert o.pkt_ctr.tolist() == sent[i].tolist(), (step, i)
+            depart = np.asarray(tb_join(o.depart_hi, o.depart_lo))
+            for j in range(h):
+                got = [(o.dst[r, j], o.kind[r, j], depart[r, j], o.ctr[r, j],
+                        o.p[:, r, j].tolist()) for r in range(len(rows[i][j]))]
+                assert got == rows[i][j], (step, i, j)
+        if step % 7 == 6:
+            ob = clear(ob)
+            rows = [[[] for _ in range(h)] for _ in range(n)]
+    assert full_seen > 10       # the masks did run past the cap

@@ -2,8 +2,10 @@
 
 A few hundred random push/pop/rebase/deliver operations run against a plain
 Python heap model; every pop's (mask, time, kind, tb, payload) and the
-final buffer census must match exactly, for BOTH pop/push implementations
-(XLA reductions and the fused Pallas kernels, interpret mode on CPU).
+final buffer census must match exactly — on one plain buffer, as the solo
+engine calls the primitives, and on two stacked buffers under ``jax.vmap``,
+as FleetEngine does, the two lanes running different operation sequences
+against two heap models.
 
 This is the unstructured counterpart of tests/test_events.py: the
 structured tests pin the documented contracts; the fuzz sweep hunts the
@@ -16,17 +18,13 @@ lines of obviously-correct Python — the judge's "real OS as oracle" trick
 
 import heapq
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from shadow1_tpu.consts import NP, TB_PACKET_BASE
 from shadow1_tpu.core import events as ev
-from shadow1_tpu.core.popk import (
-    pop_until_fused,
-    push_back_fused,
-    push_local_fused,
-)
 
 
 class HeapModel:
@@ -116,121 +114,207 @@ def buf_census(buf):
     return out
 
 
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_event_core_fuzz_vs_heap_model(impl):
-    rng = np.random.default_rng(20260731)
-    H, C = 6, 10
-    buf = ev.evbuf_init(H, C)
-    model = HeapModel(H, C)
-    epoch = 0
-    until_bound = 10_000
-    pkt_ctr = 0
+class Lanes:
+    """``n`` event buffers stepped together: one plain buffer (n == 1, the
+    primitives called directly) or a stacked pytree with the primitives
+    under ``jax.vmap``; jitted either way, as the engines run them. A lane
+    may sit an operation out: it keeps its buffer, so the lanes' operation
+    sequences differ."""
 
-    def do_rebase(e, u):
-        nonlocal buf, epoch
-        epoch = e
+    def __init__(self, n, n_hosts, cap):
+        self.n = n
+        self._jitted = {}
+        one = ev.evbuf_init(n_hosts, cap)
+        self.buf = one if n == 1 else jax.tree.map(
+            lambda x: jnp.stack([x] * n), one)
+
+    def lane(self, i):
+        return self.buf if self.n == 1 else jax.tree.map(
+            lambda x: x[i], self.buf)
+
+    def apply(self, fn, args):
+        """``fn(buf, *args[i]) -> (buf, extra)`` in every lane whose
+        ``args[i]`` is not None; returns each lane's ``extra`` (None where
+        it sat out). Idle lanes are fed a busy lane's arguments and their
+        result is dropped."""
+        if fn not in self._jitted:
+            self._jitted[fn] = jax.jit(fn if self.n == 1 else jax.vmap(fn))
+        run = self._jitted[fn]
+        busy = [a for a in args if a is not None]
+        if self.n == 1:
+            self.buf, extra = run(self.buf, *busy[0])
+            return [extra]
+        cols = zip(*[a if a is not None else busy[0] for a in args])
+        new, extra = run(self.buf, *[jnp.stack(c) for c in cols])
+        sel = jnp.asarray([a is not None for a in args])
+        self.buf = jax.tree.map(
+            lambda x, old: jnp.where(
+                sel.reshape((-1,) + (1,) * (x.ndim - 1)), x, old),
+            new, self.buf)
+        return [None if a is None else jax.tree.map(lambda x: x[i], extra)
+                for i, a in enumerate(args)]
+
+
+def _rebase(buf, epoch, until):
+    return ev.rebase(buf, epoch, until), ()
+
+
+def _deliver(buf, *a):
+    buf, n_over, _ = ev.deliver_batch(buf, *a)
+    return buf, n_over
+
+
+N_DELIVER = 7   # deliver batches are padded to this (masked) width
+
+
+@pytest.mark.parametrize("lanes", [1, 2], ids=["solo", "vmap2"])
+def test_event_core_fuzz_vs_heap_model(lanes):
+    H, C = 6, 10
+    until_bound = 10_000
+    rngs = [np.random.default_rng(20260731 + i) for i in range(lanes)]
+    models = [HeapModel(H, C) for _ in range(lanes)]
+    epoch = [0] * lanes
+    pkt_ctr = [0] * lanes
+    bufs = Lanes(lanes, H, C)
+
+    def do_rebase(new_epoch):
         # Engine convention: the eligibility bound is epoch-relative
         # (win_end = win_start + W); pops below use until ≤ e + u.
-        buf = ev.rebase(buf, e, e + u)
+        args = []
+        for i, e in enumerate(new_epoch):
+            if e is not None:
+                epoch[i] = e
+            args.append(None if e is None else
+                        (jnp.int64(e), jnp.int64(e + until_bound)))
+        bufs.apply(_rebase, args)
 
-    do_rebase(0, until_bound)
+    do_rebase([0] * lanes)
     for step in range(300):
-        op = rng.choice(["push", "pop", "pop", "rebase", "deliver",
-                         "pushback"])
-        if op == "push":
-            mask = rng.random(H) < 0.7
-            # Narrow time range forces (time, tb) ties; occasional far
-            # future and past-due (pre-epoch) values exercise the clamps.
-            t = epoch + rng.integers(-50, 200, H)
-            t = np.maximum(t, 0)
-            if rng.random() < 0.1:
-                t = t + 5 * 10**9          # beyond the i32 horizon
-            kind = rng.integers(1, 5, H)
-            p = rng.integers(0, 100, (NP, H))
-            over_m = model.push_local(mask, t, kind, p)
-            buf, over = ev.push_local(
-                buf, jnp.asarray(mask), jnp.asarray(t, jnp.int64),
-                jnp.asarray(kind, jnp.int32), jnp.asarray(p, jnp.int32),
-            ) if impl == "xla" else push_local_fused(
-                buf, jnp.asarray(mask), jnp.asarray(t, jnp.int64),
-                jnp.asarray(kind, jnp.int32), jnp.asarray(p, jnp.int32),
-            )
-            assert np.asarray(over).tolist() == over_m, step
-        elif op == "pop":
-            until = epoch + int(rng.integers(0, until_bound))
-            got = model.pop_until(until)
-            if impl == "xla":
-                buf, pe = ev.pop_until(buf, jnp.int64(until))
-            else:
-                buf, pe = pop_until_fused(buf, jnp.int64(until))
-            for i, exp in enumerate(got):
-                if exp is None:
-                    assert not bool(pe.mask[i]), (step, i)
-                else:
-                    assert bool(pe.mask[i]), (step, i)
-                    assert int(pe.time[i]) == exp[0], (step, i)
-                    assert int(pe.tb[i]) == exp[1], (step, i)
-                    assert int(pe.kind[i]) == exp[2], (step, i)
-                    assert tuple(int(x) for x in pe.p[:, i]) == exp[3]
-        elif op == "pushback":
-            # Re-insert events with EXPLICIT (caller-owned) tie-breaks —
-            # the cpu-model defer/requeue path (events.push_back).
-            mask = rng.random(H) < 0.5
-            t = epoch + rng.integers(0, 300, H)
-            tb = TB_PACKET_BASE + pkt_ctr + np.arange(H)
-            pkt_ctr += H
-            kind = rng.integers(1, 5, H)
-            p = rng.integers(0, 100, (NP, H))
-            over_m = model.push_back(mask, t, tb, kind, p)
-            fn = ev.push_back if impl == "xla" else push_back_fused
-            buf, over = fn(
-                buf, jnp.asarray(mask), jnp.asarray(t, jnp.int64),
-                jnp.asarray(tb, jnp.int64), jnp.asarray(kind, jnp.int32),
-                jnp.asarray(p, jnp.int32),
-            )
-            assert np.asarray(over).tolist() == over_m, step
-        elif op == "rebase":
-            # Epoch only advances (window starts are monotone).
-            do_rebase(epoch + int(rng.integers(0, 300)), until_bound)
-        else:  # deliver (window-granularity: rebase precedes next pops)
-            n = int(rng.integers(1, 8))
-            dst = rng.integers(0, H, n)
-            t = epoch + rng.integers(0, 500, n)
-            tb = TB_PACKET_BASE + np.arange(pkt_ctr, pkt_ctr + n)
-            pkt_ctr += n
-            kind = rng.integers(1, 5, n)
-            p = rng.integers(0, 100, (NP, n))
-            mask = rng.random(n) < 0.9
-            n_over_m = model.deliver(dst, t, tb, kind, p, mask)
-            buf, n_over, _ = ev.deliver_batch(
-                buf, jnp.asarray(dst, jnp.int32), jnp.asarray(t, jnp.int64),
-                jnp.asarray(tb, jnp.int64), jnp.asarray(kind, jnp.int32),
-                jnp.asarray(p, jnp.int32), jnp.asarray(mask),
-            )
-            assert int(n_over) == n_over_m, step
-            do_rebase(epoch, until_bound)
+        ops = [rng.choice(["push", "pop", "pop", "rebase", "deliver",
+                           "pushback"]) for rng in rngs]
+        for op in dict.fromkeys(ops):
+            on = [i for i in range(lanes) if ops[i] == op]
+            args = [None] * lanes
+            want = [None] * lanes
+            if op == "push":
+                for i in on:
+                    rng = rngs[i]
+                    mask = rng.random(H) < 0.7
+                    # Narrow time range forces (time, tb) ties; occasional
+                    # far future and past-due (pre-epoch) values exercise
+                    # the clamps.
+                    t = np.maximum(epoch[i] + rng.integers(-50, 200, H), 0)
+                    if rng.random() < 0.1:
+                        t = t + 5 * 10**9          # beyond the i32 horizon
+                    kind = rng.integers(1, 5, H)
+                    p = rng.integers(0, 100, (NP, H))
+                    want[i] = models[i].push_local(mask, t, kind, p)
+                    args[i] = (jnp.asarray(mask), jnp.asarray(t, jnp.int64),
+                               jnp.asarray(kind, jnp.int32),
+                               jnp.asarray(p, jnp.int32))
+                over = bufs.apply(ev.push_local, args)
+                for i in on:
+                    assert np.asarray(over[i]).tolist() == want[i], (step, i)
+            elif op == "pop":
+                for i in on:
+                    until = epoch[i] + int(rngs[i].integers(0, until_bound))
+                    want[i] = models[i].pop_until(until)
+                    args[i] = (jnp.int64(until),)
+                popped = bufs.apply(ev.pop_until, args)
+                for i in on:
+                    pe = popped[i]
+                    for h, exp in enumerate(want[i]):
+                        if exp is None:
+                            assert not bool(pe.mask[h]), (step, i, h)
+                        else:
+                            assert bool(pe.mask[h]), (step, i, h)
+                            assert int(pe.time[h]) == exp[0], (step, i, h)
+                            assert int(pe.tb[h]) == exp[1], (step, i, h)
+                            assert int(pe.kind[h]) == exp[2], (step, i, h)
+                            assert tuple(int(x) for x in pe.p[:, h]) == exp[3]
+            elif op == "pushback":
+                # Re-insert events with EXPLICIT (caller-owned) tie-breaks —
+                # the cpu-model defer/requeue path (events.push_back).
+                for i in on:
+                    rng = rngs[i]
+                    mask = rng.random(H) < 0.5
+                    t = epoch[i] + rng.integers(0, 300, H)
+                    tb = TB_PACKET_BASE + pkt_ctr[i] + np.arange(H)
+                    pkt_ctr[i] += H
+                    kind = rng.integers(1, 5, H)
+                    p = rng.integers(0, 100, (NP, H))
+                    want[i] = models[i].push_back(mask, t, tb, kind, p)
+                    args[i] = (jnp.asarray(mask), jnp.asarray(t, jnp.int64),
+                               jnp.asarray(tb, jnp.int64),
+                               jnp.asarray(kind, jnp.int32),
+                               jnp.asarray(p, jnp.int32))
+                over = bufs.apply(ev.push_back, args)
+                for i in on:
+                    assert np.asarray(over[i]).tolist() == want[i], (step, i)
+            elif op == "rebase":
+                # Epoch only advances (window starts are monotone).
+                do_rebase([epoch[i] + int(rngs[i].integers(0, 300))
+                           if i in on else None for i in range(lanes)])
+            else:  # deliver (window-granularity: rebase precedes next pops)
+                for i in on:
+                    rng = rngs[i]
+                    n = N_DELIVER
+                    mask = (rng.random(n) < 0.9) & (
+                        np.arange(n) < rng.integers(1, n + 1))
+                    dst = rng.integers(0, H, n)
+                    t = epoch[i] + rng.integers(0, 500, n)
+                    tb = TB_PACKET_BASE + np.arange(pkt_ctr[i], pkt_ctr[i] + n)
+                    pkt_ctr[i] += n
+                    kind = rng.integers(1, 5, n)
+                    p = rng.integers(0, 100, (NP, n))
+                    want[i] = models[i].deliver(dst, t, tb, kind, p, mask)
+                    args[i] = (jnp.asarray(dst, jnp.int32),
+                               jnp.asarray(t, jnp.int64),
+                               jnp.asarray(tb, jnp.int64),
+                               jnp.asarray(kind, jnp.int32),
+                               jnp.asarray(p, jnp.int32), jnp.asarray(mask))
+                n_over = bufs.apply(_deliver, args)
+                for i in on:
+                    assert int(n_over[i]) == want[i], (step, i)
+                do_rebase([epoch[i] if i in on else None
+                           for i in range(lanes)])
 
-    assert buf_census(buf) == model.census()
+    for i in range(lanes):
+        assert buf_census(bufs.lane(i)) == models[i].census(), i
 
 
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_n_elig_counter_matches_plane_scan(impl):
+@pytest.mark.parametrize("lanes", [1, 2], ids=["solo", "vmap2"])
+def test_n_elig_counter_matches_plane_scan(lanes):
     """After an arbitrary op sequence the maintained eligibility counters
     equal a fresh plane scan (the invariant any_eligible/compaction rely
-    on) — for both implementations."""
-    rng = np.random.default_rng(7)
+    on) — in every lane, the lanes pushing back and popping at different
+    steps."""
+    rngs = [np.random.default_rng(7 + i) for i in range(lanes)]
     H, C = 5, 8
-    buf = ev.evbuf_init(H, C)
-    buf = ev.rebase(buf, 0, 1000)
+    bufs = Lanes(lanes, H, C)
+    bufs.apply(_rebase, [(jnp.int64(0), jnp.int64(1000))] * lanes)
     k = jnp.full(H, 1, jnp.int32)
-    push = ev.push_local if impl == "xla" else push_local_fused
-    pop = ev.pop_until if impl == "xla" else pop_until_fused
-    for _ in range(40):
-        m = jnp.asarray(rng.random(H) < 0.6)
-        t = jnp.asarray(rng.integers(0, 2000, H), jnp.int64)  # some inelig
-        buf, _ = push(buf, m, t, k, jnp.zeros((NP, H), jnp.int32))
-        if rng.random() < 0.5:
-            buf, _ = pop(buf, jnp.int64(1000))
-        scan = ((np.asarray(buf.kind) != 0)
-                & (np.asarray(buf.t32) < int(buf.u32))).sum(axis=0)
-        assert np.asarray(buf.n_elig).tolist() == scan.tolist()
+    for step in range(40):
+        bufs.apply(ev.push_local, [
+            (jnp.asarray(rng.random(H) < 0.6),
+             jnp.asarray(rng.integers(0, 2000, H), jnp.int64),  # some inelig
+             k, jnp.zeros((NP, H), jnp.int32)) for rng in rngs])
+        # push_back keeps the counters too (the cpu model's requeue): every
+        # few steps, in the lanes that draw it.
+        backs = [(jnp.asarray(rng.random(H) < 0.5),
+                  jnp.asarray(rng.integers(0, 2000, H), jnp.int64),
+                  jnp.asarray(TB_PACKET_BASE + step * H + np.arange(H),
+                              jnp.int64),
+                  k, jnp.zeros((NP, H), jnp.int32))
+                 if rng.random() < 0.3 else None for rng in rngs]
+        if any(a is not None for a in backs):
+            bufs.apply(ev.push_back, backs)
+        pops = [(jnp.int64(1000),) if rng.random() < 0.5 else None
+                for rng in rngs]
+        if any(a is not None for a in pops):
+            bufs.apply(ev.pop_until, pops)
+        for i in range(lanes):
+            buf = bufs.lane(i)
+            scan = ((np.asarray(buf.kind) != 0)
+                    & (np.asarray(buf.t32) < int(buf.u32))).sum(axis=0)
+            assert np.asarray(buf.n_elig).tolist() == scan.tolist(), i

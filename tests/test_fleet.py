@@ -306,6 +306,20 @@ def test_fleet_accepts_auto_caps_and_retry():
     assert eng.params.on_overflow == "retry"
 
 
+def test_fleet_given_compact_cap_warns_and_runs_full_width(fleet_run):
+    """``compact_cap`` under vmap would run both branches of its cond every
+    window, so the fleet says so, drops it, and runs the program it runs
+    without the knob — lane for lane the shared run's counters."""
+    plan, _, ref = fleet_run
+    with pytest.warns(UserWarning, match="fleet mode ignores compact_cap"):
+        eng = FleetEngine(plan.exps,
+                          dataclasses.replace(plan.params, compact_cap=8),
+                          plan.max_rounds)
+    assert eng.params == plan.params     # compact_cap 0, nothing else moved
+    st = eng.run(n_windows=N_WINDOWS)
+    assert FleetEngine.metrics_per_exp(st) == FleetEngine.metrics_per_exp(ref)
+
+
 def test_fleet_halt_names_the_overflowing_experiment():
     """on_overflow=halt under --fleet: the boundary check runs per
     experiment and the structured error names the lane (and seed) whose

@@ -51,7 +51,7 @@ def test_native_matches_oracle(n_threads):
 # --------------------------------------------------------------------------
 # Net-model comparator (round 4): full virtual-TCP stack + model apps.
 # Counter equality against the oracle on every app family is what entitles
-# bench_ladder to quote vs_cpp on the net rungs (VERDICT r3 missing #3).
+# a benchmark to quote vs_cpp on the net rungs (VERDICT r3 missing #3).
 # --------------------------------------------------------------------------
 NET_KEYS = (
     "events", "pkts_sent", "pkts_delivered", "pkts_lost", "ev_overflow",

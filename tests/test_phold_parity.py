@@ -38,38 +38,6 @@ def test_phold_parity(loss):
                                "pkts_lost"))
 
 
-def test_phold_pallas_pop_parity():
-    """The fused Pallas pop (EngineParams.pop_impl="pallas", interpret mode
-    on the CPU test platform) leaves the full engine bit-identical to the
-    XLA pop across a complete PHOLD run — metrics and per-host hops."""
-    exp = make_exp(n_hosts=8, end=300 * MS)
-    a = Engine(exp, EngineParams(ev_cap=32, outbox_cap=32))
-    b = Engine(exp, EngineParams(ev_cap=32, outbox_cap=32,
-                                 pop_impl="pallas"))
-    sa, sb = a.run(), b.run()
-    assert Engine.metrics_dict(sa) == Engine.metrics_dict(sb)
-    np.testing.assert_array_equal(
-        np.asarray(a.model_summary(sa)["hops"]),
-        np.asarray(b.model_summary(sb)["hops"]),
-    )
-
-
-def test_phold_pallas_push_parity():
-    """The fused Pallas push/outbox-append (EngineParams.push_impl="pallas")
-    is likewise engine-level bit-exact (trace-scoped dispatch,
-    events.push_impl_ctx; PHOLD exercises outbox_append every round)."""
-    exp = make_exp(n_hosts=8, end=300 * MS)
-    a = Engine(exp, EngineParams(ev_cap=32, outbox_cap=32))
-    b = Engine(exp, EngineParams(ev_cap=32, outbox_cap=32,
-                                 pop_impl="pallas", push_impl="pallas"))
-    sa, sb = a.run(), b.run()
-    assert Engine.metrics_dict(sa) == Engine.metrics_dict(sb)
-    np.testing.assert_array_equal(
-        np.asarray(a.model_summary(sa)["hops"]),
-        np.asarray(b.model_summary(sb)["hops"]),
-    )
-
-
 def test_phold_seed_determinism():
     exp = make_exp(seed=123)
     e1 = Engine(exp)

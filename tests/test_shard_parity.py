@@ -60,25 +60,6 @@ def test_phold_sharded_parity():
     assert_same(m1, s1, m8, s8, summary_keys=("hops",))
 
 
-def test_phold_sharded_parity_pallas():
-    """The fused Pallas pop/push/outbox kernels inside shard_map on the
-    8-device mesh (interpret mode on CPU): prerequisite for ever flipping
-    the pop_impl/push_impl defaults — the driver's multichip gate and the
-    sharded engine must run them, not just the single-device path."""
-    exp = single_vertex_experiment(
-        n_hosts=64,
-        seed=7,
-        end_time=50 * MS,
-        latency_ns=1 * MS,
-        model="phold",
-        model_cfg={"mean_delay_ns": float(2 * MS), "init_events": 2},
-    )
-    params = EngineParams(pop_impl="pallas", push_impl="pallas")
-    m1, s1, m8, s8 = run_pair(exp, params)
-    assert m1["events"] > 500
-    assert_same(m1, s1, m8, s8, summary_keys=("hops",))
-
-
 def test_x2x_bucket_overflow_is_counted():
     """A deliberately tiny all_to_all bucket must DROP (not corrupt), count
     every dropped packet in x2x_overflow, and fail loudly by default."""
