@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from shadow1_tpu.core.dense import extract_col, onehot_col
+
 _U64 = jnp.uint64
 
 # splitmix64 finalizer constants (public domain, Stafford mix13).
@@ -107,6 +109,47 @@ _LOG_TBL_NP = np.round(
 ).astype(np.uint64)
 _LN2_Q32 = np.uint64(round(np.log(2.0) * 2 ** 32))
 
+# The table as it is read: idx ≤ 2^_LOG_BITS − 1, so only tbl[:-1] is ever
+# ``lo`` (all < 2^32; the 33-bit tbl[-1] = 2^32 is only ever ``hi``) and
+# hi − lo = diff(tbl)[idx] < 2^21. Two u32 words at ONE index, cut into
+# seven byte planes (4 of lo, 3 of hi − lo) and laid out for the two-level
+# read of ``_log_tbl_read``: idx = a·_LOG_B + b, rows (plane, b), columns a.
+_LOG_B_BITS = 4
+_LOG_B = 2 ** _LOG_B_BITS
+_LOG_A = 2 ** (_LOG_BITS - _LOG_B_BITS)
+_LOG_BYTES_NP = np.stack(
+    [(t >> np.uint64(8 * i)) & np.uint64(0xFF)
+     for t, n in ((_LOG_TBL_NP[:-1], 4), (np.diff(_LOG_TBL_NP), 3))
+     for i in range(n)]
+).reshape(7, _LOG_A, _LOG_B).swapaxes(1, 2).reshape(7 * _LOG_B, _LOG_A)
+
+
+def _log_tbl_read(idx: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """``(tbl[idx], tbl[idx + 1] − tbl[idx])`` as u32, without a gather.
+
+    On the v5e XLA walks a gather one element at a time, 7 ns each here:
+    1.87 ms of dense PHOLD's 5.99 ms round (PERF.md §6, PR 31). So the read
+    is core/dense's one-hot read (a one-hot over the sublane axis, elements
+    on lanes), in two levels so that the one-hots are _LOG_A + _LOG_B tall,
+    not 4096: level 1 picks row-block ``a`` for every ``b`` at once, a
+    matmul of the byte planes with ``onehot(a)``; level 2 is ``extract_col``
+    at ``b``. Exact on any backend: each sum has one non-zero term, a byte.
+    XLA:TPU makes one fusion of ``onehot(a)``, the matmul and the reduce
+    (nothing [rows, N] wide is stored); 256 × 16 is the fastest split at
+    N = 65,536 and no slower than the gather at N = 100 (PERF.md §6, PR 31)."""
+    shape = idx.shape
+    idx = idx.reshape(-1)
+    rows = jnp.dot(
+        jnp.asarray(_LOG_BYTES_NP, jnp.bfloat16),
+        onehot_col(idx >> _LOG_B_BITS, _LOG_A).astype(jnp.bfloat16),
+        preferred_element_type=jnp.float32,
+    ).reshape(7, _LOG_B, -1)
+    b0, b1, b2, b3, d0, d1, d2 = extract_col(
+        onehot_col(idx & (_LOG_B - 1), _LOG_B), rows).astype(jnp.uint32)  # [7, N]
+    lo = b0 | (b1 << 8) | (b2 << 16) | (b3 << 24)
+    d = d0 | (d1 << 8) | (d2 << 16)
+    return lo.reshape(shape), d.reshape(shape)
+
 
 def _neg_log1m_q32(b: jax.Array) -> jax.Array:
     """u32 bits → Q32 fixed-point −ln(1 − b/2^32), exact integer pipeline."""
@@ -116,10 +159,8 @@ def _neg_log1m_q32(b: jax.Array) -> jax.Array:
     frac = (m << np.uint64(1)) >> np.uint64(1)              # low 63 = fraction
     idx = (frac >> np.uint64(63 - _LOG_BITS)).astype(jnp.int32)
     rem = (frac >> np.uint64(63 - _LOG_BITS - 24)) & np.uint64((1 << 24) - 1)
-    tbl = jnp.asarray(_LOG_TBL_NP, _U64)
-    lo = tbl[idx]
-    hi = tbl[idx + 1]
-    log2_frac_q32 = lo + (((hi - lo) * rem) >> np.uint64(24))
+    lo, d = _log_tbl_read(idx)                              # d = hi − lo
+    log2_frac_q32 = lo.astype(_U64) + ((d.astype(_U64) * rem) >> np.uint64(24))
     log2_x_q32 = (k << np.uint64(32)) + log2_frac_q32
     e2_q32 = (np.uint64(32) << np.uint64(32)) - log2_x_q32  # (32 − log2 x)
     # × ln2 at Q27 (e2 ≤ 2^37, so the product stays under 2^64; ln2's Q27

@@ -9,9 +9,10 @@ or above C, eagerly, under ``jit`` and under ``vmap`` (what the fleet engine
 compiles).
 
 Shape of the program: in the ``rounds`` phase of a TCP model, solo and under
-``vmap`` over two lanes, no ``gather`` equation comes from ``_tcp_flush``.
-On the v5e such a gather is an element-serial kCustom fusion, 12–13.5 ns an
-element (PERF.md §6, PR 26).
+``vmap`` over two lanes, no ``gather`` equation comes from ``_tcp_flush``,
+and in PHOLD's and tgen's none from ``rng._neg_log1m_q32``. On the v5e such
+a gather is an element-serial kCustom fusion, 7–13.5 ns an element (PERF.md
+§6, PR 26 and PR 31).
 """
 
 from __future__ import annotations
@@ -105,33 +106,58 @@ def _functions(eqn) -> set[str]:
     return set() if tb is None else {f.function_name for f in tb.frames}
 
 
-@pytest.fixture(scope="module")
-def tcp_rounds():
-    """(rounds-phase fn, its frame) for rung1_filexfer — the jaxpr
+def _rounds(config: str):
+    """(rounds-phase fn, its frame) for a file under configs/ — the jaxpr
     tools/opcensus.py traces."""
     from shadow1_tpu.core.engine import window_frame, window_phases
     from shadow1_tpu.tools.phaseprobe import build_engine
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    eng, _ = build_engine(os.path.join(root, "configs", "rung1_filexfer.yaml"))
+    eng, _ = build_engine(os.path.join(root, "configs", config))
     phases = dict(window_phases(eng.ctx, eng._handlers, None, eng._pre_window,
                                 eng._model.make_handlers, None))
     return phases["rounds"], window_frame(eng.init_state(), eng.ctx)
 
 
-@pytest.mark.parametrize("lanes", [0, 2], ids=["solo", "vmap2"])
-def test_tcp_flush_has_no_gather(tcp_rounds, lanes):
+def _eqns_from(rounds, lanes: int, function: str):
+    """(primitive, frames' function names) of every equation of the phase,
+    solo or under ``vmap`` over ``lanes``, that ``function`` is a frame of."""
     from shadow1_tpu.tools.opcensus import iter_eqns
 
-    fn, fr = tcp_rounds
+    fn, fr = rounds
     if lanes:
         fn = jax.vmap(fn)
         fr = jax.tree_util.tree_map(lambda x: jnp.stack([x] * lanes), fr)
-    flush = [(e.primitive.name, fns)
-             for e in iter_eqns(jax.make_jaxpr(fn)(fr).jaxpr)
-             if "_tcp_flush" in (fns := _functions(e))]
+    return [(e.primitive.name, fns)
+            for e in iter_eqns(jax.make_jaxpr(fn)(fr).jaxpr)
+            if function in (fns := _functions(e))]
+
+
+@pytest.fixture(scope="module")
+def tcp_rounds():
+    return _rounds("rung1_filexfer.yaml")
+
+
+@pytest.mark.parametrize("lanes", [0, 2], ids=["solo", "vmap2"])
+def test_tcp_flush_has_no_gather(tcp_rounds, lanes):
+    flush = _eqns_from(tcp_rounds, lanes, "_tcp_flush")
     # The guard can see the flush: its reads are there, as one-hot reduces.
     assert len(flush) > 1000
     assert any("extract_col" in fns for _, fns in flush)
     n_gather = sum(prim == "gather" for prim, _ in flush)
     assert n_gather == 0, f"{n_gather} gather eqns traced from _tcp_flush"
+
+
+@pytest.fixture(scope="module", params=["serve_phold.yaml", "rung2_tgen100.yaml"],
+                ids=["phold", "tgen100"])
+def draw_rounds(request):
+    return _rounds(request.param)
+
+
+@pytest.mark.parametrize("lanes", [0, 2], ids=["solo", "vmap2"])
+def test_exponential_draw_has_no_gather(draw_rounds, lanes):
+    draw = _eqns_from(draw_rounds, lanes, "_neg_log1m_q32")
+    # The guard can see the draw: its table read is there, as a matmul.
+    assert any("_log_tbl_read" in fns and prim == "dot_general" for prim, fns in draw)
+    n_gather = sum(prim == "gather" for prim, _ in draw)
+    assert n_gather == 0, f"{n_gather} gather eqns traced from _neg_log1m_q32"
