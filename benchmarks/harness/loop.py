@@ -12,7 +12,8 @@ first cycle's are held against the reference's, counter for counter.
 Each cycle starts from an initial state made anew between cycles, outside the
 clock: the window's wall is the sum of its cycles', and the run never holds a
 state that a user's run would not (the state going into a chunk and the one
-coming out).
+coming out). What the check reads of a cycle's end is fetched to the host
+between cycles, off the clock too, and nothing of it stays on the device.
 """
 
 from __future__ import annotations
@@ -147,33 +148,35 @@ def _window(sim, fresh, c: dict, seconds: float, trace_dir: str | None):
     """Whole cycles, each from ``fresh()``, until their summed wall reaches
     ``seconds`` (one cycle when tracing, the profiler on over the traced
     chunks). Returns every cycle's final metrics, what the check reads of
-    the first cycle's end, the metrics at the traced stretch's end, the
+    the first cycle's end (both as host copies, fetched between cycles off
+    the clock), the metrics at the traced stretch's start and end, the
     summed wall of the cycles, and every chunk's wall (to find a stall by)."""
     import jax
 
     t_from, t_to = c["traced"]
-    finals, kept, traced_end, wall, chunk_walls = [], None, None, 0.0, []
+    finals, kept, traced, wall, chunk_walls = [], None, [], 0.0, []
     while True:
         st = fresh()
         jax.block_until_ready(st)
         t0 = at = time.perf_counter()
         for done in range(0, c["cycle"], c["chunk"]):
             if trace_dir and done == t_from:
+                traced.append(jax.device_get(st.metrics))
                 shutil.rmtree(trace_dir, ignore_errors=True)
                 jax.profiler.start_trace(trace_dir)
             st = run_chunk(sim, st, c["chunk"])
             if trace_dir and done + c["chunk"] == t_to:
                 jax.profiler.stop_trace()
-                traced_end = jax.device_get(st.metrics)
+                traced.append(jax.device_get(st.metrics))
             at, was = time.perf_counter(), at
             chunk_walls.append(at - was)
         wall += at - t0
-        finals.append(st.metrics)
+        finals.append(jax.device_get(st.metrics))
         if kept is None:
             kept = sim.keep(st)
         del st
         if trace_dir or wall >= seconds:
-            return finals, kept, traced_end, wall, chunk_walls
+            return finals, kept, traced, wall, chunk_walls
 
 
 def loop_rounds(per_window) -> int:
@@ -217,17 +220,18 @@ def _check(sim, kept, finals, seeds, ref_exps, ref_params, c: dict):
     against the reference. Prints each number compared beside the
     reference's; the limit on every difference, and on every counter under
     ``must_be_zero``, is 0. Returns the lanes' counters, how many lanes
-    failed, and the reference's wall seconds."""
-    import jax
-
+    failed, the reference's wall seconds, and every number compared as
+    ``{name: [number, limit]}``: per counter the largest difference from the
+    reference over the lanes, per ``must_be_zero`` counter its largest
+    value, and the cycles that ended on other counters than the first."""
     from benchmarks.reference import comparator
 
     lanes = sim.lane_counters(kept)
-    finals = jax.device_get(finals)
-    same = all(all((a == b).all() for a, b in zip(finals[0], f))
-               for f in finals[1:])
+    unlike = sum(not all((a == b).all() for a, b in zip(finals[0], f))
+                 for f in finals[1:])
+    same = not unlike
     _say(cycles=len(finals), every_cycle_ends_on_the_same_counters=same)
-    ref_wall, failed = 0.0, 0
+    ref_wall, failed, worst = 0.0, 0, {}
     for e, have in enumerate(lanes):
         ref_seed = seeds[e] + int(c["control"].get("reference_seed_offset", 0))
         ref = comparator.counters(ref_exps[e], ref_params, ref_seed, c["cycle"])
@@ -238,6 +242,13 @@ def _check(sim, kept, finals, seeds, ref_exps, ref_params, c: dict):
         dropped = {k: have[k] for k in c["meta"]["must_be_zero"] if have.get(k)}
         bad = bool(differ or dropped or not same)
         failed += bad
+        for k, (a, b) in compared.items():
+            # A counter the engine does not have differs by all of it.
+            gap = abs(a - b) if a is not None else max(1, abs(b))
+            worst[k] = max(worst.get(k, 0), gap)
+        for k in c["meta"]["must_be_zero"]:
+            name = k + ".must_be_zero"
+            worst[name] = max(worst.get(name, 0), have.get(k, 0))
         # How near the run came to a cap (never compared: a run that
         # reaches one shows in must_be_zero).
         gauges = {k: have[k] for k in ("rounds", "ev_max_fill", "ob_max_fill")
@@ -251,7 +262,8 @@ def _check(sim, kept, finals, seeds, ref_exps, ref_params, c: dict):
                   f"differ {({k: compared[k] for k in differ})}, must be zero "
                   f"{dropped}, gauges {gauges}, cycles end alike {same}",
                   file=sys.stderr, flush=True)
-    return lanes, failed, ref_wall
+    worst["cycles_ending_unlike_the_first"] = unlike
+    return lanes, failed, ref_wall, {k: [v, 0] for k, v in worst.items()}
 
 
 def _prime_cache(c: dict, doc: dict, base_dir: str, prime_seeds) -> None:
@@ -300,6 +312,7 @@ def main(argv, root: str, started: float, require_chip: bool = True) -> int:
         import jax
         import shadow1_tpu  # noqa: F401  (x64 on, before any jax array)
 
+        from benchmarks.harness import phases
         from benchmarks.harness import sim as simmod
         from benchmarks.harness import trace as tr
         from benchmarks.harness.meter import CompileMeter
@@ -324,7 +337,10 @@ def main(argv, root: str, started: float, require_chip: bool = True) -> int:
     prime_seed = (c["meta"].get("compile_cache") or {}).get("written_under_seed")
     if prime_seed is not None and not args.control:
         with spans.span("prime"):
-            _prime_cache(c, doc, base_dir, simmod.lane_seeds(traffic, prime_seed))
+            # Under that seed itself, whatever the mix makes of ``--seed``
+            # (a pool's run would otherwise warm the cache for itself).
+            _prime_cache(c, doc, base_dir,
+                         [int(prime_seed) + i for i in range(len(seeds))])
     with spans.span("build"):
         sim = simmod.build(simmod.merge(doc, control.get("program") or {}),
                            base_dir, engine_kind, seeds)
@@ -351,7 +367,7 @@ def main(argv, root: str, started: float, require_chip: bool = True) -> int:
 
     # ---- the measured window, then the check outside it ------------------
     trace_dir = os.path.join(root, ".bench_trace") if args.trace else None
-    finals, kept, traced_end, window_s, chunk_walls = _window(
+    finals, kept, traced, window_s, chunk_walls = _window(
         sim, fresh, c, args.seconds, trace_dir)
     in_window = {k: v - setup[k] for k, v in meter.snapshot().items()}
     meter.close()
@@ -361,8 +377,8 @@ def main(argv, root: str, started: float, require_chip: bool = True) -> int:
               f"({in_window}); the warm-up does not cover what the window "
               "runs. No result.", file=sys.stderr)
         return EXIT_COMPILED_IN_WINDOW
-    lanes, failed, ref_wall = _check(sim, kept, finals, seeds, ref_exps,
-                                     ref_params, c)
+    lanes, failed, ref_wall, compared = _check(sim, kept, finals, seeds,
+                                               ref_exps, ref_params, c)
     events = len(finals) * sum(ln["events"] for ln in lanes)
     windows = len(finals) * c["cycle"]
     _say(windows_run=windows, window_wall_s=window_s, chunk_walls_s=chunk_walls,
@@ -375,17 +391,22 @@ def main(argv, root: str, started: float, require_chip: bool = True) -> int:
     dev = {**device, "memory_peak_bytes": peak}
     result = {"correct": failed == 0, "attempted": sim.lanes, "failed": failed}
     if args.trace:
-        raw = tr.read_xplane(trace_dir)
-        if args.keep_trace:
-            import gzip
-
-            with gzip.open(args.keep_trace, "wt") as f:
-                json.dump(raw, f)
+        after = _Spans()    # what a traced run costs after its window
+        with after.span("read_capture"):
+            raw = tr.read_xplane(trace_dir)
         shutil.rmtree(trace_dir, ignore_errors=True)
         red = tr.reduce(raw)
         # The round loop's iterations in the traced chunks: per window, the
         # slowest lane's rounds (a fleet's loop is one while over all lanes).
-        per_window = _replay_rounds(sim, c, traced_end)
+        with after.span("replay_rounds"):
+            per_window = _replay_rounds(sim, c, traced[1])
+        # Device time by phase: the join of the op line with the compiled
+        # program's scopes (the executable is in memory; nothing recompiles).
+        with after.span("phase_table"):
+            table = phases.phase_table(sim.engine.hlo_text())
+        with after.span("phase_report"):
+            report = phases.phase_report(raw, table)
+            gaps = phases.gap_report(raw, table)
         counters = {"rounds": loop_rounds(per_window),
                     "windows": len(per_window)}
         counters.update(
@@ -394,7 +415,8 @@ def main(argv, root: str, started: float, require_chip: bool = True) -> int:
             compile_seconds=setup["seconds"],
             persistent_cache_misses=setup["persistent_misses"],
             persistent_cache_hits=setup["persistent_hits"],
-            hbm_bytes_per_s=peaks.get(device["kind"], {}).get("hbm_bytes_per_s"))
+            hbm_bytes_per_s=peaks.get(device["kind"], {}).get("hbm_bytes_per_s"),
+            **phases.counters_of(raw, report, table, *traced))
         values = _layer_values(root, c, red, counters, spans.seconds)
         dev.update(busy_s=red.busy_ns / 1e9, window_s=red.window_ns / 1e9)
         result["breakdown"] = {"device_ops": red.device_ops,
@@ -404,7 +426,19 @@ def main(argv, root: str, started: float, require_chip: bool = True) -> int:
              lane_rounds_per_window=per_window,
              # The trace's own count of the loop: an op of the round body
              # that no branch guards runs once an iteration.
-             op_names_seen_once_a_round=tr.names_seen(raw, counters["rounds"]))
+             op_names_seen_once_a_round=tr.names_seen(raw, counters["rounds"]),
+             after_the_window_s=after.seconds)
+        _say(phases=report["rows"], rollup=report["rollup"],
+             phases_busy_s=report["busy_s"], unknown_ops=report["unknown_ops"],
+             inherited_s=report["inherited_s"], table_instructions=len(table),
+             program_spans=sorted({n for n, _, _ in tr.program_spans(raw)}),
+             fires_by_lane=counters["fires_by_lane"],
+             handler_kinds=counters["handler_kinds"],
+             **gaps)
+        if args.keep_trace:
+            phases.keep(args.keep_trace, raw, table, {
+                k: counters[k] for k in ("rounds", "windows", "fires_by_lane",
+                                         "handler_kinds")})
     else:
         window = {"events": events, "wall_s": window_s,
                   "setup_seconds": setup_seconds, "peak_bytes": peak}
@@ -413,6 +447,11 @@ def main(argv, root: str, started: float, require_chip: bool = True) -> int:
                                            m["name"])(window),
                         "unit": m["unit"]}
             for m in mf.metrics_of(c["man"], "end_to_end", cell["name"])}
-    result.update(metrics=values, device=dev)
+    # Every number compared beside its limit: last in the result line, and
+    # the last lines of stderr (all that a check keeps of a refused run).
+    result.update(metrics=values, device=dev, compared=compared)
+    for name, (number, limit) in compared.items():
+        print(f"compared {name} {number} limit {limit}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0

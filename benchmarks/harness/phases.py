@@ -1,118 +1,188 @@
 """Device time by window phase, the program's own spans, and the idle gaps
-named by both: what a traced run can say once the program provides the join.
+named by both: what a traced run says once the program provides the join.
 
-The program (``shadow1_tpu/telemetry/phases.py``) turns the optimized HLO
-text of its window program into a table, instruction name → phase path, and
-attributes a device's op line to it; its chunk loops put their spans into
-any ``jax.profiler`` capture as ``shadow1:<name>``. This file is the
-benchmark's side: attribute a capture to the table (``phase_report``), name
-each of the longest idle gaps by program span, bracketing ops and their
-phases (``gap_report``), and compute the per-layer quantities
-(``layer_values``).
+The program runs every stage of its window under
+``jax.named_scope("phase:<name>")``. A TPU trace does not carry those
+scopes: it names a device op by its HLO text. They are in the compiled
+program, whose every instruction has ``metadata={op_name=".../phase:rounds/
+while/body/phase:pop/add"}``; so the phase of a traced op is a join,
+instruction name → ``op_name``, made here from the text the engine's
+``hlo_text()`` returns (``phase_table``), and ``attribute`` sums a device's
+op line by it. Both are this benchmark's own copies of the arithmetic in
+``shadow1_tpu/telemetry/phases.py`` (the yardstick lives under the
+benchmark's paths; ``tests/test_phase_reading.py`` holds the two to the same
+answer on a capture recorded on the chip). The program's chunk loops put
+their spans into any ``jax.profiler`` capture as ``shadow1:<name>``.
 
-Nothing in ``loop.py`` calls this yet: a PR that is not of the benchmark
-kind may not edit the harness's files, and ``loop.main`` drops the raw trace
-before the readers run (PERF.md §7a3 names the edits that remain). Until it
-is wired in, the same reading is a command, on the chip:
+``loop.main`` calls ``counters_of`` under ``--trace 1`` and hands what it
+returns to the per-layer readers as further keys of ``counters``; the full
+phase table and the named gaps (``gap_report``) go on an earlier line of the
+run's output. The same run is a command of its own, for a capture to keep:
 
     python benchmarks/harness/phases.py --workload <cell> --seed <n>
-
-which sets a cell up as ``run.py`` does, runs its traced stretch once
-untraced and once under the profiler, and prints the phase table, the gaps
-and the quantities as JSON lines.
+        [--root benchmarks/tests/rehearsal] [--keep-trace f.json.gz]
 """
 
 from __future__ import annotations
 
-import argparse
 import json
 import os
-import shutil
-import statistics
+import re
 import sys
 import time
 
 if __name__ == "__main__":      # run as a script from the root of a checkout
+    _STARTED = time.perf_counter()
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))))
 
 from benchmarks.harness import trace as tr  # noqa: E402
 
-# The program's spans are TraceAnnotations under this prefix.
-PROGRAM_PREFIX = "shadow1:"
+# Per lane: rounds in which a handler pass had an event of its kind.
 FIRES = ("fires_pkt", "fires_deliver", "fires_timer", "fires_txr", "fires_app")
 
+PHASE = re.compile(r"phase:(\w+)")
+# `  ROOT %fusion.172 = s32[...] fusion(...), ..., metadata={op_name="..."}`
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
 
-def _program():
-    """The program's ``telemetry.phases`` (imported late: the command sets
-    the compile cache's place before ``shadow1_tpu`` is imported)."""
-    from shadow1_tpu.telemetry import phases
-
-    return phases
-
-
-def read_capture(log_dir: str) -> dict:
-    """``trace.read_xplane`` with the program's spans kept beside the
-    harness's. It keeps a host event by ``startswith(SPAN_PREFIX)``, which
-    takes a tuple as well; this goes when ``read_xplane`` keeps both itself."""
-    from unittest import mock
-
-    with mock.patch.object(tr, "SPAN_PREFIX", (tr.SPAN_PREFIX, PROGRAM_PREFIX)):
-        return tr.read_xplane(log_dir)
+# The fixed roll-up (every row of `attribute` lands in exactly one; `h_<kind>`
+# rows repeat their part of `handlers`).
+PREPARE, POP, HANDLERS, DELIVER, TELEM = ("prepare", "pop", "handlers",
+                                          "deliver", "telem")
+ROUNDS_OTHER = "rounds_other"   # the round loop outside pop and handler passes
+OTHER = "other"                 # a phase: scope this roll-up does not know
+UNATTRIBUTED = "unattributed"   # no phase: on the op nor on what contains it
+OTHER_PROGRAMS = "other_programs"   # ops of another module than the table's
+_DELIVER_PARTS = ("route", "exchange", "deliver")
 
 
-def program_spans(trace: dict) -> list[tuple[str, int, int]]:
-    """The program's host spans as ``(name, start_ns, end_ns)``, prefix
-    removed, in time order."""
-    out = []
-    for p in trace["planes"]:
-        if p["name"].startswith(tr.DEVICE_PLANE):
+def phase_path(op_name: str) -> str:
+    """The ``phase:`` components of an HLO ``op_name`` in order, joined by
+    ``/``; ``""`` where there is none. ``vmap`` wraps a scope
+    (``vmap(phase:rounds)``), so the components are searched for, not split."""
+    return "/".join(PHASE.findall(op_name))
+
+
+def phase_table(hlo_text: str) -> dict[str, str]:
+    """Instruction name → phase path for every instruction of every
+    computation of an optimized HLO module. An instruction with no metadata
+    maps to ``""``."""
+    table: dict[str, str] = {}
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if not m:
             continue
-        for ln in p["lines"]:
-            out += [(n[len(PROGRAM_PREFIX):], s, s + d)
-                    for n, s, d in ln["events"] if n.startswith(PROGRAM_PREFIX)]
-    return sorted(out, key=lambda x: x[1])
+        op = _OP_NAME.search(line)
+        table[m.group(1)] = phase_path(op.group(1)) if op else ""
+    return table
 
 
-def main_executions(plane: dict) -> list[tuple[int, int]]:
-    """Runs of the window program on a device: the module that took most of
-    the time (as ``trace.reduce`` picks it)."""
-    mods = tr._line(plane, tr.MODULES_LINE)
-    total: dict[str, int] = {}
-    for n, _, d in mods:
-        total[n] = total.get(n, 0) + d
-    if not total:
-        return []
-    main = max(total, key=total.get)
-    return sorted((s, s + d) for n, s, d in mods if n == main)
+def rollup_key(path: str) -> tuple[str, str | None]:
+    """The roll-up row of a phase path, and its ``h_<kind>`` sub-row."""
+    if not path:
+        return UNATTRIBUTED, None
+    parts = path.split("/")
+    if parts[0] == "rounds":
+        kinds = [p for p in parts[1:] if p.startswith("h_")]
+        if kinds:
+            return HANDLERS, kinds[0]
+        return (POP, None) if "pop" in parts[1:] else (ROUNDS_OTHER, None)
+    if parts[0] in _DELIVER_PARTS:
+        return DELIVER, None
+    if parts[0] in (PREPARE, TELEM):
+        return parts[0], None
+    return OTHER, None
 
 
-def device_ops(plane: dict) -> list:
-    """The ops of a device's op line by the program's rule
-    (``telemetry.phases.ops``): control flow is told by the instruction's
-    kind. ``trace.leaves`` takes any event for a container whose successor
-    starts before it ends, so a fusion that a zero-length op (an async
-    start, a ``ConcatBitcast``) shares its start timestamp with leaves
-    ``busy`` and shows as an idle gap inside the execution (PERF.md §3)."""
-    return _program().ops(tr._line(plane, tr.OPS_LINE))
+def attribute(events: list, table: dict[str, str],
+              executions: list[tuple[int, int]] | None = None) -> dict:
+    """Device time by phase. ``events`` is one device's op line, control
+    flow included; ``table`` is ``phase_table`` of the program that ran;
+    ``executions`` are the ``(start, end)`` of that program's runs where the
+    trace holds other programs too (their ops go to ``other_programs``:
+    instruction names are unique only inside a module).
+
+    An op (``trace.leaves``' rule) takes the phase path of its instruction;
+    one with no ``phase:`` of its own (a layout copy, a fusion merged across
+    a boundary) inherits the path of the innermost control-flow event that
+    contains it and has one. Ops run one after another on a device's line,
+    so the rows sum to busy exactly, in integer ns (``overlap_ns`` says by
+    how much the ops' intervals overlap: 0).
+
+    Returns ``{"rows": {path: {"seconds", "ops", "instances"}},
+    "rollup": {row: seconds}, "busy_s", "busy_ns", "unknown_ops",
+    "inherited_s", "overlap_ns"}``; ``unknown_ops`` counts the distinct
+    instructions of the program that ran and the table does not hold (0
+    when the table is of the program that ran)."""
+    evs = tr.in_order(events)
+    rows: dict[str, dict] = {}
+    unknown: set[str] = set()
+    stack: list[tuple[int, str]] = []    # (end_ns, own or inherited path)
+    busy, inherited, covered, overlap = 0, 0, 0, 0
+    for i, (name, start, dur) in enumerate(evs):
+        end = start + dur
+        while stack and stack[-1][0] <= start:
+            stack.pop()
+        instr = tr.instruction_name(name)
+        ours = executions is None or any(s <= start < e for s, e in executions)
+        path = table.get(instr, "") if ours else ""
+        own = bool(path)
+        if not path and stack:
+            path = stack[-1][1]
+        if tr.contains_next(evs, i):
+            stack.append((end, path))
+            continue
+        overlap += max(0, min(end, covered) - start)
+        covered = max(covered, end)
+        if not ours:
+            path = OTHER_PROGRAMS
+        elif instr not in table:
+            unknown.add(instr)
+        elif not own and path:
+            inherited += dur
+        row = rows.setdefault(path, {"ns": 0, "names": set(), "instances": 0})
+        row["ns"] += dur
+        row["names"].add(instr)
+        row["instances"] += 1
+        busy += dur
+    rollup = {k: 0 for k in (PREPARE, POP, HANDLERS, ROUNDS_OTHER, DELIVER,
+                             TELEM, OTHER, UNATTRIBUTED, OTHER_PROGRAMS)}
+    for path, row in rows.items():
+        key, kind = ((OTHER_PROGRAMS, None) if path == OTHER_PROGRAMS
+                     else rollup_key(path))
+        rollup[key] += row["ns"]
+        if kind:
+            rollup[kind] = rollup.get(kind, 0) + row["ns"]
+    return {
+        "rows": {p: {"seconds": r["ns"] / 1e9, "ops": len(r["names"]),
+                     "instances": r["instances"]}
+                 for p, r in sorted(rows.items(), key=lambda kv: -kv[1]["ns"])},
+        "rollup": {k: v / 1e9 for k, v in rollup.items()},
+        "busy_s": busy / 1e9,
+        "busy_ns": busy,
+        "unknown_ops": len(unknown),
+        "inherited_s": inherited / 1e9,
+        "overlap_ns": overlap,
+    }
 
 
 def phase_report(trace: dict, table: dict) -> dict:
     """``attribute`` of the first device's op line against ``table``: rows
     by phase path, the roll-up, busy seconds, ``unknown_ops``."""
     plane = tr.device_planes(trace)[0]
-    runs = main_executions(plane)
-    return _program().attribute(tr._line(plane, tr.OPS_LINE), table,
-                                runs or None)
+    return attribute(tr._line(plane, tr.OPS_LINE), table,
+                     tr.main_executions(plane) or None)
 
 
 def execution_idle_ns(plane: dict) -> int | None:
     """Idle time inside executions of the window program: of each run, its
-    duration less the time a leaf op was running in it."""
-    runs = main_executions(plane)
+    duration less the time an op was running in it."""
+    runs = tr.main_executions(plane)
     if not runs:
         return None
-    busy = tr.union([(s, s + d) for _, s, d in device_ops(plane)])
+    busy = tr.union([(s, s + d)
+                     for _, s, d in tr.leaves(tr._line(plane, tr.OPS_LINE))])
     idle = 0
     for r0, r1 in runs:
         inside = sum(min(e, r1) - max(s, r0) for s, e in busy
@@ -124,20 +194,20 @@ def execution_idle_ns(plane: dict) -> int | None:
 def gap_report(trace: dict, table: dict, n: int = 5) -> dict:
     """The ``n`` longest idle gaps of the first device's op line, each as
     ``{span, before, after, phase_before, phase_after, seconds,
-    inside_execution, other_lines}``: the innermost program span open at its
-    middle (else the harness's), the ops on either side by instruction name
+    inside_execution, other_lines}``: the innermost span of the program or
+    the harness open at its middle, the ops on either side by instruction name
     and phase path, and what every other line of the device plane had open
     during it. Also the names of every line the plane has, and under
     ``idle_after`` the ``n`` instructions that idle time follows most:
     ``[instruction, phase, instances, instances followed by idle, seconds]``
     (a gap that recurs is a property of the op before it)."""
     plane = tr.device_planes(trace)[0]
-    runs = main_executions(plane)
-    prog, harness = program_spans(trace), tr.spans(trace)
-    name = _program().instruction_name
+    runs = tr.main_executions(plane)
+    on_host = tr.host_spans(trace)
+    name = tr.instruction_name
     gaps, covered, last = [], None, None
     follows: dict[str, list] = {}    # instruction -> [instances, gaps, ns]
-    for ev in device_ops(plane):
+    for ev in tr.leaves(tr._line(plane, tr.OPS_LINE)):
         if covered is not None and ev[1] > covered:
             gaps.append((ev[1] - covered, covered, last, ev))
             row = follows[name(last[0])]
@@ -149,9 +219,7 @@ def gap_report(trace: dict, table: dict, n: int = 5) -> dict:
     out = []
     for dur, at, before, after in sorted(gaps, key=lambda g: -g[0])[:n]:
         mid = at + dur // 2
-        span = tr.covering_span(prog, mid)
-        if span == tr.NO_SPAN:
-            span = tr.covering_span(harness, mid)
+        span = tr.covering_span(on_host, mid)
         others = {}
         for ln in plane["lines"]:
             if ln["name"] in (tr.OPS_LINE, tr.MODULES_LINE):
@@ -181,83 +249,62 @@ def handler_kinds(table: dict) -> int:
     return len(kinds)
 
 
-def useful_pass_share(fires_by_lane: list[int], rounds: int,
-                      kinds: int) -> float | None:
-    """Mean over lanes of the handler passes that had an event of their kind
-    ÷ the handler passes run (loop iterations × kinds), in %. None where the
-    model has one handler (its pass is not guarded and not counted)."""
-    if kinds < 2 or not rounds or not fires_by_lane:
-        return None
-    return 100.0 * statistics.mean(fires_by_lane) / (rounds * kinds)
-
-
-def layer_values(report: dict, trace: dict, red, counters: dict) -> dict:
-    """The per-layer quantities this reading gives, under the names ISSUE 25
-    gives them. ``counters`` holds ``rounds`` and ``windows`` of the traced
-    stretch (as the harness's), and where known ``fires_by_lane`` and
-    ``handler_kinds``. A quantity with nothing to read is left out."""
-    vals: dict[str, float] = {}
-    rounds, windows = counters.get("rounds"), counters.get("windows")
-    roll = report["rollup"]
-    if windows:
-        vals["prepare_ms_per_window"] = 1e3 * roll["prepare"] / windows
-        vals["deliver_ms_per_window"] = 1e3 * roll["deliver"] / windows
-    if rounds:
-        vals["pop_ms_per_round"] = 1e3 * roll["pop"] / rounds
-        vals["handlers_ms_per_round"] = 1e3 * roll["handlers"] / rounds
-    if report["busy_s"]:
-        vals["phase_unattributed_share"] = (
-            100.0 * roll["unattributed"] / report["busy_s"])
-    idle = execution_idle_ns(tr.device_planes(trace)[0])
-    if idle is not None and red.window_ns:
-        vals["exec_idle_share"] = 100.0 * idle / red.window_ns
-    useful = useful_pass_share(counters.get("fires_by_lane") or [], rounds,
-                               counters.get("handler_kinds", 0))
-    if useful is not None:
-        vals["handler_pass_useful_share"] = useful
-    dispatch = [e - s for n, s, e in program_spans(trace) if n == "dispatch"]
-    if dispatch:
-        vals["dispatch_ms_per_chunk"] = statistics.median(dispatch) / 1e6
-    return vals
-
-
-# ---- the command -----------------------------------------------------------
-
-def _say(**kw) -> None:
-    print(json.dumps(kw), flush=True)
-
-
-def _lane_sums(metrics, names) -> list[int]:
+def lane_sums(metrics, names) -> list[int]:
+    """Per lane, the sum of the counters ``names`` of a fetched ``Metrics``."""
     import numpy as np
 
     return [int(x) for x in sum(np.asarray(getattr(metrics, n)).reshape(-1)
                                 for n in names)]
 
 
-def _keep(path: str, raw: dict, table: dict, counters: dict) -> None:
+def counters_of(trace: dict, report: dict, table: dict,
+                at_from, at_to) -> dict:
+    """What the per-layer readers are handed of this reading, as further
+    keys of ``counters``: ``phase_s`` (the roll-up, seconds by row),
+    ``phase_busy_s``, ``unknown_ops``, ``exec_idle_ns`` (None where the
+    trace has no module line), ``dispatch_ns`` (every ``dispatch`` span of
+    the program), ``handler_kinds`` and ``fires_by_lane`` (per lane, the
+    handler passes that had an event of their kind between the two fetched
+    ``Metrics``, at the traced stretch's start and end)."""
+    return {
+        "phase_s": report["rollup"], "phase_busy_s": report["busy_s"],
+        "unknown_ops": report["unknown_ops"],
+        "exec_idle_ns": execution_idle_ns(tr.device_planes(trace)[0]),
+        "dispatch_ns": [e - s for n, s, e in tr.program_spans(trace)
+                        if n == "dispatch"],
+        "handler_kinds": handler_kinds(table),
+        "fires_by_lane": [b - a for a, b in zip(lane_sums(at_from, FIRES),
+                                                lane_sums(at_to, FIRES))],
+    }
+
+
+def keep(path: str, raw: dict, table: dict, counters: dict) -> None:
     """The capture as a plain dict (an op named by the head of its HLO
-    text), and beside it the table's rows for the instructions it holds."""
+    text), and beside it the table's rows for the instructions it holds
+    and the stretch's counters: how ``tests/data/`` was recorded."""
     import gzip
 
-    name = _program().instruction_name
     for p in raw["planes"]:
         for ln in p["lines"]:
             ln["events"] = [[n[:tr.NAME_CHARS], s, d] for n, s, d in ln["events"]]
     with gzip.open(path, "wt") as f:
         json.dump(raw, f)
-    seen = {name(e[0]) for p in tr.device_planes(raw)
+    seen = {tr.instruction_name(e[0]) for p in tr.device_planes(raw)
             for ln in p["lines"] for e in ln["events"]}
     with open(path.removesuffix(".json.gz") + ".phase_table.json", "w") as f:
         json.dump({"counters": counters, "table": {
             k: v for k, v in sorted(table.items()) if k in seen}}, f, indent=0)
 
 
-def main(argv, root: str) -> int:
+def main(argv, root: str, started: float) -> int:
+    """One traced run of a cell, as ``run.py --trace 1`` makes it."""
+    import argparse
+
     from benchmarks.harness import loop
 
     ap = argparse.ArgumentParser(prog="benchmarks/harness/phases.py")
     ap.add_argument("--workload", required=True)
-    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seed", required=True)
     ap.add_argument("--root", default=root,
                     help="the directory of the BENCHMARK.json to read "
                          "(benchmarks/tests/rehearsal for a small cell)")
@@ -265,90 +312,12 @@ def main(argv, root: str) -> int:
                     help="write the capture, reduced to a plain dict, to "
                          "this .json.gz, and the phase table beside it")
     args = ap.parse_args(argv)
-    args.control, root = None, os.path.abspath(args.root)
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                          os.path.join(root, ".jax_cache"))
-    c = loop._load_cell(root, args)
-
-    import jax
-    import shadow1_tpu  # noqa: F401  (x64 on, before any jax array)
-
-    from benchmarks.harness import sim as simmod
-
-    device = loop._device(int(c["cell"]["chips"]), True)
-    if device is None:
-        return loop.EXIT_NO_CHIP
-    _say(run=c["cell"]["name"], seed=args.seed, **device)
-    doc, base_dir = simmod.experiment_doc(c["cfg_path"], c["meta"], c["traffic"])
-    sim = simmod.build(doc, base_dir, c["meta"]["engine"],
-                       simmod.lane_seeds(c["traffic"], args.seed))
-    jax.block_until_ready(
-        loop.run_chunk(sim, sim.engine.init_state(), c["chunk"]))
-    t_from, t_to = c["traced"]
-    trace_dir = os.path.join(root, ".bench_trace")
-
-    def stretch(traced: bool):
-        """Windows 0..t_to from a fresh state, the stretch t_from..t_to on
-        the clock and, if ``traced``, under the profiler."""
-        st = sim.engine.init_state()
-        jax.block_until_ready(st)
-        for done in range(0, t_to, c["chunk"]):
-            if done == t_from:
-                at_from = jax.device_get(st.metrics)
-                if traced:
-                    shutil.rmtree(trace_dir, ignore_errors=True)
-                    jax.profiler.start_trace(trace_dir)
-                t0 = time.perf_counter()
-            st = loop.run_chunk(sim, st, c["chunk"])
-        wall = time.perf_counter() - t0
-        if traced:
-            jax.profiler.stop_trace()       # writes the capture: seconds
-        return (at_from, jax.device_get(st.metrics), wall,
-                time.perf_counter() - t0 - wall)
-
-    _, _, wall_off, _ = stretch(False)
-    at_from, at_to, wall_on, stop_s = stretch(True)
-    t0 = time.perf_counter()
-    raw = read_capture(trace_dir)
-    read_s = time.perf_counter() - t0
-    shutil.rmtree(trace_dir, ignore_errors=True)
-    t0 = time.perf_counter()
-    table = _program().phase_table(sim.engine.hlo_text())
-    hlo_s = time.perf_counter() - t0
-
-    per_window = loop._replay_rounds(sim, c, at_to)
-    events = (sum(_lane_sums(at_to, ["events"]))
-              - sum(_lane_sums(at_from, ["events"])))
-    fires = [b - a for a, b in zip(_lane_sums(at_from, FIRES),
-                                   _lane_sums(at_to, FIRES))]
-    counters = {"rounds": loop.loop_rounds(per_window),
-                "windows": len(per_window), "fires_by_lane": fires,
-                "handler_kinds": handler_kinds(table)}
-    if args.keep_trace:
-        _keep(args.keep_trace, raw, table, counters)
-    _say(traced_windows=[t_from, t_to], table_instructions=len(table),
-         program_spans=sorted({n for n, _, _ in program_spans(raw)}),
-         **counters)
-    _say(tracing_overhead={
-        "events_in_stretch": events,
-        "stretch_wall_s_trace_off": wall_off, "stretch_wall_s_trace_on": wall_on,
-        "events_per_s_trace_off": events / wall_off,
-        "events_per_s_trace_on": events / wall_on,
-        "after_the_stretch_s": {"stop_trace": stop_s, "read_capture": read_s,
-                                "hlo_text": hlo_s}})
-    red = tr.reduce(raw)
-    report = phase_report(raw, table)
-    _say(busy_s=red.busy_ns / 1e9, window_s=red.window_ns / 1e9,
-         executions=red.executions, phases=report["rows"],
-         rollup=report["rollup"], phases_busy_s=report["busy_s"],
-         unknown_ops=report["unknown_ops"], inherited_s=report["inherited_s"])
-    _say(**gap_report(raw, table))
-    _say(layer_values=layer_values(report, raw, red, counters),
-         breakdown_phases=[[p, r["seconds"]]
-                           for p, r in list(report["rows"].items())[:10]])
-    return 0
+    more = ["--keep-trace", args.keep_trace] if args.keep_trace else []
+    return loop.main(["--workload", args.workload, "--seed", args.seed,
+                      "--seconds", "0", "--trace", "1", *more],
+                     os.path.abspath(args.root), started)
 
 
 if __name__ == "__main__":
     sys.exit(main(sys.argv[1:], os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))))
+        os.path.abspath(__file__)))), _STARTED))

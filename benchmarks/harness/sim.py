@@ -55,25 +55,27 @@ class Sim:
         return len(self.exps)
 
     def keep(self, st):
-        """The part of a state the check reads, holding no plane alive."""
-        return st._replace(evbuf=None, outbox=None, cpu_busy=None)
+        """The part of a state the check reads, as host copies: nothing of
+        it stays on the device once ``st`` is dropped."""
+        import jax
+
+        return jax.device_get(st._replace(evbuf=None, outbox=None,
+                                          cpu_busy=None))
 
     def lane_counters(self, kept) -> list[dict]:
         """Per lane, every scalar the engine counted: its metrics and the
         scalars of the model's summary. ``kept`` is a ``keep()`` result."""
-        import jax
         import numpy as np
 
-        host = jax.device_get(kept)
         out = []
         for e in range(self.lanes):
             if self.fleet:
                 m = {k: int(np.asarray(v)[e])
-                     for k, v in host.metrics._asdict().items()}
-                s = self.engine.model_summary(host, e)
+                     for k, v in kept.metrics._asdict().items()}
+                s = self.engine.model_summary(kept, e)
             else:
-                m = {k: int(v) for k, v in host.metrics._asdict().items()}
-                s = self.engine.model_summary(host)
+                m = {k: int(v) for k, v in kept.metrics._asdict().items()}
+                s = self.engine.model_summary(kept)
             s = {k: int(v) for k, v in s.items() if np.ndim(v) == 0}
             out.append({**s, **m})
         return out

@@ -9,8 +9,11 @@ on the chip:
 
 A TPU's device plane carries a line of XLA ops in which control flow (a
 ``while``, a ``conditional``, a ``call``) is an event that *contains* the ops
-it runs. Busy time is therefore taken over leaf events only: events that
-contain no other event of their line.
+it runs. Busy time is therefore taken over leaf events only: every event but
+the control flow that contains another. Control flow is told by the
+instruction's kind, never by overlap alone: a zero-length op (an async start,
+a ``ConcatBitcast``) can carry the start timestamp of the fusion after it
+and sort behind it, and that fusion is an op, not a container.
 """
 
 from __future__ import annotations
@@ -22,8 +25,12 @@ import os
 DEVICE_PLANE = "/device:"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
-# The harness's own host spans are TraceAnnotations under this prefix.
+# The harness's own host spans are TraceAnnotations under this prefix, the
+# program's (its chunk loops') under the other.
 SPAN_PREFIX = "bench:"
+PROGRAM_PREFIX = "shadow1:"
+# The instructions that contain others on a device's op line.
+CONTROL_FLOW = ("while", "conditional", "call")
 # Where no harness span is open the host is in the loop between two chunks.
 NO_SPAN = "between-chunks"
 # The TPU names an op by its whole HLO text; the breakdown keeps its head.
@@ -36,7 +43,8 @@ class TraceError(RuntimeError):
 
 def read_xplane(log_dir: str) -> dict:
     """The newest ``.xplane.pb`` under ``log_dir`` as a plain dict. Of host
-    planes only the harness's spans are kept: the rest is large and unread."""
+    planes only the harness's spans and the program's are kept: the rest is
+    large and unread."""
     import jax
 
     files = sorted(glob.glob(os.path.join(
@@ -51,7 +59,8 @@ def read_xplane(log_dir: str) -> dict:
         for line in plane.lines:
             events = [[ev.name, int(ev.start_ns), int(ev.duration_ns)]
                       for ev in line.events
-                      if device or ev.name.startswith(SPAN_PREFIX)]
+                      if device or ev.name.startswith((SPAN_PREFIX,
+                                                       PROGRAM_PREFIX))]
             if events:
                 lines.append({"name": line.name, "events": events})
         if lines:
@@ -73,17 +82,37 @@ def _line(plane: dict, name: str) -> list:
     return []
 
 
+def instruction_name(event_name: str) -> str:
+    """The instruction name at the head of a TPU trace event's name (the
+    event is named by the op's HLO text, cut anywhere after the name)."""
+    head = event_name.split(" = ", 1)[0].split("=", 1)[0].strip()
+    return head[1:] if head.startswith("%") else head
+
+
+def is_control_flow(instruction: str) -> bool:
+    """Whether an instruction (by its name, ``while.12``) is one that
+    contains the ops it runs on a device's op line."""
+    return instruction.split(".", 1)[0] in CONTROL_FLOW
+
+
+def contains_next(evs: list, i: int) -> bool:
+    """Whether event ``i`` of an op line sorted by (start, -duration) is
+    control flow that contains the event after it."""
+    name, start, dur = evs[i]
+    return (is_control_flow(instruction_name(name)) and i + 1 < len(evs)
+            and evs[i + 1][1] < start + dur)
+
+
+def in_order(events: list) -> list:
+    """An op line sorted so that a container comes before what it contains."""
+    return sorted(events, key=lambda e: (e[1], -e[2]))
+
+
 def leaves(events: list) -> list:
-    """Events that contain no other event: sorted by start, an event is a
-    parent if the next one starts before it ends."""
-    evs = sorted(events, key=lambda e: (e[1], -e[2]))
-    out = []
-    for i, ev in enumerate(evs):
-        end = ev[1] + ev[2]
-        if i + 1 < len(evs) and evs[i + 1][1] < end:
-            continue
-        out.append(ev)
-    return out
+    """The ops of a device's op line, in time order: every event but the
+    control flow that contains the next."""
+    evs = in_order(events)
+    return [ev for i, ev in enumerate(evs) if not contains_next(evs, i)]
 
 
 def union(intervals: list[tuple[int, int]]) -> list[tuple[int, int]]:
@@ -97,26 +126,50 @@ def union(intervals: list[tuple[int, int]]) -> list[tuple[int, int]]:
     return [(s, e) for s, e in out]
 
 
-def spans(trace: dict) -> list[tuple[str, int, int]]:
-    """The harness's host spans as ``(name, start_ns, end_ns)``, prefix
-    removed, in time order."""
+def spans(trace: dict, prefix: str = SPAN_PREFIX) -> list[tuple[str, int, int]]:
+    """The host spans under ``prefix`` (the harness's own by default) as
+    ``(name, start_ns, end_ns)``, prefix removed, in time order."""
     out = []
     for p in trace["planes"]:
         if p["name"].startswith(DEVICE_PLANE):
             continue
         for ln in p["lines"]:
-            out += [(n[len(SPAN_PREFIX):], s, s + d) for n, s, d in ln["events"]
-                    if n.startswith(SPAN_PREFIX)]
+            out += [(n[len(prefix):], s, s + d) for n, s, d in ln["events"]
+                    if n.startswith(prefix)]
     return sorted(out, key=lambda x: x[1])
 
 
+def host_spans(trace: dict) -> list[tuple[str, int, int]]:
+    """The harness's spans and the program's together: what the host was
+    doing, as far as either says."""
+    return sorted(spans(trace) + program_spans(trace), key=lambda x: x[1])
+
+
 def covering_span(host_spans: list, at_ns: int) -> str:
-    """The innermost harness span open at ``at_ns``."""
+    """The innermost of ``host_spans`` open at ``at_ns``."""
     best = None
     for name, s, e in host_spans:
         if s <= at_ns < e and (best is None or e - s < best[1]):
             best = (name, e - s)
     return best[0] if best else NO_SPAN
+
+
+def program_spans(trace: dict) -> list[tuple[str, int, int]]:
+    """The program's host spans, as ``spans`` gives the harness's."""
+    return spans(trace, PROGRAM_PREFIX)
+
+
+def main_executions(plane: dict) -> list[tuple[int, int]]:
+    """Runs of the window program on a device, ``(start_ns, end_ns)`` in time
+    order: the module that took most of the time."""
+    mods = _line(plane, MODULES_LINE)
+    total: dict[str, int] = {}
+    for n, _, d in mods:
+        total[n] = total.get(n, 0) + d
+    if not total:
+        return []
+    main = max(total, key=total.get)
+    return sorted((s, s + d) for n, s, d in mods if n == main)
 
 
 def names_seen(trace: dict, times: int) -> int:
@@ -138,7 +191,7 @@ class Reduction:
     executions: float         # runs of the window program
     execution_gaps_ns: list   # idle between one run's end and the next's start
     device_ops: list          # [[name, seconds]] by total time, ten
-    idle_gaps: list           # [[harness span, seconds]] longest first, five
+    idle_gaps: list           # [[host span, seconds]] longest first, five
     n_devices: int
 
     @property
@@ -158,16 +211,8 @@ def _reduce_plane(plane: dict, host_spans: list) -> Reduction:
     top = sorted(by_name.items(), key=lambda kv: -kv[1])[:10]
     gaps = sorted(((b[0] - a[1], a[1]) for a, b in zip(busy, busy[1:])),
                   reverse=True)[:5]
-    # Runs of the window program: the module that took most of the time.
-    mods = _line(plane, MODULES_LINE)
-    runs, run_gaps = [], []
-    if mods:
-        total: dict[str, int] = {}
-        for n, _, d in mods:
-            total[n] = total.get(n, 0) + d
-        main = max(total, key=total.get)
-        runs = sorted((s, s + d) for n, s, d in mods if n == main)
-        run_gaps = [b[0] - a[1] for a, b in zip(runs, runs[1:])]
+    runs = main_executions(plane)
+    run_gaps = [b[0] - a[1] for a, b in zip(runs, runs[1:])]
     return Reduction(
         window_ns=float(t1 - t0),
         busy_ns=float(sum(e - s for s, e in busy)),
@@ -188,8 +233,8 @@ def reduce(trace: dict) -> Reduction:
                 for p in trace["planes"]}
         raise TraceError(f"no device plane with an {OPS_LINE!r} line in the "
                          f"trace; it has {have}")
-    host_spans = spans(trace)
-    rs = [_reduce_plane(p, host_spans) for p in planes]
+    on_host = host_spans(trace)
+    rs = [_reduce_plane(p, on_host) for p in planes]
     n = len(rs)
     first = rs[0]
     return Reduction(

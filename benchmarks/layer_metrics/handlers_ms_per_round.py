@@ -1,0 +1,10 @@
+"""Device time of every handler pass (scopes ``phase:rounds`` /
+``phase:h_<kind>``, whatever runs inside them) per iteration of the round
+loop, in ms."""
+
+
+def read(trace, counters, spans):
+    phase_s = counters.get("phase_s")
+    if not phase_s or not counters["rounds"]:
+        return None
+    return 1e3 * phase_s["handlers"] / counters["rounds"]

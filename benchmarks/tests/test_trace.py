@@ -4,14 +4,24 @@ work out by hand, and on one recorded on the chip (data/)."""
 import gzip
 import json
 import os
+import types
 
 import pytest
 
 from benchmarks.harness import manifest as mf
+from benchmarks.harness import phases as ph
 from benchmarks.harness import trace as tr
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
+
+
+PR24_METRICS = ["build_s", "compile_s", "cache_misses", "chunk_gap_ms", "ms_per_round",
+                "rounds_per_window", "ops_per_round", "round_hbm_share", "device_idle_share"]
+# What a traced run reads of the phase join (PR 32).
+PHASE_METRICS = ["prepare_ms_per_window", "pop_ms_per_round", "handlers_ms_per_round",
+                 "deliver_ms_per_window", "phase_unattributed_share", "exec_idle_share",
+                 "dispatch_ms_per_chunk", "handler_pass_useful_share"]
 
 
 def ev(name, start, dur):
@@ -28,6 +38,8 @@ def by_hand():
             ev("jit_tiny(2)", 300, 1)]
     host = [ev(tr.SPAN_PREFIX + "run-chunk", 90, 20), ev(tr.SPAN_PREFIX + "block", 110, 95),
             ev(tr.SPAN_PREFIX + "run-chunk", 380, 25), ev(tr.SPAN_PREFIX + "block", 405, 50),
+            ev(tr.PROGRAM_PREFIX + "dispatch", 92, 10),
+            ev(tr.PROGRAM_PREFIX + "dispatch", 382, 14),
             ev("something else", 0, 1000)]
     return {"planes": [
         {"name": "/device:TPU:0", "lines": [{"name": tr.OPS_LINE, "events": ops},
@@ -39,6 +51,32 @@ def by_hand():
 def test_leaves_drop_the_events_that_contain_others(by_hand):
     ops = by_hand["planes"][0]["lines"][0]["events"]
     assert [e[0] for e in tr.leaves(ops)] == ["fusion.a", "copy.b", "fusion.a", "fusion.a"]
+
+
+def test_a_zero_length_op_at_a_fusion_s_start_does_not_make_it_a_container(by_hand):
+    """A `ConcatBitcast` custom-call or an async start often carries the start
+    timestamp of the fusion after it and sorts behind it. The fusion is an op
+    and stays in busy; a `while` that contains ops is still a container."""
+    ops = by_hand["planes"][0]["lines"][0]["events"]
+    before = tr.reduce(by_hand)
+    ops += [ev("%fusion.big = s32[8]{0} fusion(...)", 200, 150),
+            ev("%custom-call.7 = s32[8]{0} custom-call(...)", 200, 0)]
+    names = [tr.instruction_name(e[0]) for e in tr.leaves(ops)]
+    assert names == ["fusion.a", "copy.b", "fusion.big", "custom-call.7",
+                     "fusion.a", "fusion.a"]
+    r = tr.reduce(by_hand)
+    assert r.busy_ns == before.busy_ns + 150 and r.n_ops == before.n_ops + 2
+    # 200..400 was the longest gap; what is left of it is 350..400.
+    assert before.idle_gaps[0][1] == 200 / 1e9 and r.idle_gaps[0][1] == 50 / 1e9
+    # Control flow that contains nothing (it ran no op) is an op's worth of
+    # time on the line, not a container.
+    assert tr.leaves([ev("while.9", 0, 5), ev("fusion.z", 5, 1)]) == [
+        ev("while.9", 0, 5), ev("fusion.z", 5, 1)]
+    assert [tr.is_control_flow(n) for n in (
+        "while.12", "conditional", "call.3", "fusion.1", "while_thing")] == [
+        True, True, True, False, False]
+    assert tr.instruction_name("%while.12 = (s32[]) while(%x), condition=") == "while.12"
+    assert tr.instruction_name("%fusion.3 = s32[8]{0:T(128)} fus") == "fusion.3"
 
 
 def test_names_seen_counts_the_op_names_that_ran_so_often(by_hand):
@@ -81,12 +119,19 @@ def test_every_layer_metric_reads_the_hand_trace(by_hand):
                 "persistent_cache_hits": 0}
     spans = {"imports": 2.0, "backend": 1.0, "build": 3.0, "warmup": 4.0}
     red = tr.reduce(by_hand)
+    # What a traced run adds to the counters: the phases of a program with
+    # one handler kind (PHOLD's shape), and no handler pass counted.
+    table = {"while.1": "", "fusion.a": "rounds/pop", "copy.b": "deliver/route"}
+    fires = types.SimpleNamespace(**{k: [0] for k in ph.FIRES})
+    counters.update(ph.counters_of(by_hand, ph.phase_report(by_hand, table),
+                                   table, fires, fires))
     got = {e["name"]: mf.reader(ROOT, m, "layer_metrics", e["name"])(red, counters, spans)
            for e in m["per_layer"]}
     # A quantity split by what it moves (<quantity>.<suffix>) has one reader.
     for name in [n for n in got if "." in n]:
         assert got.pop(name) == got[name.split(".")[0]]
-    assert got == {
+    # (A metric a later PR adds is that PR's to test.)
+    assert {k: got[k] for k in PR24_METRICS + PHASE_METRICS} == {
         "build_s": 6.0, "compile_s": 1.5, "cache_misses": 1,
         "chunk_gap_ms": 200 / 1e6,
         "ms_per_round": 140 / 1e6 / 7,
@@ -94,12 +139,32 @@ def test_every_layer_metric_reads_the_hand_trace(by_hand):
         "ops_per_round": 4 / 7,
         "round_hbm_share": pytest.approx(100 * (2 * 819 / 819e9) / (140e-9 / 7)),
         "device_idle_share": pytest.approx(60.0),
+        "prepare_ms_per_window": 0.0,
+        "pop_ms_per_round": pytest.approx(110 / 1e6 / 7),
+        "handlers_ms_per_round": 0.0,
+        "deliver_ms_per_window": pytest.approx(30 / 1e6 / 2),
+        "phase_unattributed_share": 0.0,
+        # Idle inside the two runs: 160..170 of 100..200, of a window 100..450.
+        "exec_idle_share": pytest.approx(100 * 10 / 350),
+        "dispatch_ms_per_chunk": pytest.approx(12 / 1e6),
+        "handler_pass_useful_share": None,      # one handler: nothing to read
     }
-    # Nothing to read: no rounds advanced, one execution only.
-    counters.update(rounds=0, windows=0)
+    # Nothing to read: no rounds advanced, one execution only, a capture
+    # with no module line and none of the program's spans.
+    counters.update(rounds=0, windows=0, exec_idle_ns=None, dispatch_ns=[],
+                    phase_busy_s=0.0)
     red.execution_gaps_ns = []
     for name in ("ms_per_round", "rounds_per_window", "ops_per_round",
-                 "round_hbm_share", "chunk_gap_ms"):
+                 "round_hbm_share", "chunk_gap_ms", "prepare_ms_per_window",
+                 "pop_ms_per_round", "handlers_ms_per_round",
+                 "deliver_ms_per_window", "phase_unattributed_share",
+                 "exec_idle_share", "dispatch_ms_per_chunk"):
+        assert mf.reader(ROOT, m, "layer_metrics", name)(red, counters, spans) is None
+    # A run that made no phase reading at all (the keys are not there).
+    for k in ("phase_s", "phase_busy_s", "exec_idle_ns", "dispatch_ns",
+              "fires_by_lane", "handler_kinds"):
+        del counters[k]
+    for name in PHASE_METRICS:
         assert mf.reader(ROOT, m, "layer_metrics", name)(red, counters, spans) is None
 
 
@@ -120,6 +185,10 @@ def test_the_recorded_trace_reduces_to_what_a_sweep_over_its_events_gives(record
     assert len(ops) == 2454 and len(parents) == 10
     assert {e[0].split(" = ")[0].rstrip(".0123456789") for e in parents} <= {
         "%while", "%conditional", "%call"}
+    # Busy is the time of every event that is not control flow, no more and
+    # no less: ops run one after another on a device's line.
+    assert r.busy_ns == sum(e[2] for e in ops if not tr.is_control_flow(
+        tr.instruction_name(e[0])))
     # Busy time again, by sweeping the sorted end points of every leaf.
     points = sorted([(s, 1) for _, s, d in tr.leaves(ops)]
                     + [(s + d, -1) for _, s, d in tr.leaves(ops)])
