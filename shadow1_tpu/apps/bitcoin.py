@@ -46,7 +46,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from shadow1_tpu.core.dense import get_col, set_col
+from shadow1_tpu.core.dense import extract_col, get_col, read_sel, set_col
 from shadow1_tpu.consts import (
     K_APP,
     N_ACCEPTED,
@@ -192,13 +192,11 @@ def on_wakeup(st, ctx, ev, mask):
     with jax.named_scope("phase:btc_msg"):
         tx = mask & (op == OP_TX_MSG)
         sock, meta, nbytes = ev.p[1], ev.p[2], ev.p[3]
-        tcp = st.model.tcp
-        sk = jnp.where(tx, sock, 0)
-        snd_una = get_col(tcp["snd_una"], sk)
-        app_end = get_col(tcp["app_end"], sk)
+        r = T.Sock(st.model.tcp, sock, tx)
+        snd_una, app_end = r.g("snd_una"), r.g("app_end")
         buffered = (app_end - snd_una) - (snd_una == 0).astype(jnp.int32)
         fits = (ctx.params.sndbuf - buffered) >= nbytes
-        mq_ok = ~get_col(tcp["mq_valid"], sk).all(axis=0)
+        mq_ok = ~r.g("mq_valid").all(axis=0)
         can = tx & fits & mq_ok
         retry = tx & ~can
         st, _acc = T.tcp_send(st, ctx, can, sock, nbytes, meta, ev.time)
@@ -246,8 +244,9 @@ def _on_msg(st, ctx, nf, now, mask, tx_size, inv_size):
     txid = nf.meta & TXID_MASK
     app = st.model.app
     t_safe = jnp.where(msg, txid, 0)
-    seen = get_col(app["seen"], t_safe)
-    req = get_col(app["req"], t_safe)
+    tsel = read_sel(t_safe, app["seen"].shape[0])
+    seen = extract_col(tsel, app["seen"])
+    req = extract_col(tsel, app["req"])
 
     # INV for an unknown tx → GETDATA back on the same conn.
     want = msg & (cmd == CMD_INV) & ~seen & ~req

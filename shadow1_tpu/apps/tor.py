@@ -61,7 +61,14 @@ from shadow1_tpu.consts import (
     TCP_FREE,
     TCP_LISTEN,
 )
-from shadow1_tpu.core.dense import add_col, first_true_idx, get_col, set_col
+from shadow1_tpu.core.dense import (
+    add_col,
+    extract_col,
+    first_true_idx,
+    get_col,
+    read_sel,
+    set_col,
+)
 from shadow1_tpu.core.engine import push_local_event
 from shadow1_tpu.core.events import push_local
 from shadow1_tpu.consts import NP as NPCOLS
@@ -308,7 +315,9 @@ def _relay_on_cell(st, ctx, m, sock, meta, now):
     from_in = other & f_in
     from_out = other & ~f_in & f_out
     idx = jnp.where(from_in, i_in, jnp.where(from_out, i_out, 0))
-    out_sock0 = get_col(app["ct_out_sock"], idx)
+    # One read one-hot [ct_cap, H] for the five table reads at this entry.
+    isel = read_sel(idx, app["ct_used"].shape[0])
+    out_sock0 = extract_col(isel, app["ct_out_sock"])
 
     # --- C_EXTEND from the in-side with no out leg yet: open/reuse the
     # onward conn and queue its CREATE.
@@ -345,8 +354,8 @@ def _relay_on_cell(st, ctx, m, sock, meta, now):
     # --- C_CREATED arriving on an out leg: translate to EXTENDED inward.
     app = st.model.app
     created = from_out & (cmd == C_CREATED)
-    in_sock = get_col(app["ct_in_sock"], idx)
-    in_circ = get_col(app["ct_in_circ"], idx)
+    in_sock = extract_col(isel, app["ct_in_sock"])
+    in_circ = extract_col(isel, app["ct_in_circ"])
     st = _push_cell(
         st, ctx, created, in_sock, _meta(in_circ, 0, C_EXTENDED), CELL, now
     )
@@ -361,8 +370,8 @@ def _relay_on_cell(st, ctx, m, sock, meta, now):
 
     # --- forwarding: everything else crosses the relay.
     app = st.model.app
-    out_sock = get_col(app["ct_out_sock"], idx)
-    out_circ = get_col(app["ct_out_circ"], idx)
+    out_sock = extract_col(isel, app["ct_out_sock"])
+    out_circ = extract_col(isel, app["ct_out_circ"])
     # EXTEND with an existing out leg telescopes onward (the next relay does
     # the extending); only the ext-handled case (fresh out leg this round)
     # must not also forward.
@@ -410,13 +419,11 @@ def on_wakeup(st, ctx, ev, mask):
     # otherwise retry at the next window start (deterministic backoff).
     tx = mask & (op == OP_TX_CELL)
     sock, meta, nbytes = ev.p[1], ev.p[2], ev.p[3]
-    tcp = st.model.tcp
-    sk = jnp.where(tx, sock, 0)
-    snd_una = get_col(tcp["snd_una"], sk)
-    app_end = get_col(tcp["app_end"], sk)
+    r = T.Sock(st.model.tcp, sock, tx)
+    snd_una, app_end = r.g("snd_una"), r.g("app_end")
     buffered = (app_end - snd_una) - (snd_una == 0).astype(jnp.int32)
     fits = (ctx.params.sndbuf - buffered) >= nbytes
-    mq_ok = ~get_col(tcp["mq_valid"], sk).all(axis=0)
+    mq_ok = ~r.g("mq_valid").all(axis=0)
     can = tx & fits & mq_ok
     retry = tx & ~can
     st, _acc = T.tcp_send(st, ctx, can, sock, nbytes, meta, now)

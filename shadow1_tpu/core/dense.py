@@ -9,11 +9,13 @@ one-hot mask + ``where`` (dense, fuses into one cheap elementwise kernel)
 instead of a scatter.
 
 Reads of one slot per host go the same way: on the v5e XLA runs a
-``take_along_axis`` gather one element at a time, 12–13.5 ns an element,
-while ``extract_col(read_sel(col, C), arr)`` streams the plane once at HBM
-speed and wins while the slot axis is under ~2,500 tall (configs reach
-1,024). The rule: reads and writes of one slot per host are both one-hot
-passes; ``get_col`` is the gather that remains (PERF.md §6, PR 26).
+gather of one element per host one element at a time, 12–13.5 ns an
+element, while ``extract_col(read_sel(col, C), arr)`` streams the plane once
+at HBM speed and wins while the slot axis is under ~2,500 tall (configs
+reach 1,024). The rule: reads and writes of one slot per host are both
+one-hot passes; ``get_col`` is that read for a single plane, and callers
+that read several planes at one column build ``read_sel`` once (PERF.md §6,
+PR 26 and PR 33).
 
 Layout contract (round-4 rewrite): the HOST axis is the LAST (minor/lane)
 axis of every per-host state tensor, the slot/capacity axis is second-to-
@@ -74,18 +76,16 @@ def add_col(arr, col, val, mask=None):
 
 def read_sel(col, cap: int) -> jnp.ndarray:
     """The read one-hot, bool [C, H]: True at (clip(col[h]), h).
-    ``extract_col(read_sel(col, C), arr)`` equals ``get_col(arr, col)`` bit
-    for bit without the gather; build it once per column vector and hand it
-    to ``extract_col`` for every plane read at that column."""
+    ``extract_col(read_sel(col, C), arr)`` is ``get_col(arr, col)``; build
+    it once per column vector and hand it to ``extract_col`` for every plane
+    read at that column."""
     return onehot_col(jnp.clip(col, 0, cap - 1), cap)
 
 
 def get_col(arr, col):
-    """Gather ``arr[..., col[h], h]`` → [*L, H] (col clipped into range)."""
-    c = jnp.clip(col, 0, arr.shape[-2] - 1)
-    idx = c.reshape((1,) * (arr.ndim - 2) + (1,) + c.shape)
-    idx = jnp.broadcast_to(idx, arr.shape[:-2] + (1,) + c.shape)
-    return jnp.take_along_axis(arr, idx, axis=-2).squeeze(-2)
+    """Read ``arr[..., col[h], h]`` → [*L, H] (col clipped into range): one
+    one-hot pass over the plane, not a gather."""
+    return extract_col(read_sel(col, arr.shape[-2]), arr)
 
 
 def extract_col(sel, arr):

@@ -70,7 +70,6 @@ from shadow1_tpu.core.dense import (
     extract_col,
     payload,
     first_true_idx,
-    get_col,
     last_true,
     onehot_col,
     read_sel,
@@ -133,13 +132,15 @@ class Sock:
         self.S = tcp["st"].shape[0]
         self.sock = sock
         self.mask = mask
+        self._sel = None  # read one-hot [S, H]; sock and mask never change
 
     def g(self, k):
-        col = jnp.where(self.mask, self.sock, 0)
+        if self._sel is None:
+            self._sel = read_sel(jnp.where(self.mask, self.sock, 0), self.S)
         if k in _I64_SET:
-            return tb_join(get_col(self.d[k + "_hi"], col),
-                           get_col(self.d[k + "_lo"], col))
-        return get_col(self.d[k], col)
+            return tb_join(extract_col(self._sel, self.d[k + "_hi"]),
+                           extract_col(self._sel, self.d[k + "_lo"]))
+        return extract_col(self._sel, self.d[k])
 
     def s(self, k, val, where=None):
         m = self.mask if where is None else (self.mask & where)

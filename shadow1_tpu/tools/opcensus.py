@@ -19,7 +19,7 @@ any benchmark moves. This automates the round-5 manual census:
   deterministic: two runs produce identical counts.
 * **source table** (``--sources``) — eqns grouped by the deepest user frame
   (``file.function``), reproducing the round-5 deliver-pass breakdown
-  (tcp_flush / dense.get_col / events.push_local / ...) mechanically
+  (tcp_flush / dense.extract_col / events.push_local / ...) mechanically
   instead of by hand.
 * **fusion census** (``--fusion``) — the phase programs are compiled and
   the fusion-kernel instructions counted from the optimized HLO: the
@@ -84,7 +84,7 @@ def iter_eqns(jaxpr):
 
 def _source_label(eqn) -> str:
     """``file.function`` of the deepest user frame that created the eqn —
-    the round-5 census's grouping (dense.get_col, events.push_local, ...)."""
+    the round-5 census's grouping (dense.extract_col, events.push_local, ...)."""
     from jax._src import source_info_util
 
     frame = source_info_util.user_frame(eqn.source_info.traceback)

@@ -1,18 +1,19 @@
 """core/dense's one-hot read (``read_sel`` + ``extract_col``) against the
 gather it replaces.
 
-Values: the dense read must equal ``take_along_axis`` at the clipped column
-— and ``get_col``, which still is that gather — bit for bit, in every dtype
+Values: the dense read — spelled out, and as ``get_col`` — must equal numpy's
+``take_along_axis`` at the clipped column bit for bit, in every dtype
 the state planes use (bool, i32, u32 with the top bit set, i64), at every
 rank callers pass ([C,H], [L,C,H], [L1,L2,C,H]), for columns below 0 and at
 or above C, eagerly, under ``jit`` and under ``vmap`` (what the fleet engine
 compiles).
 
-Shape of the program: in the ``rounds`` phase of a TCP model, solo and under
-``vmap`` over two lanes, no ``gather`` equation comes from ``_tcp_flush``,
-and in PHOLD's and tgen's none from ``rng._neg_log1m_q32``. On the v5e such
-a gather is an element-serial kCustom fusion, 7–13.5 ns an element (PERF.md
-§6, PR 26 and PR 31).
+Shape of the program: in the ``rounds`` phase of a TCP model (filexfer, Tor,
+Bitcoin), solo and under ``vmap`` over two lanes, no ``gather`` equation has
+a frame in ``core/dense.py`` or ``tcp/tcp.py``, and in PHOLD's and tgen's
+none comes from ``rng._neg_log1m_q32``. On the v5e such a gather is an
+element-serial kCustom fusion, 7–13.5 ns an element (PERF.md §6, PR 26,
+PR 31 and PR 33).
 """
 
 from __future__ import annotations
@@ -54,6 +55,9 @@ def dense_read(arr, col):
     return extract_col(read_sel(col, arr.shape[-2]), arr)
 
 
+READERS = {"sel_extract": dense_read, "get_col": get_col}
+
+
 def _gather_ref(arr: np.ndarray, col: np.ndarray) -> np.ndarray:
     """The read as a gather: take_along_axis at the clipped column."""
     c = np.clip(col, 0, arr.shape[-2] - 1)
@@ -61,11 +65,13 @@ def _gather_ref(arr: np.ndarray, col: np.ndarray) -> np.ndarray:
     return np.take_along_axis(arr, idx, axis=-2).squeeze(-2)
 
 
+@pytest.mark.parametrize("reader", READERS)
 @pytest.mark.parametrize("mode", MODES)
 @pytest.mark.parametrize("cols", COLS)
 @pytest.mark.parametrize("rank", RANKS)
 @pytest.mark.parametrize("dtype", DTYPES)
-def test_dense_read_equals_gather(dtype, rank, cols, mode):
+def test_dense_read_equals_gather(dtype, rank, cols, mode, reader):
+    read = READERS[reader]
     arr = _plane(dtype, RANKS[rank])
     col = COLS[cols]
     if mode == "vmap":
@@ -73,82 +79,103 @@ def test_dense_read_equals_gather(dtype, rank, cols, mode):
         # its experiments.
         arrs = np.stack([arr, arr[..., ::-1, :]])
         colv = np.stack([col, col[::-1]])
-        got = jax.vmap(dense_read)(jnp.asarray(arrs), jnp.asarray(colv))
+        got = jax.vmap(read)(jnp.asarray(arrs), jnp.asarray(colv))
         want = np.stack([_gather_ref(a, c) for a, c in zip(arrs, colv)])
     else:
-        f = jax.jit(dense_read) if mode == "jit" else dense_read
+        f = jax.jit(read) if mode == "jit" else read
         got = f(jnp.asarray(arr), jnp.asarray(col))
         want = _gather_ref(arr, col)
-        np.testing.assert_array_equal(
-            np.asarray(get_col(jnp.asarray(arr), jnp.asarray(col))), want)
     assert got.dtype == arr.dtype
     np.testing.assert_array_equal(np.asarray(got), want)
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_one_sel_serves_many_planes(dtype):
-    """_tcp_flush builds read_sel once and extracts every plane through it:
-    the same values as a get_col per plane, [S,H] and [MQ,S,H]."""
-    col = jnp.asarray(COLS["clipped"])
-    sel = read_sel(col, C)
+    """_tcp_flush and tcp.Sock build read_sel once and extract every plane
+    through it: the same values as a gather per plane, [S,H] and [MQ,S,H]."""
+    col = COLS["clipped"]
+    sel = read_sel(jnp.asarray(col), C)
     for lead in RANKS.values():
-        arr = jnp.asarray(_plane(dtype, lead))
-        np.testing.assert_array_equal(np.asarray(extract_col(sel, arr)),
-                                      np.asarray(get_col(arr, col)))
+        arr = _plane(dtype, lead)
+        np.testing.assert_array_equal(
+            np.asarray(extract_col(sel, jnp.asarray(arr))), _gather_ref(arr, col))
 
 
 # ---------------------------------------------------------------------------
-# static guard: no gather from _tcp_flush in the TCP round
+# static guard: no gather from core/dense.py or tcp/tcp.py in the TCP round
 # ---------------------------------------------------------------------------
 
-def _functions(eqn) -> set[str]:
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+READ_FILES = ("shadow1_tpu/core/dense.py", "shadow1_tpu/tcp/tcp.py")
+
+
+def _frames(eqn) -> set[tuple[str, str]]:
+    """(function name, file name) of every frame of an equation's traceback."""
     tb = eqn.source_info.traceback
-    return set() if tb is None else {f.function_name for f in tb.frames}
+    return set() if tb is None else {(f.function_name, f.file_name)
+                                     for f in tb.frames}
 
 
 def _rounds(config: str):
-    """(rounds-phase fn, its frame) for a file under configs/ — the jaxpr
-    tools/opcensus.py traces."""
+    """(rounds-phase fn, its frame) for an experiment file, its path from
+    the repo's root — the jaxpr tools/opcensus.py traces."""
     from shadow1_tpu.core.engine import window_frame, window_phases
     from shadow1_tpu.tools.phaseprobe import build_engine
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    eng, _ = build_engine(os.path.join(root, "configs", config))
+    eng, _ = build_engine(os.path.join(ROOT, config))
     phases = dict(window_phases(eng.ctx, eng._handlers, None, eng._pre_window,
                                 eng._model.make_handlers, None))
     return phases["rounds"], window_frame(eng.init_state(), eng.ctx)
 
 
-def _eqns_from(rounds, lanes: int, function: str):
-    """(primitive, frames' function names) of every equation of the phase,
-    solo or under ``vmap`` over ``lanes``, that ``function`` is a frame of."""
+def _eqns(rounds, lanes: int):
+    """(primitive, frames) of every equation of the phase, solo or under
+    ``vmap`` over ``lanes``."""
     from shadow1_tpu.tools.opcensus import iter_eqns
 
     fn, fr = rounds
     if lanes:
         fn = jax.vmap(fn)
         fr = jax.tree_util.tree_map(lambda x: jnp.stack([x] * lanes), fr)
-    return [(e.primitive.name, fns)
-            for e in iter_eqns(jax.make_jaxpr(fn)(fr).jaxpr)
-            if function in (fns := _functions(e))]
+    return [(e.primitive.name, _frames(e))
+            for e in iter_eqns(jax.make_jaxpr(fn)(fr).jaxpr)]
 
 
-@pytest.fixture(scope="module")
-def tcp_rounds():
-    return _rounds("rung1_filexfer.yaml")
+def _eqns_from(rounds, lanes: int, function: str):
+    """(primitive, function names) of the equations ``function`` is a frame
+    of."""
+    return [(prim, fns) for prim, frames in _eqns(rounds, lanes)
+            if function in (fns := {fn for fn, _ in frames})]
+
+
+@pytest.fixture(scope="module", params=[
+    "configs/rung1_filexfer.yaml",
+    "benchmarks/tests/rehearsal/configs/tor20.yaml",
+    "tests/rehearsal_bitcoin64/configs/bitcoin64.yaml",
+], ids=["filexfer", "tor20", "bitcoin64"])
+def tcp_rounds(request):
+    return _rounds(request.param)
 
 
 @pytest.mark.parametrize("lanes", [0, 2], ids=["solo", "vmap2"])
-def test_tcp_flush_has_no_gather(tcp_rounds, lanes):
-    flush = _eqns_from(tcp_rounds, lanes, "_tcp_flush")
-    # The guard can see the flush: its reads are there, as one-hot reduces.
-    assert len(flush) > 1000
-    assert any("extract_col" in fns for _, fns in flush)
-    n_gather = sum(prim == "gather" for prim, _ in flush)
-    assert n_gather == 0, f"{n_gather} gather eqns traced from _tcp_flush"
+def test_tcp_round_has_no_gather(tcp_rounds, lanes):
+    """Every socket-field and app-table read of the round — ``_tcp_flush``,
+    ``tcp.Sock.g``, the apps' ``get_col`` sites — is a one-hot pass."""
+    reads = [(prim, {fn for fn, file in frames if "shadow1_tpu" in file})
+             for prim, frames in _eqns(tcp_rounds, lanes)
+             if any(file.endswith(READ_FILES) for _, file in frames)]
+    # The guard can see the reads: they are there, as one-hot reduces.
+    assert len(reads) > 1000
+    assert any("extract_col" in fns for _, fns in reads)
+    assert any("_tcp_flush" in fns for _, fns in reads)
+    gathers = [sorted(fns) for prim, fns in reads if prim == "gather"]
+    assert not gathers, (
+        f"{len(gathers)} gather eqns traced through core/dense.py or "
+        f"tcp/tcp.py, the first from {gathers[0]}")
 
 
-@pytest.fixture(scope="module", params=["serve_phold.yaml", "rung2_tgen100.yaml"],
+@pytest.fixture(scope="module", params=["configs/serve_phold.yaml",
+                                        "configs/rung2_tgen100.yaml"],
                 ids=["phold", "tgen100"])
 def draw_rounds(request):
     return _rounds(request.param)
