@@ -10,7 +10,7 @@ rounds + src/main/core/worker.c event loop, SURVEY §3.1–3.2):
   masked vectorized handlers run; a round is the SIMD analogue of "each
   worker runs the next event of one host"; hosts interact only through
   packets, which conservative lookahead guarantees land ≥ one window later;
-* window end  — the buffered packet outboxes are routed (latency gather over
+* window end  — the buffered packet outboxes are routed (path latency from
   the vertex matrix, Bernoulli loss draws) and scattered into destination
   event buffers: the one cross-host exchange per window.
 
@@ -358,7 +358,7 @@ def run_round(st: SimState, ctx: Ctx, handlers: dict, win_end) -> SimState:
 
 
 def route_outbox(ctx: Ctx, ob: Outbox, links=None, win_start=None):
-    """Route this block's outbox: latency gather + fault gates + loss draws.
+    """Route this block's outbox: path latency + fault gates + loss draws.
 
     The tensor analogue of the reference's topology path lookup at send time
     (src/main/routing/topology.c getLatency/getReliability, SURVEY §3.3),
@@ -368,6 +368,12 @@ def route_outbox(ctx: Ctx, ob: Outbox, links=None, win_start=None):
     in ``pkts_lost``); otherwise the Bernoulli loss draw applies at the
     path's threshold — replaced by an active timed loss ramp's, same coin
     bits either way. Returns (flat_packets, n_sent, n_lost, n_linkdown).
+
+    The path tables are read per outbox row as ``table[vs, vd]`` — except on
+    a one-vertex network (static table shape [1, 1]: every ``configs/`` file
+    but the GraphML ones), where each read is the table's single element
+    broadcast over the rows and ``host_vertex`` is not looked at: a gather
+    there is an element-serial fusion on the TPU (PERF.md §6, PR 34).
 
     With the link plane on (``links`` a LinkAccum, ``win_start`` the window
     start), every offered packet's edge contribution — counts, wire bytes,
@@ -388,13 +394,24 @@ def route_outbox(ctx: Ctx, ob: Outbox, links=None, win_start=None):
     # (core/outbox.py layout note); fctr is exact below 2**31 pkts/host.
     fdep = flat(ob.abs_depart())
     fctr = flat(ob.ctr).astype(jnp.int64)
-    vs = ctx.host_vertex[fsrc]
-    vd = ctx.host_vertex[fdst_safe]
-    arrival = fdep + ctx.lat_vv[vs, vd]
+    if ctx.lat_vv.shape == (1, 1):
+        vs = vd = jnp.zeros_like(fdst_safe)
+
+        def vv(table):
+            # Inside the fleet's vmap a per-lane table is [1, 1] too.
+            return jnp.broadcast_to(table.reshape(()), fmask.shape)
+    else:
+        vs = ctx.host_vertex[fsrc]
+        vd = ctx.host_vertex[fdst_safe]
+
+        def vv(table):
+            return table[vs, vd]
+
+    arrival = fdep + vv(ctx.lat_vv)
     if ctx.has_jitter:
         # Per-packet edge jitter in [-J, +J] (reference: topology edge
         # jitter attribute); J < lat so the conservative window holds.
-        jit = ctx.jitter_vv[vs, vd]
+        jit = vv(ctx.jitter_vv)
         jbits = rng.bits_v(ctx.key, R_JITTER, fsrc, fctr)
         arrival = arrival + rng.randint(jbits, 2 * jit + 1).astype(jnp.int64) - jit
     linkdown = jnp.zeros_like(fmask)
@@ -402,7 +419,7 @@ def route_outbox(ctx: Ctx, ob: Outbox, links=None, win_start=None):
         from shadow1_tpu.fault.plane import link_down_mask
 
         linkdown = fmask & link_down_mask(ctx.link_fault, vs, vd, fdep)
-    thr = ctx.loss_thr_vv[vs, vd]
+    thr = vv(ctx.loss_thr_vv)
     if ctx.has_loss_ramp:
         from shadow1_tpu.fault.plane import ramp_loss_thr
 

@@ -4,8 +4,8 @@ In the reference, a packet send walks NIC → topology path lookup → a locked
 push onto the destination host's queue (SURVEY §3.3, src/main/routing/
 topology.c + core/scheduler). Conservative windows guarantee every
 cross-host event lands at least one window in the future, so the batched
-engine buffers all sends of a window here and performs routing (latency
-gather, loss draws) plus the destination scatter once per window — and, when
+engine buffers all sends of a window here and performs routing (path
+latency, loss draws) plus the destination scatter once per window — and, when
 sharded, exactly one all_to_all per window over ICI (SURVEY §2.5).
 
 Layout: slot-major, host-minor ([P, H]; payload [NP, P, H]) — see

@@ -133,7 +133,9 @@ def make_pre_window(ctx):
     K_PKT eligible in a window exists in the event buffer at window start
     (packets are created only by the window-end exchange). So one batched
     per-host pass computes the exact FIFO schedule the per-round handler
-    would: sort each host's eligible K_PKT slots by (time, tb), run a
+    would: sort each host's eligible K_PKT slots by (time, tb) — the
+    packet-length plane rides that sort as a payload operand, so nothing is
+    gathered back through the sort's index — run a
     max-plus associative scan ``free_j = max(free_{j-1}, arr_j) + ser_j``,
     and convert each slot IN PLACE to K_PKT_DELIVER at its queue-cleared
     time, keeping the packet's own tie-break (docs/SEMANTICS.md §packet
@@ -177,11 +179,12 @@ def make_pre_window(ctx):
         idx = jnp.broadcast_to(
             jnp.arange(cap, dtype=jnp.int32)[:, None], (cap, h)
         )
-        t_s, _hi_s, _lo_s, idx_s = jax.lax.sort(
-            (t_key, hi_key, lo_key, idx), dimension=0, num_keys=3
+        # plen is a payload of the sort, not a take_along_axis through
+        # idx_s (an element-serial fusion on the TPU: PERF.md §6, PR 34).
+        t_s, _hi_s, _lo_s, idx_s, plen = jax.lax.sort(
+            (t_key, hi_key, lo_key, idx, buf.p[4]), dimension=0, num_keys=3
         )
         valid = t_s < I64_MAX
-        plen = jnp.take_along_axis(buf.p[4], idx_s, axis=0)
         wire = jnp.where(valid, plen.astype(jnp.int64) + WIRE_OVERHEAD, 0)
         ser = jnp.where(
             valid, ser_delay(wire, ctx.bw_dn[None, :], ctx.ser_dn), 0)
@@ -201,7 +204,9 @@ def make_pre_window(ctx):
         )
         vo = valid_o != 0
         nic = st.model.nic._replace(
-            rx_free=free[-1, :],
+            # A static slice, under vmap too (free[-1, :] batches to a
+            # gather).
+            rx_free=jax.lax.index_in_dim(free, cap - 1, 0, keepdims=False),
             rx_bytes=st.model.nic.rx_bytes + wire.sum(axis=0),
         )
         new_time = jnp.where(vo, ready_o, time0)

@@ -11,13 +11,17 @@ compiles).
 Shape of the program: in the ``rounds`` phase of a TCP model (filexfer, Tor,
 Bitcoin), solo and under ``vmap`` over two lanes, no ``gather`` equation has
 a frame in ``core/dense.py`` or ``tcp/tcp.py``, and in PHOLD's and tgen's
-none comes from ``rng._neg_log1m_q32``. On the v5e such a gather is an
+none comes from ``rng._neg_log1m_q32``. At the window's ends of a one-vertex
+TCP model (tgen, Tor, Bitcoin) the ``prepare`` phase holds no ``gather`` at
+all and ``route_outbox`` none in the ``deliver`` phase; rung 1, which has two
+vertices, keeps its four lookups. On the v5e such a gather is an
 element-serial kCustom fusion, 7–13.5 ns an element (PERF.md §6, PR 26,
-PR 31 and PR 33).
+PR 31, PR 33 and PR 34).
 """
 
 from __future__ import annotations
 
+import functools
 import os
 
 import jax
@@ -116,24 +120,32 @@ def _frames(eqn) -> set[tuple[str, str]]:
                                      for f in tb.frames}
 
 
-def _rounds(config: str):
-    """(rounds-phase fn, its frame) for an experiment file, its path from
-    the repo's root — the jaxpr tools/opcensus.py traces."""
+@functools.lru_cache(maxsize=None)
+def _phases(config: str):
+    """(the window's phase fns by name, their entry frame) for an experiment
+    file, its path from the repo's root — the jaxprs tools/opcensus.py
+    traces."""
     from shadow1_tpu.core.engine import window_frame, window_phases
     from shadow1_tpu.tools.phaseprobe import build_engine
 
     eng, _ = build_engine(os.path.join(ROOT, config))
     phases = dict(window_phases(eng.ctx, eng._handlers, None, eng._pre_window,
                                 eng._model.make_handlers, None))
-    return phases["rounds"], window_frame(eng.init_state(), eng.ctx)
+    return phases, window_frame(eng.init_state(), eng.ctx)
 
 
-def _eqns(rounds, lanes: int):
-    """(primitive, frames) of every equation of the phase, solo or under
-    ``vmap`` over ``lanes``."""
+def _rounds(config: str):
+    """(rounds-phase fn, its frame)."""
+    phases, frame = _phases(config)
+    return phases["rounds"], frame
+
+
+def _eqns(phase, lanes: int):
+    """(primitive, frames) of every equation of a (phase fn, frame), solo or
+    under ``vmap`` over ``lanes``."""
     from shadow1_tpu.tools.opcensus import iter_eqns
 
-    fn, fr = rounds
+    fn, fr = phase
     if lanes:
         fn = jax.vmap(fn)
         fr = jax.tree_util.tree_map(lambda x: jnp.stack([x] * lanes), fr)
@@ -188,3 +200,55 @@ def test_exponential_draw_has_no_gather(draw_rounds, lanes):
     assert any("_log_tbl_read" in fns and prim == "dot_general" for prim, fns in draw)
     n_gather = sum(prim == "gather" for prim, _ in draw)
     assert n_gather == 0, f"{n_gather} gather eqns traced from _neg_log1m_q32"
+
+
+# ---------------------------------------------------------------------------
+# static guard: no gather at the window's ends of a one-vertex TCP model
+# ---------------------------------------------------------------------------
+
+RUNG1 = "configs/rung1_filexfer.yaml"   # two vertices: the lookups stay
+ONE_VERTEX = {
+    "tgen100": "configs/rung2_tgen100.yaml",
+    "tor20": "benchmarks/tests/rehearsal/configs/tor20.yaml",
+    "bitcoin64": "tests/rehearsal_bitcoin64/configs/bitcoin64.yaml",
+}
+
+
+def _window_end_eqns(config: str, lanes: int):
+    """(primitive, function names) of the ``prepare`` phase's equations, and
+    of those of the ``deliver`` phase that ``route_outbox`` is a frame of."""
+    phases, frame = _phases(config)
+    names = lambda eqns: [(prim, {fn for fn, _ in frames})
+                          for prim, frames in eqns]
+    prepare = names(_eqns((phases["prepare"], frame), lanes))
+    route = [(prim, fns)
+             for prim, fns in names(_eqns((phases["deliver"], frame), lanes))
+             if "route_outbox" in fns]
+    # The guard can see both sites: the arrival batch's sort and un-sort,
+    # and the loss draw.
+    assert sum(prim == "sort" for prim, _ in prepare) == 2
+    assert len(route) > 50 and any("uniform_lt" in fns for _, fns in route)
+    return prepare, route
+
+
+@pytest.mark.parametrize("lanes", [0, 2], ids=["solo", "vmap2"])
+@pytest.mark.parametrize("config", ONE_VERTEX)
+def test_one_vertex_window_ends_have_no_gather(config, lanes):
+    """``pre_window``'s packet lengths ride its sort and ``route_outbox``
+    reads a [1, 1] table as a broadcast: neither end of the window gathers."""
+    prepare, route = _window_end_eqns(ONE_VERTEX[config], lanes)
+    for phase, eqns in (("prepare", prepare), ("deliver/route_outbox", route)):
+        gathers = [sorted(fns) for prim, fns in eqns if prim == "gather"]
+        assert not gathers, (
+            f"{len(gathers)} gather eqns in {phase}, the first from "
+            f"{gathers[0]}")
+
+
+@pytest.mark.parametrize("lanes", [0, 2], ids=["solo", "vmap2"])
+def test_two_vertex_route_keeps_its_lookups(lanes):
+    """Rung 1 has two vertices: host_vertex[src], host_vertex[dst],
+    lat_vv[vs, vd] and loss_thr_vv[vs, vd] are read per outbox row, as
+    before — which also shows that the guard above sees such reads."""
+    prepare, route = _window_end_eqns(RUNG1, lanes)
+    assert sum(prim == "gather" for prim, _ in route) == 4
+    assert not [fns for prim, fns in prepare if prim == "gather"]

@@ -1,0 +1,343 @@
+"""The two window-end reads that are no longer gathers (PR 34), each against
+the gathered form it replaced, which is kept here as reference code.
+
+``net.make_pre_window``: the packet-length plane rides the (time, tb) sort as
+a payload operand where it used to be read back with ``take_along_axis``
+through the sorted index. The sort is stable, so the two agree on every row,
+tied ones included; the whole output state must be equal on a buffer with
+time ties, full (time, tb) ties, unselected slots and a down host.
+
+``core/engine.route_outbox``: on a one-vertex network (table shape [1, 1])
+``lat_vv[vs, vd]``, ``jitter_vv[vs, vd]`` and ``loss_thr_vv[vs, vd]`` are the
+table's one element broadcast over the outbox rows; under ``vmap`` each lane
+keeps its own threshold. A two-vertex network goes through the lookups, as
+before.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from shadow1_tpu import rng
+from shadow1_tpu.config.compiled import CompiledExperiment
+from shadow1_tpu.consts import (
+    K_NONE,
+    K_PKT,
+    K_PKT_DELIVER,
+    MS,
+    NP,
+    R_JITTER,
+    R_LOSS,
+    SEC,
+    WIRE_OVERHEAD,
+    EngineParams,
+    packet_tb,
+)
+from shadow1_tpu.core.engine import Engine, FlatPackets, route_outbox
+from shadow1_tpu.core.events import I64_MAX, tb_split
+from shadow1_tpu.core.outbox import Outbox
+from shadow1_tpu.net.nic import ser_delay
+from tests.test_net_parity import filexfer_exp
+
+H, EV_CAP, OB_CAP = 6, 16, 8
+WIN = 10 * MS
+MODES = ("jit", "vmap2")
+
+
+def _assert_trees_equal(got, want):
+    g, tree_g = jax.tree_util.tree_flatten(got)
+    w, tree_w = jax.tree_util.tree_flatten(want)
+    assert tree_g == tree_w
+    for a, b in zip(g, w):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _stack(trees):
+    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
+
+
+# ---------------------------------------------------------------------------
+# pre_window
+# ---------------------------------------------------------------------------
+
+def _net_engine() -> Engine:
+    exp = filexfer_exp(n_hosts=H, seed=5, end=SEC)
+    exp.stop_time[3] = 2 * MS  # host 3 is down from 2 ms on: has_stop
+    return Engine(exp, EngineParams(ev_cap=EV_CAP, outbox_cap=OB_CAP))
+
+
+def _arrivals(seed: int):
+    """EventBuf planes of a window's start: K_PKT slots due in the window and
+    after it, other kinds, free slots holding stale payload; times from a
+    handful of values (ties), tie-breaks from three (full ties)."""
+    r = np.random.default_rng(seed)
+    shape = (EV_CAP, H)
+    kind = r.choice([K_PKT, K_PKT, K_PKT, K_NONE, K_PKT_DELIVER], shape)
+    time = r.choice([1 * MS, 2 * MS, 2 * MS, 3 * MS, 7 * MS, 12 * MS], shape)
+    time = np.where(kind == K_NONE, I64_MAX, time)
+    tb = r.choice([5, (3 << 32) + 1, (3 << 32) + 0x9000_0000], shape)
+    p = r.integers(0, 1 << 20, (NP,) + shape)
+    p[4] = r.integers(1, 1461, shape)  # the packet length: no two runs alike
+    thi, tlo = tb_split(jnp.asarray(time, jnp.int64))
+    bhi, blo = tb_split(jnp.asarray(tb, jnp.int64))
+    return dict(time_hi=thi, time_lo=tlo, tb_hi=bhi, tb_lo=blo,
+                kind=jnp.asarray(kind, jnp.int32), p=jnp.asarray(p, jnp.int32))
+
+
+def _sort_keys(buf, sel):
+    """pre_window's sort operands, payload aside."""
+    cap, h = buf.kind.shape
+    i32max = jnp.iinfo(jnp.int32).max
+    idx = jnp.broadcast_to(jnp.arange(cap, dtype=jnp.int32)[:, None], (cap, h))
+    return (jnp.where(sel, buf.abs_time(), I64_MAX),
+            jnp.where(sel, buf.tb_hi, i32max),
+            jnp.where(sel, buf.tb_lo, i32max), idx)
+
+
+def _pre_window_gathered(ctx):
+    """net.make_pre_window as it stood before PR 34: plen read back through
+    the sorted index, rx_free as ``free[-1, :]``."""
+    from shadow1_tpu.fault.plane import hosts_down_at
+
+    neg = -(1 << 62)
+
+    def pre_window(st, _ctx, win_end):
+        buf = st.evbuf
+        abs_t = buf.abs_time()
+        sel = (buf.kind == K_PKT) & (abs_t < win_end)
+        kind0, time0 = buf.kind, abs_t
+        m = st.metrics
+        if ctx.has_stop:
+            down = sel & hosts_down_at(ctx.fault_down, ctx.fault_up, abs_t)
+            sel = sel & ~down
+            kind0 = jnp.where(down, K_NONE, kind0)
+            time0 = jnp.where(down, I64_MAX, time0)
+            m = m._replace(down_events=m.down_events
+                           + down.sum(dtype=jnp.int64))
+        t_s, _hi_s, _lo_s, idx_s = jax.lax.sort(
+            _sort_keys(buf, sel), dimension=0, num_keys=3)
+        valid = t_s < I64_MAX
+        plen = jnp.take_along_axis(buf.p[4], idx_s, axis=0)
+        wire = jnp.where(valid, plen.astype(jnp.int64) + WIRE_OVERHEAD, 0)
+        ser = jnp.where(
+            valid, ser_delay(wire, ctx.bw_dn[None, :], ctx.ser_dn), 0)
+        pq = (ser, jnp.where(valid, t_s + ser, neg))
+        p_pre, q_pre = jax.lax.associative_scan(
+            lambda a, b: (a[0] + b[0], jnp.maximum(a[1] + b[0], b[1])),
+            pq, axis=0,
+        )
+        free = jnp.maximum(st.model.nic.rx_free[None, :] + p_pre, q_pre)
+        ready = free - ser
+        _i, ready_o, valid_o = jax.lax.sort(
+            (idx_s, ready, valid.astype(jnp.int32)), dimension=0, num_keys=1)
+        vo = valid_o != 0
+        nic = st.model.nic._replace(
+            rx_free=free[-1, :],
+            rx_bytes=st.model.nic.rx_bytes + wire.sum(axis=0),
+        )
+        thi, tlo = tb_split(jnp.where(vo, ready_o, time0))
+        evbuf = buf._replace(
+            kind=jnp.where(vo, K_PKT_DELIVER, kind0), time_hi=thi, time_lo=tlo)
+        return st._replace(
+            evbuf=evbuf, model=st.model._replace(nic=nic), metrics=m)
+
+    return pre_window
+
+
+@pytest.fixture(scope="module")
+def net():
+    eng = _net_engine()
+    assert eng.ctx.has_stop and eng._pre_window is not None
+    st0 = eng.init_state()
+
+    def state(seed):
+        st = st0._replace(evbuf=st0.evbuf._replace(**_arrivals(seed)))
+        # A downlink still busy from the window before on some hosts.
+        busy = jnp.asarray([0, 3 * MS, 0, 0, 1 * MS, 9 * MS], jnp.int64)
+        return st._replace(model=st.model._replace(
+            nic=st.model.nic._replace(rx_free=busy)))
+
+    return eng, state
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_pre_window_equals_gathered_form(net, mode):
+    eng, state = net
+    new, old = eng._pre_window, _pre_window_gathered(eng.ctx)
+    win_end = jnp.asarray(WIN, jnp.int64)
+    if mode == "vmap2":
+        st = _stack([state(1), state(2)])
+        run = lambda f: jax.jit(jax.vmap(lambda s: f(s, eng.ctx, win_end)))(st)
+    else:
+        st = state(1)
+        run = lambda f: jax.jit(lambda s: f(s, eng.ctx, win_end))(st)
+    got, want = run(new), run(old)
+    _assert_trees_equal(got, want)
+    # The case is not empty: arrivals were scheduled, some behind a busy
+    # downlink, the down host's were discarded, later ones left alone.
+    n_pkt = lambda s: int((np.asarray(s.evbuf.kind) == K_PKT).sum())
+    n_dlv = lambda s: int((np.asarray(s.evbuf.kind) == K_PKT_DELIVER).sum())
+    assert n_dlv(got) - n_dlv(st) >= 20
+    assert 0 < n_pkt(got) < n_pkt(st)
+    assert int(np.asarray(got.metrics.down_events).sum()) > 0
+    assert (np.asarray(got.model.nic.rx_free)
+            != np.asarray(st.model.nic.rx_free)).any()
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_plen_as_sort_operand_equals_take_along_axis(net, mode):
+    """The premise alone: a payload operand of the stable sort is the plane
+    gathered through the sorted index — on every row, ties included."""
+    _, state = net
+
+    def both(buf):
+        sel = (buf.kind == K_PKT) & (buf.abs_time() < WIN)
+        keys = _sort_keys(buf, sel)
+        *_, idx_s = jax.lax.sort(keys, dimension=0, num_keys=3)
+        t_s, *_, plen = jax.lax.sort(keys + (buf.p[4],), dimension=0,
+                                     num_keys=3)
+        return plen, jnp.take_along_axis(buf.p[4], idx_s, axis=0), t_s, keys
+
+    if mode == "vmap2":
+        buf = _stack([state(3).evbuf, state(4).evbuf])
+        plen, taken, t_s, keys = jax.jit(jax.vmap(both))(buf)
+    else:
+        plen, taken, t_s, keys = jax.jit(both)(state(3).evbuf)
+    valid = np.asarray(t_s) < I64_MAX
+    assert valid.any() and not valid.all()
+    np.testing.assert_array_equal(np.asarray(plen)[valid],
+                                  np.asarray(taken)[valid])
+    np.testing.assert_array_equal(np.asarray(plen), np.asarray(taken))
+    # Some host holds two selected rows with one (time, tb_hi, tb_lo).
+    t, hi, lo, _ = (np.asarray(k).reshape(-1, EV_CAP, H)[0] for k in keys)
+    assert any(
+        len({(t[c, h], hi[c, h], lo[c, h]) for c in range(EV_CAP)
+             if t[c, h] < I64_MAX}) < (t[:, h] < I64_MAX).sum()
+        for h in range(H))
+
+
+# ---------------------------------------------------------------------------
+# route_outbox
+# ---------------------------------------------------------------------------
+
+def _route_outbox_gathered(ctx, ob):
+    """core/engine.route_outbox as it stood before PR 34, fault and link
+    planes off: one lookup per outbox row whatever the tables' shape."""
+    cap, h = ob.dst.shape
+    mask = jnp.arange(cap)[:, None] < ob.cnt[None, :]
+    src = jnp.broadcast_to(ctx.hosts[None, :], (cap, h))
+
+    def flat(x):
+        return x.reshape(x.shape[:-2] + (cap * h,))
+
+    fmask, fsrc, fdst = flat(mask), flat(src), flat(ob.dst)
+    fdst_safe = jnp.where(fmask, fdst, 0)
+    fdep = flat(ob.abs_depart())
+    fctr = flat(ob.ctr).astype(jnp.int64)
+    vs = ctx.host_vertex[fsrc]
+    vd = ctx.host_vertex[fdst_safe]
+    arrival = fdep + ctx.lat_vv[vs, vd]
+    if ctx.has_jitter:
+        jit = ctx.jitter_vv[vs, vd]
+        jbits = rng.bits_v(ctx.key, R_JITTER, fsrc, fctr)
+        arrival = (arrival + rng.randint(jbits, 2 * jit + 1).astype(jnp.int64)
+                   - jit)
+    bits = rng.bits_v(ctx.key, R_LOSS, fsrc, fctr)
+    lost = fmask & rng.uniform_lt(bits, ctx.loss_thr_vv[vs, vd])
+    fp = FlatPackets(
+        dst=fdst_safe, arrival=arrival,
+        tb=packet_tb(fsrc.astype(jnp.int64), fctr), kind=flat(ob.kind),
+        p=flat(ob.p), keep=fmask & ~lost,
+    )
+    return (fp, fmask.sum(dtype=jnp.int64), lost.sum(dtype=jnp.int64),
+            jnp.zeros((), jnp.int64))
+
+
+def _phold(**net):
+    return Engine(CompiledExperiment(
+        n_hosts=H, seed=7, end_time=SEC,
+        bw_up=np.full(H, 10**9, np.int64), bw_dn=np.full(H, 10**9, np.int64),
+        model="phold", model_cfg={"mean_delay_ns": float(MS)}, **net)).ctx
+
+
+def _ctx(vertices: int):
+    if vertices == 1:
+        return _phold(lat_vv=np.full((1, 1), WIN, np.int64),
+                      loss_vv=np.full((1, 1), 0.3, np.float32),
+                      jitter_vv=np.full((1, 1), 2 * MS, np.int64),
+                      host_vertex=np.zeros(H, np.int32))
+    return _phold(lat_vv=np.array([[10, 25], [40, 15]], np.int64) * MS,
+                  loss_vv=np.array([[0.0, 0.5], [0.9, 0.2]], np.float32),
+                  jitter_vv=np.array([[0, 2], [3, 1]], np.int64) * MS,
+                  host_vertex=np.array([0, 1, 1, 0, 1, 0], np.int32))
+
+
+def _outbox(seed: int) -> Outbox:
+    r = np.random.default_rng(seed)
+    shape = (OB_CAP, H)
+    dhi, dlo = tb_split(jnp.asarray(r.integers(0, WIN, shape), jnp.int64))
+    i32 = lambda a: jnp.asarray(a, jnp.int32)
+    cnt = r.integers(0, OB_CAP + 1, H)
+    cnt[0], cnt[1] = OB_CAP, 0  # a full column and an empty one
+    # Live rows name real hosts, as the engine's do; rows at or above cnt
+    # hold stale destinations, some out of range.
+    dst = r.integers(0, H + 3, shape)
+    dst = np.where(np.arange(OB_CAP)[:, None] < cnt[None, :], dst % H, dst)
+    return Outbox(
+        dst=i32(dst), kind=i32(r.integers(1, 5, shape)),
+        depart_hi=dhi, depart_lo=dlo, ctr=i32(r.integers(0, 1 << 20, shape)),
+        p=i32(r.integers(0, 1 << 20, (NP,) + shape)), cnt=i32(cnt),
+        pkt_ctr=jnp.asarray(r.integers(0, 1 << 20, H), jnp.int64),
+    )
+
+
+@pytest.mark.parametrize("vertices", [1, 2], ids=["V1", "V2"])
+def test_route_outbox_equals_gathered_form(vertices):
+    ctx = _ctx(vertices)
+    assert ctx.has_jitter and ctx.lat_vv.shape == (vertices, vertices)
+    ob = _outbox(11)
+    got = jax.jit(lambda o: route_outbox(ctx, o))(ob)
+    want = jax.jit(lambda o: _route_outbox_gathered(ctx, o))(ob)
+    _assert_trees_equal(got, want)
+    fp, n_sent, n_lost, n_linkdown = got
+    assert int(n_sent) == int(np.asarray(ob.cnt).sum()) > 0
+    assert 0 < int(n_lost) < int(n_sent) and int(n_linkdown) == 0
+    flight = (np.asarray(fp.arrival)
+              - np.asarray(ob.abs_depart()).reshape(-1))[np.asarray(fp.keep)]
+    if vertices == 1:
+        assert flight.min() >= 8 * MS and flight.max() <= 12 * MS
+        assert len(set(flight.tolist())) > 1          # the jitter draws
+    else:
+        assert flight.min() < 12 * MS and flight.max() > 35 * MS  # by path
+
+
+def test_route_outbox_one_vertex_fleet_lanes_keep_their_thresholds():
+    """Under the fleet's vmap the lane's loss_thr_vv (and its key) are
+    batched leaves of shape [1, 1]: each lane draws against its own."""
+    ctx = _ctx(1)
+    obs = [_outbox(21), _outbox(22)]
+    thr = jnp.stack([
+        jnp.asarray(rng.prob_threshold(np.full((1, 1), p, np.float32)))
+        for p in (0.05, 0.6)])
+    key = jnp.stack([rng.base_key(101), rng.base_key(202)])
+
+    def lanes(route):
+        return jax.jit(jax.vmap(lambda o, t, k: route(
+            dataclasses.replace(ctx, loss_thr_vv=t, key=k), o)))(
+                _stack(obs), thr, key)
+
+    got = lanes(route_outbox)
+    _assert_trees_equal(got, lanes(_route_outbox_gathered))
+    # Each lane equals the solo program closed over that lane's constants.
+    for i, ob in enumerate(obs):
+        solo = route_outbox(
+            dataclasses.replace(ctx, loss_thr_vv=thr[i], key=key[i]), ob)
+        _assert_trees_equal(jax.tree_util.tree_map(lambda x: x[i], got), solo)
+    n_sent, n_lost = np.asarray(got[1]), np.asarray(got[2])
+    assert 0 < n_lost[0] / n_sent[0] < 0.25 < 0.4 < n_lost[1] / n_sent[1] < 0.8
