@@ -591,5 +591,6 @@ class CpuTor:
             "total_cells_rx": int(self.cells_rx.sum()),
             "total_cells_fwd": int(self.cells_fwd.sum()),
             "total_ct_overflow": int(self.ct_overflow.sum()),
+            "total_cell_retries": int(self.cell_retries.sum()),
             "clients_done": int((self.done_time > 0).sum()),
         }

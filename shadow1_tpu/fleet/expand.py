@@ -236,7 +236,8 @@ def shape_class(exp) -> dict:
     or an engine-cache entry (``serve/cache.shape_class_key``) iff their
     shape classes compare equal. Left out: the ``_VARIABLE_EXP`` fields,
     and the VALUES of the app's lane tables (``apps.LANE_TABLES``) — of
-    those only shape and dtype stay, every other ``model_cfg`` key whole."""
+    those only shape and dtype stay, every other ``model_cfg`` key whole
+    but an app's own memo (a key with a leading underscore)."""
     tables = lane_table_keys(exp)
     out = {}
     names = [f.name for f in dataclasses.fields(type(exp))]
@@ -244,9 +245,12 @@ def shape_class(exp) -> dict:
         if f in _VARIABLE_EXP:
             continue
         v = getattr(exp, f)
-        if f == "model_cfg" and tables:
+        if f == "model_cfg":
+            # The memo: what the other keys derive (tor's consensus tables,
+            # written at the first trace), not configuration.
             v = {k: (("lane table", np.shape(x), str(np.asarray(x).dtype))
-                     if k in tables else x) for k, x in v.items()}
+                     if k in tables else x) for k, x in v.items()
+                 if not k.startswith("_")}
         out[f] = v
     return out
 

@@ -245,6 +245,9 @@ MODEL_TOTALS: dict[str, str] = {
     "total_cells_rx": "tor: cells received at an endpoint",
     "total_cells_fwd": "tor: cells a relay forwarded",
     "total_ct_overflow": "tor: circuits refused, relay circuit table full",
+    "total_cell_retries": "tor: cell sends deferred a window "
+                          "(send buffer or boundary FIFO full)",
+    "clients_done": "tor: clients that finished every circuit and stream",
     "total_seen": "bitcoin: (tx, node) first sights, origins included",
     "total_tx_rx": "bitcoin: TX payloads received",
     "total_msg_retries": "bitcoin: protocol sends deferred a window "

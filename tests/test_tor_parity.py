@@ -71,6 +71,10 @@ def test_tor_circuits_parity_fast():
     assert int(ts["total_cells_rx"]) > 0
     assert int(ts["total_cells_fwd"]) > 0
     assert int(ts["total_ct_overflow"]) == 0
+    # The run totals are the oracle's too (registry.MODEL_TOTALS).
+    assert {k: int(v) for k, v in ts.items() if np.ndim(v) == 0} == \
+        {k: int(v) for k, v in cs.items() if np.ndim(v) == 0}
+    assert int(ts["total_cell_retries"]) == int(np.sum(ts["cell_retries"]))
     assert_parity(cm, cs, tm, ts, keys=TOR_KEYS)
 
 
