@@ -54,7 +54,7 @@ from shadow1_tpu.consts import (
     NP,
     TCP_LISTEN,
 )
-from shadow1_tpu.core.engine import push_local_event
+from shadow1_tpu.core.engine import any_host, lane_branch, push_local_event
 from shadow1_tpu.core.events import push_local
 from shadow1_tpu.tcp import tcp as T
 
@@ -168,7 +168,8 @@ def on_wakeup(st, ctx, ev, mask):
         return T.tcp_connect(st, ctx, conn, sock, peer, zero, ev.time)
 
     with jax.named_scope("phase:btc_dial"):
-        st = jax.lax.cond(conn.any(), _op_conn, lambda s: s, st)
+        st = jax.lax.cond(any_host(ctx, conn), lane_branch(ctx, _op_conn),
+                          lambda s: s, st)
 
     # OP_TX_CREATE: origin marks the tx seen and queues the announcements
     # (a few hundred per run — cond-gated).
@@ -183,7 +184,8 @@ def on_wakeup(st, ctx, ev, mask):
         return _announce(st, ctx, new, txid, none, ev.time)
 
     with jax.named_scope("phase:btc_create"):
-        st = jax.lax.cond(create.any(), _op_create, lambda s: s, st)
+        st = jax.lax.cond(any_host(ctx, create), lane_branch(ctx, _op_create),
+                          lambda s: s, st)
 
     # OP_TX_MSG: the single transport-send site. Admission: the message must
     # fit the send buffer and a boundary slot must be free, else retry at the
@@ -231,7 +233,8 @@ def on_notify(st, ctx, nf: T.Notif, now, mask):
         return st._replace(model=st.model._replace(app=app))
 
     with jax.named_scope("phase:btc_dial"):
-        st = jax.lax.cond(acc.any(), _accepted, lambda s: s, st)
+        st = jax.lax.cond(any_host(ctx, acc), lane_branch(ctx, _accepted),
+                          lambda s: s, st)
     with jax.named_scope("phase:btc_notify"):
         return _on_msg(st, ctx, nf, now, mask, tx_size, inv_size)
 

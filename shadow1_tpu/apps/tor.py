@@ -86,7 +86,7 @@ from shadow1_tpu.core.dense import (
     read_sel,
     set_col,
 )
-from shadow1_tpu.core.engine import push_local_event
+from shadow1_tpu.core.engine import any_host, lane_branch, push_local_event
 from shadow1_tpu.core.events import push_local
 from shadow1_tpu.consts import NP as NPCOLS
 from shadow1_tpu.tcp import tcp as T
@@ -448,7 +448,8 @@ def on_wakeup(st, ctx, ev, mask):
             st = st._replace(model=st.model._replace(app=app))
             return T.tcp_connect(st, ctx, start, two, dirauth, zero, now)
 
-        st = jax.lax.cond(start.any(), _op_start, lambda s: s, st)
+        st = jax.lax.cond(any_host(ctx, start), lane_branch(ctx, _op_start),
+                          lambda s: s, st)
 
     with jax.named_scope("phase:tor_relay"):
         # OP_TX_CELL: the single transport-send site. Admission: the full
@@ -477,8 +478,9 @@ def on_wakeup(st, ctx, ev, mask):
         # OP_CONNECT_RELAY: dial an onward relay conn.
         dial = mask & (op == OP_CONNECT_RELAY)
         st = jax.lax.cond(
-            dial.any(),
-            lambda s: T.tcp_connect(s, ctx, dial, ev.p[1], ev.p[2], zero, now),
+            any_host(ctx, dial),
+            lane_branch(ctx, lambda s: T.tcp_connect(
+                s, ctx, dial, ev.p[1], ev.p[2], zero, now)),
             lambda s: s, st,
         )
 
@@ -507,7 +509,8 @@ def on_wakeup(st, ctx, ev, mask):
                 st, ctx, more, now, K_APP, p0=OP_DRAIN, p1=sock
             )
 
-        st = jax.lax.cond(drain.any(), _op_drain, lambda s: s, st)
+        st = jax.lax.cond(any_host(ctx, drain), lane_branch(ctx, _op_drain),
+                          lambda s: s, st)
 
     with jax.named_scope("phase:tor_stream"):
         # OP_THINK: next stream on this circuit, or next circuit.
@@ -523,7 +526,8 @@ def on_wakeup(st, ctx, ev, mask):
                 )
                 return _client_begin_circuit(st2, ctx, next_circ, now)
 
-        return jax.lax.cond(think.any(), _op_think, lambda s: s, st)
+        return jax.lax.cond(any_host(ctx, think), lane_branch(ctx, _op_think),
+                          lambda s: s, st)
 
 
 def on_notify(st, ctx, nf: T.Notif, now, mask):
@@ -560,7 +564,8 @@ def on_notify(st, ctx, nf: T.Notif, now, mask):
                 st, ctx, dir_up, two, _meta(0, 0, C_DIRREQ), CELL, now
             )
 
-        st = jax.lax.cond(dir_up.any(), _dir_up, lambda s: s, st)
+        st = jax.lax.cond(any_host(ctx, dir_up), lane_branch(ctx, _dir_up),
+                          lambda s: s, st)
 
         # Client: consensus received → close dir conn, dial the drawn guard.
         app = st.model.app
@@ -582,7 +587,8 @@ def on_notify(st, ctx, nf: T.Notif, now, mask):
             zero = jnp.zeros(ctx.n_hosts, jnp.int32)
             return T.tcp_connect(st, ctx, got_dir, one, guard, zero, now)
 
-        st = jax.lax.cond(got_dir.any(), _got_dir, lambda s: s, st)
+        st = jax.lax.cond(any_host(ctx, got_dir), lane_branch(ctx, _got_dir),
+                          lambda s: s, st)
 
     with jax.named_scope("phase:tor_build"):
         # Client: guard conn up → first circuit.
@@ -592,8 +598,9 @@ def on_notify(st, ctx, nf: T.Notif, now, mask):
             & (app["cl_state"] == CL_GUARD_CONN)
         )
         st = jax.lax.cond(
-            guard_up.any(),
-            lambda s: _client_begin_circuit(s, ctx, guard_up, now),
+            any_host(ctx, guard_up),
+            lane_branch(ctx, lambda s: _client_begin_circuit(
+                s, ctx, guard_up, now)),
             lambda s: s, st,
         )
 
@@ -622,7 +629,8 @@ def on_notify(st, ctx, nf: T.Notif, now, mask):
                 return _client_begin_stream(st, ctx, ext3, now)
 
         st = jax.lax.cond(
-            (creatd | ext2 | ext3).any(), _circ_build, lambda s: s, st
+            any_host(ctx, creatd | ext2 | ext3), lane_branch(ctx, _circ_build),
+            lambda s: s, st,
         )
 
     with jax.named_scope("phase:tor_stream"):
@@ -660,7 +668,8 @@ def on_notify(st, ctx, nf: T.Notif, now, mask):
             )
             return T.tcp_close(st, ctx, d_fin, sock, now)
 
-        st = jax.lax.cond((dreq | d_fin).any(), _dirauth, lambda s: s, st)
+        st = jax.lax.cond(any_host(ctx, dreq | d_fin),
+                          lane_branch(ctx, _dirauth), lambda s: s, st)
 
     with jax.named_scope("phase:tor_relay"):
         # Relay: onward conn established → drain pending CREATEs.

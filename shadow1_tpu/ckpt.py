@@ -78,7 +78,12 @@ import jax
 #      times its rank block, core/events.deliver_batch): one more i64 leaf
 #      in every snapshot. A running sum like the other counters, so a
 #      resumed run continues it bit-identically.
-CKPT_FORMAT = 13
+#  14: Metrics gains runs_pkt/deliver/timer/txr/app (rounds in which the
+#      program ran each handler pass: the guard predicate reduced over a
+#      fleet's lanes, core/engine.any_host): five more i64 leaves in every
+#      snapshot. Running sums; a lane sliced out of a fleet carries the
+#      fleet's count so far and continues it solo as fires_* would.
+CKPT_FORMAT = 14
 
 
 class CorruptCheckpointError(ValueError):

@@ -106,13 +106,14 @@ K_APP = 6         # application state-machine wakeup (p0 = app opcode)
 N_KINDS = 7
 
 # Per-kind occupancy metric fields shared by both engines (kind →
-# (pops-field, fires-field)): one table so the engines cannot drift.
+# (pops-field, fires-field, runs-field)): one table so the engines cannot
+# drift.
 KIND_METRIC_FIELDS = {
-    K_PKT: ("pops_pkt", "fires_pkt"),
-    K_PKT_DELIVER: ("pops_deliver", "fires_deliver"),
-    K_TCP_TIMER: ("pops_timer", "fires_timer"),
-    K_TX_RESUME: ("pops_txr", "fires_txr"),
-    K_APP: ("pops_app", "fires_app"),
+    K_PKT: ("pops_pkt", "fires_pkt", "runs_pkt"),
+    K_PKT_DELIVER: ("pops_deliver", "fires_deliver", "runs_deliver"),
+    K_TCP_TIMER: ("pops_timer", "fires_timer", "runs_timer"),
+    K_TX_RESUME: ("pops_txr", "fires_txr", "runs_txr"),
+    K_APP: ("pops_app", "fires_app", "runs_app"),
 }
 
 # Human-readable kind names — the phase attribution plane's handler-pass

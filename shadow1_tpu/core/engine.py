@@ -39,6 +39,7 @@ from shadow1_tpu.consts import (
     packet_tb,
 )
 from shadow1_tpu.core.events import (
+    NEVER,
     EventBuf,
     Popped,
     any_eligible,
@@ -108,6 +109,17 @@ class Metrics(NamedTuple):
     fires_timer: jnp.ndarray
     fires_txr: jnp.ndarray
     fires_app: jnp.ndarray
+    # Rounds in which the lane's PROGRAM ran each pass: the guard's
+    # predicate as the ``lax.cond`` saw it (``any_host``), which on a fleet
+    # is "some lane has the kind". Solo: equal to fires_*. Fleet: one number
+    # in every lane (every lane rides every iteration of the one loop), >=
+    # each lane's fires_*. Batch-engine-only like fires_*, and the one family
+    # a lane-against-solo comparison leaves out (registry.LANE_PROGRAM_FIELDS).
+    runs_pkt: jnp.ndarray
+    runs_deliver: jnp.ndarray
+    runs_timer: jnp.ndarray
+    runs_txr: jnp.ndarray
+    runs_app: jnp.ndarray
     # Arriving ranks the window-end merge swept (events.deliver_batch: its
     # fill loop's trips * RB, summed over windows) — against windows * ev_cap
     # it says what a fill by slot would sweep. Batch-engine-only like fires_*.
@@ -215,6 +227,10 @@ class Ctx:
     has_tx_qlen: bool = False
     has_rx_qlen: bool = False
     has_aqm: bool = False
+    # The name of the vmap axis the window step runs under (FleetEngine
+    # sets it: its lanes), None where there is none (solo, sharded). Read
+    # by ``any_host`` and by nothing else.
+    lane_axis: str | None = None
 
     def __post_init__(self):
         if self.hosts is None:
@@ -277,6 +293,63 @@ class FlatPackets(NamedTuple):
     keep: jnp.ndarray     # bool [N]
 
 
+def any_host(ctx: Ctx, mask) -> jnp.ndarray:
+    """The predicate of a "some host has this" guard: ``mask.any()``, and
+    under a fleet's lane axis that, reduced over the lanes.
+
+    ``vmap`` turns a ``lax.cond`` whose predicate differs by lane into both
+    branches and a select of every leaf they carry. ``pmax`` over the named
+    axis is one value for all lanes, so the ``cond`` stays a conditional and
+    a pass (or a block inside one) that no lane needs is skipped, as on the
+    solo engine. Use it for a guard's predicate and for nothing else: what
+    it returns says nothing about this lane's hosts (``run_round``'s
+    docstring has the contract the guarded code must keep)."""
+    return any_lane(ctx, jnp.any(mask))
+
+
+def any_lane(ctx: Ctx, hit) -> jnp.ndarray:
+    """A lane's scalar ``hit``, or-ed over the fleet's lanes; ``hit`` itself
+    where no lane axis is bound (``any_host`` of an already reduced mask)."""
+    if ctx.lane_axis is None:
+        return hit
+    return jax.lax.pmax(hit.astype(jnp.int32), ctx.lane_axis) > 0
+
+
+def lane_branch(ctx: Ctx, fn):
+    """``fn`` as the taken branch of a guard on ``any_host``: itself where no
+    lane axis is bound; under a fleet's, one call of a jitted callee.
+
+    ``vmap``'s rule for a ``cond`` whose predicate is one value for all lanes
+    batches each branch twice (once to learn which outputs come out batched,
+    once to match the branches), and a handler pass is seconds of tracing: a
+    fleet's warm set-up grew by a third (PERF.md §6, PR 38). A jitted callee
+    is batched through a cache keyed on the callee and its operands' axes
+    alone, so it is batched once however often the branch is; XLA inlines
+    the call. The callee returns only the leaves ``fn`` replaced and the
+    rest are handed back as they came in — what ``lax.cond`` itself does
+    for a branch it can see into, and what keeps a guard's outputs (and the
+    round loop's carry) to the leaves its block writes."""
+    if ctx.lane_axis is None:
+        return fn
+
+    def branch(*args):
+        fwd = []    # set while ``written`` is traced, i.e. by the call below
+
+        def written(*a):
+            came = {id(x): i for i, x in enumerate(jax.tree.leaves(a))}
+            out, tree = jax.tree.flatten(fn(*a))
+            fwd[:] = tree, [came.get(id(o)) for o in out]
+            return [o for o in out if id(o) not in came]
+
+        new = iter(jax.jit(written)(*args))
+        tree, src = fwd
+        old = jax.tree.leaves(args)
+        return tree.unflatten(
+            [next(new) if i is None else old[i] for i in src])
+
+    return branch
+
+
 def run_round(st: SimState, ctx: Ctx, handlers: dict, win_end) -> SimState:
     """One inner round: per-host pop-min + the handler passes.
 
@@ -296,10 +369,24 @@ def run_round(st: SimState, ctx: Ctx, handlers: dict, win_end) -> SimState:
       so the busy clocks evolve identically (docs/SEMANTICS.md).
 
     Each kind's pass is wrapped in ``lax.cond`` on "any host popped this
-    kind this round" — most rounds touch 1–2 of the 5 kinds, so skipping
-    the dead passes cuts the round cost correspondingly (handlers draw RNG
-    and advance counters only where masked, so an all-false pass is a
-    no-op by construction and skipping it is exact)."""
+    kind this round" (``any_host``) — most rounds touch 1–2 of the 5 kinds,
+    so skipping the dead passes cuts the round cost correspondingly
+    (handlers draw RNG and advance counters only where masked, so an
+    all-false pass is a no-op by construction and skipping it is exact).
+
+    On a fleet the same contract carries one axis up: the predicate is
+    "any host of ANY lane", so a lane with no event of the kind runs the
+    pass whenever another lane has one — exactly what happens to host 7
+    when only host 3 popped a timer. A handler (or a guarded block below
+    one, in tcp/ or apps/) that writes, draws or counts outside its mask
+    therefore breaks fleets, lane against solo run, not only speed.
+    (Below a pass, ``any_host`` guards the blocks a run leaves behind —
+    Tor's bootstrap and build, bitcoin's dial; the per-stream guards of
+    tcp/, tgen and filexfer keep the lane's own ``mask.any()``: some lane
+    is in them in nearly every round, and a conditional inside a running
+    pass splits its fused writes of the event planes. PERF.md §6, PR 38.)
+    ``fires_*`` counts the lane's own ``present``; ``runs_*`` counts what
+    the ``cond`` was handed."""
     with jax.named_scope("phase:pop"):
         evbuf, ev = pop_until(st.evbuf, win_end)
     st = st._replace(evbuf=evbuf)
@@ -346,14 +433,17 @@ def run_round(st: SimState, ctx: Ctx, handlers: dict, win_end) -> SimState:
                 st = fn(st, ev)
         else:
             present = (ev.mask & (ev.kind == kind)).any()
+            runs = any_lane(ctx, present)
             if kind in KIND_METRIC_FIELDS:
-                fires = KIND_METRIC_FIELDS[kind][1]
+                _, f_fires, f_runs = KIND_METRIC_FIELDS[kind]
                 m2 = st.metrics
                 st = st._replace(metrics=m2._replace(**{
-                    fires: getattr(m2, fires) + present.astype(jnp.int64)
+                    f_fires: getattr(m2, f_fires) + present.astype(jnp.int64),
+                    f_runs: getattr(m2, f_runs) + runs.astype(jnp.int64),
                 }))
             with jax.named_scope(scope):
-                st = jax.lax.cond(present, fn, lambda s, _e: s, st, ev)
+                st = jax.lax.cond(runs, lane_branch(ctx, fn),
+                                  lambda s, _e: s, st, ev)
     return st
 
 
@@ -530,18 +620,36 @@ def run_rounds(st: SimState, ctx: Ctx, handlers: dict, win_end):
     """The inner round loop to quiescence (or the safety cap).
 
     Returns (st, cap_hit). Shared by the full-width path and the compacted
-    path (core/compact.py), which calls it at bucket width."""
+    path (core/compact.py), which calls it at bucket width. On a fleet the
+    loop's own predicate is reduced over the lanes like every guard's
+    (``any_lane``), and a lane whose own loop has ended rides the remaining
+    iterations as the identity: its ``rounds``, ``r`` and ``cap_hit`` are
+    what its solo run's are."""
     max_rounds = ctx.params.max_rounds
 
-    def cond(carry):
+    def live(carry):
         s, r = carry
         return (r < max_rounds) & any_eligible(s.evbuf, win_end)
 
     def body(carry):
         s, r = carry
-        return run_round(s, ctx, handlers, win_end), r + 1
+        if ctx.lane_axis is None:
+            return run_round(s, ctx, handlers, win_end), r + 1
+        # A fleet's loop is one loop: it runs while ANY lane's own would
+        # (its predicate is one more guard's). vmap would freeze a lane
+        # whose loop has ended by selecting every leaf of the carry, every
+        # iteration, old against new, which keeps both whole; here the round
+        # itself is the identity for such a lane: it runs to an end that no
+        # event is before (past-due ones of a capped window included), so
+        # nothing pops and every mask in run_round is false.
+        on = live(carry)
+        s2 = run_round(s, ctx, handlers, jnp.where(on, win_end, NEVER))
+        return (s2._replace(metrics=s2.metrics._replace(
+            rounds=s.metrics.rounds + on.astype(jnp.int64))),
+            r + on.astype(r.dtype))
 
-    st, r = jax.lax.while_loop(cond, body, (st, jnp.zeros((), jnp.int32)))
+    st, r = jax.lax.while_loop(lambda c: any_lane(ctx, live(c)), body,
+                               (st, jnp.zeros((), jnp.int32)))
     return st, (r >= max_rounds) & any_eligible(st.evbuf, win_end)
 
 

@@ -54,6 +54,7 @@ from shadow1_tpu.core.dense import extract_col, first_true
 
 I64_MAX = jnp.iinfo(jnp.int64).max
 I32_MAX = jnp.iinfo(jnp.int32).max
+I32_MIN = jnp.iinfo(jnp.int32).min
 # Free/ineligible sentinel for the t32 plane; live far-future events clamp
 # to I32_HORIZON. Both are ≥ any valid until32 (window < 2**31 — validated
 # by the engine), so neither can pop.
@@ -221,15 +222,22 @@ def push_back(buf: EventBuf, mask, time, tb, kind, p) -> tuple[EventBuf, jnp.nda
     return buf, mask & ~has_free
 
 
+# An ``until`` that no event is before, past-due ones (negative t32)
+# included: ``until32`` clamps it below every key a slot can hold.
+NEVER = -(1 << 62)
+
+
 def until32(buf: EventBuf, until) -> jnp.ndarray:
     """Rebased eligibility bound. Exact when until - epoch <= I32_HORIZON
     = 2**31 - 2 (the engine's window-size validation guarantees it for
-    win_end bounds: window < 2**31 - 1, config/compiled.py)."""
-    return jnp.clip(until - buf.epoch, 0, I32_HORIZON).astype(jnp.int32)
+    win_end bounds: window < 2**31 - 1, config/compiled.py). ``NEVER``
+    comes out below every key, so it admits nothing."""
+    return jnp.clip(until - buf.epoch, I32_MIN, I32_HORIZON).astype(jnp.int32)
 
 
 def pop_until(buf: EventBuf, until) -> tuple[EventBuf, Popped]:
-    """Per-host pop of the minimum-(time, tb) event with time < until.
+    """Per-host pop of the minimum-(time, tb) event with time < until
+    (none at all, and ``buf`` back as it went in, for ``until = NEVER``).
 
     A 3-step lexicographic masked min over the slot (sublane) axis — t32,
     then tb_hi among time-ties, then tb_lo — ending in an equality one-hot;

@@ -22,9 +22,15 @@ and model state are bit-identical to running experiment e alone (solo tpu
 engine or the cpu oracle) — ``tools/fleetprobe.py`` verifies it, and
 ``tests/test_fleet.py`` asserts it per PR. The mechanism: ``vmap`` batches
 the identical integer ops (RNG is counter-based per (key, host, ctr), so
-lanes cannot interact), and the batched ``while_loop`` freezes finished
-lanes with per-lane selects, so even per-lane ``rounds`` counts stay
-exact.
+lanes cannot interact). The one thing lanes share is the PREDICATE of
+each "any host has this" guard and of the round loop itself, reduced over
+the named lane axis (``LANE_AXIS``, ``core/engine.any_host`` /
+``any_lane``) so that each stays a conditional (a loop) under ``vmap``:
+a pass that some other lane needs runs here too, over masks that are all
+false, which is the identity by the handlers' contract (``run_round``),
+and a lane whose own round loop has ended pops nothing in the rounds the
+slower lanes still need, so even per-lane ``rounds`` counts stay exact.
+Only ``Metrics.runs_*`` can tell which lanes rode along.
 
 **Recovery plane** (docs/SEMANTICS.md §"Fleet recovery contract"): the
 ``[E, ...]`` state pytree is a well-defined transaction unit, so
@@ -70,6 +76,12 @@ from shadow1_tpu.fleet.expand import (
     lane_table_keys,
     same_shape_class,
 )
+
+
+# The name of the vmap axis every per-lane function here runs under. A lane's
+# Ctx carries it (``Ctx.lane_axis``), so a guard's predicate can be reduced
+# over the lanes and its ``lax.cond`` stay a conditional (core/engine.any_host).
+LANE_AXIS = "lane"
 
 
 def slice_experiment(st: SimState, e: int) -> SimState:
@@ -325,7 +337,8 @@ class FleetEngine:
             # (eager vmap) and carried as a batched variant leaf —
             # window_step restores restarted hosts' columns from lane e's
             # capture, same as the solo engine's device constant.
-            cap = jax.vmap(self._lane_init_model)(self._variants)
+            cap = jax.vmap(self._lane_init_model,
+                           axis_name=LANE_AXIS)(self._variants)
             self._variants["init_model"] = jax.tree.map(
                 lambda x: jnp.asarray(np.asarray(x)), cap)
         self._run_jit = jax.jit(self._make_run())
@@ -398,6 +411,7 @@ class FleetEngine:
             link_fault=var.get("link_fault"),
             loss_ramp=var.get("loss_ramp"),
             init_model=var.get("init_model"),
+            lane_axis=LANE_AXIS,
         )
 
     def _lane_init_model(self, var: dict):
@@ -431,7 +445,8 @@ class FleetEngine:
         )
 
     def init_state(self) -> SimState:
-        return jax.vmap(self._lane_init_state)(self._variants)
+        return jax.vmap(self._lane_init_state,
+                        axis_name=LANE_AXIS)(self._variants)
 
     def place_state(self, st: SimState) -> SimState:
         return jax.device_put(st)
@@ -454,7 +469,8 @@ class FleetEngine:
         # hot-engine cache (shadow1_tpu/serve/cache.py) rests on this.
         def run(st: SimState, n_windows, variants) -> SimState:
             def body(_, s):
-                return jax.vmap(self._lane_window_step)(s, variants)
+                return jax.vmap(self._lane_window_step,
+                                axis_name=LANE_AXIS)(s, variants)
 
             return jax.lax.fori_loop(0, n_windows, body, st)
 
@@ -550,7 +566,8 @@ class FleetEngine:
                     f"traced without those passes; build a fresh engine",
                     kind="mode", knob="faults")
             if has["restart"]:
-                cap = jax.vmap(self._lane_init_model)(variants)
+                cap = jax.vmap(self._lane_init_model,
+                               axis_name=LANE_AXIS)(variants)
                 variants["init_model"] = jax.tree.map(
                     lambda x: jnp.asarray(np.asarray(x)), cap)
             if self._signature(variants, has) \
@@ -579,13 +596,17 @@ class FleetEngine:
         """Fleet AGGREGATE: counters sum across experiments, gauges max —
         keeps generic chunk plumbing (progress display, normalize)
         working; per-experiment truth is metrics_per_exp."""
-        from shadow1_tpu.telemetry.registry import gauge_names
+        from shadow1_tpu.telemetry.registry import (
+            LANE_PROGRAM_FIELDS,
+            gauge_names,
+        )
 
-        gauges = set(gauge_names())
+        # runs_* is one number in every lane (the program's), not a sum.
+        maxed = set(gauge_names()) | set(LANE_PROGRAM_FIELDS)
         out = {}
         for k, v in st.metrics._asdict().items():
             a = np.asarray(v)
-            out[k] = int(a.max()) if k in gauges else int(a.sum())
+            out[k] = int(a.max()) if k in maxed else int(a.sum())
         # windows advance in lockstep across lanes — report one fleet
         # window count, not E× it.
         out["windows"] = int(np.asarray(st.metrics.windows).max())

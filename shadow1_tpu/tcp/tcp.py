@@ -645,6 +645,10 @@ def tcp_rx(st, ctx, mask, p, now):
         st = st._replace(model=st.model._replace(tcp=rc.d))
         return tcp_flush(st, ctx, new_conn, child, now)  # emits SYN|ACK
 
+    # (The lane's own predicate, not ``any_host``: on a fleet some lane is in
+    # this block in nearly every deliver round, and a real conditional here
+    # only splits the pass's fused writes of the event planes into separate
+    # sweeps — PERF.md §6, PR 38.)
     st = jax.lax.cond(syn_to_listen.any(), _accept, lambda s: s, st)
 
     # ---- established-path demux: peer must match (guards stale/reused slots)

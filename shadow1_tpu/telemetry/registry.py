@@ -28,6 +28,8 @@ from __future__ import annotations
 
 import threading
 
+from shadow1_tpu.consts import KIND_METRIC_FIELDS
+
 COUNTER = "counter"
 GAUGE = "gauge"
 
@@ -67,6 +69,14 @@ METRIC_SPECS: dict[str, tuple[str, str]] = {
     "fires_timer": (COUNTER, "rounds where the K_TCP_TIMER pass fired"),
     "fires_txr": (COUNTER, "rounds where the K_TX_RESUME pass fired"),
     "fires_app": (COUNTER, "rounds where the K_APP pass fired"),
+    "runs_pkt": (COUNTER, "rounds where the program ran the K_PKT pass"),
+    "runs_deliver": (COUNTER, "rounds where the program ran the "
+                              "K_PKT_DELIVER pass"),
+    "runs_timer": (COUNTER, "rounds where the program ran the K_TCP_TIMER "
+                            "pass"),
+    "runs_txr": (COUNTER, "rounds where the program ran the K_TX_RESUME "
+                          "pass"),
+    "runs_app": (COUNTER, "rounds where the program ran the K_APP pass"),
     "deliver_ranks": (COUNTER, "arriving ranks swept by the window-end merge "
                                "(deliver_batch's trips * RB; batch engines)"),
     "link_down_pkts": (COUNTER, "packets dropped: link outage window (fault plane)"),
@@ -99,6 +109,14 @@ METRIC_SPECS: dict[str, tuple[str, str]] = {
 # sync contract, from heartbeat deltas (the retries block carries them) and
 # from ring percentile stats (chunk-level, not per-window).
 HOST_FIELDS = ("chunk_retries", "retry_windows_rerun")
+
+# Counters of the PROGRAM a lane rode in, not of the lane's simulation: the
+# guard predicate reduced over a fleet's lanes (core/engine.any_host). Equal
+# to fires_* on a solo engine; on a fleet one number in every lane, and
+# another number for the same lane in a fleet of other lanes. A comparison
+# of a fleet lane with its solo run (or with the same lane in another fleet)
+# leaves out exactly these and nothing else.
+LANE_PROGRAM_FIELDS = tuple(f[2] for f in KIND_METRIC_FIELDS.values())
 
 # JSONL record types every consumer recognises (docs/OBSERVABILITY.md).
 # ``digest`` is the CPU oracle's per-window state-digest row (the batched

@@ -46,13 +46,20 @@ EXIT_AUDIT_FAILED = 1
 
 
 def _parity_counter_names():
-    from shadow1_tpu.telemetry.registry import METRIC_SPECS, gauge_names
+    from shadow1_tpu.telemetry.registry import (
+        LANE_PROGRAM_FIELDS,
+        METRIC_SPECS,
+        gauge_names,
+    )
 
     # Per-lane parity comparands: every canonical counter that is not a
     # batch-engine-only occupancy artifact (rounds/fires are trace-shape
     # dependent and excluded from cross-run parity everywhere else too).
     skip = set(gauge_names()) | {"rounds", "round_cap_hits"}
     skip |= {n for n in METRIC_SPECS if n.startswith("fires_")}
+    # ... and what counts the program a lane rode in: a sub-batch is
+    # another fleet.
+    skip |= set(LANE_PROGRAM_FIELDS)
     return [n for n in METRIC_SPECS if n not in skip]
 
 

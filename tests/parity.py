@@ -81,3 +81,45 @@ def assert_parity(cm, cs, tm, ts, keys=("rx_bytes", "flows_done", "done_time"),
             np.asarray(ts[k]), np.asarray(cs[k]),
             err_msg=f"summary {k!r} diverged" + hint,
         )
+
+
+# ---- a fleet lane against its solo run -----------------------------------------
+# Everything is compared but ``registry.LANE_PROGRAM_FIELDS`` (``runs_*``):
+# those count the program a lane rode in, which a solo run is not.
+
+def lane_metrics(d: dict) -> dict:
+    """A metrics dict without the counters of the program the lane rode in."""
+    from shadow1_tpu.telemetry.registry import LANE_PROGRAM_FIELDS
+
+    return {k: v for k, v in d.items() if k not in LANE_PROGRAM_FIELDS}
+
+
+def unlike_leaves(got, want) -> list[str]:
+    """Paths of the leaves in which two states of one treedef differ,
+    ``metrics.runs_*`` left out."""
+    import jax
+
+    from shadow1_tpu.telemetry.registry import LANE_PROGRAM_FIELDS
+
+    paths = [jax.tree_util.keystr(p)
+             for p, _ in jax.tree_util.tree_leaves_with_path(want)]
+    a, b = jax.tree.leaves(got), jax.tree.leaves(want)
+    assert len(a) == len(b) == len(paths) > 50
+    return [p for p, x, y in zip(paths, a, b)
+            if p.rsplit(".", 1)[-1] not in LANE_PROGRAM_FIELDS
+            and not np.array_equal(np.asarray(x), np.asarray(y))]
+
+
+def assert_runs_contract(lanes: list[dict], solos: list[dict]) -> None:
+    """``runs_*`` is the program's count and ``fires_*`` the lane's: one
+    ``runs`` number in every lane of a fleet, at least each lane's
+    ``fires``, which is its solo run's, where ``runs == fires``."""
+    from shadow1_tpu.consts import KIND_METRIC_FIELDS
+
+    assert len(lanes) == len(solos) > 1
+    for _, fires, runs in KIND_METRIC_FIELDS.values():
+        assert len({ln[runs] for ln in lanes}) == 1, (runs, lanes)
+        for ln, solo in zip(lanes, solos):
+            assert ln[runs] >= ln[fires] == solo[fires] == solo[runs], (
+                runs, ln, solo)
+    assert sum(ln[f[2]] for ln in lanes for f in KIND_METRIC_FIELDS.values())

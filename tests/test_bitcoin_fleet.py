@@ -34,6 +34,7 @@ from shadow1_tpu.fleet.expand import (
 )
 from shadow1_tpu.telemetry import phases
 from shadow1_tpu.telemetry.registry import MODEL_TOTALS
+from tests.parity import assert_runs_contract, lane_metrics
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 REHEARSAL = os.path.join(ROOT, "tests", "rehearsal_bitcoin64")
@@ -127,12 +128,21 @@ def test_a_lane_equals_the_solo_engine_bit_for_bit(fleet, solos, lane):
         assert np.array_equal(got[k], summary[k]), k
     assert {k: int(v) for k, v in got.items() if np.ndim(v) == 0} == \
         {k: int(v) for k, v in summary.items() if np.ndim(v) == 0}
-    assert fleet_metrics_per_exp(st)[lane] == metrics
+    assert lane_metrics(fleet_metrics_per_exp(st)[lane]) \
+        == lane_metrics(metrics)
     assert int(summary["total_tx_rx"]) > 0
     assert eng.model_totals(st)[lane] == {
         k: int(summary[k])
         for k in ("total_seen", "total_tx_rx", "total_msg_retries")}
     assert set(eng.model_totals(st)[lane]) <= set(MODEL_TOTALS)
+
+
+def test_runs_count_the_program_and_fires_the_lane(fleet, solos):
+    """``runs_*`` (the guard as the program took it: some lane has the
+    kind) is one number in every lane and at least the lane's own
+    ``fires_*``; on the solo engine they are equal."""
+    _, st = fleet
+    assert_runs_contract(fleet_metrics_per_exp(st), [m for _, m in solos])
 
 
 # ---- (c) every lane is the C++ reference's run under that lane's seed --------

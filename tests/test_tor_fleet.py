@@ -37,6 +37,7 @@ from shadow1_tpu.fleet.engine import (
 from shadow1_tpu.fleet.expand import expand_sweep
 from shadow1_tpu.telemetry import phases
 from shadow1_tpu.telemetry.registry import MODEL_TOTALS
+from tests.parity import assert_runs_contract, lane_metrics, unlike_leaves
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 REHEARSAL = os.path.join(ROOT, "tests", "rehearsal_tor20")
@@ -93,15 +94,9 @@ def lane_counters(eng, st, lane):
 def test_a_lane_equals_the_solo_engine_leaf_for_leaf(fleet, solos, lane):
     eng, st = fleet
     solo, want = solos[lane]
-    got = slice_experiment(st, lane)
-    paths = [jax.tree_util.keystr(p)
-             for p, _ in jax.tree_util.tree_leaves_with_path(want)]
-    a, b = jax.tree.leaves(got), jax.tree.leaves(want)
-    assert len(a) == len(b) == len(paths) > 50
-    unlike = [p for p, x, y in zip(paths, a, b)
-              if not np.array_equal(np.asarray(x), np.asarray(y))]
-    assert not unlike, unlike
-    assert fleet_metrics_per_exp(st)[lane] == Engine.metrics_dict(want)
+    assert not unlike_leaves(slice_experiment(st, lane), want)
+    assert lane_metrics(fleet_metrics_per_exp(st)[lane]) \
+        == lane_metrics(Engine.metrics_dict(want))
     totals = solo.model_totals(want)
     assert eng.model_totals(st)[lane] == totals
     assert set(totals) == set(TOR_TOTALS) <= set(MODEL_TOTALS)
@@ -109,6 +104,24 @@ def test_a_lane_equals_the_solo_engine_leaf_for_leaf(fleet, solos, lane):
     assert totals["total_cell_retries"] == int(summary["cell_retries"].sum())
     assert totals["clients_done"] == int((summary["done_time"] > 0).sum())
     assert totals["total_streams_done"] > 0 and totals["total_cells_fwd"] > 0
+
+
+def test_the_guards_engage_and_runs_count_the_program(fleet, solos):
+    """What ``any_host`` brings: a pass no lane has an event for is skipped
+    (``runs_app`` below the loop's iterations), a pass some OTHER lane has one
+    for runs in this lane too (``runs_*`` above its ``fires_*``: the lanes of
+    test (a) were equal to their solo runs through such rounds), and
+    ``runs_*`` is one number in every lane."""
+    _, st = fleet
+    lanes = fleet_metrics_per_exp(st)
+    assert_runs_contract(lanes, [Engine.metrics_dict(s) for _, s in solos])
+    for ln in lanes:
+        assert ln["runs_app"] > ln["fires_app"] > 0, ln
+        assert ln["runs_deliver"] > ln["fires_deliver"] > 0, ln
+    # The loop runs each window to its slowest lane, so its iterations are
+    # at least any one lane's rounds.
+    iterations_at_least = max(ln["rounds"] for ln in lanes)
+    assert lanes[0]["runs_app"] < iterations_at_least
 
 
 # ---- (b) every lane is the C++ reference's run under that lane's seed --------
