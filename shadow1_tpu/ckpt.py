@@ -316,11 +316,14 @@ def run_chunked(engine, st=None, n_windows: int | None = None,
     every full chunk. Returns the final state.
 
     Every chunk is spanned (telemetry/profiler.py): ``run-chunk`` ⊃
-    ``dispatch`` (the run call returning; + ``sync`` under a profiler),
-    then ``commit`` (guard), ``on-chunk``, ``retune``, each with the chunk's
-    first window as ``done``. The spans are ``shadow1:`` annotations in any
-    ``jax.profiler`` capture; ``profiler`` (telemetry.PhaseProfiler) also
-    records them for ``--trace`` and makes ``run-chunk`` cover execution.
+    ``dispatch`` (the run call returning, ⊃ ``args``, ``call``; + ``sync``
+    under a profiler), ``wait`` on the chunk log's waiter thread (the
+    result ready), then ``commit`` (guard), ``on-chunk``, ``retune``, each
+    with the chunk's first window as ``done``. The spans are ``shadow1:``
+    annotations in any ``jax.profiler`` capture and one row of
+    ``telemetry.chunk_log()``, always; ``profiler``
+    (telemetry.PhaseProfiler) also records them for ``--trace`` and makes
+    ``run-chunk`` cover execution.
 
     ``retune(engine, st) -> (engine, st)`` is the between-chunk adaptation
     hook (tune/autocap.CapController): it may hand back a DIFFERENT engine
@@ -354,11 +357,12 @@ def run_chunked(engine, st=None, n_windows: int | None = None,
         PH_INIT,
         PH_ON_CHUNK,
         PH_RETUNE,
-        PH_RUN_CHUNK,
         PH_SYNC,
+        chunk_log,
         maybe_span,
     )
 
+    chunks = chunk_log()
     if st is None:
         with maybe_span(profiler, PH_INIT):
             st = engine.init_state()
@@ -375,7 +379,7 @@ def run_chunked(engine, st=None, n_windows: int | None = None,
         # Rollback point: jax states are immutable and run() never donates,
         # so holding the reference is free until the commit drops it.
         st0 = st if guard is not None else None
-        with maybe_span(profiler, PH_RUN_CHUNK, **ids):
+        with chunks.chunk(profiler, engine, st, **ids) as ch:
             # Under a guard the sharded engine's eager x2x safety net
             # stands down (guard.run_guarded passes check_x2x=False) — the
             # commit below owns the overflow response.
@@ -383,6 +387,8 @@ def run_chunked(engine, st=None, n_windows: int | None = None,
                 st = (guard.run_guarded(engine, st, step)
                       if guard is not None
                       else engine.run(st, n_windows=step))
+            # Readiness is the log's waiter's to take: no sync here.
+            ch.watch(st)
             if profiler is not None:
                 # Only under a PhaseProfiler: make the span cover execution,
                 # not just async dispatch. Chunk boundary — never inside a

@@ -457,6 +457,18 @@ def _run_traced(args, engine=None):
         phases.write(os.path.join(args.profile, "phases.trace.json"))
 
 
+def _chunks_block(out: dict) -> None:
+    """The final JSON's ``chunks`` block where the run went through a chunk
+    loop: the chunk log's summary (how many chunks, the boundary from the
+    host's side in ms a chunk and as a share of the chunks' wall, stalls —
+    docs/OBSERVABILITY.md "Chunk log")."""
+    from shadow1_tpu.telemetry import chunk_log
+
+    chunks = chunk_log().summary()
+    if chunks["count"]:
+        out["chunks"] = chunks
+
+
 def _fleet_main(args, params, plan, log, t0, capacity_exit,
                 preempted_exit, memory_exit=None, sub_batch=None,
                 auto_caps=False, pre_downshift_retry=False) -> int:
@@ -642,6 +654,7 @@ def _fleet_main(args, params, plan, log, t0, capacity_exit,
                                   recovery=hb.recovery)
     for r in recs:
         print(json.dumps(r))
+    _chunks_block(summary)
     print(json.dumps(summary))
     return 0
 
@@ -847,6 +860,7 @@ def _fleet_subbatched(args, params, plan, log, t0, capacity_exit,
         "sub_batches": n_batches,
         "lanes_per_batch": sub,
     }
+    _chunks_block(merged)
     print(json.dumps(merged))
     return 0
 
@@ -1713,6 +1727,7 @@ def main(argv=None) -> int:
             k: int(v) for k, v in summary.items()
             if getattr(v, "ndim", 1) == 0 or isinstance(v, (int, float))
         }
+    _chunks_block(out)
     print(json.dumps(out))
     return 0
 

@@ -49,6 +49,7 @@ from shadow1_tpu.core.events import (
     push_back,
 )
 from shadow1_tpu.core.outbox import Outbox, outbox_clear, outbox_init
+from shadow1_tpu.telemetry.profiler import PH_ARGS, PH_CALL, run_span
 
 
 class Metrics(NamedTuple):
@@ -1137,7 +1138,12 @@ class Engine:
         if st is None:
             st = self.init_state()
         n = n_windows if n_windows is not None else self.n_windows
-        return self._run_jit(st, jnp.asarray(n, jnp.int32))
+        # dispatch ⊃ args, call (telemetry/profiler.py): what the call is
+        # handed, made; then the jitted call returning.
+        with run_span(PH_ARGS):
+            n = jnp.asarray(n, jnp.int32)
+        with run_span(PH_CALL):
+            return self._run_jit(st, n)
 
     def hlo_text(self, st: SimState | None = None, n_windows: int = 0) -> str:
         """The optimized HLO text of the one window program ``run`` drives

@@ -70,6 +70,7 @@ from shadow1_tpu.core.engine import (
 )
 from shadow1_tpu.core.events import evbuf_init
 from shadow1_tpu.core.outbox import outbox_init
+from shadow1_tpu.telemetry.profiler import PH_ARGS, PH_CALL, run_span
 from shadow1_tpu.fleet.expand import (
     FleetConfigError,
     check_uniform,
@@ -481,7 +482,10 @@ class FleetEngine:
         if st is None:
             st = self.init_state()
         n = n_windows if n_windows is not None else self.n_windows
-        return self._run_jit(st, jnp.asarray(n, jnp.int32), self._variants)
+        with run_span(PH_ARGS):
+            n = jnp.asarray(n, jnp.int32)
+        with run_span(PH_CALL):
+            return self._run_jit(st, n, self._variants)
 
     def hlo_text(self, st: SimState | None = None, n_windows: int = 0) -> str:
         """The optimized HLO text of the fleet's one window program, as

@@ -60,6 +60,7 @@ import time
 import numpy as np
 
 from shadow1_tpu.consts import SEC
+from shadow1_tpu.telemetry.profiler import chunk_log
 from shadow1_tpu.telemetry.registry import DROP_FIELDS, normalize
 
 
@@ -157,6 +158,9 @@ class FleetHeartbeat:
             delta.pop(f, None)
         if self.guard is not None and self.guard.chunk_retries:
             rec["retries"] = self.guard.report()
+        block = chunk_log().block()     # the chunk's boundary, host side
+        if block is not None:
+            rec["chunk"] = block
         self.records.append(rec)
         if self.emit_heartbeat:
             self._emit(rec)
@@ -269,11 +273,13 @@ def run_fleet(engine, st=None, n_windows=None, every_windows=None,
 
     The loop is spanned like ``ckpt.run_chunked``, under the same names
     (telemetry/profiler.py): ``init``, ``compile``, then per chunk
-    ``run-chunk`` ⊃ ``dispatch`` (+ ``sync`` under a profiler), ``commit``
-    (guard), ``drain`` (the per-experiment metrics fetch), ``on-chunk`` (the
+    ``run-chunk`` ⊃ ``dispatch`` (⊃ ``args``, ``call``; + ``sync`` under a
+    profiler), ``wait`` (the chunk log's waiter), ``commit`` (guard),
+    ``drain`` (the per-experiment metrics fetch), ``on-chunk`` (the
     heartbeat), ``retune``, ``checkpoint`` — ``shadow1:`` annotations in any
-    ``jax.profiler`` capture, and Chrome-trace events of ``profiler``
-    (telemetry.PhaseProfiler — CLI ``--fleet --trace``)."""
+    ``jax.profiler`` capture, rows of ``telemetry.chunk_log()``, and
+    Chrome-trace events of ``profiler`` (telemetry.PhaseProfiler — CLI
+    ``--fleet --trace``)."""
     import jax
 
     from shadow1_tpu import ckpt as _ckpt
@@ -293,7 +299,6 @@ def run_fleet(engine, st=None, n_windows=None, every_windows=None,
         PH_INIT,
         PH_ON_CHUNK,
         PH_RETUNE,
-        PH_RUN_CHUNK,
         PH_SYNC,
         maybe_span,
     )
@@ -518,6 +523,7 @@ def run_fleet(engine, st=None, n_windows=None, every_windows=None,
                                    outbox_cap=p.outbox_cap)
         return _repack(keep, st_roll)
 
+    chunks = chunk_log()
     done = 0
     while done < total and engine.n_exp > 0:
         step = min(every_windows, total - done)
@@ -527,11 +533,12 @@ def run_fleet(engine, st=None, n_windows=None, every_windows=None,
         w0 = int(np.asarray(st.win_start).max()) // engine.window
         # What every span of this chunk carries: its first window and size.
         ids = {"done": done, "windows": step}
-        with maybe_span(profiler, PH_RUN_CHUNK, **ids):
+        with chunks.chunk(profiler, engine, st, **ids) as ch:
             with maybe_span(profiler, PH_DISPATCH, **ids):
                 st_new = (OverflowGuard.run_guarded(engine, st, step)
                           if guard is not None
                           else engine.run(st, n_windows=step))
+            ch.watch(st_new)
             if profiler is not None:
                 # Only under a PhaseProfiler: the span covers execution.
                 with maybe_span(profiler, PH_SYNC, **ids):

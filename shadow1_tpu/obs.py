@@ -28,6 +28,7 @@ from shadow1_tpu.telemetry import (
     PH_CHECKPOINT,
     PH_COMPILE,
     PH_DRAIN,
+    chunk_log,
     maybe_span,
     normalize,
 )
@@ -189,6 +190,11 @@ class Heartbeat:
                 "full_cap": getattr(self.engine, "_full_cap", None),
             }
             delta.pop("x2x_max_fill", None)  # a high-water mark, not a rate
+        # The boundary of the chunk that just ran, as the chunk log has it
+        # (the drain above has synced: its row is complete or a moment off).
+        block = chunk_log().block()
+        if block is not None:
+            rec["chunk"] = block
         self.records.append(rec)
         if self.emit_heartbeat:
             self._emit(rec)

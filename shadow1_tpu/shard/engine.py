@@ -62,6 +62,7 @@ from shadow1_tpu.core.engine import (
 )
 from shadow1_tpu.core.events import _hi, _join, _lo, evbuf_init
 from shadow1_tpu.core.outbox import outbox_init
+from shadow1_tpu.telemetry.profiler import PH_ARGS, PH_CALL, run_span
 
 
 class ShardedEngine:
@@ -475,7 +476,10 @@ class ShardedEngine:
             st = self.init_state()
         n = n_windows if n_windows is not None else self.n_windows
         base = int(st.metrics.x2x_overflow)
-        out = self._get_run(self._x2x_cap)(st, jnp.asarray(n, jnp.int32))
+        with run_span(PH_ARGS):
+            n = jnp.asarray(n, jnp.int32)
+        with run_span(PH_CALL):
+            out = self._get_run(self._x2x_cap)(st, n)
         if not check_x2x:
             # A supervising OverflowGuard passes check_x2x=False (through
             # ckpt.run_chunked): the chunk-boundary policy then owns the
@@ -506,7 +510,8 @@ class ShardedEngine:
                 stacklevel=2,
             )
             self._x2x_cap = self._full_cap
-            out = self._get_run(self._x2x_cap)(st, jnp.asarray(n, jnp.int32))
+            with run_span(PH_CALL):
+                out = self._get_run(self._x2x_cap)(st, n)
         total = int(out.metrics.x2x_overflow)
         if total:
             # Loud failure beats silently-wrong results: a full all_to_all

@@ -6,8 +6,9 @@ Four coordinated observability pieces (see docs/OBSERVABILITY.md):
   the jitted window loop, drained at chunk boundaries (the true time series
   the chunk-averaged heartbeat cannot provide);
 * ``telemetry.profiler`` — host-side phase spans: ``shadow1:`` annotations
-  in any ``jax.profiler`` capture, and Chrome trace-event JSON
-  (Perfetto-viewable) under a PhaseProfiler;
+  in any ``jax.profiler`` capture, Chrome trace-event JSON
+  (Perfetto-viewable) under a PhaseProfiler, and one row a chunk in the
+  always-on ``chunk_log()`` (readiness, host health, the ``stall`` line);
 * ``telemetry.phases`` — the window phase of every traced device op, joined
   from the compiled program's text (the TPU trace does not carry scopes);
 * ``telemetry.registry`` — the one named-counter namespace shared by the
@@ -33,6 +34,7 @@ from shadow1_tpu.telemetry.profiler import (  # noqa: F401
     PH_SYNC,
     CompileMeter,
     PhaseProfiler,
+    chunk_log,
     device_trace,
     maybe_span,
 )

@@ -207,6 +207,29 @@ REC_FLOW_GAP = "flow_gap"
 # Fleet rows add the ``exp`` id, same rule as ring records.
 REC_LINK = "link"
 REC_LINK_GAP = "link_gap"
+# Chunk-runner plane (telemetry/profiler.py ChunkLog, docs/OBSERVABILITY.md
+# §"Chunk log"): ``stall`` = one record on stderr, traced run or not, for a
+# chunk that took far longer than the chunks it repeats (or, with no twin,
+# than the chunks before it) — which chunk, its wall against the median,
+# where the excess sat (STALL_PARTS: disjoint; the four spans of the
+# boundary only where the loop ran them) and the host's health over it
+# (CHUNK_HEALTH) beside the medians. The same log feeds every heartbeat's
+# ``chunk`` block (CHUNK_BLOCK + CHUNK_HEALTH; the health keys the host
+# does not expose are absent) and the final JSON's ``chunks`` block
+# (CHUNKS_BLOCK); in both, CHUNK_BOUNDARY are the spans of the boundary
+# before a chunk, present where the loop ran them. Host-clock numbers,
+# never in ring percentile math.
+REC_STALL = "stall"
+STALL_PARTS = ("args", "call", "wait", "commit", "drain", "checkpoint",
+               "retune", "turnaround")
+CHUNK_BOUNDARY = ("commit_ms", "on_chunk_ms", "drain_ms", "checkpoint_ms",
+                  "retune_ms")
+CHUNK_BLOCK = ("dispatch_ms", "wait_ms", "turnaround_ms") + CHUNK_BOUNDARY
+CHUNK_HEALTH = ("cpu_s", "nivcsw", "nvcsw", "majflt", "inblock", "oublock",
+                "psi_cpu_us", "psi_io_us", "psi_mem_us", "load1")
+CHUNKS_BLOCK = ("count", "stalls", "rows", "windows", "dispatch_ms",
+                "args_ms", "call_ms", "wait_ms", "turnaround_ms",
+                "boundary_ms", "boundary_share") + CHUNK_BOUNDARY
 RECORD_TYPES = (REC_HEARTBEAT, REC_TRACKER, REC_RING, REC_RING_GAP,
                 REC_DIGEST, REC_FLEET_EXP, REC_FLEET_SUMMARY,
                 REC_FLEET_RETRY, REC_FLEET_QUARANTINE,
@@ -214,7 +237,7 @@ RECORD_TYPES = (REC_HEARTBEAT, REC_TRACKER, REC_RING, REC_RING_GAP,
                 REC_SERVE, REC_SERVE_JOB, REC_SERVE_QUEUE,
                 REC_SERVE_DEADLINE, REC_SERVE_RETRY,
                 REC_FLOW, REC_FLOW_GAP,
-                REC_LINK, REC_LINK_GAP)
+                REC_LINK, REC_LINK_GAP, REC_STALL)
 
 # Serve-plane job-ledger namespace (shadow1_tpu/serve/daemon.py): exported
 # on the daemon's Prometheus endpoint (--metrics-port) with the

@@ -54,6 +54,11 @@ def test_three_cycles_a_run_leave_nothing_of_the_first_on_the_device(
         # From the initial state: set-up's runs (a solo cell's cache priming
         # in a new checkout, the warm-up), then every cycle's first chunk.
         if not np.asarray(st.metrics.windows).any():
+            # The chunk log's waiter holds a chunk's one scalar until the
+            # result is ready AND its thread has run: let it.
+            from shadow1_tpu.telemetry import chunk_log
+
+            assert chunk_log().settle(5.0)
             live.append(sum(a.nbytes for a in jax.live_arrays()))
         return real_chunk(sim, st, windows)
 
