@@ -83,7 +83,10 @@ import jax
 #      fleet's lanes, core/engine.any_host): five more i64 leaves in every
 #      snapshot. Running sums; a lane sliced out of a fleet carries the
 #      fleet's count so far and continues it solo as fires_* would.
-CKPT_FORMAT = 14
+#  15: Metrics gains runs_window_end (windows in which the program ran the
+#      window end, core/engine.deliver_window's guard): one more i64 leaf in
+#      every snapshot. A running sum like runs_*.
+CKPT_FORMAT = 15
 
 
 class CorruptCheckpointError(ValueError):

@@ -121,6 +121,11 @@ class Metrics(NamedTuple):
     runs_timer: jnp.ndarray
     runs_txr: jnp.ndarray
     runs_app: jnp.ndarray
+    # Windows in which the program ran the window end (``deliver_window``'s
+    # guard: some host of some lane had sent): ``1 - runs_window_end /
+    # windows`` is the share skipped. The same family as runs_*; the sharded
+    # engine, whose window end is unguarded, counts every window.
+    runs_window_end: jnp.ndarray
     # Arriving ranks the window-end merge swept (events.deliver_batch: its
     # fill loop's trips * RB, summed over windows) — against windows * ev_cap
     # it says what a fill by slot would sweep. Batch-engine-only like fires_*.
@@ -573,7 +578,40 @@ def deliver_window(st: SimState, ctx: Ctx, exchange=None) -> SimState:
 
     ``exchange`` maps FlatPackets → (FlatPackets, n_dropped, fill_high_water)
     across the mesh (identity on a single device; a bucketed all_to_all over
-    the host axis when sharded — the one collective per window, SURVEY §2.5)."""
+    the host axis when sharded — the one collective per window, SURVEY §2.5).
+
+    Without an ``exchange`` the window end is one more guard on ``any_host``:
+    it runs only when some host (of some lane, on a fleet) sent this window.
+    With every ``outbox.cnt`` 0 it is the identity on the state — no row is
+    routed or drawn for (the RNG is counter-based, so a draw not made changes
+    no later one), the fill loop makes no trip, the clear writes the zeros
+    ``cnt`` holds, every counter gets ``+ 0`` or ``max(·, 0)`` — so skipping
+    it is exact, and an empty window costs a test of a maintained ``[H]``
+    counter, not a sort of the outbox's capacity (PERF.md §6, PR 40). A lane
+    that sent nothing while another did runs it, as that identity
+    (``run_round``'s contract, one axis up). ``runs_window_end`` counts the
+    windows the program ran it.
+
+    The sharded engine keeps its window end unguarded: ``exchange`` is a
+    collective that every shard must enter, and a predicate that differs by
+    shard would hang it (reducing the predicate over the mesh axis as well is
+    the follow-up for whoever times that engine)."""
+    if exchange is not None:
+        runs = jnp.ones((), bool)
+        st = _window_end(st, ctx, exchange)
+    else:
+        runs = any_host(ctx, st.outbox.cnt > 0)
+        st = jax.lax.cond(runs,
+                          lane_branch(ctx, lambda s: _window_end(s, ctx)),
+                          lambda s: s, st)
+    m = st.metrics
+    return st._replace(metrics=m._replace(
+        runs_window_end=m.runs_window_end + runs.astype(jnp.int64)))
+
+
+def _window_end(st: SimState, ctx: Ctx, exchange=None) -> SimState:
+    """``deliver_window``'s taken branch: route the outbox, exchange, merge
+    into the event buffers, clear, count."""
     from shadow1_tpu.core.outbox import outbox_fill
 
     with jax.named_scope("phase:route"):

@@ -30,7 +30,8 @@ a pass that some other lane needs runs here too, over masks that are all
 false, which is the identity by the handlers' contract (``run_round``),
 and a lane whose own round loop has ended pops nothing in the rounds the
 slower lanes still need, so even per-lane ``rounds`` counts stay exact.
-Only ``Metrics.runs_*`` can tell which lanes rode along.
+Only ``Metrics.runs_*`` and ``runs_window_end`` can tell which lanes rode
+along.
 
 **Recovery plane** (docs/SEMANTICS.md §"Fleet recovery contract"): the
 ``[E, ...]`` state pytree is a well-defined transaction unit, so

@@ -433,7 +433,9 @@ class ShardedEngine:
             )
             # ``windows`` advances identically on every shard (replicated, like
             # win_start) — keep the local count rather than the 8× sum; same
-            # for the pmax-replicated exchange high-water mark. The capacity
+            # for ``runs_window_end`` (the window end is unguarded here: every
+            # shard counts every window) and for the pmax-replicated exchange
+            # high-water mark. The capacity
             # gauges accumulated per-shard LOCAL maxima inside the loop; one
             # cross-shard max here makes them the global run high-water —
             # bit-identical to the single-device values (max of per-window
@@ -442,6 +444,7 @@ class ShardedEngine:
             # is exactly the number that sizes the per-shard bucket.
             return st._replace(metrics=mfin._replace(
                 windows=st.metrics.windows,
+                runs_window_end=st.metrics.runs_window_end,
                 x2x_max_fill=st.metrics.x2x_max_fill,
                 ev_max_fill=pmax_(st.metrics.ev_max_fill),
                 ob_max_fill=pmax_(st.metrics.ob_max_fill),

@@ -77,6 +77,9 @@ METRIC_SPECS: dict[str, tuple[str, str]] = {
     "runs_txr": (COUNTER, "rounds where the program ran the K_TX_RESUME "
                           "pass"),
     "runs_app": (COUNTER, "rounds where the program ran the K_APP pass"),
+    "runs_window_end": (COUNTER, "windows where the program ran the window "
+                                 "end (some host had sent; every window "
+                                 "under sharding)"),
     "deliver_ranks": (COUNTER, "arriving ranks swept by the window-end merge "
                                "(deliver_batch's trips * RB; batch engines)"),
     "link_down_pkts": (COUNTER, "packets dropped: link outage window (fault plane)"),
@@ -111,12 +114,14 @@ METRIC_SPECS: dict[str, tuple[str, str]] = {
 HOST_FIELDS = ("chunk_retries", "retry_windows_rerun")
 
 # Counters of the PROGRAM a lane rode in, not of the lane's simulation: the
-# guard predicate reduced over a fleet's lanes (core/engine.any_host). Equal
-# to fires_* on a solo engine; on a fleet one number in every lane, and
-# another number for the same lane in a fleet of other lanes. A comparison
-# of a fleet lane with its solo run (or with the same lane in another fleet)
-# leaves out exactly these and nothing else.
-LANE_PROGRAM_FIELDS = tuple(f[2] for f in KIND_METRIC_FIELDS.values())
+# guard predicate reduced over a fleet's lanes (core/engine.any_host): the
+# handler passes' (equal to fires_* on a solo engine) and the window end's.
+# On a fleet one number in every lane, and another number for the same lane
+# in a fleet of other lanes. A comparison of a fleet lane with its solo run
+# (or with the same lane in another fleet) leaves out exactly these and
+# nothing else.
+LANE_PROGRAM_FIELDS = tuple(f[2] for f in KIND_METRIC_FIELDS.values()) + (
+    "runs_window_end",)
 
 # JSONL record types every consumer recognises (docs/OBSERVABILITY.md).
 # ``digest`` is the CPU oracle's per-window state-digest row (the batched

@@ -145,6 +145,26 @@ def test_runs_count_the_program_and_fires_the_lane(fleet, solos):
     assert_runs_contract(fleet_metrics_per_exp(st), [m for _, m in solos])
 
 
+def test_runs_window_end_counts_the_windows_in_which_some_lane_sent(fleet):
+    """The window end runs only in a window some lane sent in (``core/engine
+    .deliver_window``): the same run again one window at a call (the same
+    compiled program; the window count is an argument), reading every lane's
+    ``pkts_sent`` after each. The miniature is quiet between the dial and
+    the first transaction at 300 ms, as the cell ``bitcoin5k.flood`` is to
+    2 s, so the guard engages."""
+    eng, st_end = fleet
+    st, sent, before = eng.init_state(), [], np.zeros(len(SEEDS), np.int64)
+    for _ in range(N_WINDOWS):
+        st = eng.run(st, n_windows=1)
+        now = np.asarray(st.metrics.pkts_sent)
+        sent.append(bool((now != before).any()))
+        before = now
+    lanes = fleet_metrics_per_exp(st)
+    assert lanes == fleet_metrics_per_exp(st_end)
+    assert {ln["runs_window_end"] for ln in lanes} == {sum(sent)}
+    assert 0 < sum(sent) < N_WINDOWS == lanes[0]["windows"], sent
+
+
 # ---- (c) every lane is the C++ reference's run under that lane's seed --------
 
 @pytest.mark.parametrize("lane", range(len(SEEDS)))

@@ -217,7 +217,10 @@ def test_fleet_digest_and_metric_parity_vs_solo_tpu_and_cpu(fleet_run):
 
         solo = Engine(exp, plan.params)
         st_solo = solo.run(n_windows=N_WINDOWS)
-        assert Engine.metrics_dict(st_solo) == fleet_m, f"exp {e} metrics"
+        # All but the program's own counts (a PHOLD lane that sends in a
+        # window runs the window end for the lanes that do not).
+        assert lane_metrics(Engine.metrics_dict(st_solo)) \
+            == lane_metrics(fleet_m), f"exp {e} metrics"
         assert digest_stream(st_solo, solo.window) == fleet_digs, \
             f"exp {e} vs solo tpu"
 

@@ -9,11 +9,11 @@ contract). Held here, on miniatures of the fleets the other test files build
 
 * the helper alone: the literal ``mask.any()`` where no lane axis is named,
   one unbatched value under the named axis;
-* the lowered programs: a fleet holds one ``case`` for every handler pass
-  and every bootstrap-phase guard of its app (the per-stream guards of the
-  TCP stack, tgen and filexfer keep the lane's own predicate, which
-  ``vmap`` turns into selects: PERF.md §6, PR 38), and the solo program is
-  what the literal ``mask.any()`` lowers to;
+* the lowered programs: a fleet holds one ``case`` for every handler pass,
+  one for the window end and one for every bootstrap-phase guard of its app
+  (the per-stream guards of the TCP stack, tgen and filexfer keep the lane's
+  own predicate, which ``vmap`` turns into selects: PERF.md §6, PR 38), and
+  the solo program is what the literal ``mask.any()`` lowers to;
 * a tgen fleet whose lanes disagree on which kinds a round holds still
   equals its solo runs leaf for leaf, and ``runs_*`` counts what it should
   (``test_tor_fleet`` and ``test_bitcoin_fleet`` hold the same for their
@@ -47,11 +47,12 @@ from tests.test_tgen_parity import tgen_exp
 from tests.test_tor_fleet import doc20
 
 GUARDED = (core_engine, bitcoin, tor)
-# The TCP stack's passes (deliver, timer, tx-resume, app): reduced over the
-# lanes. Its two guards (passive open, FIN) and tgen's / filexfer's two
-# (teardown) recur with every connection: a ``case`` on the solo engine, the
-# lane's own predicate (selects) on a fleet. Then the app's bootstrap guards.
-PASSES, PER_STREAM = 4, 2
+# The TCP stack's passes (deliver, timer, tx-resume, app) and the window end
+# (``deliver_window``, PR 40): reduced over the lanes. Its two guards (passive
+# open, FIN) and tgen's / filexfer's two (teardown) recur with every
+# connection: a ``case`` on the solo engine, the lane's own predicate
+# (selects) on a fleet. Then the app's bootstrap guards.
+PASSES, PER_STREAM = 4 + 1, 2
 
 
 def _filexfer_exps():

@@ -12,6 +12,14 @@ time ties, full (time, tb) ties, unselected slots and a down host.
 table's one element broadcast over the outbox rows; under ``vmap`` each lane
 keeps its own threshold. A two-vertex network goes through the lookups, as
 before.
+
+And the guard on the whole window end (PR 40): ``core/engine.deliver_window``
+runs its body only when some host (of some lane, on a fleet) sent this
+window. Held against the unguarded body on states that sent and that did
+not, solo and under the fleet's named ``vmap``; on a fleet whose lanes
+disagree, lane against solo run; in the traced programs (the merge's sort
+under a ``cond``); and on the sharded engine, which keeps its window end
+unguarded because its exchange is a collective.
 """
 
 from __future__ import annotations
@@ -38,10 +46,26 @@ from shadow1_tpu.consts import (
     EngineParams,
     packet_tb,
 )
-from shadow1_tpu.core.engine import Engine, FlatPackets, route_outbox
+from shadow1_tpu.core.engine import (
+    Engine,
+    FlatPackets,
+    _window_end,
+    deliver_window,
+    route_outbox,
+)
 from shadow1_tpu.core.events import I64_MAX, tb_split
 from shadow1_tpu.core.outbox import Outbox
+from shadow1_tpu.fleet.engine import (
+    LANE_AXIS,
+    FleetEngine,
+    fleet_metrics_per_exp,
+    slice_experiment,
+)
 from shadow1_tpu.net.nic import ser_delay
+from shadow1_tpu.shard.engine import ShardedEngine
+from shadow1_tpu.telemetry.registry import LANE_PROGRAM_FIELDS
+from shadow1_tpu.tools.opcensus import _sub_jaxprs
+from tests.parity import lane_metrics, unlike_leaves
 from tests.test_net_parity import filexfer_exp
 
 H, EV_CAP, OB_CAP = 6, 16, 8
@@ -66,10 +90,16 @@ def _stack(trees):
 # pre_window
 # ---------------------------------------------------------------------------
 
-def _net_engine() -> Engine:
-    exp = filexfer_exp(n_hosts=H, seed=5, end=SEC)
-    exp.stop_time[3] = 2 * MS  # host 3 is down from 2 ms on: has_stop
-    return Engine(exp, EngineParams(ev_cap=EV_CAP, outbox_cap=OB_CAP))
+def _net_exp(seed=5, loss=0.0, stop=True):
+    exp = filexfer_exp(n_hosts=H, seed=seed, loss=loss, flow=30_000, end=SEC)
+    if stop:
+        exp.stop_time[3] = 2 * MS  # host 3 is down from 2 ms on: has_stop
+    return exp
+
+
+def _net_engine(**params) -> Engine:
+    return Engine(_net_exp(), EngineParams(ev_cap=EV_CAP, outbox_cap=OB_CAP,
+                                           **params))
 
 
 def _arrivals(seed: int):
@@ -341,3 +371,202 @@ def test_route_outbox_one_vertex_fleet_lanes_keep_their_thresholds():
         _assert_trees_equal(jax.tree_util.tree_map(lambda x: x[i], got), solo)
     n_sent, n_lost = np.asarray(got[1]), np.asarray(got[2])
     assert 0 < n_lost[0] / n_sent[0] < 0.25 < 0.4 < n_lost[1] / n_sent[1] < 0.8
+
+
+# ---------------------------------------------------------------------------
+# deliver_window: the window end runs only when some host sent
+# ---------------------------------------------------------------------------
+
+def _end_state(eng: Engine, seed: int, sent: bool):
+    """A state at a window's end: events pending, and an outbox that holds
+    rows (``sent``) or none — stale rows above ``cnt`` either way."""
+    st = eng.init_state()
+    ob = _outbox(seed)
+    if not sent:
+        ob = ob._replace(cnt=jnp.zeros_like(ob.cnt))
+    return st._replace(evbuf=st.evbuf._replace(**_arrivals(seed)),
+                       outbox=ob._replace(pkt_ctr=st.outbox.pkt_ctr))
+
+
+def _counted(st, runs):
+    m = st.metrics
+    return st._replace(metrics=m._replace(
+        runs_window_end=m.runs_window_end + jnp.asarray(runs, jnp.int64)))
+
+
+@pytest.fixture(scope="module", params=[0, 1], ids=["links_off", "links_on"])
+def end_engine(request):
+    eng = _net_engine(link_telem=request.param)
+    assert eng.ctx.has_stop and eng.ctx.lane_axis is None
+    assert (eng.init_state().links is not None) == bool(request.param)
+    return eng
+
+
+@pytest.mark.parametrize("sent", [False, True], ids=["empty", "sent"])
+def test_the_guarded_window_end_equals_the_unguarded_body(end_engine, sent):
+    """Solo: with an empty outbox the state comes back leaf for leaf
+    (``runs_window_end`` too: + 0), which is also what the body makes of it;
+    with rows in it, the body's result and one window counted."""
+    ctx = end_engine.ctx
+    st = _end_state(end_engine, 31, sent)
+    got = jax.jit(lambda s: deliver_window(s, ctx))(st)
+    body = jax.jit(lambda s: _window_end(s, ctx))(st)
+    _assert_trees_equal(got, _counted(body, int(sent)))
+    if not sent:
+        _assert_trees_equal(got, st)
+    else:
+        assert int(got.metrics.pkts_sent) == int(np.asarray(st.outbox.cnt).sum())
+        assert int(got.metrics.pkts_delivered) > 0
+        assert int(got.metrics.down_pkts) > 0          # has_stop is in play
+        assert int(np.asarray(got.outbox.cnt).sum()) == 0
+        assert unlike_leaves(got, st)
+
+
+@pytest.mark.parametrize("sent", [(False, False), (True, False), (True, True)],
+                         ids=["none_sent", "one_sent", "both_sent"])
+def test_under_the_lane_axis_the_window_end_runs_for_all_lanes_or_none(
+        end_engine, sent):
+    """Fleet: the predicate is "some host of some lane". A lane that sent
+    nothing beside one that did runs the body, as the identity; every lane
+    equals its solo ``deliver_window`` but for the program's own count,
+    which is one number in all lanes."""
+    ctx = dataclasses.replace(end_engine.ctx, lane_axis=LANE_AXIS)
+    sts = [_end_state(end_engine, 41 + i, s) for i, s in enumerate(sent)]
+    got = jax.jit(jax.vmap(lambda s: deliver_window(s, ctx),
+                           axis_name=LANE_AXIS))(_stack(sts))
+    body = jax.jit(jax.vmap(lambda s: _window_end(s, ctx)))(_stack(sts))
+    _assert_trees_equal(got, _counted(body, [int(any(sent))] * len(sent)))
+    for i, st in enumerate(sts):
+        lane = jax.tree_util.tree_map(lambda x: x[i], got)
+        solo = jax.jit(lambda s: deliver_window(s, end_engine.ctx))(st)
+        assert not unlike_leaves(lane, solo)
+        assert int(solo.metrics.runs_window_end) == int(sent[i])
+        assert int(lane.metrics.runs_window_end) == int(any(sent))
+        if not sent[i]:
+            assert not unlike_leaves(lane, st)    # rode along as the identity
+
+
+def _eqns_under(jaxpr, inside=()):
+    """(eqn, names of the control-flow eqns it sits in) for every equation,
+    sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn, inside
+        for v in eqn.params.values():
+            for sub in _sub_jaxprs(v):
+                yield from _eqns_under(sub, inside + (eqn.primitive.name,))
+
+
+def _merge_sorts(jaxpr):
+    """``deliver_batch``'s sort (one operand; ``pre_window``'s carry four or
+    five), each with the control flow it sits in."""
+    return [inside for eqn, inside in _eqns_under(jaxpr)
+            if eqn.primitive.name == "sort" and len(eqn.invars) == 1]
+
+
+@pytest.mark.parametrize("which", ["solo", "fleet"])
+def test_the_merge_s_sort_sits_inside_the_window_end_s_conditional(which):
+    """The traced window program: the one sort of ``deliver_batch`` is under
+    a ``cond`` (so an empty window does not pay it), on a fleet too: a
+    ``cond`` with a per-lane predicate would have been batched away
+    (``test_fleet_guards`` counts the lowered ``case`` ops)."""
+    if which == "solo":
+        eng = _net_engine()
+        args = (jax.eval_shape(eng.init_state), jnp.asarray(0, jnp.int32))
+    else:
+        eng = FleetEngine([_net_exp(5), _net_exp(6, loss=0.05)],
+                          EngineParams(ev_cap=EV_CAP, outbox_cap=OB_CAP))
+        args = (jax.eval_shape(eng.init_state), jnp.asarray(0, jnp.int32),
+                eng._variants)
+    jaxpr = jax.make_jaxpr(eng._run_jit)(*args).jaxpr
+    sorts = _merge_sorts(jaxpr)
+    assert len(sorts) == 1 and "cond" in sorts[0], sorts
+    # The window loop holds it; the round loop (a while inside) does not.
+    assert sorts[0].count("while") == 1, sorts
+
+
+N_RUN = 120
+
+
+@pytest.fixture(scope="module")
+def quiet_fleet():
+    """Two filexfer lanes: the lossy one stalls on a lost segment in window
+    10 while the lossless one sends on to window 16; both are silent until
+    the lossy lane's retransmit timers pop at 1.0 s (window 100)."""
+    exps = [_net_exp(5, stop=False), _net_exp(6, loss=0.2, stop=False)]
+    params = EngineParams(ev_cap=256, outbox_cap=32, metrics_ring=N_RUN)
+    st = FleetEngine(exps, params).run(n_windows=N_RUN)
+    solos = [Engine(exp, params).run(n_windows=N_RUN) for exp in exps]
+    return st, solos
+
+
+@pytest.mark.parametrize("lane", range(2))
+def test_a_lane_of_a_fleet_whose_lanes_send_in_other_windows_equals_its_solo_run(
+        quiet_fleet, lane):
+    st, solos = quiet_fleet
+    assert not unlike_leaves(slice_experiment(st, lane), solos[lane])
+    m = fleet_metrics_per_exp(st)[lane]
+    assert m["pkts_sent"] > 40 and m["ev_overflow"] == m["ob_overflow"] == 0
+    assert lane_metrics(m) == lane_metrics(Engine.metrics_dict(solos[lane]))
+
+
+def test_runs_window_end_counts_the_windows_in_which_some_lane_sent(quiet_fleet):
+    from shadow1_tpu.telemetry.ring import drain_ring
+
+    st, solos = quiet_fleet
+    assert "runs_window_end" in LANE_PROGRAM_FIELDS
+    lanes = fleet_metrics_per_exp(st)
+    solo = [Engine.metrics_dict(s) for s in solos]
+
+    def sent_windows(s):
+        rows = drain_ring(s, 10 * MS)
+        assert len(rows) == N_RUN
+        return {r["window"] for r in rows if r["pkts_sent"]}
+
+    per_lane = [sent_windows(s) for s in solos]
+    assert all(len(w) == m["runs_window_end"] for w, m in zip(per_lane, solo))
+    union = per_lane[0] | per_lane[1]
+    # One number in every lane: the windows in which SOME lane sent — more
+    # than either lane's own (the lanes disagreed), fewer than all (the
+    # guard engaged).
+    assert [ln["runs_window_end"] for ln in lanes] == [len(union)] * 2
+    assert max(m["runs_window_end"] for m in solo) < len(union) < N_RUN
+    assert all(ln["windows"] == N_RUN for ln in lanes)
+    assert FleetEngine.metrics_dict(st)["runs_window_end"] == len(union)
+
+
+# ---- the sharded engine keeps its window end unguarded ------------------------
+
+@pytest.fixture(scope="module")
+def sharded2():
+    exp = _net_exp(5, stop=False)
+    params = EngineParams(ev_cap=256, outbox_cap=32)
+    return exp, params, ShardedEngine(exp, params, devices=jax.devices()[:2])
+
+
+def test_the_sharded_window_end_s_all_to_all_is_under_no_conditional(sharded2):
+    """Every shard must enter the exchange every window: a predicate that
+    differed by shard would hang the collective."""
+    _, _, sh = sharded2
+    assert sh.n_dev == 2
+    st = jax.eval_shape(sh.init_state)
+    jaxpr = jax.make_jaxpr(sh._get_run(sh._x2x_cap))(
+        st, jnp.asarray(0, jnp.int32)).jaxpr
+    a2a = [inside for eqn, inside in _eqns_under(jaxpr)
+           if eqn.primitive.name == "all_to_all"]
+    assert a2a and all("cond" not in inside for inside in a2a), a2a
+    assert all("cond" not in inside for inside in _merge_sorts(jaxpr))
+
+
+def test_the_sharded_engine_keeps_parity_over_empty_windows_and_counts_them_all(
+        sharded2):
+    exp, params, sh = sharded2
+    m2 = ShardedEngine.metrics_dict(sh.run(n_windows=N_RUN))
+    m1 = Engine.metrics_dict(Engine(exp, params).run(n_windows=N_RUN))
+    for k in ("events", "windows", "pkts_sent", "pkts_delivered", "pkts_lost",
+              "ev_overflow", "ob_overflow", "x2x_overflow", "pops_deliver",
+              "pops_timer", "pops_app", "outbox_hosts", "active_hosts"):
+        assert m2[k] == m1[k], (k, m2[k], m1[k])
+    assert m1["pkts_sent"] > 40
+    # The solo engine skipped its empty windows; the sharded one ran all.
+    assert m1["runs_window_end"] < m1["windows"] == N_RUN
+    assert m2["runs_window_end"] == m2["windows"] == N_RUN
