@@ -242,26 +242,102 @@ def _group_values(name, val, conv, count, idx):
     return conv(val)
 
 
+def _ring_chord_peers(h: int, k: int) -> np.ndarray:
+    """Ring ±1 plus power-of-4 chords: a circulant, the same for every h."""
+    chords = [1]
+    while len(chords) < k // 2:
+        chords.append(chords[-1] * 4)
+    peers = np.zeros((h, k), np.int32)
+    for ci, c in enumerate(chords):
+        peers[:, 2 * ci] = (np.arange(h) - c) % h
+        peers[:, 2 * ci + 1] = (np.arange(h) + c) % h
+    return peers
+
+
+def _random_regular_peers(h: int, k: int, seed: int) -> np.ndarray:
+    """A random simple connected k-regular graph on h nodes as a ``[h, k]``
+    table, each row its node's neighbours in ascending order.
+
+    The pairing model (a random perfect matching of the h·k half-edges),
+    then every self-loop and double edge is switched against an edge drawn
+    at random ((u, v), (x, y) → (u, x), (v, y), redrawn until neither new
+    edge is a loop or already there): near uniform for k ≪ h (McKay &
+    Wormald 1990). The whole draw is made again until the graph is
+    connected, which at k ≥ 3 it nearly always is the first time. Every
+    draw comes from ``np.random.RandomState(seed)``, whose stream numpy has
+    frozen: one (h, k, seed) is one table, here and in ten years."""
+    from scipy.sparse import coo_matrix
+    from scipy.sparse.csgraph import connected_components
+
+    assert 0 < k < h and (h * k) % 2 == 0, (
+        f"graph.kind random_regular: no simple {k}-regular graph on {h} nodes")
+    rs = np.random.RandomState(seed)
+
+    def key(a, b):
+        return (a, b) if a < b else (b, a)
+
+    while True:
+        stubs = rs.permutation(np.repeat(np.arange(h), k)).tolist()
+        edges, have, bad = [], set(), []
+        for u, v in zip(stubs[0::2], stubs[1::2]):
+            if u == v or key(u, v) in have:
+                bad.append((u, v))
+            else:
+                have.add(key(u, v))
+                edges.append((u, v))
+        for u, v in bad:
+            for _ in range(64 * k):
+                i = int(rs.randint(len(edges)))
+                x, y = edges[i] if rs.randint(2) else edges[i][::-1]
+                new = {key(u, x), key(v, y)}
+                if u != x and v != y and len(new) == 2 and not new & have:
+                    break
+            else:
+                break       # no switch found (a graph near complete): redraw
+            have.remove(key(x, y))
+            have |= new
+            edges[i] = (u, x)
+            edges.append((v, y))
+        if len(edges) != h * k // 2:
+            continue
+        a, b = np.asarray(edges, np.int32).T
+        src, dst = np.concatenate([a, b]), np.concatenate([b, a])
+        adj = coo_matrix((np.ones(src.size, np.int8), (src, dst)), shape=(h, h))
+        if connected_components(adj, directed=False)[0] == 1:
+            order = np.lexsort((dst, src))
+            return dst[order].reshape(h, k)
+
+
+_GRAPH_KINDS = ("ring_chord", "random_regular")
+
+
 def _gen_bitcoin_cfg(model_cfg: dict, h: int, seed: int) -> None:
     """Expand bitcoin's generator specs into concrete arrays.
 
-    ``graph: {kind: ring_chord, k: K}`` → symmetric K-regular peer graph
-    (ring ±1 plus power-of-4 chords); ``tx: {count, start, interval}`` →
-    staggered transactions at config-RNG-chosen origins. Explicit ``peers``
-    / ``tx_origin`` / ``tx_time`` arrays may be given instead.
+    ``graph: {kind: ring_chord, k: K}`` (the default) → symmetric K-regular
+    peer graph (ring ±1 plus power-of-4 chords); ``graph: {kind:
+    random_regular, k: K, seed: S}`` → a random simple connected K-regular
+    graph drawn from ``S`` alone, not from ``general.seed``: every lane of a
+    seed study peers alike (the fleet refuses lanes that differ in
+    ``peers``). ``tx: {count, start, interval}`` → staggered transactions at
+    config-RNG-chosen origins. Explicit ``peers`` / ``tx_origin`` /
+    ``tx_time`` arrays may be given instead.
     """
     if "peers" not in model_cfg:
         gspec = model_cfg.pop("graph", {})
+        _reject_unknown("app.params.graph", gspec, ("kind", "k", "seed"))
+        kind = gspec.get("kind", "ring_chord")
+        assert kind in _GRAPH_KINDS, (
+            f"unknown app.params.graph.kind {kind!r} (known: {_GRAPH_KINDS})")
         k = int(gspec.get("k", 8))
-        assert k % 2 == 0 and k >= 2
-        chords = [1]
-        while len(chords) < k // 2:
-            chords.append(chords[-1] * 4)
-        peers = np.zeros((h, k), np.int32)
-        for ci, c in enumerate(chords):
-            peers[:, 2 * ci] = (np.arange(h) - c) % h
-            peers[:, 2 * ci + 1] = (np.arange(h) + c) % h
-        model_cfg["peers"] = peers
+        if kind == "random_regular":
+            model_cfg["peers"] = _random_regular_peers(
+                h, k, int(gspec.get("seed", 0)))
+        else:
+            assert "seed" not in gspec, \
+                "app.params.graph.seed: ring_chord draws nothing"
+            assert k % 2 == 0 and k >= 2
+            model_cfg["peers"] = _ring_chord_peers(h, k)
     if "tx_origin" not in model_cfg:
         tspec = model_cfg.pop("tx", {})
         count = int(tspec.get("count", 50))
@@ -462,8 +538,9 @@ def build_experiment(doc: dict, base_dir: str = ".") -> tuple[CompiledExperiment
         path = net["graphml"]
         if not os.path.isabs(path):
             path = os.path.join(base_dir, path)
-        names, lat_e, loss_e, directed = load_graphml(path)
-        lat_vv, loss_vv = compile_paths(lat_e, loss_e, directed=directed)
+        names, lat_e, loss_e, directed, prefer_direct = load_graphml(path)
+        lat_vv, loss_vv = compile_paths(lat_e, loss_e, directed=directed,
+                                        prefer_direct=prefer_direct)
     else:
         sv = net.get("single_vertex", {})
         names = ["v0"]

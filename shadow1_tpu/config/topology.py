@@ -14,7 +14,10 @@ vertices with many attached hosts):
 
 Edge attributes honored (reference GraphML schema): ``latency`` (float,
 *milliseconds* — Shadow convention) or ``latency_ns``; ``packetloss``
-(probability). Vertices are the points of presence hosts attach to.
+(probability). Vertices are the points of presence hosts attach to. The
+graph attribute ``preferdirectpaths`` (Shadow's; "True"/"False") makes an
+edge the path between its two ends even where a detour is shorter: a table
+of measured end-to-end latencies is then read as it stands.
 """
 
 from __future__ import annotations
@@ -25,8 +28,9 @@ from shadow1_tpu.consts import MS
 
 
 def load_graphml(path: str):
-    """Returns (vertex_ids, lat_e, loss_e, directed): node id list (stable
-    order) and dense [V, V] edge matrices (np.inf / 0 where no edge).
+    """Returns (vertex_ids, lat_e, loss_e, directed, prefer_direct): node id
+    list (stable order), dense [V, V] edge matrices (np.inf / 0 where no
+    edge), and whether the graph sets ``preferdirectpaths``.
 
     Directed GraphML (Shadow's published Tor topologies use
     edgedefault="directed" with possibly asymmetric latencies) keeps each
@@ -56,13 +60,21 @@ def load_graphml(path: str):
         if not directed:
             lat[j, i] = l_ns
             loss[j, i] = p
-    return nodes, lat, loss, directed
+    prefer = str(g.graph.get("preferdirectpaths", "false")).lower()
+    if prefer not in ("true", "false"):
+        raise ValueError(f"{path}: preferdirectpaths must be True or False, "
+                         f"not {prefer!r}")
+    return nodes, lat, loss, directed, prefer == "true"
 
 
 def compile_paths(lat_e: np.ndarray, loss_e: np.ndarray,
                   self_latency_ns: int | None = None,
-                  directed: bool = False) -> tuple[np.ndarray, np.ndarray]:
+                  directed: bool = False,
+                  prefer_direct: bool = False) -> tuple[np.ndarray, np.ndarray]:
     """All-pairs min-latency paths → (lat_vv i64 ns, loss_vv f32).
+
+    With ``prefer_direct`` two vertices joined by an edge use that edge's
+    latency and loss, whatever a detour would give.
 
     Loss accumulates along each chosen latency-shortest path via the
     predecessor matrix (vectorized back-walk, ≤V steps). The diagonal
@@ -103,6 +115,10 @@ def compile_paths(lat_e: np.ndarray, loss_e: np.ndarray,
         p_safe = np.where(active, prev, 0)
         rel *= np.where(active, rel_e[p_safe, cur], 1.0)
         cur = np.where(active, p_safe, cur)
+    if prefer_direct:
+        direct = np.isfinite(lat_e)
+        dist = np.where(direct, lat_e, dist)
+        rel = np.where(direct, rel_e, rel)
     lat_vv = np.rint(dist).astype(np.int64)
     np.fill_diagonal(lat_vv, np.rint(self_lat).astype(np.int64))
     loss_vv = (1.0 - rel).astype(np.float32)

@@ -497,11 +497,16 @@ def route_outbox(ctx: Ctx, ob: Outbox, links=None, win_start=None):
             # Inside the fleet's vmap a per-lane table is [1, 1] too.
             return jnp.broadcast_to(table.reshape(()), fmask.shape)
     else:
-        vs = ctx.host_vertex[fsrc]
-        vd = ctx.host_vertex[fdst_safe]
+        # The two kinds of read a V > 1 network pays per outbox row, each
+        # under a scope of its own so that a trace prices them apart
+        # (docs/OBSERVABILITY.md "Phase spans, device traces ...").
+        with jax.named_scope("phase:route_vertex"):
+            vs = ctx.host_vertex[fsrc]
+            vd = ctx.host_vertex[fdst_safe]
 
         def vv(table):
-            return table[vs, vd]
+            with jax.named_scope("phase:route_path"):
+                return table[vs, vd]
 
     arrival = fdep + vv(ctx.lat_vv)
     if ctx.has_jitter:
