@@ -237,8 +237,16 @@ class Ctx:
     # sets it: its lanes), None where there is none (solo, sharded). Read
     # by ``any_host`` and by nothing else.
     lane_axis: str | None = None
+    # ``host_vertex`` as its runs of equal vertex over contiguous global
+    # ids — ((first id, vertex), …), read off the concrete map below —
+    # where they are few; None where they are many (``vertex: spread``).
+    # Read by ``vertex_of`` and by nothing else.
+    vertex_runs: tuple | None = None
 
     def __post_init__(self):
+        if self.vertex_runs is None:
+            object.__setattr__(self, "vertex_runs",
+                               _vertex_runs(self.host_vertex))
         if self.hosts is None:
             # Single-device default: the block IS the whole host range.
             object.__setattr__(self, "hosts", jnp.arange(self.n_hosts, dtype=jnp.int32))
@@ -250,6 +258,49 @@ class Ctx:
                 "loss_thr_vv",
                 jnp.asarray(rng.prob_threshold(np.asarray(self.loss_vv))),
             )
+
+
+# route_outbox's two static bounds, on what it can see when it is traced.
+# A read of ``host_vertex`` or of a [V, V] path table with one index per
+# outbox row is an element-serial gather on the TPU, 7–13 ns a row and half
+# word whatever the outbox holds (PERF.md §6, PR 34 and PR 42); the same read
+# as compares and selects over the rows fuses into the arrival add and the
+# loss compare. What bounds the dense form is the size of the traced
+# program, not the chip's time:
+# - a host → vertex map costs one compare and select per run and row, so it
+#   is dense up to this many runs (one per host group attached in order; the
+#   largest config under configs/ has 6) and a gather beyond (``vertex:
+#   spread`` has one run per host);
+MAX_VERTEX_RUNS = 32
+# - a path table costs V − 1 selects per row and V · (V − 1) more over [H]
+#   for the per-host rows, for each of up to three tables: 720 + 45 at 16
+#   vertices, four times that at 32. A GraphML topology with more vertices
+#   keeps ``table[vs, vd]``.
+MAX_DENSE_VERTICES = 16
+
+
+def _vertex_runs(host_vertex):
+    """((first global id, vertex), …) of the map's runs, or None where it
+    has more than MAX_VERTEX_RUNS of them."""
+    hv = np.asarray(host_vertex)
+    starts = np.flatnonzero(np.diff(hv, prepend=hv[:1] - 1))
+    if len(starts) > MAX_VERTEX_RUNS:
+        return None
+    return tuple((int(s), int(hv[s])) for s in starts)
+
+
+def vertex_of(ctx: Ctx, ids):
+    """``ctx.host_vertex[ids]`` for in-range GLOBAL host ids: a chain of
+    compares against the runs' first ids where the map has few runs (vertex
+    ids need not rise with host id: a later run overwrites), the gather
+    where it has many."""
+    if ctx.vertex_runs is None:
+        return ctx.host_vertex[ids]
+    (_, v), *runs = ctx.vertex_runs
+    out = jnp.full(ids.shape, v, ctx.host_vertex.dtype)
+    for start, v in runs:
+        out = jnp.where(ids >= start, jnp.asarray(v, out.dtype), out)
+    return out
 
 
 Handler = Callable[[SimState, Popped], SimState]
@@ -465,11 +516,22 @@ def route_outbox(ctx: Ctx, ob: Outbox, links=None, win_start=None):
     path's threshold — replaced by an active timed loss ramp's, same coin
     bits either way. Returns (flat_packets, n_sent, n_lost, n_linkdown).
 
-    The path tables are read per outbox row as ``table[vs, vd]`` — except on
-    a one-vertex network (static table shape [1, 1]: every ``configs/`` file
-    but the GraphML ones), where each read is the table's single element
-    broadcast over the rows and ``host_vertex`` is not looked at: a gather
-    there is an element-serial fusion on the TPU (PERF.md §6, PR 34).
+    Every outbox row needs ``table[vs, vd]`` of each path table, and no
+    read has an index per row where the network lets it be arithmetic: a
+    gather over the rows is an element-serial fusion on the TPU, paid for
+    every slot, filled or not (PERF.md §6, PR 34 and PR 42). On a one-vertex
+    network (static table shape [1, 1]: every ``configs/`` file but the
+    GraphML ones) each read is the table's single element broadcast over the
+    rows and ``host_vertex`` is not looked at. With more vertices ``vs`` is
+    the block's slice of ``host_vertex`` broadcast down the slot axis
+    (``src`` is ``ctx.hosts`` in every slot, a contiguous range of ids);
+    ``vd`` is ``vertex_of(dst)``: compares against the runs of
+    ``host_vertex`` where they are few, the lookup where they are many
+    (``vertex: spread``); and a table of up to MAX_DENSE_VERTICES vertices
+    is read by two selects, over ``vs`` per host and over ``vd`` per slot,
+    the table's own integers bit for bit — a larger one by the lookup
+    ``table[vs, vd]``. Which form is traced depends on the map and the
+    tables' shape alone, the same on every engine.
 
     With the link plane on (``links`` a LinkAccum, ``win_start`` the window
     start), every offered packet's edge contribution — counts, wire bytes,
@@ -499,14 +561,39 @@ def route_outbox(ctx: Ctx, ob: Outbox, links=None, win_start=None):
     else:
         # The two kinds of read a V > 1 network pays per outbox row, each
         # under a scope of its own so that a trace prices them apart
-        # (docs/OBSERVABILITY.md "Phase spans, device traces ...").
+        # (docs/OBSERVABILITY.md "Phase spans, device traces ..."), and
+        # neither with an index per row where the bounds above allow: the
+        # block's hosts are a contiguous range of ids, and every slot of a
+        # column has the column's source.
+        n_v = ctx.lat_vv.shape[0]
         with jax.named_scope("phase:route_vertex"):
-            vs = ctx.host_vertex[fsrc]
-            vd = ctx.host_vertex[fdst_safe]
+            vs_h = ctx.host_vertex   # of the whole range, or of this block
+            if h != ctx.n_total:
+                vs_h = jax.lax.dynamic_slice_in_dim(vs_h, ctx.hosts[0], h)
+            vs = flat(jnp.broadcast_to(vs_h[None, :], (cap, h)))
+            vd_ch = vertex_of(ctx, jnp.where(mask, ob.dst, 0))
+            vd = flat(vd_ch)
 
         def vv(table):
             with jax.named_scope("phase:route_path"):
-                return table[vs, vd]
+                if n_v > MAX_DENSE_VERTICES:
+                    return table[vs, vd]
+                # table[vs, vd], the table's own integers: per host the row
+                # table[vs_h, :] by a select over the source vertex (a
+                # constant for a closed-over table, [H] a lane for the
+                # fleet's thresholds), then per slot a select over vd, the
+                # row broadcast down the slot axis; flattened once.
+                def col(j):
+                    c = table[0, j]
+                    for i in range(1, n_v):
+                        c = jnp.where(vs_h == i, table[i, j], c)
+                    return c
+
+                out = jnp.broadcast_to(col(0)[None, :], (cap, h))
+                for j in range(1, n_v):
+                    c = col(j)
+                    out = jnp.where(vd_ch == j, c[None, :], out)
+                return flat(out)
 
     arrival = fdep + vv(ctx.lat_vv)
     if ctx.has_jitter:

@@ -13,10 +13,12 @@ Bitcoin), solo and under ``vmap`` over two lanes, no ``gather`` equation has
 a frame in ``core/dense.py`` or ``tcp/tcp.py``, and in PHOLD's and tgen's
 none comes from ``rng._neg_log1m_q32``. At the window's ends of a one-vertex
 TCP model (tgen, Tor, Bitcoin) the ``prepare`` phase holds no ``gather`` at
-all and ``route_outbox`` none in the ``deliver`` phase; rung 1, which has two
-vertices, keeps its four lookups. On the v5e such a gather is an
+all and ``route_outbox`` none in the ``deliver`` phase; nor on rung 1, whose
+two vertices each hold one run of hosts (its four lookups are compares and
+selects since PR 42), while a ``vertex: spread`` map of the same network
+keeps exactly one, ``host_vertex[dst]``. On the v5e such a gather is an
 element-serial kCustom fusion, 7–13.5 ns an element (PERF.md §6, PR 26,
-PR 31, PR 33 and PR 34).
+PR 31, PR 33, PR 34 and PR 42).
 """
 
 from __future__ import annotations
@@ -121,14 +123,21 @@ def _frames(eqn) -> set[tuple[str, str]]:
 
 
 @functools.lru_cache(maxsize=None)
-def _phases(config: str):
-    """(the window's phase fns by name, their entry frame) for an experiment
-    file, its path from the repo's root — the jaxprs tools/opcensus.py
-    traces."""
-    from shadow1_tpu.core.engine import window_frame, window_phases
+def _engine(config: str):
+    """The solo engine of an experiment file, its path from the repo's
+    root."""
     from shadow1_tpu.tools.phaseprobe import build_engine
 
-    eng, _ = build_engine(os.path.join(ROOT, config))
+    return build_engine(os.path.join(ROOT, config))[0]
+
+
+@functools.lru_cache(maxsize=None)
+def _phases(config: str):
+    """(the window's phase fns by name, their entry frame) for an experiment
+    file — the jaxprs tools/opcensus.py traces."""
+    from shadow1_tpu.core.engine import window_frame, window_phases
+
+    eng = _engine(config)
     phases = dict(window_phases(eng.ctx, eng._handlers, None, eng._pre_window,
                                 eng._model.make_handlers, None))
     return phases, window_frame(eng.init_state(), eng.ctx)
@@ -206,7 +215,7 @@ def test_exponential_draw_has_no_gather(draw_rounds, lanes):
 # static guard: no gather at the window's ends of a one-vertex TCP model
 # ---------------------------------------------------------------------------
 
-RUNG1 = "configs/rung1_filexfer.yaml"   # two vertices: the lookups stay
+RUNG1 = "configs/rung1_filexfer.yaml"   # two vertices, two runs of hosts
 ONE_VERTEX = {
     "tgen100": "configs/rung2_tgen100.yaml",
     "tor20": "benchmarks/tests/rehearsal/configs/tor20.yaml",
@@ -244,11 +253,49 @@ def test_one_vertex_window_ends_have_no_gather(config, lanes):
             f"{gathers[0]}")
 
 
+# Rung 1 with forty more clients dealt round-robin over its two vertices
+# (``vertex: spread``): a run of ``host_vertex`` per host, more than
+# core/engine.MAX_VERTEX_RUNS.
+SPREAD = """
+general: {{seed: 11, stop_time: 2 s}}
+engine: {{scheduler: tpu, ev_cap: 128}}
+network: {{graphml: {root}/configs/topology_2pop.graphml}}
+hosts:
+  - {{name: server, count: 1, vertex: pop_west, bandwidth_up: 10 Mbit, bandwidth_down: 10 Mbit}}
+  - {{name: client, count: 40, vertex: spread, bandwidth_up: 10 Mbit, bandwidth_down: 10 Mbit}}
+app:
+  model: filexfer
+  groups:
+    server: {{role: 0}}
+    client: {{role: 1, server: "@server", flow_bytes: 10000, flow_count: 1, start_time: 1 ms}}
+"""
+
+
+@pytest.fixture(scope="module")
+def route_nets(tmp_path_factory):
+    """name -> (experiment file, gathers ``route_outbox`` may trace)."""
+    spread = tmp_path_factory.mktemp("spread") / "spread_filexfer.yaml"
+    spread.write_text(SPREAD.format(root=ROOT))
+    return {"rung1": (RUNG1, 0), "spread": (str(spread), 1)}
+
+
 @pytest.mark.parametrize("lanes", [0, 2], ids=["solo", "vmap2"])
-def test_two_vertex_route_keeps_its_lookups(lanes):
-    """Rung 1 has two vertices: host_vertex[src], host_vertex[dst],
-    lat_vv[vs, vd] and loss_thr_vv[vs, vd] are read per outbox row, as
-    before — which also shows that the guard above sees such reads."""
-    prepare, route = _window_end_eqns(RUNG1, lanes)
-    assert sum(prim == "gather" for prim, _ in route) == 4
+@pytest.mark.parametrize("net", ["rung1", "spread"])
+def test_route_gathers_by_network(route_nets, net, lanes):
+    """With two vertices ``route_outbox`` reads the vertices and both path
+    tables: where ``host_vertex`` is a few runs of hosts (rung 1) none of
+    the reads is a lookup; where it is a run per host, ``host_vertex[dst]``
+    stays one and is the only one — which also shows that the guard above
+    sees such reads."""
+    from shadow1_tpu.core.engine import MAX_VERTEX_RUNS
+
+    config, n_gathers = route_nets[net]
+    ctx = _engine(config).ctx
+    assert ctx.lat_vv.shape == (2, 2)
+    assert (ctx.vertex_runs is None) == (net == "spread")
+    runs = 1 + int((np.diff(np.asarray(ctx.host_vertex)) != 0).sum())
+    assert (runs > MAX_VERTEX_RUNS) == (net == "spread")
+    prepare, route = _window_end_eqns(config, lanes)
+    assert sum(prim == "gather" for prim, _ in route) == n_gathers
+    assert all("vertex_of" in fns for prim, fns in route if prim == "gather")
     assert not [fns for prim, fns in prepare if prim == "gather"]

@@ -169,6 +169,70 @@ def test_link_drop_columns_reconcile_with_global_counters(churn):
     assert tm["pkts_lost"] > 0 and tm["link_down_pkts"] > 0
 
 
+def test_fault_and_link_planes_on_three_vertices_equal_the_oracle():
+    """The fault plane's gates and the link plane's scatter take
+    ``route_outbox``'s ``vs`` / ``vd``, which on a network of a few vertices
+    are compares against the runs of ``host_vertex`` and no lookup (PR 42):
+    three vertices, four runs of unequal length whose vertex ids do not rise
+    with host id, an outage on one directed edge, a ramp on another — the
+    drop counters and the [V, V] matrix of every link column equal the CPU
+    oracle's."""
+    import dataclasses
+
+    from shadow1_tpu.consts import MS, SEC
+    from shadow1_tpu.fault.schedule import FaultSchedule
+    from tests.test_fault import assert_fault_parity
+
+    h = 8
+    exp = filexfer_exp(n_hosts=h, seed=5, flow=300_000, end=2 * SEC)
+    exp.model_cfg["start_time"] = (1 + np.arange(h) * 10) * MS
+    exp = dataclasses.replace(
+        exp,
+        # the server alone on vertex 2; clients on 0, 1 and 0 again
+        host_vertex=np.array([2, 0, 0, 0, 1, 1, 0, 0], np.int32),
+        lat_vv=np.array([[10, 30, 20], [30, 12, 25], [20, 25, 15]],
+                        np.int64) * MS,
+        loss_vv=np.array([[0, 0, 0.01], [0, 0, 0], [0.02, 0, 0]], np.float32),
+        # under a packet's 1 ms on the wire: no reordering
+        jitter_vv=np.array([[0, 0, 2], [0, 0, 1], [2, 1, 0]], np.int64)
+        * (MS // 10),
+        faults=FaultSchedule(
+            link_src=[2], link_dst=[0], link_t0=[500 * MS], link_t1=[620 * MS],
+            ramp_src=[2, 1], ramp_dst=[1, 2], ramp_t0=[700 * MS, 700 * MS],
+            ramp_t1=[1600 * MS, 1600 * MS], ramp_loss=[0.10, 0.10]),
+    )
+    exp.validate()
+    params = EngineParams(ev_cap=512, link_telem=1)
+    ctx = Engine(exp, params).ctx
+    assert ctx.vertex_runs == ((0, 2), (1, 0), (4, 1), (6, 0))
+    assert ctx.has_link_fault and ctx.has_loss_ramp and ctx.has_jitter
+
+    st, trows = tpu_rows(exp, params, n_windows=None)
+    tm = Engine.metrics_dict(st)
+    ceng = CpuEngine(exp, params)
+    cm = ceng.run()
+    assert_fault_parity(cm, tm)
+    assert trows == sorted(ceng.link_rows, key=_key)
+
+    def matrix(col):
+        m = np.zeros((3, 3), np.int64)
+        for r in trows:   # cumulative snapshots: the last window's stands
+            m[r["src_vertex"], r["dst_vertex"]] = r[col]
+        return m
+
+    pkts, down, lost = (matrix(c) for c in
+                        ("pkts", "link_down_drops", "loss_drops"))
+    assert pkts.sum() == tm["pkts_sent"]
+    # Clients talk to the server only: every packet has vertex 2 at an end.
+    assert (pkts[2, :2] > 0).all() and (pkts[:2, 2] > 0).all()
+    assert not pkts[:2, :2].any() and not pkts[2, 2]
+    # The outage took packets on its edge alone; the ramp's edges, which
+    # have no loss of their own, lost some.
+    assert down[2, 0] == tm["link_down_pkts"] > 0 and down.sum() == down[2, 0]
+    assert lost[2, 1] > 0 and lost[1, 2] > 0
+    assert lost.sum() == tm["pkts_lost"]
+
+
 @pytest.mark.slow
 def test_link_nic_backlog_attribution():
     from tests.test_fidelity import _filexfer

@@ -10,8 +10,13 @@ time ties, full (time, tb) ties, unselected slots and a down host.
 ``core/engine.route_outbox``: on a one-vertex network (table shape [1, 1])
 ``lat_vv[vs, vd]``, ``jitter_vv[vs, vd]`` and ``loss_thr_vv[vs, vd]`` are the
 table's one element broadcast over the outbox rows; under ``vmap`` each lane
-keeps its own threshold. A two-vertex network goes through the lookups, as
-before.
+keeps its own threshold. With more vertices (PR 42) ``vs`` is ``host_vertex``
+broadcast down the slot axis, ``vd`` compares of ``dst`` against the runs of
+``host_vertex`` and ``table[vs, vd]`` two selects: held to the gathered form
+on two, three, six and seventeen vertices, on runs of unequal length and
+vertex ids that do not rise with host id, on a ``spread`` map and beyond
+MAX_DENSE_VERTICES (the two forms that stay lookups), for a shard's block of
+hosts, and under ``vmap`` with per-lane ``[V, V]`` thresholds.
 
 And the guard on the whole window end (PR 40): ``core/engine.deliver_window``
 runs its body only when some host (of some lane, on a fleet) sent this
@@ -47,6 +52,8 @@ from shadow1_tpu.consts import (
     packet_tb,
 )
 from shadow1_tpu.core.engine import (
+    MAX_DENSE_VERTICES,
+    MAX_VERTEX_RUNS,
     Engine,
     FlatPackets,
     _window_end,
@@ -290,48 +297,95 @@ def _route_outbox_gathered(ctx, ob):
 
 
 def _phold(**net):
+    h = len(net["host_vertex"])
     return Engine(CompiledExperiment(
-        n_hosts=H, seed=7, end_time=SEC,
-        bw_up=np.full(H, 10**9, np.int64), bw_dn=np.full(H, 10**9, np.int64),
+        n_hosts=h, seed=7, end_time=SEC,
+        bw_up=np.full(h, 10**9, np.int64), bw_dn=np.full(h, 10**9, np.int64),
         model="phold", model_cfg={"mean_delay_ns": float(MS)}, **net)).ctx
 
 
-def _ctx(vertices: int):
-    if vertices == 1:
+def _runs(*runs):
+    """host_vertex from (vertex, length) runs."""
+    return np.repeat([v for v, _ in runs], [n for _, n in runs]).astype(
+        np.int32)
+
+
+# name -> (host_vertex, vertex_runs is kept). V1 and V2 have written-out
+# tables; the others' are drawn: every entry of a table distinct, so that a
+# read of the wrong entry shows.
+NETS = {
+    "V1": (np.zeros(H, np.int32), True),
+    "V2": (np.array([0, 1, 1, 0, 1, 0], np.int32), True),
+    # six runs of unequal length, as bitcoin5k_regions' six regions
+    "V6_runs6": (_runs((0, 5), (1, 7), (2, 1), (3, 3), (4, 2), (5, 2)), True),
+    # vertex ids that do not rise with host id, one vertex in two runs
+    "V3_unordered": (_runs((2, 3), (0, 4), (1, 2), (0, 3)), True),
+    # ``vertex: spread``: a run per host, more than MAX_VERTEX_RUNS
+    "V3_spread": (np.arange(MAX_VERTEX_RUNS + 8, dtype=np.int32) % 3, False),
+    # more vertices than MAX_DENSE_VERTICES: table[vs, vd] stays a lookup
+    "V17": (np.repeat(np.arange(MAX_DENSE_VERTICES + 1), 2).astype(np.int32),
+            True),
+}
+
+
+def _ctx(net: str):
+    host_vertex, _ = NETS[net]
+    if net == "V1":
         return _phold(lat_vv=np.full((1, 1), WIN, np.int64),
                       loss_vv=np.full((1, 1), 0.3, np.float32),
                       jitter_vv=np.full((1, 1), 2 * MS, np.int64),
-                      host_vertex=np.zeros(H, np.int32))
-    return _phold(lat_vv=np.array([[10, 25], [40, 15]], np.int64) * MS,
-                  loss_vv=np.array([[0.0, 0.5], [0.9, 0.2]], np.float32),
-                  jitter_vv=np.array([[0, 2], [3, 1]], np.int64) * MS,
-                  host_vertex=np.array([0, 1, 1, 0, 1, 0], np.int32))
+                      host_vertex=host_vertex)
+    if net == "V2":
+        return _phold(lat_vv=np.array([[10, 25], [40, 15]], np.int64) * MS,
+                      loss_vv=np.array([[0.0, 0.5], [0.9, 0.2]], np.float32),
+                      jitter_vv=np.array([[0, 2], [3, 1]], np.int64) * MS,
+                      host_vertex=host_vertex)
+    v = int(host_vertex.max()) + 1
+    r = np.random.default_rng(v)
+    # Two thirds of the latencies beyond 32 bits of ns, so that both half
+    # words are read; the smallest, 7 ms, is the window.
+    perm = r.permutation(v * v).reshape(v, v).astype(np.int64)
+    lat = (1 + perm) * 7 * MS + (perm % 3 << 33)
+    return _phold(lat_vv=lat,
+                  loss_vv=(r.permutation(v * v).reshape(v, v)
+                           / (v * v)).astype(np.float32),
+                  jitter_vv=r.permutation(v * v).reshape(v, v) * 1000 + 1,
+                  host_vertex=host_vertex)
 
 
-def _outbox(seed: int) -> Outbox:
+def _outbox(seed: int, h: int = H) -> Outbox:
     r = np.random.default_rng(seed)
-    shape = (OB_CAP, H)
+    shape = (OB_CAP, h)
     dhi, dlo = tb_split(jnp.asarray(r.integers(0, WIN, shape), jnp.int64))
     i32 = lambda a: jnp.asarray(a, jnp.int32)
-    cnt = r.integers(0, OB_CAP + 1, H)
+    cnt = r.integers(0, OB_CAP + 1, h)
     cnt[0], cnt[1] = OB_CAP, 0  # a full column and an empty one
     # Live rows name real hosts, as the engine's do; rows at or above cnt
     # hold stale destinations, some out of range.
-    dst = r.integers(0, H + 3, shape)
-    dst = np.where(np.arange(OB_CAP)[:, None] < cnt[None, :], dst % H, dst)
+    dst = r.integers(0, h + 3, shape)
+    dst = np.where(np.arange(OB_CAP)[:, None] < cnt[None, :], dst % h, dst)
     return Outbox(
         dst=i32(dst), kind=i32(r.integers(1, 5, shape)),
         depart_hi=dhi, depart_lo=dlo, ctr=i32(r.integers(0, 1 << 20, shape)),
         p=i32(r.integers(0, 1 << 20, (NP,) + shape)), cnt=i32(cnt),
-        pkt_ctr=jnp.asarray(r.integers(0, 1 << 20, H), jnp.int64),
+        pkt_ctr=jnp.asarray(r.integers(0, 1 << 20, h), jnp.int64),
     )
 
 
-@pytest.mark.parametrize("vertices", [1, 2], ids=["V1", "V2"])
-def test_route_outbox_equals_gathered_form(vertices):
-    ctx = _ctx(vertices)
-    assert ctx.has_jitter and ctx.lat_vv.shape == (vertices, vertices)
-    ob = _outbox(11)
+@pytest.mark.parametrize("net", NETS)
+def test_route_outbox_equals_gathered_form(net):
+    ctx = _ctx(net)
+    host_vertex, few_runs = NETS[net]
+    v = int(host_vertex.max()) + 1
+    assert ctx.has_jitter and ctx.lat_vv.shape == (v, v)
+    assert (ctx.vertex_runs is not None) == few_runs
+    if few_runs:
+        starts, verts = zip(*ctx.vertex_runs)
+        assert starts[0] == 0 and len(starts) <= MAX_VERTEX_RUNS
+        np.testing.assert_array_equal(
+            np.repeat(verts, np.diff(starts + (len(host_vertex),))),
+            host_vertex)
+    ob = _outbox(11, ctx.n_hosts)
     got = jax.jit(lambda o: route_outbox(ctx, o))(ob)
     want = jax.jit(lambda o: _route_outbox_gathered(ctx, o))(ob)
     _assert_trees_equal(got, want)
@@ -340,22 +394,45 @@ def test_route_outbox_equals_gathered_form(vertices):
     assert 0 < int(n_lost) < int(n_sent) and int(n_linkdown) == 0
     flight = (np.asarray(fp.arrival)
               - np.asarray(ob.abs_depart()).reshape(-1))[np.asarray(fp.keep)]
-    if vertices == 1:
+    if net == "V1":
         assert flight.min() >= 8 * MS and flight.max() <= 12 * MS
         assert len(set(flight.tolist())) > 1          # the jitter draws
-    else:
+    elif net == "V2":
         assert flight.min() < 12 * MS and flight.max() > 35 * MS  # by path
+    else:
+        # Many paths of the network were taken, each at its own latency.
+        assert len(set((flight // MS).tolist())) > v
 
 
-def test_route_outbox_one_vertex_fleet_lanes_keep_their_thresholds():
+@pytest.mark.parametrize("block", [0, 1], ids=["lo", "hi"])
+@pytest.mark.parametrize("net", ["V6_runs6", "V3_spread"])
+def test_route_outbox_of_a_shard_block_equals_gathered_form(net, block):
+    """As the sharded engine calls it: ``hosts`` a traced block of the
+    global ids, ``host_vertex`` the whole map, destinations global."""
+    ctx = _ctx(net)
+    n = ctx.n_hosts // 2
+    ob = _outbox(31 + block, n)
+    # Live rows name hosts of either block.
+    ob = ob._replace(dst=jnp.where(
+        jnp.arange(OB_CAP)[:, None] < ob.cnt[None, :],
+        (ob.dst * 7 + 3) % ctx.n_hosts, ob.dst))
+    hosts = jnp.arange(block * n, (block + 1) * n, dtype=jnp.int32)
+
+    def of_block(route):
+        return jax.jit(lambda o, hs: route(
+            dataclasses.replace(ctx, n_hosts=n, hosts=hs), o))(ob, hosts)
+
+    got = of_block(route_outbox)
+    _assert_trees_equal(got, of_block(_route_outbox_gathered))
+    assert int(got[1]) > int(got[2]) > 0
+    assert (np.asarray(got[0].dst)[np.asarray(got[0].keep)] >= n).any()
+
+
+def _lanes_keep_their_thresholds(ctx, thr, key, seeds=(21, 22)):
     """Under the fleet's vmap the lane's loss_thr_vv (and its key) are
-    batched leaves of shape [1, 1]: each lane draws against its own."""
-    ctx = _ctx(1)
-    obs = [_outbox(21), _outbox(22)]
-    thr = jnp.stack([
-        jnp.asarray(rng.prob_threshold(np.full((1, 1), p, np.float32)))
-        for p in (0.05, 0.6)])
-    key = jnp.stack([rng.base_key(101), rng.base_key(202)])
+    batched leaves: each lane draws against its own, and equals the solo
+    program closed over that lane's constants."""
+    obs = [_outbox(s, ctx.n_hosts) for s in seeds]
 
     def lanes(route):
         return jax.jit(jax.vmap(lambda o, t, k: route(
@@ -364,13 +441,42 @@ def test_route_outbox_one_vertex_fleet_lanes_keep_their_thresholds():
 
     got = lanes(route_outbox)
     _assert_trees_equal(got, lanes(_route_outbox_gathered))
-    # Each lane equals the solo program closed over that lane's constants.
     for i, ob in enumerate(obs):
         solo = route_outbox(
             dataclasses.replace(ctx, loss_thr_vv=thr[i], key=key[i]), ob)
         _assert_trees_equal(jax.tree_util.tree_map(lambda x: x[i], got), solo)
+    return got, obs
+
+
+def test_route_outbox_one_vertex_fleet_lanes_keep_their_thresholds():
+    thr = jnp.stack([
+        jnp.asarray(rng.prob_threshold(np.full((1, 1), p, np.float32)))
+        for p in (0.05, 0.6)])
+    key = jnp.stack([rng.base_key(101), rng.base_key(202)])
+    got, _ = _lanes_keep_their_thresholds(_ctx("V1"), thr, key)
     n_sent, n_lost = np.asarray(got[1]), np.asarray(got[2])
     assert 0 < n_lost[0] / n_sent[0] < 0.25 < 0.4 < n_lost[1] / n_sent[1] < 0.8
+
+
+@pytest.mark.parametrize("net", ["V2", "V6_runs6", "V3_spread"])
+def test_route_outbox_fleet_lanes_keep_their_path_thresholds(net):
+    """The same with V > 1: a traced, batched [V, V] table read by selects
+    (or, on the spread map, through the gathered vd)."""
+    ctx = _ctx(net)
+    v = ctx.lat_vv.shape[0]
+    # Lane 0 loses only on paths out of vertex 0, lane 1 only on the others.
+    p = np.zeros((2, v, v), np.float32)
+    p[0, 0, :], p[1, 1:, :] = 0.7, 0.7
+    thr = jnp.stack([jnp.asarray(rng.prob_threshold(x)) for x in p])
+    key = jnp.stack([rng.base_key(101), rng.base_key(202)])
+    (fp, _, n_lost, _), obs = _lanes_keep_their_thresholds(ctx, thr, key)
+    sent = (np.arange(OB_CAP)[:, None]
+            < np.asarray(_stack(obs).cnt)[:, None, :]).reshape(2, -1)
+    lost = ~np.asarray(fp.keep) & sent
+    from_v0 = np.tile(np.asarray(ctx.host_vertex) == 0, OB_CAP)
+    assert lost[0].any() and lost[1].any()
+    assert not (lost[0] & ~from_v0).any() and not (lost[1] & from_v0).any()
+    assert (np.asarray(n_lost) == lost.sum(axis=1)).all()
 
 
 # ---------------------------------------------------------------------------
