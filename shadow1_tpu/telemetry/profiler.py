@@ -44,7 +44,7 @@ import sys
 import threading
 import time
 
-from shadow1_tpu.telemetry.registry import REC_STALL
+from shadow1_tpu.telemetry.registry import CHUNK_TOTALS, REC_STALL
 
 # Canonical phase names (docs/OBSERVABILITY.md) — free-form names are
 # allowed, but the wired-in call sites use these.
@@ -236,8 +236,41 @@ def _engine_no(engine) -> int:
 
 
 def _windows_leaf(st):
-    """The one scalar of a state that the log ever touches."""
+    """The one scalar of a RESULT that the log ever touches."""
     return getattr(getattr(st, "metrics", None), "windows", None)
+
+
+# What a row keeps of its chunk's INPUT state besides ``first_window``: the
+# running totals a reader subtracts from the next row's (``work_between``).
+_TOTALS = CHUNK_TOTALS[:-1]
+
+
+def _input_leaves(st) -> tuple:
+    """The scalars of an input state that the log reads: ``metrics.windows``
+    and the ``_TOTALS`` (None where the state has none)."""
+    m = getattr(st, "metrics", None)
+    return tuple(getattr(m, k, None) for k in ("windows", *_TOTALS))
+
+
+def _host_count(engine) -> int | None:
+    """``n_hosts`` x lanes of an engine, or None where it does not say."""
+    n = getattr(getattr(engine, "exp", None), "n_hosts", None)
+    return None if n is None else int(n) * int(getattr(engine, "n_exp", 1))
+
+
+def work_between(row: dict, after: dict | None) -> dict | None:
+    """What the chunk of ``row`` did — events, rounds (a lane's own, summed
+    over lanes), ``active_hosts`` and ``elig_events`` (sums over its
+    windows) — where ``after`` is the row of the chunk that continued it:
+    the same engine's, adjacent in ``seq``, starting on the window ``row``
+    ended on. Else None: a row's totals are of its chunk's START."""
+    if (after is None or after["engine"] != row["engine"]
+            or after["seq"] != row["seq"] + 1
+            or row.get("first_window") is None
+            or after.get("first_window") != row["first_window"] + row["windows"]
+            or any(k not in r for r in (row, after) for k in _TOTALS)):
+        return None
+    return {k: after[k] - row[k] for k in _TOTALS}
 
 
 def _pressure_us(what: str) -> int | None:
@@ -290,9 +323,12 @@ class _Chunk:
         self.log = log
         self.row = self.leaf = None
         if log.enabled:
-            self.leaf = _windows_leaf(st)
+            self.leaf = _input_leaves(st)
             self.row = {"seq": next(log._seq), "engine": _engine_no(engine),
                         "done": done, "windows": windows}
+            hosts = _host_count(engine)
+            if hosts is not None:
+                self.row["hosts"] = hosts
 
     def __enter__(self):
         _THREAD.chunk = self
@@ -329,6 +365,10 @@ class ChunkLog:
     A row: ``seq`` (chunks in the order they were opened), ``engine`` (a
     number of the engine object), ``done`` (the loop's count),
     ``first_window`` (the input state's ``metrics.windows``), ``windows``,
+    ``events``, ``rounds``, ``active_hosts``, ``elig_events`` (the input
+    state's running totals, summed over a fleet's lanes: what the chunk did
+    is the NEXT row's less these, ``work_between``) and ``hosts`` (the
+    engine's ``n_hosts`` x lanes), where the state and the engine have them,
     ``enter_ns`` (the ``run-chunk`` span opening), ``dispatched_ns``
     (``dispatch`` closing), ``ready_ns`` (the result ready), the durations
     ``dispatch_ns`` ⊃ ``args_ns``, ``call_ns``; of the boundary before the
@@ -344,12 +384,14 @@ class ChunkLog:
     a chunk judged a stall; ``error`` where the result's readiness raised.
 
     Readiness is taken by ONE daemon thread, started with the first chunk,
-    asleep on its queue between chunks: it blocks on one scalar leaf of the
-    result (never the state) under a ``wait`` span, stamps ``ready_ns`` and
-    gives the leaf up at once; it closes the row (health, verdict, into the
-    log) when the next chunk is handed over, not while the caller runs
-    (``_wait``). The caller pays two clock reads and one queue put a chunk;
-    a row in the log is complete, and holds plain numbers only. Loops on
+    asleep on its queue between chunks: handed a chunk, it reads the five
+    scalars of the input state's metrics and gives them up, blocks
+    on one scalar leaf of the result (never the state) under a ``wait``
+    span, stamps ``ready_ns`` and gives the leaf up at once; it closes the
+    row (health, verdict, into the log) when the next chunk is handed over
+    and its input read, not while the caller runs (``_wait``). The caller
+    pays two clock reads and one queue put a chunk; a row in the log is
+    complete, and holds plain numbers only. Loops on
     two threads share the waiter: one's chunk is stamped after the
     other's that was handed over before it."""
 
@@ -434,9 +476,17 @@ class ChunkLog:
                     timeout=None if row is None else self.LINGER_S)
             except queue.Empty:
                 item = _FLUSH
+            handed = isinstance(item, list)
+            if handed:
+                # Before the held row is closed: it is this chunk's totals
+                # that say what the held one did (work_between).
+                try:
+                    self._input(item)
+                except Exception as e:
+                    item[0]["error"] = repr(e)
             if row is not None:
                 try:
-                    self._close(row)
+                    self._close(row, item[0] if handed else None)
                 except Exception as e:  # the log must never end a run
                     row["error"] = repr(e)
                 row = None
@@ -453,16 +503,40 @@ class ChunkLog:
             finally:
                 item.clear()    # nothing of a state stays, whatever came
 
+    @staticmethod
+    def _input(item: list) -> None:
+        """Read what the row of ``item`` keeps of its chunk's INPUT state
+        (``_input_leaves``: ``first_window``, the maximum over a fleet's
+        lanes, and the running totals, summed over them) and give the
+        leaves up. The input is ready no later than the result, and the
+        caller has just handed the chunk over: it is about to wait. One
+        plain read after another: ``copy_to_host_async`` (``device_get``
+        starts every copy with it) leaves the array it was last called on
+        alive until its next call, a leaf of a state its chunk is done with
+        (tests/test_bench_cycles.py's live-array sum finds the 8 bytes)."""
+        import numpy as np
+
+        row, leaves = item[0], item[1]
+        item[1] = None
+        if leaves is None:
+            return
+        values = [None if x is None else np.asarray(x) for x in leaves]
+        del leaves
+        row["first_window"] = (None if values[0] is None
+                               else int(np.max(values[0])))
+        for k, v in zip(_TOTALS, values[1:]):
+            if v is not None:
+                row[k] = int(np.sum(v))
+
     def _ready(self, item: list) -> dict:
-        """Wait for the chunk of ``item`` (its row, the input state's
-        scalar, the result's, the PhaseProfiler, the spans' arguments) and
-        stamp its row. The scalars are given up the moment the result is
-        ready and nothing else is done then: the caller may drop its state
-        right away, and the log must keep no leaf of it alive."""
-        row, leaf_in, leaf_out, profiler, ids = item
-        # The input is ready no later than the result: read it first.
-        row["first_window"] = self._scalar(leaf_in)
-        item[1] = leaf_in = None
+        """Wait for the chunk of ``item`` (its row, nothing where the input
+        state's scalars were, the result's one scalar, the PhaseProfiler,
+        the spans' arguments) and stamp its row. The scalar is given up the
+        moment the result is ready and nothing else is done then: the
+        caller may drop its state right away, and the log must keep no
+        leaf of it alive."""
+        row, _, leaf_out, profiler, ids = item
+        row.setdefault("first_window", None)
         with _carried(profiler, PH_WAIT, ids):
             try:
                 block = getattr(leaf_out, "block_until_ready", None)
@@ -474,18 +548,11 @@ class ChunkLog:
             item[2] = leaf_out = block = None
         return row
 
-    @staticmethod
-    def _scalar(leaf) -> int | None:
-        if leaf is None:
-            return None
-        import numpy as np
-
-        return int(np.max(np.asarray(leaf)))
-
-    def _close(self, row: dict) -> None:
+    def _close(self, row: dict, after: dict | None = None) -> None:
         """The host's health up to now, the turnaround, the verdict; then
         the row is in the log. Every chunk, while the caller waits for the
-        next one: three walks of at most ``KEEP`` rows."""
+        next one (``after`` is that one's row, its input's totals read):
+        three walks of at most ``KEEP`` rows."""
         now = _health_now()
         health = {k: now[k] - self._health[k] for k in now if k in self._health}
         health["cpu_s"] = round(health["cpu_s"], 6)
@@ -527,6 +594,7 @@ class ChunkLog:
                 row["stall"] = round(wall / median, 2)
                 self.stalls += 1
                 line = self._stall_line(row, base, against, median)
+                line.update(self._stall_work(row, after, base, mine))
         with self._lock:
             self._rows.append(row)
             self.count += 1
@@ -582,6 +650,26 @@ class ChunkLog:
             "health": row["health"], "median_of_health": health,
         }
 
+    @staticmethod
+    def _stall_work(row: dict, after: dict | None, base: list,
+                    mine: list) -> dict:
+        """Beside a stall line's wall: the rounds and events of the chunk,
+        where the chunk that continued it is known, and the medians over
+        the rows it was judged by that have one in the log — a chunk that
+        did more than they did was slow for that."""
+        did = work_between(row, after)
+        if did is None:
+            return {}
+        by_seq = {r["seq"]: r for r in (*mine, row)}
+        theirs = [w for w in (work_between(r, by_seq.get(r["seq"] + 1))
+                              for r in base) if w is not None]
+        out = {"rounds": did["rounds"], "events": did["events"]}
+        if theirs:
+            out.update(
+                median_of_rounds=statistics.median(w["rounds"] for w in theirs),
+                median_of_events=statistics.median(w["events"] for w in theirs))
+        return out
+
     # -- readers -----------------------------------------------------------------
     def rows(self, wait_s: float = 1.0) -> list[dict]:
         """Copies of the kept rows, oldest first (after ``settle(wait_s)``:
@@ -608,6 +696,7 @@ class ChunkLog:
         for key in ("turnaround_ns", *_BOUNDARY_SPANS.values()):
             if key in row:
                 out[_ms_key(key)] = _ms(row[key])
+        out.update({k: row[k] for k in CHUNK_TOTALS if k in row})
         return {**out, **row["health"]}
 
     def summary(self, wait_s: float = 1.0) -> dict:
@@ -644,6 +733,12 @@ class ChunkLog:
         for key in _BOUNDARY_SPANS.values():
             if any(key in r for r in rows):
                 out[_ms_key(key)] = med(r[key] for r in rows if key in r)
+        # What those chunks did, of each that the next row continues.
+        did = [w for w in map(work_between, rows, rows[1:]) if w is not None]
+        if did:
+            out.update({k: sum(w[k] for w in did) for k in _TOTALS})
+        if "hosts" in rows[-1]:
+            out["hosts"] = rows[-1]["hosts"]
         out["boundary_ms"] = round(out["dispatch_ms"]
                                    + (out["turnaround_ms"] or 0.0), 4)
         # Medians, so that the warm-up's dispatch (it compiles) is one row.

@@ -229,12 +229,22 @@ STALL_PARTS = ("args", "call", "wait", "commit", "drain", "checkpoint",
                "retune", "turnaround")
 CHUNK_BOUNDARY = ("commit_ms", "on_chunk_ms", "drain_ms", "checkpoint_ms",
                   "retune_ms")
-CHUNK_BLOCK = ("dispatch_ms", "wait_ms", "turnaround_ms") + CHUNK_BOUNDARY
+# A chunk-log row's running totals of its chunk's INPUT state (Metrics
+# fields, summed over a fleet's lanes) and ``hosts`` (n_hosts x lanes): in a
+# heartbeat's ``chunk`` block as the row has them (totals at the chunk's
+# START); in ``chunks`` what the kept chunks did, each the next row's totals
+# less its own (absent with no two rows that follow one another). A stall
+# line prints the chunk's ``rounds`` and ``events`` and their medians over
+# the rows it was judged by (STALL_WORK) where the next row is known.
+CHUNK_TOTALS = ("events", "rounds", "active_hosts", "elig_events", "hosts")
+STALL_WORK = ("rounds", "events", "median_of_rounds", "median_of_events")
+CHUNK_BLOCK = (("dispatch_ms", "wait_ms", "turnaround_ms") + CHUNK_TOTALS
+               + CHUNK_BOUNDARY)
 CHUNK_HEALTH = ("cpu_s", "nivcsw", "nvcsw", "majflt", "inblock", "oublock",
                 "psi_cpu_us", "psi_io_us", "psi_mem_us", "load1")
 CHUNKS_BLOCK = ("count", "stalls", "rows", "windows", "dispatch_ms",
                 "args_ms", "call_ms", "wait_ms", "turnaround_ms",
-                "boundary_ms", "boundary_share") + CHUNK_BOUNDARY
+                "boundary_ms", "boundary_share") + CHUNK_TOTALS + CHUNK_BOUNDARY
 RECORD_TYPES = (REC_HEARTBEAT, REC_TRACKER, REC_RING, REC_RING_GAP,
                 REC_DIGEST, REC_FLEET_EXP, REC_FLEET_SUMMARY,
                 REC_FLEET_RETRY, REC_FLEET_QUARANTINE,

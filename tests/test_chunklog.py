@@ -553,10 +553,157 @@ def test_the_cli_s_heartbeats_and_last_line_carry_the_documented_blocks(tmp_path
         # heartbeat of the chunk before (its drain inside it, or beside).
         want = REQUIRED_CHUNK | {"on_chunk_ms", "drain_ms"}
         if i == 0:
-            want = {"dispatch_ms", "wait_ms"}
+            want = {"dispatch_ms", "wait_ms"} | set(registry.CHUNK_TOTALS)
         assert want <= set(block) <= set(registry.CHUNK_BLOCK + registry.CHUNK_HEALTH)
         assert want == set(block) - set(registry.CHUNK_HEALTH)
         assert block["dispatch_ms"] > 0 and block["wait_ms"] >= 0
         assert "cpu_s" in block and "load1" in block
     # Nothing of the log on stdout: the last line is still the result.
     assert all('"stall"' not in ln for ln in out.stdout.splitlines())
+    # The totals: a heartbeat's are of its chunk's START (none before the
+    # first chunk; they only grow), the last line's what the chunks did that
+    # a kept row continues: all but the last one's.
+    hosts = 8 * (2 if fleet else 1)
+    assert [hb["chunk"]["hosts"] for hb in beats] == [hosts] * 4
+    assert [beats[0]["chunk"][k] for k in registry.CHUNK_TOTALS[:-1]] == [0] * 4
+    for k in ("events", "rounds", "elig_events"):
+        at = [hb["chunk"][k] for hb in beats]
+        assert at == sorted(at) and at[-1] > 0, (k, at)
+        assert last["chunks"][k] == at[-1]
+    assert last["chunks"]["hosts"] == hosts
+    assert 0 < last["chunks"]["active_hosts"] <= 6 * hosts
+
+
+# ---- what a chunk did: the totals of its input state ---------------------------
+
+@pytest.mark.parametrize("loop", [_solo, _fleet])
+def test_a_row_carries_the_totals_of_its_input_state_and_the_host_count(log, loop):
+    """Read with ``first_window`` from the state a chunk is handed: zeros on
+    the first row, and on the last what a run of the windows before it ends
+    on (summed over a fleet's lanes); two rows that follow one another give
+    the first one's work, and the last row, which nothing follows, none."""
+    eng, st = loop(6, 2)
+    jax.block_until_ready(st)
+    rows = log.rows()
+    lanes = getattr(eng, "n_exp", 1)
+    assert [r["hosts"] for r in rows] == [16 * lanes] * 3
+    assert [rows[0][k] for k in profiler._TOTALS] == [0, 0, 0, 0]
+    at4 = eng.run(n_windows=4).metrics
+    assert {k: rows[2][k] for k in profiler._TOTALS} == {
+        k: int(np.sum(np.asarray(getattr(at4, k)))) for k in profiler._TOTALS}
+    did = [profiler.work_between(a, b) for a, b in zip(rows, rows[1:])]
+    assert all(d["events"] > 0 and d["rounds"] > 0 and d["elig_events"] > 0
+               and 0 < d["active_hosts"] <= 2 * 16 * lanes for d in did)
+    assert {k: sum(d[k] for d in did) for k in profiler._TOTALS} == {
+        k: rows[2][k] for k in profiler._TOTALS}
+    assert profiler.work_between(rows[2], None) is None
+    # Not adjacent, or not the window the first ended on: no work either.
+    assert profiler.work_between(rows[0], rows[2]) is None
+    assert profiler.work_between(rows[0], {**rows[1], "first_window": 0}) is None
+    assert profiler.work_between(rows[0], {**rows[1], "engine": -1}) is None
+    s = log.summary()
+    assert {k: s[k] for k in registry.CHUNK_TOTALS} == {
+        **{k: rows[2][k] for k in profiler._TOTALS}, "hosts": 16 * lanes}
+
+
+def counted(windows, clock=None, late_ms=0.0, **totals):
+    st = state(windows, clock, late_ms)
+    for k, v in totals.items():
+        setattr(st.metrics, k, Leaf(v))
+    return st
+
+
+class CountingEngine(FakeEngine):
+    """A fake engine whose states count: a call adds ``(rounds, events)``
+    from ``work`` (by the call's number) or ``(10, 100)`` to every lane."""
+
+    exp = types.SimpleNamespace(n_hosts=8)
+    n_exp = 2
+
+    def __init__(self, clock, work=None, **kw):
+        super().__init__(clock, **kw)
+        self.work = work or {}
+
+    def run(self, st, n_windows=None):
+        rounds, events = self.work.get(self.calls, (10, 100))
+        out = super().run(st, n_windows)
+        m = st.metrics
+        for k, add in (("rounds", rounds), ("events", events),
+                       ("active_hosts", 3), ("elig_events", events)):
+            setattr(out.metrics, k, Leaf(np.asarray(getattr(m, k)) + add))
+        return out
+
+
+def zeros(lanes=2):
+    return counted(0, **{k: np.zeros(lanes, np.int64) for k in profiler._TOTALS})
+
+
+def test_rows_of_fake_states_without_totals_carry_none(log, clock):
+    cycles(log, FakeEngine(clock), 1)
+    rows = log.rows()
+    assert len(rows) == 3
+    assert not any(set(r) & set(registry.CHUNK_TOTALS) for r in rows)
+    assert all(profiler.work_between(a, b) is None for a, b in zip(rows, rows[1:]))
+    assert not set(log.summary()) & set(registry.CHUNK_TOTALS)
+
+
+def test_the_stall_line_prints_the_chunk_s_rounds_and_events_beside_its_wall(
+        log, clock, capsys):
+    """A loop whose chunks follow one another: the chunk that took 60x its
+    neighbours' wall did 50x their rounds, and the line says so; the loop's
+    last chunk stalls too, nothing continues it, and its line has no work."""
+    eng = CountingEngine(clock, slow={5: (10.0, 1200.0), 7: (10.0, 900.0)},
+                         work={5: (500, 4000)})
+    eng.n_windows = 16
+    run_chunked(eng, zeros(), n_windows=16, chunk=2,
+                on_chunk=lambda st, done: log.settle(5.0) or time.sleep(0.08)
+                if done == 16 else None)
+    assert log.settle(5.0)
+    busy, last = stall_lines(capsys)
+    assert (busy["first_window"], busy["against"]) == (10, "neighbours")
+    assert set(registry.STALL_WORK) <= set(busy)
+    # Summed over the two lanes.
+    assert (busy["rounds"], busy["events"]) == (1000, 8000)
+    assert (busy["median_of_rounds"], busy["median_of_events"]) == (20, 200)
+    assert last["first_window"] == 14 and not set(registry.STALL_WORK) & set(last)
+    rows = log.rows()
+    assert [r["hosts"] for r in rows] == [16] * 8
+    assert [r["rounds"] for r in rows] == [0, 20, 40, 60, 80, 100, 1100, 1120]
+    assert log.summary()["rounds"] == 1120
+
+
+def test_the_input_s_scalars_are_read_when_a_chunk_is_handed_over_not_when_it_is_ready(
+        log, clock):
+    """Every fetch of a state's scalar happens before the waiter blocks on
+    the result (the caller is then about to wait); none after the result
+    turned ready, when the caller runs."""
+    order = []
+
+    class Noting(Leaf):
+        def __init__(self, value, name):
+            super().__init__(value)
+            self.name = name
+
+        def __array__(self, dtype=None, copy=None):
+            order.append("read " + self.name)
+            return super().__array__(dtype, copy)
+
+        def block_until_ready(self):
+            order.append("block " + self.name)
+            return self
+
+    def st(windows):
+        s = state(windows)
+        s.metrics.windows = Noting(windows, "windows")
+        for k in profiler._TOTALS:
+            setattr(s.metrics, k, Noting(windows * 7, k))
+        return s
+
+    eng = FakeEngine(clock)
+    for i in range(2):
+        with log.chunk(None, eng, st(2 * i), done=2 * i, windows=2) as ch:
+            ch.watch(st(2 * i + 2))
+    assert log.settle(5.0)
+    reads = ["read " + k for k in ("windows", *profiler._TOTALS)]
+    assert order == (reads + ["block windows"]) * 2
+    assert [r["events"] for r in log.rows()] == [0, 14]

@@ -1,0 +1,341 @@
+"""BASELINE's config 4 as a supported deployment: ``tor10k`` (rung 4, one
+10,000-host Tor network) and its cell ``tor10k.join``.
+
+The real width is held by its files and by ``eval_shape`` only; what RUNS
+here is rung 4's shape in miniature (``tests/rehearsal_tor_join``: the three
+relay classes at rung 4's weights, 2 authorities, 24 clients that join 45 ms
+apart — so that in one window one client fetches the directory, another
+builds a circuit, a third streams — 2 circuits x 3 streams) as ONE lane of
+the fleet engine, held to the C++ reference counter for counter and to the
+solo engine leaf for leaf, then run through the benchmark's own harness with
+its controls and its two new per-layer readers.
+"""
+
+import contextlib
+import dataclasses
+import io
+import json
+import os
+import time
+import warnings
+
+import jax
+import numpy as np
+import pytest
+import yaml
+
+from shadow1_tpu.core.engine import Engine
+from shadow1_tpu.fleet.engine import (
+    FleetEngine,
+    fleet_metrics_per_exp,
+    slice_experiment,
+)
+from shadow1_tpu.fleet.expand import expand_sweep
+from shadow1_tpu.telemetry import chunk_log
+from tests.parity import lane_metrics, unlike_leaves
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+REHEARSAL = os.path.join(ROOT, "tests", "rehearsal_tor_join")
+RUNG4 = os.path.join(ROOT, "configs", "rung4_tor10k.yaml")
+BENCH = os.path.join(ROOT, "benchmarks")
+CELL = "tor33.join24"
+SEED = 600000004000             # the cell's pool of one; past 2**32
+N_WINDOWS, MIDWAY = 60, 40
+COMPACT_WARNING = "fleet mode ignores compact_cap"
+MUST_BE_ZERO = ["ev_overflow", "ob_overflow", "round_cap_hits",
+                "total_ct_overflow"]
+
+
+def doc33():
+    with open(os.path.join(REHEARSAL, "configs", "tor33.yaml")) as f:
+        doc = yaml.safe_load(f)
+    doc["sweep"] = {"seeds": [SEED]}
+    return doc
+
+
+@pytest.fixture(scope="module")
+def plan():
+    return expand_sweep(doc33())
+
+
+@pytest.fixture(scope="module")
+def fleet(plan):
+    """The fleet of one, its state after 40 windows and after all 60."""
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", UserWarning)    # compact_cap dropped
+        eng = FleetEngine(plan.exps, plan.params, plan.max_rounds)
+    midway = eng.run(n_windows=MIDWAY)
+    return eng, midway, eng.run(midway, n_windows=N_WINDOWS - MIDWAY)
+
+
+def lane_counters(eng, st):
+    return {**eng.model_totals(st)[0], **fleet_metrics_per_exp(st)[0]}
+
+
+# ---- (a) the miniature has rung 4's shape --------------------------------------
+
+def test_the_miniature_keeps_rung_4_s_classes_weights_and_arrivals():
+    with open(RUNG4) as f:
+        real = yaml.safe_load(f)
+    small = doc33()
+    assert small["app"]["groups"].keys() == real["app"]["groups"].keys()
+    for name in ("guard", "middle", "exit", "dirauth"):
+        assert small["app"]["groups"][name] == real["app"]["groups"][name]
+    a, b = small["app"]["groups"]["client"], real["app"]["groups"]["client"]
+    assert (a["n_circuits"], a["n_streams"]) == (b["n_circuits"], b["n_streams"]) == (2, 3)
+    assert set(a["start_time"]) == set(b["start_time"]) == {"start", "interval"}
+    assert small["app"]["defaults"]["mean_think_ns"] == real["app"]["defaults"]["mean_think_ns"]
+    counts = {g["name"]: g["count"] for g in small["hosts"]}
+    assert counts == {"guard": 2, "middle": 3, "exit": 2, "dirauth": 2, "client": 24}
+    assert small["engine"]["compact_cap"] and real["engine"]["compact_cap"] == 1280
+
+
+def test_in_one_window_clients_are_in_every_phase(fleet, plan):
+    """At window 40 of the miniature: a client that has not got its
+    directory yet, one that has and has finished no stream (it builds or
+    streams), and one with a stream behind it — what ``tor1k.seeds8``, whose
+    clients all wake in window 3, never shows."""
+    eng, midway, end = fleet
+    client = np.asarray(plan.exps[0].model_cfg["role"]) == 1
+    s = eng.model_summary(midway, 0)
+    boot = np.asarray(s["bootstrap_time"])[client]
+    streams = np.asarray(s["streams_done"])[client]
+    assert client.sum() == 24
+    assert (boot == 0).any()
+    assert ((boot > 0) & (streams == 0)).sum() >= 5
+    assert (streams > 0).sum() >= 3
+    # They join one every 45 ms: the directory arrives in that order, over
+    # more than a simulated second.
+    boot_end = np.asarray(eng.model_summary(end, 0)["bootstrap_time"])[client]
+    assert (boot_end > 0).all() and (np.diff(boot_end) > 0).all()
+    assert boot_end[-1] - boot_end[0] > 1_000_000_000
+    m = fleet_metrics_per_exp(end)[0]
+    # Few hosts have an eligible event in a window: the regime of the cell.
+    assert 0 < m["active_hosts"] < 0.4 * N_WINDOWS * 33
+    assert m["compact_max_fill"] < 33
+
+
+# ---- (b) one lane of the fleet = the reference = the solo engine ---------------
+
+def test_the_lane_equals_the_reference_counter_for_counter(fleet, plan):
+    from benchmarks.reference import comparator
+
+    eng, _, st = fleet
+    ref = comparator.counters(plan.exps[0], eng.params, SEED, N_WINDOWS)
+    have = lane_counters(eng, st)
+    compared = {k: (have.get(k), v) for k, v in ref.items()
+                if k not in comparator.NOT_COUNTERS}
+    assert len(compared) >= 18, sorted(compared)
+    assert {"total_streams_done", "total_cells_rx", "total_cells_fwd",
+            "total_ct_overflow", "clients_done", "tcp_rto",
+            "tcp_ooo_drops"} <= set(compared)
+    assert all(a == b for a, b in compared.values()), compared
+    assert have["total_streams_done"] >= 10 and have["events"] > 3000
+    assert all(have[k] == 0 for k in MUST_BE_ZERO)
+
+
+def test_the_lane_equals_the_solo_engine_leaf_for_leaf(fleet, plan):
+    eng, _, st = fleet
+    solo = Engine(plan.exps[0], eng.params)
+    want = solo.run(n_windows=N_WINDOWS)
+    assert not unlike_leaves(slice_experiment(st, 0), want)
+    assert lane_metrics(fleet_metrics_per_exp(st)[0]) \
+        == lane_metrics(Engine.metrics_dict(want))
+    assert eng.model_totals(st)[0] == solo.model_totals(want)
+    # One lane: the program ran a pass exactly where the lane had the kind.
+    m = fleet_metrics_per_exp(st)[0]
+    assert all(m["runs_" + k] == m["fires_" + k]
+               for k in ("deliver", "timer", "txr", "app"))
+
+
+# ---- (c) the cell in miniature through the benchmark's harness -----------------
+
+def _bench(seed, *more):
+    from benchmarks.harness import loop
+
+    out = io.StringIO()
+    with contextlib.redirect_stdout(out), warnings.catch_warnings():
+        warnings.simplefilter("ignore", UserWarning)
+        rc = loop.main(["--workload", CELL, "--seed", str(seed), "--seconds",
+                        "0.2", "--trace", "0", *more], REHEARSAL,
+                       time.perf_counter(), require_chip=False)
+    lines = [json.loads(ln) for ln in out.getvalue().strip().splitlines()]
+    return rc, lines[-1], [ln for ln in lines if "engine_vs_reference" in ln]
+
+
+@pytest.mark.parametrize("seed", [7, 3_000_000_019])
+def test_the_cell_in_miniature_is_correct_and_every_run_does_the_same_work(
+        fleet, seed):
+    rc, res, lanes = _bench(seed)
+    assert rc == 0 and res["correct"] is True
+    assert (res["attempted"], res["failed"]) == (1, 0)
+    (ln,) = lanes
+    assert ln["seed"] == ln["reference_seed"] == SEED and ln["ok"]
+    assert ln["windows"] == N_WINDOWS and not ln["must_be_zero"]
+    # A pool of one: whatever --seed, the counters are the fixture's.
+    have = lane_counters(fleet[0], fleet[2])
+    assert all(a == b == have[k] for k, (a, b) in ln["engine_vs_reference"].items())
+    assert all(res["compared"][k + ".must_be_zero"] == [0, 0] for k in MUST_BE_ZERO)
+    assert set(res["metrics"]) == {"events_per_s", "peak_hbm_mb", "setup_s"}
+
+
+@pytest.mark.parametrize("control", ["wrong_seed", "small_caps"])
+def test_the_cell_in_miniature_under_a_control_is_not_correct(control):
+    """Tor draws at run time, so the reference under the next seed differs;
+    with ``ev_cap`` 20 the relays drop events (``ev_max_fill`` is 83)."""
+    rc, res, (ln,) = _bench(11, "--control", control)
+    assert rc == 0 and res["correct"] is False and res["failed"] == 1
+    assert "events" in ln["differ"] and not ln["ok"]
+    if control == "wrong_seed":
+        assert ln["reference_seed"] == SEED + 1 and not ln["must_be_zero"]
+    else:
+        assert ln["must_be_zero"].get("ev_overflow")
+        assert res["compared"]["ev_overflow.must_be_zero"][0] > 0
+
+
+# ---- (d) the two new readers, on the rows of a traced run's shape --------------
+
+@pytest.fixture()
+def traced_rows(fleet, plan):
+    """The chunk log after what a traced run of the miniature leaves in it:
+    a warm-up chunk, the cycle's twelve, the replay of windows 0-35 (one row
+    of 30 windows, five of one). Gives the metrics at windows 30 and 35."""
+    from benchmarks.harness import loop
+    from benchmarks.harness import sim as simmod
+
+    eng = fleet[0]
+    sim = simmod.Sim(eng, plan.exps, plan.params, True)
+    log = chunk_log()
+    log.clear()
+    log.enabled = True
+    loop.run_chunk(sim, eng.init_state(), 5)
+    st, at = eng.init_state(), {}
+    for done in range(0, N_WINDOWS, 5):
+        at[done] = jax.device_get(st.metrics)
+        st = loop.run_chunk(sim, st, 5)
+    loop._replay_rounds(sim, {"traced": (30, 35)}, at[35])
+    yield at[30], at[35]
+    log.clear()
+
+
+def _readers():
+    from benchmarks.harness import manifest as mf
+
+    m = mf.load(REHEARSAL)
+    names = [e["name"] for e in mf.metrics_of(m, "per_layer", CELL)]
+    assert names[-2:] == ["active_host_share", "events_per_round"]
+    return [mf.reader(REHEARSAL, m, "layer_metrics", n) for n in names[-2:]]
+
+
+def test_the_new_readers_read_the_traced_chunk_s_work_off_the_chunk_log(traced_rows):
+    m30, m35 = traced_rows
+    share, per_round = _readers()
+    counters = {"chunks": 1, "windows": 5, "rounds": 1}
+
+    def delta(k):
+        return int(np.sum(getattr(m35, k)) - np.sum(getattr(m30, k)))
+
+    assert share(None, counters, {}) == pytest.approx(
+        100.0 * delta("active_hosts") / (5 * 33))
+    assert 0 < share(None, counters, {}) < 100
+    assert per_round(None, counters, {}) == pytest.approx(
+        delta("events") / delta("rounds"))
+    assert per_round(None, counters, {}) > 1
+    # No traced chunk in the counters, or a stretch this log has no rows of.
+    assert share(None, {"chunks": 0, "windows": 0}, {}) is None
+    assert per_round(None, {"chunks": 2, "windows": 20}, {}) is None
+
+
+def test_on_rows_without_the_totals_the_new_readers_return_none(traced_rows,
+                                                                monkeypatch):
+    """The parent's rows (PR 39's): ``first_window`` and the clock stamps,
+    no totals and no ``hosts``. And a log without the replay's rows (an
+    untraced run) says nothing of where the stretch is."""
+    from shadow1_tpu.telemetry.registry import CHUNK_TOTALS
+
+    share, per_round = _readers()
+    counters = {"chunks": 1, "windows": 5, "rounds": 1}
+    log = chunk_log()
+    rows = log.rows()
+    assert all(set(CHUNK_TOTALS) <= set(r) for r in rows) and len(rows) == 19
+    monkeypatch.setattr(log, "rows", lambda wait_s=1.0: [
+        {k: v for k, v in r.items() if k not in CHUNK_TOTALS} for r in rows])
+    assert share(None, counters, {}) is None
+    assert per_round(None, counters, {}) is None
+    monkeypatch.setattr(log, "rows", lambda wait_s=1.0: [
+        r for r in rows if r["windows"] == 5])
+    assert share(None, counters, {}) is None
+    assert per_round(None, counters, {}) is None
+
+
+# ---- (e) the real cell's data files ---------------------------------------------
+
+def test_the_benchmark_s_experiment_file_is_rung_4_byte_for_byte():
+    with open(RUNG4, "rb") as a, \
+            open(os.path.join(BENCH, "configs", "tor10k.yaml"), "rb") as b:
+        assert a.read() == b.read()
+
+
+def test_the_cell_s_files_state_what_the_issue_fixed():
+    with open(os.path.join(BENCH, "configs", "tor10k.json")) as f:
+        meta = json.load(f)
+    with open(os.path.join(BENCH, "traffic", "join.json")) as f:
+        mix = json.load(f)
+    assert (meta["experiment"], meta["engine"]) == ("tor10k.yaml", "fleet")
+    assert meta["architecture"] is None
+    assert meta["source"].startswith("BASELINE.json config 4, '10k-host Tor "
+                                     "network at consensus scale")
+    assert "configs/rung4_tor10k.yaml" in meta["source"] and len(meta["source"]) <= 200
+    assert meta["reduced"] == ["stop_time"] == list(meta["reduced_why"])
+    assert "No width is cut" in meta["reduced_why"]["stop_time"]
+    assert meta["must_be_zero"] == MUST_BE_ZERO and len(meta["guarantees"]) >= 3
+    fallback = mix["cycle_windows"] == 80
+    assert {k: mix[k] for k in ("lanes", "seed_pool_first", "overrides",
+                                "chunk_windows", "cycle_windows",
+                                "trace_from_window", "trace_chunks")} == {
+        "lanes": 1, "seed_pool_first": SEED, "overrides": {},
+        "chunk_windows": 5, "cycle_windows": 80 if fallback else 120,
+        "trace_from_window": 40 if fallback else 60, "trace_chunks": 1}
+    # Every value the source does not fix is stated with its reason, and the
+    # finding that rung 4 cannot reach its own end is where the cap is.
+    assert {"why", "hosts", "relay_mix", "clients", "bandwidth_up/down",
+            "network.single_vertex.latency", "sockets_per_host", "msgq_cap",
+            "ct_cap", "cells_max", "ev_cap", "outbox_cap", "max_rounds",
+            "compact_cap", "lanes", "cycle", "walls"} <= set(meta["assumed"])
+    assert all(isinstance(v, str) and len(v) > 20 for v in meta["assumed"].values())
+    assert "590" in meta["assumed"]["ct_cap"] and "1,564" in meta["assumed"]["ct_cap"]
+    assert "recollection" in meta["assumed"]["why"]
+    # The rehearsal is the cell's own mix at the miniature's cycle.
+    with open(os.path.join(REHEARSAL, "traffic", "join24.json")) as f:
+        small = json.load(f)
+    assert {**mix, "cycle_windows": 60, "trace_from_window": 30, "what": None} \
+        == {**small, "what": None}
+
+
+def test_rung_4_itself_builds_a_fleet_of_one_at_full_width():
+    """The real file under the cell's seed, shapes only (no state is made):
+    1,271.6 MB in one lane, the message-queue planes [1, 64, 128, 10000];
+    its ``compact_cap`` 1,280 is dropped with a warning and nothing else of
+    its widths moves."""
+    with open(RUNG4) as f:
+        doc = yaml.safe_load(f)
+    doc["sweep"] = {"seeds": [SEED]}
+    plan = expand_sweep(doc, base_dir=os.path.dirname(RUNG4))
+    assert plan.params.compact_cap == 1280
+    with pytest.warns(UserWarning, match=COMPACT_WARNING):
+        eng = FleetEngine(plan.exps, plan.params, plan.max_rounds)
+    assert eng.params == dataclasses.replace(plan.params, compact_cap=0)
+    assert (eng.n_exp, plan.exps[0].n_hosts) == (1, 10000)
+    assert (eng.params.ev_cap, eng.params.sockets_per_host,
+            eng.params.msgq_cap, eng.params.max_rounds) == (256, 128, 64, 1024)
+    cfg = plan.exps[0].model_cfg
+    assert (int(cfg["ct_cap"]), int(cfg["cells_max"])) == (1024, 120)
+    role = np.asarray(cfg["role"])
+    assert [int((role == r).sum()) for r in (0, 1, 2)] == [1000, 8990, 10]
+    leaves = jax.tree.leaves(jax.eval_shape(eng.init_state))
+    sizes = sorted(((x.size * x.dtype.itemsize, x.shape) for x in leaves),
+                   reverse=True)
+    assert len(leaves) == 130
+    assert sum(b for b, _ in sizes) == 1_271_600_372
+    assert sizes[0] == sizes[1] == (327_680_000, (1, 64, 128, 10000))
+    assert sizes[2] == (102_400_000, (1, 10, 256, 10000))
