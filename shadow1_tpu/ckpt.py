@@ -86,7 +86,11 @@ import jax
 #  15: Metrics gains runs_window_end (windows in which the program ran the
 #      window end, core/engine.deliver_window's guard): one more i64 leaf in
 #      every snapshot. A running sum like runs_*.
-CKPT_FORMAT = 15
+#  16: SimState gains the optional ``compact_buckets`` leaf (i64 scalar;
+#      fleet: [E]), the compacted round loop's trips, present only where a
+#      compact_cap is in force (core/compact.py). A snapshot of a run
+#      without a cap is leaf for leaf a v15 one.
+CKPT_FORMAT = 16
 
 
 class CorruptCheckpointError(ValueError):

@@ -213,11 +213,20 @@ class EngineParams:
     # per window. 0 = auto (2× the uniform-traffic expectation, min 16).
     # Bucket-full drops are counted (x2x_overflow); parity requires 0.
     x2x_cap: int = 0
-    # Sparse-window compaction bucket (active-host lanes per window; see
-    # core/compact.py). 0 = off. Windows whose active-host count exceeds
-    # the bucket run full-width — results are bit-identical either way, so
-    # this is purely a perf knob. Size from tools/activeprobe.py (rung3
-    # p99 = 284 of 1000; rung4 max = 1082 of 10000).
+    # Compaction bucket: a window's rounds run on its active hosts only,
+    # this many columns a TRIP (core/compact.py), on the solo, the sharded
+    # (per-shard share) and the fleet engine alike. 0 = off (the round loop
+    # at full width). There is no fall-back: a window whose active-host
+    # count exceeds the bucket takes ceil(active / cap) trips, each one
+    # move of the bucket's columns out and back (two one-hot matmuls over
+    # the state) on top of the same rounds, so a dense window costs
+    # ceil(H / cap) moves and a cap near H buys nothing. Results are
+    # bit-identical whatever the value — purely a perf knob (only rounds,
+    # fires_*, runs_* and SimState.compact_buckets, the program's counts of
+    # itself, can tell). Size it to cover a typical window's active set in
+    # one trip: tools/captune.py recommends it from the compact_max_fill
+    # gauge, tools/activeprobe.py gives the distribution (rung3 p99 = 284 of
+    # 1000; rung4 max = 1082 of 10000).
     compact_cap: int = 0
     # On-device telemetry ring: per-window counter-delta rows kept on
     # device (telemetry/ring.py) and drained at chunk boundaries. Value =

@@ -154,6 +154,22 @@ def _metrics_init() -> Metrics:
     return Metrics(*([z] * len(Metrics._fields)))
 
 
+def compact_cap_of(params: EngineParams, n_hosts: int) -> int:
+    """The bucket width in force for a block of ``n_hosts`` columns:
+    ``params.compact_cap`` where it is set and narrower than the block,
+    else 0 (the plain full-width round loop)."""
+    cap = params.compact_cap
+    return cap if cap and cap < n_hosts else 0
+
+
+def compact_buckets_init(params: EngineParams, n_hosts: int):
+    """``SimState.compact_buckets`` at t = 0: a counter where a cap is in
+    force, no leaf where none is."""
+    if compact_cap_of(params, n_hosts):
+        return jnp.zeros((), jnp.int64)
+    return None
+
+
 class SimState(NamedTuple):
     win_start: jnp.ndarray  # i64 scalar
     evbuf: EventBuf
@@ -173,6 +189,12 @@ class SimState(NamedTuple):
     # None when EngineParams.link_telem == 0 — same None-leaf rule again;
     # never digested, so carrying it is digest-neutral by construction.
     links: Any = None
+    # Trips of the compacted round loop, summed over windows (i64 scalar;
+    # core/compact.py), or None where no ``compact_cap`` is in force — the
+    # None-leaf rule once more, so a program without a cap is the program it
+    # was. A count the PROGRAM makes of itself, like ``Metrics.rounds`` and
+    # ``runs_*``: no parity or lane-against-solo comparison reads it.
+    compact_buckets: Any = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -750,8 +772,8 @@ def _window_end(st: SimState, ctx: Ctx, exchange=None) -> SimState:
 def run_rounds(st: SimState, ctx: Ctx, handlers: dict, win_end):
     """The inner round loop to quiescence (or the safety cap).
 
-    Returns (st, cap_hit). Shared by the full-width path and the compacted
-    path (core/compact.py), which calls it at bucket width. On a fleet the
+    Returns (st, cap_hit). Traced once: at full width, or at bucket width
+    by the compacted path (core/compact.py) where a cap is set. On a fleet the
     loop's own predicate is reduced over the lanes like every guard's
     (``any_lane``), and a lane whose own loop has ended rides the remaining
     iterations as the identity: its ``rounds``, ``r`` and ``cap_hit`` are
@@ -900,14 +922,12 @@ def window_phases(ctx: Ctx, handlers: dict, exchange=None, pre_window=None,
 
     def ph_rounds(fr: WindowFrame) -> WindowFrame:
         st = fr.st
-        ccap = ctx.params.compact_cap
-        if ccap and ccap < ctx.n_hosts and make_handlers is not None:
+        ccap = compact_cap_of(ctx.params, ctx.n_hosts)
+        if ccap and make_handlers is not None:
             from shadow1_tpu.core.compact import compact_window_rounds
 
             st, cap_hit = compact_window_rounds(
-                st, ctx, handlers, make_handlers, run_rounds,
-                fr.win_end, ccap
-            )
+                st, ctx, make_handlers, fr.win_end, ccap)
         else:
             st, cap_hit = run_rounds(st, ctx, handlers, fr.win_end)
         return fr._replace(st=st, cap_hit=cap_hit)
@@ -1000,8 +1020,9 @@ def window_step(st: SimState, ctx: Ctx, handlers: dict, exchange=None,
     arrival of the window in one scan instead of one round per packet.
 
     When ``params.compact_cap`` is set (and ``make_handlers`` provided),
-    sparse windows run their rounds on a gathered active-host bucket
-    (core/compact.py) — bit-identical results, narrow tensors.
+    a window's rounds run on its active hosts only, a bucket of that many
+    columns a trip (core/compact.py) — bit-identical results, narrow
+    tensors, and no full-width copy of the round program.
 
     When the state carries a telemetry ring (``st.telem``), the window's
     metric deltas are recorded into it here, still inside the trace —
@@ -1241,6 +1262,8 @@ class Engine:
             probes=probe_init(self.params.metrics_ring, self.params.probes),
             links=link_init(self.params.link_telem,
                             np.asarray(self.exp.lat_vv).shape[0]),
+            compact_buckets=compact_buckets_init(self.params,
+                                                 self.exp.n_hosts),
         )
 
     def place_state(self, st: SimState) -> SimState:

@@ -67,7 +67,7 @@ def reset_host_columns(tree, init_tree, mask, n_hosts: int):
     """Restore the masked hosts' columns of every per-host leaf to its
     initial value (the post-init model capture). The host axis is the LAST
     axis by the state layout contract (shard/engine._spec_for,
-    compact._gather_tree use the same rule); leaves of other shapes —
+    compact.take_cols uses the same rule); leaves of other shapes —
     scalars, config tables — pass through untouched."""
     def r(cur, ini):
         if hasattr(cur, "ndim") and cur.ndim >= 1 and cur.shape[-1] == n_hosts:

@@ -43,8 +43,9 @@ lanes, summed overflow); and failing/finished lanes are sliced out
 mid-sweep (``select_lanes`` / ``lane_done`` — fleet/run.py drives the
 policy). What the fleet plane still rejects (structured FleetConfigError,
 ``kind="mode"``): the sharded engine (vmap-over-shard_map composition is a
-follow-up). Pallas kernel impls and sparse-window compaction downgrade to
-their XLA/full-width twins with a warning (bit-identical by contract).
+follow-up). A ``compact_cap`` is in force as on the solo engine: the
+lanes' rounds run a bucket a trip, the trip loop's predicate reduced over
+the lanes (core/compact.py).
 """
 
 from __future__ import annotations
@@ -67,6 +68,7 @@ from shadow1_tpu.core.engine import (
     build_base_ctx,
     check_digest_params,
     check_probe_params,
+    compact_buckets_init,
     window_step,
 )
 from shadow1_tpu.core.events import evbuf_init
@@ -293,7 +295,6 @@ class FleetEngine:
         from shadow1_tpu.telemetry.links import check_link_params
 
         check_link_params(self.params, np.asarray(exps[0].lat_vv).shape[0])
-        self.params = self._resolve_fleet_params(self.params)
         self.exps = list(exps)
         self.exp = exps[0]
         self.n_exp = len(exps)
@@ -346,23 +347,6 @@ class FleetEngine:
         self._run_jit = jax.jit(self._make_run())
 
     # -- construction ------------------------------------------------------
-    def _resolve_fleet_params(self, params: EngineParams) -> EngineParams:
-        # auto_caps and on_overflow=retry were structured kind="mode"
-        # rejections through PR 12: both now work fleet-wide — the [E, ...]
-        # pytree is the transaction unit, caps stay fleet-uniform, and
-        # tune/resize.py migrates the batched planes per lane (PR 13,
-        # docs/SEMANTICS.md §"Fleet recovery contract").
-        if params.compact_cap:
-            import warnings
-
-            warnings.warn("fleet mode ignores compact_cap: under vmap the "
-                          "compacted and full-width branches would both "
-                          "execute per window, negating the win; running "
-                          "full-width (bit-identical by the compaction "
-                          "contract)")
-            return dataclasses.replace(params, compact_cap=0)
-        return params
-
     def _build_variants(self) -> tuple[dict, dict]:
         exps = self.exps
         variants: dict[str, Any] = {
@@ -444,6 +428,8 @@ class FleetEngine:
             probes=probe_init(self.params.metrics_ring, self.params.probes),
             links=link_init(self.params.link_telem,
                             np.asarray(self.exp.lat_vv).shape[0]),
+            compact_buckets=compact_buckets_init(self.params,
+                                                 self.exp.n_hosts),
         )
 
     def init_state(self) -> SimState:

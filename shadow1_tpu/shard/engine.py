@@ -42,6 +42,8 @@ summed), so they are performance counters, not semantic invariants.
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -57,6 +59,7 @@ from shadow1_tpu.core.engine import (
     SimState,
     _metrics_init,
     _model_module,
+    compact_buckets_init,
     fidelity_ctx_kwargs,
     window_step,
 )
@@ -196,6 +199,8 @@ class ShardedEngine:
             probes=probe_init(self.params.metrics_ring, self.params.probes),
             links=link_init(self.params.link_telem,
                             np.asarray(self.exp.lat_vv).shape[0]),
+            compact_buckets=compact_buckets_init(self._block_params(),
+                                                 self.h_local),
         )
         return self.place_state(st)
 
@@ -216,22 +221,27 @@ class ShardedEngine:
             f = self._run_jits[x2x_cap] = jax.jit(self._make_run(x2x_cap))
         return f
 
+    def _block_params(self) -> EngineParams:
+        """``params`` as a shard's block runs them: ``compact_cap`` is sized
+        against the GLOBAL active set (configs, tools/activeprobe.py) and
+        each shard block sees ~1/n_dev of it, so the per-shard bucket is
+        that share, rounded up to a lane tile (128) so that it stays
+        tiling-friendly. A shard whose active count overflows its bucket
+        takes more trips that window, each shard its own number (no
+        collective inside the trip loop; exact either way —
+        core/compact.py)."""
+        pr = self.params
+        if not pr.compact_cap:
+            return pr
+        local_cap = -(-pr.compact_cap // self.n_dev)
+        tile = 128 if local_cap >= 128 else 8
+        local_cap = min(-(-local_cap // tile) * tile, self.h_local)
+        return dataclasses.replace(pr, compact_cap=local_cap)
+
     def _make_run(self, x2x_cap: int):
         exp, pr, axis = self.exp, self.params, self.axis
         n_dev, h_local = self.n_dev, self.h_local
-        if pr.compact_cap:
-            # compact_cap is sized against the GLOBAL active set (configs,
-            # tools/activeprobe.py); each shard block sees ~1/n_dev of it.
-            # Scale to per-shard lanes, rounded up to a lane tile (128) so
-            # the bucket stays tiling-friendly; shards whose active count
-            # overflows the bucket fall back full-width per window (exact
-            # either way — core/compact.py).
-            local_cap = -(-pr.compact_cap // n_dev)
-            tile = 128 if local_cap >= 128 else 8
-            local_cap = min(-(-local_cap // tile) * tile, h_local)
-            import dataclasses as _dc
-
-            pr = _dc.replace(pr, compact_cap=local_cap)
+        pr = self._block_params()
         window, model = self.window, self._model
         key = self.global_ctx.key
         lat_vv = self.global_ctx.lat_vv
@@ -442,6 +452,10 @@ class ShardedEngine:
             # maxes commutes). compact_max_fill stays a per-shard bucket
             # gauge semantically (like ``rounds``), but the max over shards
             # is exactly the number that sizes the per-shard bucket.
+            # ``compact_buckets`` (where a cap is in force) is each shard's
+            # own trip count under a replicated spec: the slowest shard's.
+            if st.compact_buckets is not None:
+                st = st._replace(compact_buckets=pmax_(st.compact_buckets))
             return st._replace(metrics=mfin._replace(
                 windows=st.metrics.windows,
                 runs_window_end=st.metrics.runs_window_end,

@@ -44,7 +44,11 @@ import sys
 import threading
 import time
 
-from shadow1_tpu.telemetry.registry import CHUNK_TOTALS, REC_STALL
+from shadow1_tpu.telemetry.registry import (
+    CHUNK_CAP_TOTALS,
+    CHUNK_TOTALS,
+    REC_STALL,
+)
 
 # Canonical phase names (docs/OBSERVABILITY.md) — free-form names are
 # allowed, but the wired-in call sites use these.
@@ -243,13 +247,20 @@ def _windows_leaf(st):
 # What a row keeps of its chunk's INPUT state besides ``first_window``: the
 # running totals a reader subtracts from the next row's (``work_between``).
 _TOTALS = CHUNK_TOTALS[:-1]
+# Totals that only some programs keep, and the leaf of the state each is
+# (where a ``compact_cap`` is in force); ``_TOTALS`` are ``Metrics`` fields,
+# on every row that has any.
+_CAP_TOTALS = {"buckets": "compact_buckets"}
+assert tuple(_CAP_TOTALS) == CHUNK_CAP_TOTALS
 
 
 def _input_leaves(st) -> tuple:
-    """The scalars of an input state that the log reads: ``metrics.windows``
-    and the ``_TOTALS`` (None where the state has none)."""
+    """The scalars of an input state that the log reads: ``metrics.windows``,
+    the ``_TOTALS`` and the ``_CAP_TOTALS`` (None where the state has
+    none)."""
     m = getattr(st, "metrics", None)
-    return tuple(getattr(m, k, None) for k in ("windows", *_TOTALS))
+    return (*(getattr(m, k, None) for k in ("windows", *_TOTALS)),
+            *(getattr(st, leaf, None) for leaf in _CAP_TOTALS.values()))
 
 
 def _host_count(engine) -> int | None:
@@ -261,16 +272,19 @@ def _host_count(engine) -> int | None:
 def work_between(row: dict, after: dict | None) -> dict | None:
     """What the chunk of ``row`` did — events, rounds (a lane's own, summed
     over lanes), ``active_hosts`` and ``elig_events`` (sums over its
-    windows) — where ``after`` is the row of the chunk that continued it:
-    the same engine's, adjacent in ``seq``, starting on the window ``row``
-    ended on. Else None: a row's totals are of its chunk's START."""
+    windows), ``buckets`` (the compacted round loop's trips) where the
+    program counts them — where ``after`` is the row of the chunk that
+    continued it: the same engine's, adjacent in ``seq``, starting on the
+    window ``row`` ended on. Else None: a row's totals are of its chunk's
+    START."""
     if (after is None or after["engine"] != row["engine"]
             or after["seq"] != row["seq"] + 1
             or row.get("first_window") is None
             or after.get("first_window") != row["first_window"] + row["windows"]
             or any(k not in r for r in (row, after) for k in _TOTALS)):
         return None
-    return {k: after[k] - row[k] for k in _TOTALS}
+    return {k: after[k] - row[k] for k in (*_TOTALS, *_CAP_TOTALS)
+            if k in row and k in after}
 
 
 def _pressure_us(what: str) -> int | None:
@@ -365,7 +379,8 @@ class ChunkLog:
     A row: ``seq`` (chunks in the order they were opened), ``engine`` (a
     number of the engine object), ``done`` (the loop's count),
     ``first_window`` (the input state's ``metrics.windows``), ``windows``,
-    ``events``, ``rounds``, ``active_hosts``, ``elig_events`` (the input
+    ``events``, ``rounds``, ``active_hosts``, ``elig_events``, and where a
+    ``compact_cap`` is in force ``buckets`` (the input
     state's running totals, summed over a fleet's lanes: what the chunk did
     is the NEXT row's less these, ``work_between``) and ``hosts`` (the
     engine's ``n_hosts`` x lanes), where the state and the engine have them,
@@ -524,7 +539,7 @@ class ChunkLog:
         del leaves
         row["first_window"] = (None if values[0] is None
                                else int(np.max(values[0])))
-        for k, v in zip(_TOTALS, values[1:]):
+        for k, v in zip((*_TOTALS, *_CAP_TOTALS), values[1:]):
             if v is not None:
                 row[k] = int(np.sum(v))
 
@@ -696,7 +711,8 @@ class ChunkLog:
         for key in ("turnaround_ns", *_BOUNDARY_SPANS.values()):
             if key in row:
                 out[_ms_key(key)] = _ms(row[key])
-        out.update({k: row[k] for k in CHUNK_TOTALS if k in row})
+        out.update({k: row[k] for k in CHUNK_TOTALS + CHUNK_CAP_TOTALS
+                    if k in row})
         return {**out, **row["health"]}
 
     def summary(self, wait_s: float = 1.0) -> dict:
@@ -736,7 +752,9 @@ class ChunkLog:
         # What those chunks did, of each that the next row continues.
         did = [w for w in map(work_between, rows, rows[1:]) if w is not None]
         if did:
-            out.update({k: sum(w[k] for w in did) for k in _TOTALS})
+            out.update({k: sum(w[k] for w in did)
+                        for k in (*_TOTALS, *_CAP_TOTALS)
+                        if all(k in w for w in did)})
         if "hosts" in rows[-1]:
             out["hosts"] = rows[-1]["hosts"]
         out["boundary_ms"] = round(out["dispatch_ms"]

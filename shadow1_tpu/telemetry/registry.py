@@ -123,6 +123,16 @@ HOST_FIELDS = ("chunk_retries", "retry_windows_rerun")
 LANE_PROGRAM_FIELDS = tuple(f[2] for f in KIND_METRIC_FIELDS.values()) + (
     "runs_window_end",)
 
+# Counts the ROUND LOOP makes of itself: its iterations and the handler
+# passes they fired and ran. Where a compact_cap is in force a window's
+# rounds run on its active hosts a bucket a trip (core/compact.py) and these
+# are sums over the trips: the full-width counts in a window of one trip,
+# larger in a window of several. A comparison of a compacted run with a
+# full-width one leaves out these and ``SimState.compact_buckets`` (the
+# trips themselves, a leaf only the compacted state has) and nothing else.
+ROUND_PROGRAM_FIELDS = ("rounds",) + tuple(
+    f for fs in KIND_METRIC_FIELDS.values() for f in fs[1:])
+
 # JSONL record types every consumer recognises (docs/OBSERVABILITY.md).
 # ``digest`` is the CPU oracle's per-window state-digest row (the batched
 # engines carry the same words as ring columns instead). Fleet mode
@@ -236,15 +246,20 @@ CHUNK_BOUNDARY = ("commit_ms", "on_chunk_ms", "drain_ms", "checkpoint_ms",
 # less its own (absent with no two rows that follow one another). A stall
 # line prints the chunk's ``rounds`` and ``events`` and their medians over
 # the rows it was judged by (STALL_WORK) where the next row is known.
+# CHUNK_CAP_TOTALS are running totals too, on a row only where the program
+# has a compact_cap in force: ``buckets`` is ``SimState.compact_buckets``,
+# the compacted round loop's trips (core/compact.py).
 CHUNK_TOTALS = ("events", "rounds", "active_hosts", "elig_events", "hosts")
+CHUNK_CAP_TOTALS = ("buckets",)
 STALL_WORK = ("rounds", "events", "median_of_rounds", "median_of_events")
 CHUNK_BLOCK = (("dispatch_ms", "wait_ms", "turnaround_ms") + CHUNK_TOTALS
-               + CHUNK_BOUNDARY)
+               + CHUNK_CAP_TOTALS + CHUNK_BOUNDARY)
 CHUNK_HEALTH = ("cpu_s", "nivcsw", "nvcsw", "majflt", "inblock", "oublock",
                 "psi_cpu_us", "psi_io_us", "psi_mem_us", "load1")
 CHUNKS_BLOCK = ("count", "stalls", "rows", "windows", "dispatch_ms",
                 "args_ms", "call_ms", "wait_ms", "turnaround_ms",
-                "boundary_ms", "boundary_share") + CHUNK_TOTALS + CHUNK_BOUNDARY
+                "boundary_ms", "boundary_share") + CHUNK_TOTALS \
+    + CHUNK_CAP_TOTALS + CHUNK_BOUNDARY
 RECORD_TYPES = (REC_HEARTBEAT, REC_TRACKER, REC_RING, REC_RING_GAP,
                 REC_DIGEST, REC_FLEET_EXP, REC_FLEET_SUMMARY,
                 REC_FLEET_RETRY, REC_FLEET_QUARANTINE,

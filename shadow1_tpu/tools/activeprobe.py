@@ -6,7 +6,7 @@ The batched engine pays every inner round as a full [C, H] tensor pass
 regardless of how many hosts actually execute events — on sparse rungs the
 round path is mostly dead lanes. If the per-WINDOW active-host set is small,
 the engine can gather active hosts into a narrow static bucket at window
-start, run the rounds compact, and scatter back (exact: the active set of a
+start, run the rounds compact, and put them back (exact: the active set of a
 window is closed under round execution, because cross-host packets defer to
 the window-end exchange — handlers only self-push). This tool runs the CPU
 oracle and prints the distribution that sizes that bucket:

@@ -94,20 +94,49 @@ def lane_metrics(d: dict) -> dict:
     return {k: v for k, v in d.items() if k not in LANE_PROGRAM_FIELDS}
 
 
-def unlike_leaves(got, want) -> list[str]:
-    """Paths of the leaves in which two states of one treedef differ,
-    ``metrics.runs_*`` left out."""
+def _unlike(got, want, skip) -> list[str]:
+    """Paths of the leaves in which two states of one treedef differ, the
+    fields named in ``skip`` left out."""
     import jax
-
-    from shadow1_tpu.telemetry.registry import LANE_PROGRAM_FIELDS
 
     paths = [jax.tree_util.keystr(p)
              for p, _ in jax.tree_util.tree_leaves_with_path(want)]
     a, b = jax.tree.leaves(got), jax.tree.leaves(want)
     assert len(a) == len(b) == len(paths) > 50
     return [p for p, x, y in zip(paths, a, b)
-            if p.rsplit(".", 1)[-1] not in LANE_PROGRAM_FIELDS
+            if p.rsplit(".", 1)[-1] not in skip
             and not np.array_equal(np.asarray(x), np.asarray(y))]
+
+
+def unlike_leaves(got, want) -> list[str]:
+    """Paths of the leaves in which two states of one treedef differ,
+    ``metrics.runs_*`` left out."""
+    from shadow1_tpu.telemetry.registry import LANE_PROGRAM_FIELDS
+
+    return _unlike(got, want, LANE_PROGRAM_FIELDS)
+
+
+# ---- a compacted run against the full-width one --------------------------------
+# Everything is compared but what the round loop counts of itself
+# (``registry.ROUND_PROGRAM_FIELDS``: sums over a window's trips) and the
+# trips (``SimState.compact_buckets``, which a full-width state lacks).
+
+def trip_metrics(d: dict) -> dict:
+    """A metrics dict without the round loop's counts of itself."""
+    from shadow1_tpu.telemetry.registry import ROUND_PROGRAM_FIELDS
+
+    return {k: v for k, v in d.items() if k not in ROUND_PROGRAM_FIELDS}
+
+
+def unlike_but_trips(compacted, full, also_not=()) -> list[str]:
+    """Paths of the leaves in which a compacted run's state differs from the
+    full-width run's, ``compact_buckets`` and the round loop's own counts
+    (and the fields ``also_not``) left out."""
+    from shadow1_tpu.telemetry.registry import ROUND_PROGRAM_FIELDS
+
+    assert full.compact_buckets is None
+    return _unlike(compacted._replace(compact_buckets=None), full,
+                   {*ROUND_PROGRAM_FIELDS, *also_not})
 
 
 def assert_runs_contract(lanes: list[dict], solos: list[dict]) -> None:

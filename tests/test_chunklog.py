@@ -36,8 +36,10 @@ from tests.test_phases import _captured_spans
 PARAMS = EngineParams(ev_cap=32, outbox_cap=16)
 # Of the two blocks, the keys every chunk loop gives; registry.CHUNK_BOUNDARY
 # are there where the loop ran those spans.
-REQUIRED_CHUNKS = set(registry.CHUNKS_BLOCK) - set(registry.CHUNK_BOUNDARY)
-REQUIRED_CHUNK = set(registry.CHUNK_BLOCK) - set(registry.CHUNK_BOUNDARY)
+# registry.CHUNK_CAP_TOTALS where the program has a compact_cap in force.
+SOMETIMES = set(registry.CHUNK_BOUNDARY) | set(registry.CHUNK_CAP_TOTALS)
+REQUIRED_CHUNKS = set(registry.CHUNKS_BLOCK) - SOMETIMES
+REQUIRED_CHUNK = set(registry.CHUNK_BLOCK) - SOMETIMES
 PREFIX = profiler.ANNOTATION_PREFIX
 
 
@@ -604,6 +606,41 @@ def test_a_row_carries_the_totals_of_its_input_state_and_the_host_count(log, loo
     s = log.summary()
     assert {k: s[k] for k in registry.CHUNK_TOTALS} == {
         **{k: rows[2][k] for k in profiler._TOTALS}, "hosts": 16 * lanes}
+
+
+def _fleet_capped(n, chunk):
+    eng = FleetEngine([phold(7), phold(8)],
+                      EngineParams(ev_cap=32, outbox_cap=16, compact_cap=8))
+    st, _hb = run_fleet(eng, n_windows=n, every_windows=chunk, stream=False)
+    return eng, st
+
+
+def test_a_row_carries_the_trips_where_a_compact_cap_is_in_force(log):
+    """``buckets``: the input state's ``compact_buckets`` summed over the
+    lanes, beside the other totals; two rows that follow one another give a
+    chunk's trips, the summary their sum. A program without a cap keeps no
+    such total and its rows, work and summary lack the key."""
+    eng, st = _fleet_capped(6, 2)
+    jax.block_until_ready(st)
+    rows = log.rows()
+    assert [r["buckets"] for r in rows[:1]] == [0]
+    at4 = eng.run(n_windows=4)
+    assert rows[2]["buckets"] == int(np.sum(np.asarray(at4.compact_buckets)))
+    assert rows[2]["rounds"] == int(np.sum(np.asarray(at4.metrics.rounds)))
+    did = [profiler.work_between(a, b) for a, b in zip(rows, rows[1:])]
+    # 16 hosts, most of them active, 8 columns a trip: two trips a window
+    # and lane where every host is, one round a trip at the least.
+    assert all(2 * 2 <= d["buckets"] <= min(2 * 2 * 2, d["rounds"]) for d in did)
+    assert sum(d["buckets"] for d in did) == rows[2]["buckets"] \
+        == log.summary()["buckets"]
+    log.clear()
+    _, st = _fleet(6, 2)
+    jax.block_until_ready(st)
+    rows = log.rows()
+    did = [profiler.work_between(a, b) for a, b in zip(rows, rows[1:])]
+    assert all(d is not None and "buckets" not in d for d in did)
+    assert not any("buckets" in r for r in rows)
+    assert "buckets" not in log.summary()
 
 
 def counted(windows, clock=None, late_ms=0.0, **totals):

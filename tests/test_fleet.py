@@ -31,7 +31,7 @@ from shadow1_tpu.fleet.expand import (
     expand_sweep_docs,
 )
 from shadow1_tpu.telemetry.ring import drain_ring
-from tests.parity import lane_metrics
+from tests.parity import lane_metrics, trip_metrics
 from shadow1_tpu.txn import CapacityExceededError
 
 N_WINDOWS = 15
@@ -311,17 +311,34 @@ def test_fleet_accepts_auto_caps_and_retry():
 
 
 def test_fleet_given_compact_cap_warns_and_runs_full_width(fleet_run):
-    """``compact_cap`` under vmap would run both branches of its cond every
-    window, so the fleet says so, drops it, and runs the program it runs
-    without the knob — lane for lane the shared run's counters."""
+    """``compact_cap`` is in force on a fleet (no warning, the parameters as
+    given): the lanes' rounds run 8 columns a trip, the trip loop's
+    predicate reduced over the lanes, and lane for lane every counter but
+    the round loop's counts of itself is the full-width run's. (The name is
+    PR 12's: until PR 44 the fleet warned and ran full width, because the
+    compacted path ended in a ``cond`` on a per-lane predicate.)"""
+    import warnings
+
     plan, _, ref = fleet_run
-    with pytest.warns(UserWarning, match="fleet mode ignores compact_cap"):
-        eng = FleetEngine(plan.exps,
-                          dataclasses.replace(plan.params, compact_cap=8),
-                          plan.max_rounds)
-    assert eng.params == plan.params     # compact_cap 0, nothing else moved
+    params = dataclasses.replace(plan.params, compact_cap=8)
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        eng = FleetEngine(plan.exps, params, plan.max_rounds)
+    assert eng.params == params
     st = eng.run(n_windows=N_WINDOWS)
-    assert FleetEngine.metrics_per_exp(st) == FleetEngine.metrics_per_exp(ref)
+    got, want = (FleetEngine.metrics_per_exp(s) for s in (st, ref))
+    assert [trip_metrics(m) for m in got] == [trip_metrics(m) for m in want]
+    assert ref.compact_buckets is None
+    trips = np.asarray(st.compact_buckets)
+    # A lane's trips are its own: ceil(active / cap) of each of ITS windows
+    # (PHOLD keeps most of a lane's 16 hosts active: two trips in most, none
+    # in a window with no event), whatever the other lanes needed.
+    active = [[r["active_hosts"] for r in eng.drain_rings(st)
+               if r["type"] == "ring" and r["exp"] == e] for e in range(3)]
+    assert [sum(-(-a // 8) for a in lane) for lane in active] == list(trips)
+    assert any(0 in lane for lane in active) and max(map(max, active)) > 8
+    assert all(0 < t <= m["rounds"] for t, m in zip(trips, got))
+    assert all(g["rounds"] > w["rounds"] for g, w in zip(got, want))
 
 
 def test_fleet_halt_names_the_overflowing_experiment():

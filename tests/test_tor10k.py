@@ -11,12 +11,15 @@ solo engine leaf for leaf, then run through the benchmark's own harness with
 its controls and its two new per-layer readers.
 """
 
+import collections
 import contextlib
 import dataclasses
 import io
 import json
 import os
+import re
 import time
+import types
 import warnings
 
 import jax
@@ -24,7 +27,7 @@ import numpy as np
 import pytest
 import yaml
 
-from shadow1_tpu.core.engine import Engine
+from shadow1_tpu.core.engine import Engine, compact_cap_of
 from shadow1_tpu.fleet.engine import (
     FleetEngine,
     fleet_metrics_per_exp,
@@ -32,7 +35,8 @@ from shadow1_tpu.fleet.engine import (
 )
 from shadow1_tpu.fleet.expand import expand_sweep
 from shadow1_tpu.telemetry import chunk_log
-from tests.parity import lane_metrics, unlike_leaves
+from shadow1_tpu.telemetry.phases import phase_path
+from tests.parity import lane_metrics, unlike_but_trips, unlike_leaves
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 REHEARSAL = os.path.join(ROOT, "tests", "rehearsal_tor_join")
@@ -41,7 +45,6 @@ BENCH = os.path.join(ROOT, "benchmarks")
 CELL = "tor33.join24"
 SEED = 600000004000             # the cell's pool of one; past 2**32
 N_WINDOWS, MIDWAY = 60, 40
-COMPACT_WARNING = "fleet mode ignores compact_cap"
 MUST_BE_ZERO = ["ev_overflow", "ob_overflow", "round_cap_hits",
                 "total_ct_overflow"]
 
@@ -60,10 +63,10 @@ def plan():
 
 @pytest.fixture(scope="module")
 def fleet(plan):
-    """The fleet of one, its state after 40 windows and after all 60."""
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", UserWarning)    # compact_cap dropped
-        eng = FleetEngine(plan.exps, plan.params, plan.max_rounds)
+    """The fleet of one under the file's parameters (``compact_cap`` 8 of 33
+    columns in force: several trips a window), its state after 40 windows
+    and after all 60."""
+    eng = FleetEngine(plan.exps, plan.params, plan.max_rounds)
     midway = eng.run(n_windows=MIDWAY)
     return eng, midway, eng.run(midway, n_windows=N_WINDOWS - MIDWAY)
 
@@ -148,6 +151,118 @@ def test_the_lane_equals_the_solo_engine_leaf_for_leaf(fleet, plan):
                for k in ("deliver", "timer", "txr", "app"))
 
 
+# ---- (b2) the cap is a width, not a path: 8, 32 and no cap are one simulation ---
+
+def _fleet_at(plan, cap):
+    eng = FleetEngine(plan.exps, dataclasses.replace(plan.params, compact_cap=cap),
+                      plan.max_rounds)
+    return eng, eng.run(n_windows=N_WINDOWS)
+
+
+@pytest.fixture(scope="module")
+def full_width(plan):
+    return _fleet_at(plan, 0)
+
+
+@pytest.fixture(scope="module")
+def one_trip(plan):
+    return _fleet_at(plan, 32)
+
+
+def test_a_lane_of_several_trips_a_window_equals_the_full_width_lane(
+        fleet, full_width):
+    """``compact_cap`` 8: the busiest window has more than 8 active hosts, so
+    it takes several trips — every leaf but the round loop's counts of
+    itself is the cap-0 lane's (whose counters the reference test above
+    holds to the C++ reference through this one)."""
+    (_, _, st), (_, want) = fleet, full_width
+    assert unlike_but_trips(st, want) == []
+    m, w = fleet_metrics_per_exp(st)[0], fleet_metrics_per_exp(want)[0]
+    assert m["compact_max_fill"] == w["compact_max_fill"] > 8
+    assert m["rounds"] > w["rounds"]
+    assert N_WINDOWS < int(st.compact_buckets[0]) <= m["rounds"]
+
+
+def test_a_lane_of_one_trip_a_window_equals_the_full_width_lane_rounds_too(
+        one_trip, full_width):
+    """``compact_cap`` 32 of 33: no window has 33 active hosts, so every
+    window with an event takes exactly one trip, and then ``rounds``,
+    ``fires_*`` and ``runs_*`` are the full-width counts too: every leaf
+    but ``compact_buckets``, which counts the windows that had an event."""
+    (_, st), (_, want) = one_trip, full_width
+    assert not unlike_leaves(st._replace(compact_buckets=None), want)
+    m = fleet_metrics_per_exp(st)[0]
+    assert m["compact_max_fill"] <= 32
+    # One trip a window that had an event, none in the few that had none.
+    assert N_WINDOWS - 6 <= int(st.compact_buckets[0]) < N_WINDOWS
+
+
+# ---- (b3) what the compiled program of a fleet with a cap holds ------------------
+# Read off the compiled program's text, where every instruction carries the
+# scopes it was traced under (telemetry/phases.py): the scopes are relative
+# to the trace, so nothing an earlier test traced can show up in them.
+
+_INSTR = re.compile(r'^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(\(.*?\)|\S+)\s+'
+                    r'([\w\-]+)\(.*?op_name="([^"]*)"')
+_H_WIDE = re.compile(r"[\[,]33[\],]")      # a dimension of 33 hosts
+
+
+def _instructions(eng):
+    """(result shape, opcode, op_name) of every instruction of a fleet's
+    compiled window program that has an ``op_name``."""
+    return [m.groups() for m in map(_INSTR.match, eng.hlo_text().splitlines())
+            if m]
+
+
+def _round_loops(instrs):
+    """The ``while`` instructions of the rounds phase outside the handler
+    passes, by how deep they sit: ``.../phase:rounds)/while`` is depth 1."""
+    tails = [op.split("phase:rounds)", 1)[1] for _, opc, op in instrs
+             if opc == "while" and "phase:rounds)" in op and "phase:h_" not in op]
+    return sorted(t.count("while") for t in tails)
+
+
+def test_a_fleet_program_with_a_cap_holds_one_round_loop_bucket_wide(fleet):
+    """The census of ISSUE 44: the round loop is in the program once, under
+    the trip loop; no instruction of a pop or a handler pass is 33 columns
+    wide (they are 8 wide); and under ``phase:compact_*`` the columns move
+    by ``dot``, never by ``gather`` or ``scatter``."""
+    instrs = _instructions(fleet[0])
+    assert _round_loops(instrs) == [1, 2]        # the trips ⊃ the rounds
+    passes = [(sh, op) for sh, _, op in instrs
+              if "phase:pop" in op or "phase:h_" in op]
+    assert len(passes) > 5000
+    assert not [x for x in passes if _H_WIDE.search(x[0])][:3]
+    assert any(re.search(r"[\[,]8[\],]", sh) for sh, _ in passes)
+    mover = collections.Counter(
+        opc for _, opc, op in instrs if "phase:compact_" in op)
+    # (The CPU's compiler folds most of the matmuls into fusions.)
+    assert mover["dot"] + mover["fusion"] + mover["convolution"] > 50
+    assert not {"gather", "scatter"} & set(mover), mover
+    # Both scopes are there, inside the rounds phase and outside every pass
+    # (a fusion merged from two ops names its scope twice).
+    paths = {phase_path(op) for _, _, op in instrs if "phase:compact_" in op}
+    assert {"rounds/compact_gather", "rounds/compact_scatter"} <= paths
+    assert all(set(p.split("/")) <= {"rounds", "compact_gather",
+                                     "compact_scatter"} for p in paths), paths
+
+
+def test_a_fleet_program_without_a_cap_is_the_program_it_was(full_width):
+    """No cap: no leaf for the trips in the state, nothing under a
+    ``phase:compact_*`` scope, one round loop, 33 columns wide, straight
+    under ``phase:rounds`` — the program the parent lowered (CHANGES.md,
+    PR 44, has the four cells' hashes)."""
+    eng, st = full_width
+    assert st.compact_buckets is None
+    assert len(jax.tree.leaves(st)) + 1 == len(
+        jax.tree.leaves(jax.eval_shape(FleetEngine(
+            eng.exps, dataclasses.replace(eng.params, compact_cap=8)).init_state)))
+    instrs = _instructions(eng)
+    assert _round_loops(instrs) == [1]
+    assert not [op for _, _, op in instrs if "phase:compact_" in op]
+    assert any(_H_WIDE.search(sh) for sh, _, op in instrs if "phase:h_" in op)
+
+
 # ---- (c) the cell in miniature through the benchmark's harness -----------------
 
 def _bench(seed, *more):
@@ -199,7 +314,8 @@ def test_the_cell_in_miniature_under_a_control_is_not_correct(control):
 def traced_rows(fleet, plan):
     """The chunk log after what a traced run of the miniature leaves in it:
     a warm-up chunk, the cycle's twelve, the replay of windows 0-35 (one row
-    of 30 windows, five of one). Gives the metrics at windows 30 and 35."""
+    of 30 windows, five of one). Gives the metrics and the trips
+    (``compact_buckets``) at windows 30 and 35."""
     from benchmarks.harness import loop
     from benchmarks.harness import sim as simmod
 
@@ -211,10 +327,12 @@ def traced_rows(fleet, plan):
     loop.run_chunk(sim, eng.init_state(), 5)
     st, at = eng.init_state(), {}
     for done in range(0, N_WINDOWS, 5):
-        at[done] = jax.device_get(st.metrics)
+        at[done] = jax.device_get((st.metrics, st.compact_buckets))
         st = loop.run_chunk(sim, st, 5)
-    loop._replay_rounds(sim, {"traced": (30, 35)}, at[35])
-    yield at[30], at[35]
+    loop._replay_rounds(sim, {"traced": (30, 35)}, at[35][0])
+    yield types.SimpleNamespace(
+        m30=at[30][0], m35=at[35][0],
+        trips=int(np.sum(at[35][1]) - np.sum(at[30][1])))
     log.clear()
 
 
@@ -223,14 +341,15 @@ def _readers():
 
     m = mf.load(REHEARSAL)
     names = [e["name"] for e in mf.metrics_of(m, "per_layer", CELL)]
-    assert names[-2:] == ["active_host_share", "events_per_round"]
-    return [mf.reader(REHEARSAL, m, "layer_metrics", n) for n in names[-2:]]
+    assert names[-3:] == ["active_host_share", "events_per_round",
+                          "buckets_per_window"]
+    return [mf.reader(REHEARSAL, m, "layer_metrics", n) for n in names[-3:]]
 
 
 def test_the_new_readers_read_the_traced_chunk_s_work_off_the_chunk_log(traced_rows):
-    m30, m35 = traced_rows
-    share, per_round = _readers()
-    counters = {"chunks": 1, "windows": 5, "rounds": 1}
+    m30, m35 = traced_rows.m30, traced_rows.m35
+    share, per_round, buckets = _readers()
+    counters = {"chunks": 1, "windows": 5, "rounds": 1, "lanes": 1}
 
     def delta(k):
         return int(np.sum(getattr(m35, k)) - np.sum(getattr(m30, k)))
@@ -241,9 +360,15 @@ def test_the_new_readers_read_the_traced_chunk_s_work_off_the_chunk_log(traced_r
     assert per_round(None, counters, {}) == pytest.approx(
         delta("events") / delta("rounds"))
     assert per_round(None, counters, {}) > 1
+    # PR 44's reader: the trips of windows 30-35 a window and lane, against
+    # the state's own count (cap 8: more than one trip in some window).
+    assert buckets(None, counters, {}) == pytest.approx(traced_rows.trips / 5)
+    assert buckets(None, counters, {}) > 1
+    assert buckets(None, {**counters, "lanes": 0}, {}) is None
     # No traced chunk in the counters, or a stretch this log has no rows of.
     assert share(None, {"chunks": 0, "windows": 0}, {}) is None
     assert per_round(None, {"chunks": 2, "windows": 20}, {}) is None
+    assert buckets(None, {"chunks": 2, "windows": 20, "lanes": 1}, {}) is None
 
 
 def test_on_rows_without_the_totals_the_new_readers_return_none(traced_rows,
@@ -251,21 +376,29 @@ def test_on_rows_without_the_totals_the_new_readers_return_none(traced_rows,
     """The parent's rows (PR 39's): ``first_window`` and the clock stamps,
     no totals and no ``hosts``. And a log without the replay's rows (an
     untraced run) says nothing of where the stretch is."""
-    from shadow1_tpu.telemetry.registry import CHUNK_TOTALS
+    from shadow1_tpu.telemetry.registry import CHUNK_CAP_TOTALS, CHUNK_TOTALS
 
-    share, per_round = _readers()
-    counters = {"chunks": 1, "windows": 5, "rounds": 1}
+    share, per_round, buckets = _readers()
+    counters = {"chunks": 1, "windows": 5, "rounds": 1, "lanes": 1}
     log = chunk_log()
     rows = log.rows()
-    assert all(set(CHUNK_TOTALS) <= set(r) for r in rows) and len(rows) == 19
+    totals = set(CHUNK_TOTALS + CHUNK_CAP_TOTALS)
+    assert all(totals <= set(r) for r in rows) and len(rows) == 19
+    # PR 43's rows (and a program with no cap in force): no ``buckets``.
     monkeypatch.setattr(log, "rows", lambda wait_s=1.0: [
-        {k: v for k, v in r.items() if k not in CHUNK_TOTALS} for r in rows])
+        {k: v for k, v in r.items() if k not in CHUNK_CAP_TOTALS} for r in rows])
+    assert share(None, counters, {}) is not None
+    assert buckets(None, counters, {}) is None
+    monkeypatch.setattr(log, "rows", lambda wait_s=1.0: [
+        {k: v for k, v in r.items() if k not in totals} for r in rows])
     assert share(None, counters, {}) is None
     assert per_round(None, counters, {}) is None
+    assert buckets(None, counters, {}) is None
     monkeypatch.setattr(log, "rows", lambda wait_s=1.0: [
         r for r in rows if r["windows"] == 5])
     assert share(None, counters, {}) is None
     assert per_round(None, counters, {}) is None
+    assert buckets(None, counters, {}) is None
 
 
 # ---- (e) the real cell's data files ---------------------------------------------
@@ -315,16 +448,20 @@ def test_the_cell_s_files_state_what_the_issue_fixed():
 def test_rung_4_itself_builds_a_fleet_of_one_at_full_width():
     """The real file under the cell's seed, shapes only (no state is made):
     1,271.6 MB in one lane, the message-queue planes [1, 64, 128, 10000];
-    its ``compact_cap`` 1,280 is dropped with a warning and nothing else of
-    its widths moves."""
+    its ``compact_cap`` 1,280 is in force — no warning, not one parameter
+    moved — so the lane's rounds run 1,280 of 10,000 columns a trip, and the
+    state has one leaf more, the trips' count. (The name is PR 43's: until
+    PR 44 the fleet dropped the cap and ran full width.)"""
     with open(RUNG4) as f:
         doc = yaml.safe_load(f)
     doc["sweep"] = {"seeds": [SEED]}
     plan = expand_sweep(doc, base_dir=os.path.dirname(RUNG4))
     assert plan.params.compact_cap == 1280
-    with pytest.warns(UserWarning, match=COMPACT_WARNING):
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
         eng = FleetEngine(plan.exps, plan.params, plan.max_rounds)
-    assert eng.params == dataclasses.replace(plan.params, compact_cap=0)
+    assert eng.params == plan.params
+    assert compact_cap_of(eng.params, plan.exps[0].n_hosts) == 1280
     assert (eng.n_exp, plan.exps[0].n_hosts) == (1, 10000)
     assert (eng.params.ev_cap, eng.params.sockets_per_host,
             eng.params.msgq_cap, eng.params.max_rounds) == (256, 128, 64, 1024)
@@ -332,10 +469,12 @@ def test_rung_4_itself_builds_a_fleet_of_one_at_full_width():
     assert (int(cfg["ct_cap"]), int(cfg["cells_max"])) == (1024, 120)
     role = np.asarray(cfg["role"])
     assert [int((role == r).sum()) for r in (0, 1, 2)] == [1000, 8990, 10]
-    leaves = jax.tree.leaves(jax.eval_shape(eng.init_state))
+    st = jax.eval_shape(eng.init_state)
+    assert st.compact_buckets.shape == (1,)
+    leaves = jax.tree.leaves(st)
     sizes = sorted(((x.size * x.dtype.itemsize, x.shape) for x in leaves),
                    reverse=True)
-    assert len(leaves) == 130
-    assert sum(b for b, _ in sizes) == 1_271_600_372
+    assert len(leaves) == 131
+    assert sum(b for b, _ in sizes) == 1_271_600_372 + 8
     assert sizes[0] == sizes[1] == (327_680_000, (1, 64, 128, 10000))
     assert sizes[2] == (102_400_000, (1, 10, 256, 10000))

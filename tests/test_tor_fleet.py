@@ -7,7 +7,8 @@ for leaf, and the benchmark's reference counter for counter, under ITS seed.
 All at 20 hosts (2 guards, 3 middles, 2 exits, 1 dirauth, 12 clients), 3
 lanes, 40 windows (``tests/rehearsal_tor20``: the benchmark cell
 ``tor1k.seeds8`` in miniature — its pool's first seeds, its cycle, and like
-``configs/rung3_tor1k.yaml`` a ``compact_cap`` that the fleet drops — run
+``configs/rung3_tor1k.yaml`` a ``compact_cap``, in force on the fleet as on
+the solo engine: 8 of 20 columns a trip, several trips a window — run
 through the benchmark's own harness at the end), plus the data files of the
 real cell and the phase scopes of ``apps/tor.py``.
 """
@@ -28,7 +29,7 @@ import numpy as np
 import pytest
 import yaml
 
-from shadow1_tpu.core.engine import Engine
+from shadow1_tpu.core.engine import Engine, compact_cap_of
 from shadow1_tpu.fleet.engine import (
     FleetEngine,
     fleet_metrics_per_exp,
@@ -36,8 +37,13 @@ from shadow1_tpu.fleet.engine import (
 )
 from shadow1_tpu.fleet.expand import expand_sweep
 from shadow1_tpu.telemetry import phases
-from shadow1_tpu.telemetry.registry import MODEL_TOTALS
-from tests.parity import assert_runs_contract, lane_metrics, unlike_leaves
+from shadow1_tpu.telemetry.registry import LANE_PROGRAM_FIELDS, MODEL_TOTALS
+from tests.parity import (
+    assert_runs_contract,
+    lane_metrics,
+    unlike_but_trips,
+    unlike_leaves,
+)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 REHEARSAL = os.path.join(ROOT, "tests", "rehearsal_tor20")
@@ -49,7 +55,6 @@ SEEDS = [600000003000 + i for i in range(3)]    # the cell's pool; past 2**32
 TOR_TOTALS = ("total_streams_done", "total_cells_rx", "total_cells_fwd",
               "total_ct_overflow", "total_cell_retries", "clients_done")
 TOR_SCOPES = {"tor_dir", "tor_build", "tor_relay", "tor_stream"}
-COMPACT_WARNING = "fleet mode ignores compact_cap"
 
 
 def doc20(seeds=None):
@@ -67,16 +72,15 @@ def plan():
 
 @pytest.fixture(scope="module")
 def fleet(plan):
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", UserWarning)    # compact_cap: below
-        eng = FleetEngine(plan.exps, plan.params, plan.max_rounds)
+    eng = FleetEngine(plan.exps, plan.params, plan.max_rounds)
     return eng, eng.run(n_windows=N_WINDOWS)
 
 
 @pytest.fixture(scope="module")
 def solos(plan, fleet):
-    """Each lane's experiment alone on the solo engine, at the width the
-    fleet runs (``compact_cap`` dropped): (engine, end state)."""
+    """Each lane's experiment alone on the solo engine, under the fleet's
+    parameters (the file's ``compact_cap`` 8 in force): (engine, end
+    state)."""
     out = []
     for exp in plan.exps:
         eng = Engine(exp, fleet[0].params)
@@ -104,6 +108,26 @@ def test_a_lane_equals_the_solo_engine_leaf_for_leaf(fleet, solos, lane):
     assert totals["total_cell_retries"] == int(summary["cell_retries"].sum())
     assert totals["clients_done"] == int((summary["done_time"] > 0).sum())
     assert totals["total_streams_done"] > 0 and totals["total_cells_fwd"] > 0
+
+
+def test_a_lane_that_finishes_first_rides_padding_trips_unchanged(fleet, solos):
+    """The trip loop runs while ANY lane has an active host left
+    (``any_lane``), so a lane whose own active set is used up rides the
+    other lanes' further trips with an all-padding bucket. The lanes'
+    active sets differ in size (their trips differ), so some lane did ride
+    such trips; it counts only its own, and its state is its solo run's —
+    ``compact_buckets`` and ``rounds`` included (test (a) compares every
+    leaf): a padding trip pops nothing, writes nothing, counts nothing."""
+    _, st = fleet
+    trips = [int(t) for t in np.asarray(st.compact_buckets)]
+    assert len(set(trips)) > 1, trips
+    lanes = fleet_metrics_per_exp(st)
+    for lane, (_, want) in enumerate(solos):
+        assert trips[lane] == int(want.compact_buckets)
+        assert lanes[lane]["rounds"] == Engine.metrics_dict(want)["rounds"]
+    # A program of three lanes made at least the slowest lane's trips; the
+    # lane with the fewest rode the difference as padding.
+    assert max(trips) - min(trips) >= 1
 
 
 def test_the_guards_engage_and_runs_count_the_program(fleet, solos):
@@ -296,17 +320,21 @@ def test_the_cell_s_files_state_what_the_issue_fixed(fleet):
 
 def test_rung_3_itself_builds_a_fleet_of_eight_at_full_width():
     """The real file (1,000 hosts; config only, no state is made) under the
-    cell's eight seeds: its ``compact_cap`` 384 is dropped with a warning
-    and nothing else of its widths moves."""
+    cell's eight seeds: its ``compact_cap`` 384 is in force — no warning,
+    not one parameter moved — so the lanes' rounds run 384 of 1,000 columns a
+    trip, and nothing else of its widths moves. (The name is PR 35's: until
+    PR 44 the fleet dropped the cap and ran full width.)"""
     with open(RUNG3) as f:
         doc = yaml.safe_load(f)
     assert doc["engine"]["compact_cap"] == 384
     doc["sweep"] = {"seeds": [600000003000 + i for i in range(8)]}
     plan = expand_sweep(doc, base_dir=os.path.dirname(RUNG3))
     assert plan.params.compact_cap == 384
-    with pytest.warns(UserWarning, match=COMPACT_WARNING):
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
         eng = FleetEngine(plan.exps, plan.params, plan.max_rounds)
-    assert eng.params == dataclasses.replace(plan.params, compact_cap=0)
+    assert eng.params == plan.params
+    assert compact_cap_of(eng.params, plan.exps[0].n_hosts) == 384
     assert (eng.n_exp, plan.exps[0].n_hosts) == (8, 1000)
     assert (eng.params.ev_cap, eng.params.sockets_per_host,
             eng.params.msgq_cap, eng.params.max_rounds) == (256, 64, 64, 1024)
@@ -318,14 +346,30 @@ def test_rung_3_itself_builds_a_fleet_of_eight_at_full_width():
 
 def test_a_tor_file_s_compact_cap_warns_and_the_fleet_runs_full_width(plan, fleet):
     """Beside ``test_fleet.py``'s PHOLD case: here the knob comes from the
-    experiment file, as rung 3's does, and the lanes it was dropped for are
-    the ones every other test of this file holds to solo and reference."""
+    experiment file, as rung 3's does, and it is in force (no warning, the
+    plan's parameters as they are): the lanes every other test of this file
+    holds to solo and reference run 8 of 20 columns a trip, several trips a
+    window, and lane 0 equals its FULL-WIDTH solo run in every leaf but the
+    round loop's counts of itself. (The name is PR 35's: until PR 44 the
+    fleet warned and ran full width.)"""
     assert doc20()["engine"]["compact_cap"] == 8 == plan.params.compact_cap
-    with pytest.warns(UserWarning, match=COMPACT_WARNING):
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
         eng = FleetEngine(plan.exps, plan.params, plan.max_rounds)
-    assert eng.params == fleet[0].params
-    assert eng.params == dataclasses.replace(plan.params, compact_cap=0)
+    assert eng.params == fleet[0].params == plan.params
     assert eng.variant_signature() == fleet[0].variant_signature()
+    st = fleet[1]
+    trips = np.asarray(st.compact_buckets)
+    lanes = fleet_metrics_per_exp(st)
+    # Several trips in some window of every lane, none in a window with no
+    # event, and at least one round a trip.
+    assert all(m["compact_max_fill"] > 8 for m in lanes)
+    assert all(0 < t <= m["rounds"] for t, m in zip(trips, lanes))
+    full = Engine(plan.exps[0], dataclasses.replace(plan.params, compact_cap=0))
+    want = full.run(n_windows=N_WINDOWS)
+    assert unlike_but_trips(slice_experiment(st, 0), want,
+                            also_not=LANE_PROGRAM_FIELDS) == []
+    assert lanes[0]["rounds"] > Engine.metrics_dict(want)["rounds"]
 
 
 # ---- (f) the cell in miniature through the benchmark's harness ----------------
@@ -409,7 +453,7 @@ def test_cli_runs_the_study_under_fleet_and_its_records_carry_the_totals(tmp_pat
         [sys.executable, "-m", "shadow1_tpu", str(cfg), "--fleet",
          "--heartbeat", "20"], capture_output=True, text=True)
     assert out.returncode == 0, out.stderr[-1500:]
-    assert COMPACT_WARNING in out.stderr
+    assert "compact_cap" not in out.stderr      # in force: nothing to warn of
     recs = [json.loads(ln) for ln in out.stdout.strip().splitlines()]
     assert [r["type"] for r in recs] == ["fleet_exp"] * 3 + ["fleet_summary"]
     assert [r["seed"] for r in recs[:3]] == SEEDS

@@ -38,6 +38,7 @@ from shadow1_tpu.tune import (
     resize_state,
 )
 from shadow1_tpu.tune.ladder import classify
+from tests.parity import trip_metrics
 
 REPO = os.path.join(os.path.dirname(__file__), "..")
 
@@ -149,7 +150,9 @@ def test_compact_gauge_records_bucket_demand():
     )
     m_off = Engine.metrics_dict(Engine(exp, EngineParams()).run(n_windows=30))
     assert m_off["compact_max_fill"] > 0  # measured with compaction OFF too
-    assert m == m_off  # the perf knob stays bit-invisible, gauge included
+    # The perf knob stays bit-invisible, gauge included: all but what the
+    # round loop counts of itself (a window over the cap takes more trips).
+    assert trip_metrics(m) == trip_metrics(m_off)
     assert m["compact_max_fill"] <= 64
 
 
