@@ -218,8 +218,8 @@ class EngineParams:
     # (per-shard share) and the fleet engine alike. 0 = off (the round loop
     # at full width). There is no fall-back: a window whose active-host
     # count exceeds the bucket takes ceil(active / cap) trips, each one
-    # move of the bucket's columns out and back (two one-hot matmuls over
-    # the state) on top of the same rounds, so a dense window costs
+    # move of the bucket's columns out and back (a gather of whole columns
+    # a leaf each way) on top of the same rounds, so a dense window costs
     # ceil(H / cap) moves and a cap near H buys nothing. Results are
     # bit-identical whatever the value — purely a perf knob (only rounds,
     # fires_*, runs_* and SimState.compact_buckets, the program's counts of

@@ -41,17 +41,18 @@ Padding lanes (bucket wider than what is left) clone the last host's
 columns but are forced event-free, so they never pop, masked handlers never
 write them, and the put-back leaves them out.
 
-Columns move by one-hot contraction on the MXU, never by ``gather`` or
-``scatter`` (ISSUE 44's census, tests/test_tor10k.py): a leaf's words are
-split into byte planes, each ``dot``ted with the one-hot as ``bfloat16``
-with ``float32`` accumulation — one non-zero term a sum, a byte, so exact on
-any backend (``rng._log_tbl_read``'s arithmetic). The leaves' words are
-stacked so that the state moves in a few matmuls a byte plane
-(``move_leaves``). At rung 4's shapes the two directions, a leaf at a
-time, read 33 + 32 ms a trip on a v5e alone and 20 + 22 inside the
-program; a ``jnp.take`` along the host axis — one index for a whole
-column, not PR 41's index per element — read 10 + 20 ms alone (PERF.md §6,
-PR 44): the next step for whoever takes the mover up.
+Columns move by a ROW-UNIFORM gather along the host axis: ONE index for a
+whole column of rows, ``cap`` indices a leaf on the way out (``take_cols``:
+``x[..., idx]``, ``idx`` ascending out of ``next_bucket``'s sort) and ``H``
+on the way back (``put_cols``: a taken host reads the lane of its rank among
+the taken hosts, every other host keeps its column) — not the index per
+ELEMENT that PR 41 priced at 7 ns each and ISSUE 44's census forbade. A
+gather copies words, so it is exact on any backend for any dtype, and a
+leaf moves as it is: no byte planes, no stacking. At rung 4's shapes the
+two directions read 10 + 23 ms a trip on a v5e alone where PR 44's one-hot
+``bfloat16`` matmuls read 27 + 29, and 0.9 + 11.8 inside the window program
+where they read 20 + 23: XLA keeps the loop's carry host-major for the
+gather's sake, so the way out transposes nothing (PERF.md §5, §6, PR 45).
 """
 
 from __future__ import annotations
@@ -91,111 +92,59 @@ def next_bucket(remaining: jnp.ndarray, cap: int):
     return idx, lane_pad, remaining & (iota <= last)
 
 
-def _words(x):
-    """``x`` as int32 (or bool) arrays of its shape, and the way back."""
-    dt = x.dtype
-    if dt in (jnp.bool_, jnp.int32):
-        return [x], lambda ws: ws[0]
-    cast = jax.lax.bitcast_convert_type
-    if dt.itemsize == 4:
-        return [cast(x, jnp.int32)], lambda ws: cast(ws[0], dt)
-    if dt.itemsize == 8:
-        v = x if dt == jnp.int64 else cast(x, jnp.int64)
-
-        def join(ws):
-            v = (ws[1].astype(jnp.int64) << 32) \
-                | (ws[0].astype(jnp.int64) & 0xFFFFFFFF)
-            return v if dt == jnp.int64 else cast(v, dt)
-
-        return [v.astype(jnp.int32), (v >> 32).astype(jnp.int32)], join
-    if jnp.issubdtype(dt, jnp.integer):
-        return [x.astype(jnp.int32)], lambda ws: ws[0].astype(dt)
-    raise TypeError(f"compaction cannot move a {dt} leaf")
-
-
-def _move_word(w, sel):
-    """``w`` [R, N] (int32 or bool) through the one-hot ``sel`` [N, M]
-    (bfloat16): column m of the result is the column of ``w`` that ``sel``'s
-    column m marks, zeros where it marks none. Bit-exact: a sum has at most
-    one non-zero term, a byte, which ``bfloat16`` holds. (A ``bfloat16``
-    result is as exact and as fast, and the compiled program's scratch is
-    160 MB larger with it at rung 4's shapes: PERF.md §6, PR 44.)"""
-    def dot(p):
-        return jnp.dot(p.astype(jnp.bfloat16), sel,
-                       preferred_element_type=jnp.float32)
-
-    if w.dtype == jnp.bool_:
-        return dot(w) != 0
-    # Three unsigned bytes and the signed top one: each exact in bfloat16.
-    b0, b1, b2 = (dot((w >> s) & 0xFF).astype(jnp.int32) for s in (0, 8, 16))
-    b3 = dot(w >> 24).astype(jnp.int32)
-    return b0 | (b1 << 8) | (b2 << 16) | (b3 << 24)
-
-
-# A leaf of this many rows or more is moved by matmuls of its own; the
-# smaller ones share theirs (``move_leaves``).
-_OWN_ROWS = 4096
-
-
-def move_leaves(xs, sel):
-    """The host (last) axis of every array of ``xs`` ([..., N]) through
-    ``sel`` [N, M]; a list of arrays [..., M].
-
-    The words of the leaves are stacked row-wise into ONE matrix a kind of
-    word (int32; bool), so the whole state moves in a few matmuls a byte
-    plane, not in four a leaf: the v5e's code for ~660 small convolutions
-    was 75–130 MB of a 500 MB program, and the program's code is what
-    ``peak_hbm_mb`` reads on top of arguments and results (PERF.md §6,
-    PR 44). Stacking copies the leaves; those of ``_OWN_ROWS`` rows or more
-    (the message-queue planes: two thirds of a Tor state) go unstacked."""
-    m = sel.shape[1]
-    parts = [_words(x) for x in xs]
-    groups: dict = {}
-    for i, (ws, _) in enumerate(parts):
-        for j, w in enumerate(ws):
-            rows = w.reshape((-1, w.shape[-1]))
-            own = (i, j) if rows.shape[0] >= _OWN_ROWS else None
-            groups.setdefault((w.dtype == jnp.bool_, own), []).append(
-                (i, j, rows, w.shape[:-1]))
-    moved = {}
-    for members in groups.values():
-        out = _move_word(jnp.concatenate([r for _, _, r, _ in members]), sel)
-        at = 0
-        for i, j, rows, lead in members:
-            moved[i, j] = out[at:at + rows.shape[0]].reshape(lead + (m,))
-            at += rows.shape[0]
-    return [rebuild([moved[i, j] for j in range(len(ws))])
-            for i, (ws, rebuild) in enumerate(parts)]
-
-
-def move_cols(x, sel):
-    """One array's host (last) axis through ``sel`` [N, M]."""
-    return move_leaves([x], sel)[0]
-
-
 def _is_host_leaf(x, h: int) -> bool:
     return hasattr(x, "ndim") and x.ndim >= 1 and x.shape[-1] == h
 
 
-def take_cols(tree, sel, h: int):
-    """Every [*, H] leaf of ``tree`` down to the bucket: [*, cap]."""
-    leaves, treedef = jax.tree.flatten(tree)
-    host = [i for i, x in enumerate(leaves) if _is_host_leaf(x, h)]
-    for i, x in zip(host, move_leaves([leaves[i] for i in host], sel)):
-        leaves[i] = x
-    return treedef.unflatten(leaves)
+def _cols(x, idx):
+    """``x[..., idx]``: ONE index for a whole column of rows (a row-uniform
+    gather along the host axis), ``idx`` ascending and in bounds."""
+    return x.at[..., idx].get(indices_are_sorted=True,
+                              mode="promise_in_bounds")
 
 
-def put_cols(full, comp, sel_t, taken, h: int):
-    """Inverse of ``take_cols``: the ``taken`` hosts read their bucket lane
-    (``sel_t`` [cap, H] marks it), every other column stays; a leaf with no
-    host axis takes the round loop's value."""
-    old, treedef = jax.tree.flatten(full)
-    new = jax.tree.leaves(comp)
-    host = [i for i, x in enumerate(old) if _is_host_leaf(x, h)]
-    for i, back in zip(host, move_leaves([new[i] for i in host], sel_t)):
-        new[i] = jnp.where(taken, back, old[i])
-    return treedef.unflatten(new)
+def _as_bits(x):
+    """A float leaf as the integers of its bits, and the way back: XLA:TPU
+    may lower a float's gather or select as arithmetic, which flushes a
+    denormal and quiets a NaN (PERF.md §6, PR 45); integers move as they
+    are."""
+    if not jnp.issubdtype(x.dtype, jnp.floating):
+        return x, lambda y: y
+    cast = jax.lax.bitcast_convert_type
+    bits = {2: jnp.uint16, 4: jnp.uint32, 8: jnp.uint64}[x.dtype.itemsize]
+    return cast(x, bits), lambda y: cast(y, x.dtype)
+
+
+def take_cols(tree, idx, h: int):
+    """Every [*, H] leaf of ``tree`` down to the bucket, [*, cap]: lane m
+    holds host ``idx[m]``; a padding lane (``idx`` = H) clones host H − 1."""
+    at = jnp.minimum(idx, h - 1)
+
+    def take(x):
+        if not _is_host_leaf(x, h):
+            return x
+        bits, back = _as_bits(x)
+        return back(_cols(bits, at))
+
+    return jax.tree.map(take, tree)
+
+
+def put_cols(full, comp, taken):
+    """Inverse of ``take_cols``: a ``taken`` host reads its bucket lane — its
+    rank among the taken hosts, the bucket being filled lowest id first —
+    and every other column stays, whatever lane its position names; a leaf
+    with no host axis takes the round loop's value."""
+    h = taken.shape[0]
+    rank = jnp.cumsum(taken.astype(jnp.int32)) - 1
+
+    def put(old, new):
+        if not _is_host_leaf(old, h):
+            return new
+        pos = jnp.clip(rank, 0, new.shape[-1] - 1)
+        (old, back), (new, _) = _as_bits(old), _as_bits(new)
+        return back(jnp.where(taken, _cols(new, pos), old))
+
+    return jax.tree.map(put, full, comp)
 
 
 def ctx_tables(ctx) -> dict:
@@ -214,7 +163,6 @@ def compact_window_rounds(st, ctx, make_handlers, win_end, cap: int):
     from shadow1_tpu.core.engine import any_lane, run_rounds
 
     h = ctx.n_hosts
-    iota = jnp.arange(h, dtype=jnp.int32)
     # (The demanded-fill gauge ``compact_max_fill`` is recorded by
     # window_step for every window, compaction on or off.)
 
@@ -225,11 +173,8 @@ def compact_window_rounds(st, ctx, make_handlers, win_end, cap: int):
         with jax.named_scope("phase:compact_gather"):
             # Padding lanes clone host H−1 (a real host's tables and state,
             # so no handler meets a value no host could hold) ...
-            sel = (iota[:, None] == jnp.minimum(idx, h - 1)[None, :]) \
-                .astype(jnp.bfloat16)
-            # (the Ctx's per-host tables ride the state's matmuls)
             (evbuf_c, outbox_c, model_c, busy_c), tables_c = take_cols(
-                (host_state, ctx_tables(ctx)), sel, h)
+                (host_state, ctx_tables(ctx)), idx, h)
             ctx_c = dataclasses.replace(ctx, n_hosts=cap, **tables_c)
             # ... and must never pop: force them event-free (a clone with a
             # live n_elig copy would spin the round loop: it can never pop,
@@ -243,12 +188,10 @@ def compact_window_rounds(st, ctx, make_handlers, win_end, cap: int):
                            cpu_busy=busy_c)
         st_c, hit = run_rounds(st_c, ctx_c, make_handlers(ctx_c), win_end)
         with jax.named_scope("phase:compact_scatter"):
-            # Unclipped ``idx``: a padding lane marks no host.
-            sel_t = (idx[:, None] == iota[None, :]).astype(jnp.bfloat16)
+            # A padding lane is no host's rank: it is left out.
             evbuf_f, outbox_f, model_f, busy_f = put_cols(
                 host_state,
-                (st_c.evbuf, st_c.outbox, st_c.model, st_c.cpu_busy),
-                sel_t, taken, h)
+                (st_c.evbuf, st_c.outbox, st_c.model, st_c.cpu_busy), taken)
         st = st_c._replace(
             evbuf=evbuf_f, outbox=outbox_f, model=model_f, cpu_busy=busy_f,
             # The lane's own trips: one that rode another lane's counts 0.

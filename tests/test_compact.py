@@ -238,44 +238,90 @@ def _edge_values(dtype, shape, rs):
     return x
 
 
+@pytest.mark.parametrize("lanes", [1, 3])
 @pytest.mark.parametrize("h,cap", [(33, 8), (40, 16), (100, 7)],
                          ids=["33x8", "40x16", "100x7"])
 @pytest.mark.parametrize("dtype", [np.int32, np.bool_, np.int64, np.uint64,
                                    np.uint32, np.float32],
                          ids=lambda d: np.dtype(d).name)
-def test_the_mover_round_trips_every_bit(dtype, h, cap):
-    """``move_cols`` through a bucket's one-hot is ``take`` on the real
-    lanes, bit for bit (INT32_MIN, −1, 2**31 − 1, I32_FREE, K_NONE and bool
-    planes among the values), whether or not ``cap`` divides ``H``; and
-    ``put_cols`` writes the taken columns back and no other."""
+def test_the_mover_round_trips_every_bit(dtype, h, cap, lanes):
+    """``take_cols`` is ``take`` on the real lanes, bit for bit (INT32_MIN,
+    −1, 2**31 − 1, I32_FREE, K_NONE and bool planes among the values),
+    whether or not ``cap`` divides ``H``; and ``put_cols`` writes the taken
+    columns back and no other. Under ``jax.vmap`` as the fleet runs it, a
+    different ``remaining`` a lane: lane 0 has more active hosts than the
+    cap (a full bucket, then a padded one), lane 1 has NONE (an all-padding
+    bucket is the identity), lane 2 fewer than the cap; lane 0 un-batched
+    too, as the solo and the sharded engine run it."""
+    import jax
     import jax.numpy as jnp
 
-    from shadow1_tpu.core.compact import move_cols, next_bucket, put_cols
+    from shadow1_tpu.core.compact import next_bucket, put_cols, take_cols
 
     rs = np.random.default_rng(cap)
-    x = _edge_values(dtype, (3, 5, h), rs)
+    x = _edge_values(dtype, (lanes, 3, 5, h), rs)
     x[..., :h] = x[..., rs.permutation(h)]      # the edges on any column
-    active = np.zeros(h, bool)
-    active[rs.choice(h, cap + 3, replace=False)] = True
+    active = np.zeros((lanes, h), bool)
+    for lane, n in enumerate([cap + 3, 0, cap - 2][:lanes]):
+        active[lane, rs.choice(h, n, replace=False)] = True
     remaining = jnp.asarray(active)
-    full = jnp.asarray(x)
-    iota = jnp.arange(h, dtype=jnp.int32)
-    for n_real in (cap, 3):                      # a full bucket, a padded one
-        idx, lane_pad, taken = next_bucket(remaining, cap)
-        assert int((~lane_pad).sum()) == n_real
-        sel = (iota[:, None] == jnp.minimum(idx, h - 1)[None, :]) \
-            .astype(jnp.bfloat16)
-        got = np.asarray(move_cols(full, sel))
-        assert got.dtype == x.dtype
-        np.testing.assert_array_equal(
-            got.view(np.uint8), np.take(x, np.minimum(idx, h - 1), axis=-1)
-            .view(np.uint8))                     # pads clone host H - 1
+    full = {"plane": jnp.asarray(x), "no_host_axis": jnp.zeros((lanes, h + 1))}
+    for n_real in ([cap, 0, cap - 2][:lanes], [3, 0, 0][:lanes]):
+        idx, lane_pad, taken = jax.vmap(lambda r: next_bucket(r, cap))(remaining)
+        assert list(np.asarray(~lane_pad).sum(axis=1)) == n_real
+        got = jax.vmap(lambda t, i: take_cols(t, i, h))(full, idx)
+        assert got["plane"].dtype == x.dtype
+        np.testing.assert_array_equal(got["no_host_axis"],
+                                      full["no_host_axis"])
         # Put back something else on every lane: only the taken hosts move.
-        other = _edge_values(dtype, got.shape, rs)
-        sel_t = (idx[:, None] == iota[None, :]).astype(jnp.bfloat16)
-        back = np.asarray(put_cols(full, jnp.asarray(other), sel_t, taken, h))
-        want = x.copy()
-        want[..., np.asarray(idx)[:n_real]] = other[..., :n_real]
-        np.testing.assert_array_equal(back.view(np.uint8), want.view(np.uint8))
+        other = _edge_values(dtype, got["plane"].shape, rs)
+        back = jax.vmap(put_cols)(
+            full, {"plane": jnp.asarray(other), "no_host_axis": None}, taken)
+        assert back["no_host_axis"] is None     # the round loop's value
+        for lane in range(lanes):
+            at = np.asarray(idx[lane])
+            np.testing.assert_array_equal(
+                np.asarray(got["plane"][lane]).view(np.uint8),
+                np.take(x[lane], np.minimum(at, h - 1), axis=-1)
+                .view(np.uint8))                 # pads clone host H - 1
+            want = x[lane].copy()
+            want[..., at[:n_real[lane]]] = other[lane][..., :n_real[lane]]
+            np.testing.assert_array_equal(
+                np.asarray(back["plane"][lane]).view(np.uint8),
+                want.view(np.uint8))
+        solo = take_cols(full["plane"][0], idx[0], h)
+        np.testing.assert_array_equal(
+            np.asarray(solo).view(np.uint8),
+            np.asarray(got["plane"][0]).view(np.uint8))
+        np.testing.assert_array_equal(
+            np.asarray(put_cols(full["plane"][0], jnp.asarray(other[0]),
+                                taken[0])).view(np.uint8),
+            np.asarray(back["plane"][0]).view(np.uint8))
         remaining = remaining & ~taken
     assert not bool(remaining.any())
+
+
+@pytest.mark.parametrize("taken_hosts", [[], [0], [32], [5, 6, 20], [1, 31]],
+                         ids=lambda t: "hosts_" + "_".join(map(str, t)))
+def test_a_host_that_was_not_taken_keeps_its_column_whatever_its_position(
+        taken_hosts):
+    """``put_cols`` reads a host's bucket lane through its rank among the
+    taken hosts: before the first taken host that rank is −1 (clipped to
+    lane 0), after the last it is the last taken host's, and a padding
+    lane is nobody's — so neither lane 0's, nor the last real lane's, nor
+    a padding lane's bits may reach a host that is not in ``taken``."""
+    import jax.numpy as jnp
+
+    from shadow1_tpu.core.compact import put_cols
+
+    h, cap = 33, 8
+    old = np.arange(2 * h, dtype=np.int64).reshape(2, h)
+    bucket = -1 - np.arange(2 * cap, dtype=np.int64).reshape(2, cap)
+    taken = np.zeros(h, bool)
+    taken[taken_hosts] = True
+    back = np.asarray(put_cols(jnp.asarray(old), jnp.asarray(bucket),
+                               jnp.asarray(taken)))
+    want = old.copy()
+    want[:, taken_hosts] = bucket[:, :len(taken_hosts)]
+    np.testing.assert_array_equal(back, want)
+    assert (back[:, ~taken] >= 0).all() and (back[:, taken] < 0).all()

@@ -14,6 +14,7 @@ its controls and its two new per-layer readers.
 import collections
 import contextlib
 import dataclasses
+import functools
 import io
 import json
 import os
@@ -207,11 +208,17 @@ _INSTR = re.compile(r'^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(\(.*?\)|\S+)\s+'
 _H_WIDE = re.compile(r"[\[,]33[\],]")      # a dimension of 33 hosts
 
 
+@functools.cache
+def _hlo_lines(eng):
+    """A fleet's compiled window program, a line an instruction; compiled
+    once an engine (two tests and two parsers read each)."""
+    return eng.hlo_text().splitlines()
+
+
 def _instructions(eng):
     """(result shape, opcode, op_name) of every instruction of a fleet's
     compiled window program that has an ``op_name``."""
-    return [m.groups() for m in map(_INSTR.match, eng.hlo_text().splitlines())
-            if m]
+    return [m.groups() for m in map(_INSTR.match, _hlo_lines(eng)) if m]
 
 
 def _round_loops(instrs):
@@ -222,23 +229,62 @@ def _round_loops(instrs):
     return sorted(t.count("while") for t in tails)
 
 
-def test_a_fleet_program_with_a_cap_holds_one_round_loop_bucket_wide(fleet):
-    """The census of ISSUE 44: the round loop is in the program once, under
-    the trip loop; no instruction of a pop or a handler pass is 33 columns
-    wide (they are 8 wide); and under ``phase:compact_*`` the columns move
-    by ``dot``, never by ``gather`` or ``scatter``."""
-    instrs = _instructions(fleet[0])
+_GATHER = re.compile(r'=\s*\w+\[([\d,]*)\]\S*\s+gather\(.*?slice_sizes=\{([\d,]*)\}'
+                     r'.*?op_name="([^"]*)"')
+
+
+def _gathers(eng):
+    """(op_name, index entries) of every ``gather`` of a fleet's compiled
+    window program: the result's elements over a slice's. A row-uniform
+    gather (one index for a whole column of rows) has as many entries as
+    columns it reads, an element-wise one as many as elements."""
+    def size(dims):
+        return int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+
+    return [(m[3], size(m[1]) // size(m[2]))
+            for m in map(_GATHER.search, _hlo_lines(eng)) if m]
+
+
+def _pass_lookups(instrs):
+    """The gathers and scatters of the pops and handler passes, by phase."""
+    return collections.Counter(
+        (phase_path(op), opc) for _, opc, op in instrs
+        if opc in ("gather", "scatter") and ("phase:pop" in op or "phase:h_" in op))
+
+
+def test_a_fleet_program_with_a_cap_holds_one_round_loop_bucket_wide(
+        fleet, full_width):
+    """The census of ISSUEs 44 and 45: the round loop is in the program
+    once, under the trip loop; no instruction of a pop or a handler pass is
+    33 columns wide (they are 8 wide), and the passes look up what the
+    full-width program's do and no more; under ``phase:compact_*`` the
+    columns move by ROW-UNIFORM gathers — ``cap`` (8) index entries a leaf
+    on the way out, ``H`` (33) on the way back, never one an element — with
+    no ``dot``, ``convolution`` or ``scatter``."""
+    eng, _, st = fleet
+    instrs = _instructions(eng)
     assert _round_loops(instrs) == [1, 2]        # the trips ⊃ the rounds
     passes = [(sh, op) for sh, _, op in instrs
               if "phase:pop" in op or "phase:h_" in op]
     assert len(passes) > 5000
     assert not [x for x in passes if _H_WIDE.search(x[0])][:3]
     assert any(re.search(r"[\[,]8[\],]", sh) for sh, _ in passes)
+    assert _pass_lookups(instrs) == _pass_lookups(_instructions(full_width[0]))
     mover = collections.Counter(
         opc for _, opc, op in instrs if "phase:compact_" in op)
-    # (The CPU's compiler folds most of the matmuls into fusions.)
-    assert mover["dot"] + mover["fusion"] + mover["convolution"] > 50
-    assert not {"gather", "scatter"} & set(mover), mover
+    assert not {"dot", "convolution", "scatter"} & set(mover), mover
+    host_leaves = sum(
+        x.shape[-1] == 33
+        for x in jax.tree.leaves((st.evbuf, st.outbox, st.model, st.cpu_busy)))
+    entries = collections.Counter(
+        (phase_path(op), n) for op, n in _gathers(eng) if "phase:compact_" in op)
+    assert mover["gather"] == sum(entries.values())
+    # Out: a gather a leaf (and one a Ctx table the compiler could not
+    # fold), 8 entries each; back: a gather a leaf, 33 entries each.
+    assert set(entries) == {("rounds/compact_gather", 8),
+                            ("rounds/compact_scatter", 33)}, entries
+    assert entries["rounds/compact_scatter", 33] == host_leaves > 50
+    assert host_leaves <= entries["rounds/compact_gather", 8] <= host_leaves + 13
     # Both scopes are there, inside the rounds phase and outside every pass
     # (a fusion merged from two ops names its scope twice).
     paths = {phase_path(op) for _, _, op in instrs if "phase:compact_" in op}
@@ -341,9 +387,9 @@ def _readers():
 
     m = mf.load(REHEARSAL)
     names = [e["name"] for e in mf.metrics_of(m, "per_layer", CELL)]
-    assert names[-3:] == ["active_host_share", "events_per_round",
-                          "buckets_per_window"]
-    return [mf.reader(REHEARSAL, m, "layer_metrics", n) for n in names[-3:]]
+    assert names[-4:] == ["active_host_share", "events_per_round",
+                          "buckets_per_window", "rounds_other_ms_per_window"]
+    return [mf.reader(REHEARSAL, m, "layer_metrics", n) for n in names[-4:-1]]
 
 
 def test_the_new_readers_read_the_traced_chunk_s_work_off_the_chunk_log(traced_rows):
@@ -478,3 +524,54 @@ def test_rung_4_itself_builds_a_fleet_of_one_at_full_width():
     assert sum(b for b, _ in sizes) == 1_271_600_372 + 8
     assert sizes[0] == sizes[1] == (327_680_000, (1, 64, 128, 10000))
     assert sizes[2] == (102_400_000, (1, 10, 256, 10000))
+
+
+# ---- (e) the mover's yardstick: the round loop outside pops and passes ----------
+
+def test_rounds_other_ms_per_window_reads_the_mover_and_the_loop_s_bookkeeping():
+    """PR 45's reader on a device line worked out by hand: two windows, each
+    one trip of the compacted round loop — columns out (30 ns), a pop, a
+    handler pass, columns back (50 ns) — and 10 ns of the loop's own
+    bookkeeping. Both manifests end on its entry, every cell reads it (no
+    ``workloads`` list), and it reads nothing without a phase table."""
+    from benchmarks.harness import manifest as mf
+    from benchmarks.harness import phases as ph
+    from benchmarks.harness import trace as tr
+
+    for root, cell in ((REHEARSAL, CELL), (ROOT, "tor10k.join"),
+                       (ROOT, "phold65k.dense")):
+        m = mf.load(root)
+        assert m["per_layer"][-1] == {
+            "name": "rounds_other_ms_per_window", "unit": "ms",
+            "better": "lower", "source": "device_trace",
+            "layer": "window program", "moves": "events_per_s"}
+        assert mf.metrics_of(m, "per_layer", cell)[-1] == m["per_layer"][-1]
+        read = mf.reader(root, m, "layer_metrics", "rounds_other_ms_per_window")
+    table = {"while.0": "", "while.1": "rounds", "while.2": "rounds",
+             "gather.1": "rounds/compact_gather", "fusion.pop": "rounds/pop",
+             "fusion.h": "rounds/h_deliver/tcp_flush", "copy.1": "",
+             "gather.2": "rounds/compact_scatter", "fusion.p": "prepare",
+             "fusion.d": "deliver/deliver"}
+    assert {ph.rollup_key(v)[0] for k, v in table.items()
+            if k.startswith("gather")} == {ph.ROUNDS_OTHER}
+
+    def ev(name, start, dur):
+        return [f"%{name} = s32[8]{{0}} op(...)", start, dur]
+
+    ops = []
+    for t in (0, 1000):
+        ops += [ev("while.0", t, 400), ev("fusion.p", t, 20),
+                ev("while.1", t + 20, 300), ev("gather.1", t + 20, 30),
+                ev("while.2", t + 50, 200), ev("fusion.pop", t + 50, 40),
+                ev("fusion.h", t + 90, 150), ev("copy.1", t + 240, 10),
+                ev("gather.2", t + 250, 50), ev("fusion.d", t + 340, 60)]
+    trace = {"planes": [{"name": "/device:TPU:0", "lines": [
+        {"name": tr.OPS_LINE, "events": ops},
+        {"name": tr.MODULES_LINE, "events": [["jit_run(1)", 0, 400],
+                                             ["jit_run(1)", 1000, 400]]}]}]}
+    rep = ph.phase_report(trace, table)
+    assert rep["unknown_ops"] == 0 and rep["busy_ns"] == 2 * 360
+    counters = {"phase_s": rep["rollup"], "windows": 2}
+    assert read(None, counters, {}) == pytest.approx(1e3 * (30 + 10 + 50) * 1e-9)
+    assert read(None, {"windows": 2}, {}) is None
+    assert read(None, {"phase_s": rep["rollup"], "windows": 0}, {}) is None
