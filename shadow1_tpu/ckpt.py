@@ -322,6 +322,12 @@ def run_chunked(engine, st=None, n_windows: int | None = None,
     each (for checkpoints/heartbeats). One compiled program is reused for
     every full chunk. Returns the final state.
 
+    THE chunk loop: the benchmark calls it bare, ``obs.run_with_heartbeat``
+    and ``fleet.run.run_fleet`` hand it hooks, nothing else dispatches a
+    chunk. One order at every boundary: ``commit`` -> self-check -> drain
+    latch -> ``on_chunk`` (heartbeat, injection hooks, snapshot,
+    ``.progress``: obs.boundary_hook) -> PreemptedExit -> ``retune``.
+
     Every chunk is spanned (telemetry/profiler.py): ``run-chunk`` ⊃
     ``dispatch`` (the run call returning, ⊃ ``args``, ``call``; + ``sync``
     under a profiler), ``wait`` on the chunk log's waiter thread (the
@@ -333,15 +339,19 @@ def run_chunked(engine, st=None, n_windows: int | None = None,
     ``run-chunk`` cover execution.
 
     ``retune(engine, st) -> (engine, st)`` is the between-chunk adaptation
-    hook (tune/autocap.CapController): it may hand back a DIFFERENT engine
-    (re-jitted at new static capacities) with the state migrated to match.
+    hook (tune/autocap.CapController; the fleet's lane finalize): it may
+    hand back a DIFFERENT engine (re-jitted at new static capacities, or
+    with fewer lanes) with the state migrated to match.
     Called after ``on_chunk`` so heartbeats/checkpoints see the state that
     actually ran the chunk; never called after the final chunk.
 
-    ``guard`` (txn.OverflowGuard — CLI ``--on-overflow retry|halt``) makes
-    chunk execution TRANSACTIONAL: the chunk-start state is kept as the
-    rollback point, and the guard's commit either accepts the chunk (no
-    fresh overflow), discards it and replays at grown caps, or raises a
+    ``guard`` (txn.OverflowGuard — CLI ``--on-overflow retry|halt``; or the
+    fleet's commit hook of the same surface, ``bind`` / ``run_guarded`` /
+    ``commit``) makes chunk execution TRANSACTIONAL: the chunk-start state
+    is kept as the rollback point, and the commit either accepts the chunk
+    (no fresh overflow), discards it and replays it inside the commit (at
+    grown caps; the fleet's also with a failing lane quarantined, so with
+    fewer lanes — this loop learns nothing about lanes), or raises a
     structured CapacityExceededError. Commit runs BEFORE ``on_chunk``, so
     heartbeats and checkpoints only ever see committed (overflow-free)
     states — a checkpoint can never capture a tainted chunk. Without a

@@ -18,10 +18,13 @@ assembly, built per-experiment from the ground up:
   lane as a solo-resumable state.
 
 **The fleet recovery plane** (docs/SEMANTICS.md §"Fleet recovery
-contract") lives in this module's chunk loop — ``ckpt.run_chunked``
-cannot express a mid-loop change of E, so the loop is owned here with the
-same boundary semantics (commit before heartbeat/snapshot, drain latch
-sampled before the save, a window never split):
+contract") is hooks of the one chunk runner, ``ckpt.run_chunked``: this
+module holds no chunk loop. A quarantine is a ``commit`` that replays the
+chunk with fewer lanes (where ``OverflowGuard`` replays a grown one); a
+finalize or an ``--auto-caps`` step is a ``retune``; heartbeat, snapshot
+and ``.progress`` are ``obs.boundary_hook``, the ``on_chunk`` the solo
+runner passes too. So every boundary has the runner's one order: commit ->
+drain latch -> on-chunk (heartbeat, snapshot) -> PreemptedExit -> retune.
 
 * **transactional retry** (``--on-overflow retry``): the whole ``[E, ...]``
   pytree is the rollback point; any lane's fresh overflow taints the
@@ -45,23 +48,30 @@ sampled before the save, a window never split):
   ``fleet_exp`` final record (``finished_early: true``) emits
   immediately and they are sliced out the quarantine way.
 
-Every repacked-fleet snapshot carries the surviving global lane ids in
-its lineage manifest entry (``lanes``), so a resume mid-quarantined-sweep
-rebuilds exactly the surviving sub-fleet (cli._fleet_main).
+Every snapshot carries the global ids of the lanes its state holds in its
+lineage manifest entry (``lanes``), so a resume mid-quarantined-sweep
+rebuilds exactly the surviving sub-fleet (cli._fleet_main). A lane
+finalized at a boundary left AFTER that boundary's snapshot: the next one
+is the first without it, and a fleet resumed from the former finalizes the
+lane before its first chunk, with the uninterrupted sweep's record.
 """
 
 from __future__ import annotations
 
 import json
-import os
 import sys
 import time
+import types
 
 import numpy as np
 
 from shadow1_tpu.consts import SEC
-from shadow1_tpu.telemetry.profiler import chunk_log
-from shadow1_tpu.telemetry.registry import DROP_FIELDS, normalize
+from shadow1_tpu.telemetry.profiler import PH_DRAIN, chunk_log, maybe_span
+from shadow1_tpu.telemetry.registry import (
+    DROP_FIELDS,
+    HOST_FIELDS,
+    normalize,
+)
 
 
 class FleetHeartbeat:
@@ -77,8 +87,10 @@ class FleetHeartbeat:
     fleet shape off the heartbeat."""
 
     def __init__(self, engine, stream=None, initial_state=None,
-                 emit_heartbeat=True, emit_ring=True, guard=None):
+                 emit_heartbeat=True, emit_ring=True, guard=None,
+                 profiler=None):
         self.engine = engine
+        self.profiler = profiler
         self.stream = stream if stream is not None else sys.stderr
         self.emit_heartbeat = emit_heartbeat
         self.emit_ring = emit_ring
@@ -110,12 +122,18 @@ class FleetHeartbeat:
             print(json.dumps(rec), file=self.stream, flush=True)
 
     def __call__(self, st, done_windows: int, per_exp=None) -> None:
+        # Where a commit fetched the per-experiment dicts for its boundary
+        # checks, reuse them, don't re-sync; else this is the chunk's fetch
+        # (the first read of the metrics: what follows finds them on the
+        # host).
+        if per_exp is None:
+            with maybe_span(self.profiler, PH_DRAIN):
+                per_exp = self.engine.metrics_per_exp(st)
+        # The record's clock, read once the chunk's result is on the host:
+        # the run call returns before the chunk ends, and ``wall_s`` and the
+        # rates are of chunks that ended.
         now = time.perf_counter()
         m = normalize(self.engine.metrics_dict(st))
-        # The chunk runner already fetched the per-experiment dicts for its
-        # halt/selfcheck boundary checks — reuse them, don't re-sync.
-        if per_exp is None:
-            per_exp = self.engine.metrics_per_exp(st)
         ring_recs = self.engine.drain_rings(st, start=self._ring_next)
         self._ring_next = m.get("windows", 0)
         delta = {k: v - self.last.get(k, 0) for k, v in m.items()
@@ -152,8 +170,6 @@ class FleetHeartbeat:
         rec["drops"] = {"total": sum(drops.values()), **drops}
         # Host-side retry counters never ride engine deltas (registry
         # HOST_FIELDS); the retries block carries them cumulatively.
-        from shadow1_tpu.telemetry.registry import HOST_FIELDS
-
         for f in HOST_FIELDS:
             delta.pop(f, None)
         if self.guard is not None and self.guard.chunk_retries:
@@ -200,6 +216,26 @@ def _check_halt(engine, plan_labels, per_exp, prev_per_exp, done, step):
                 )
 
 
+def _check_identity(plan_labels, per_exp):
+    """Per-experiment ``--selfcheck``: the first violating lane's
+    SelfCheckError, its ``lanes`` every violating lane's local index (for
+    the quarantine policy, as ``_check_halt``'s)."""
+    from shadow1_tpu.txn import SelfCheckError, check_boundary_identity
+
+    first, lanes = None, []
+    for e, m in enumerate(per_exp):
+        try:
+            check_boundary_identity(
+                m, where=(f"fleet experiment {plan_labels[e].get('exp', e)}, "
+                          f"chunk boundary, window {m.get('windows', 0)}"))
+        except SelfCheckError as err:
+            first = first or err
+            lanes.append(e)
+    if first is not None:
+        first.lanes = lanes
+        raise first
+
+
 def lane_record(engine, st, i: int, label: dict, windows: int,
                 m: dict | None = None, model: dict | None = None) -> dict:
     """One ``fleet_exp`` final record for lane ``i`` of a fleet state —
@@ -241,13 +277,10 @@ def run_fleet(engine, st=None, n_windows=None, every_windows=None,
               recovery_seed=None, profiler=None):
     """Run the fleet in chunks. Returns (final_state, FleetHeartbeat).
 
-    Mirrors ``obs.run_with_heartbeat`` (compile excluded from the first
-    chunk's rate, checkpoints rotated through a ``ckpt_keep``-deep
-    lineage.Lineage generation set throttled to ``ckpt_every_s``, the
-    ``.progress`` sidecar refreshed atomically at EVERY chunk boundary,
-    a pending ``drain`` request forcing the snapshot then raising
-    preempt.PreemptedExit) — plus the fleet recovery plane described in
-    the module docstring, driven by ``engine.params``:
+    ``obs.run_with_heartbeat``'s twin: the same warm-up, the same
+    ``on_chunk`` (obs.boundary_hook: snapshots, ``.progress``, the drain's
+    forced save) — plus the fleet recovery plane described in the module
+    docstring, driven by ``engine.params``:
 
     * ``on_overflow == "retry"`` → a txn.OverflowGuard makes chunks
       transactional over the whole [E, ...] pytree;
@@ -271,15 +304,10 @@ def run_fleet(engine, st=None, n_windows=None, every_windows=None,
     crash. The heartbeat's ``engine`` / ``labels`` / ``recovery``
     attributes expose the live fleet shape.
 
-    The loop is spanned like ``ckpt.run_chunked``, under the same names
-    (telemetry/profiler.py): ``init``, ``compile``, then per chunk
-    ``run-chunk`` ⊃ ``dispatch`` (⊃ ``args``, ``call``; + ``sync`` under a
-    profiler), ``wait`` (the chunk log's waiter), ``commit`` (guard),
-    ``drain`` (the per-experiment metrics fetch), ``on-chunk`` (the
-    heartbeat), ``retune``, ``checkpoint`` — ``shadow1:`` annotations in any
-    ``jax.profiler`` capture, rows of ``telemetry.chunk_log()``, and
-    Chrome-trace events of ``profiler`` (telemetry.PhaseProfiler — CLI
-    ``--fleet --trace``)."""
+    The spans are ``ckpt.run_chunked``'s (its docstring), plus ``init`` and
+    ``compile`` before the loop, ``drain`` (the per-experiment metrics
+    fetch: inside ``commit`` where a policy can refuse a chunk, else inside
+    ``on-chunk``) and ``checkpoint`` (inside ``on-chunk``)."""
     import jax
 
     from shadow1_tpu import ckpt as _ckpt
@@ -288,49 +316,22 @@ def run_fleet(engine, st=None, n_windows=None, every_windows=None,
         select_lanes,
         slice_experiment,
     )
-    from shadow1_tpu.lineage import Lineage, write_json_atomic
-    from shadow1_tpu.preempt import PreemptedExit, run_injection_hooks
-    from shadow1_tpu.telemetry import (
-        PH_CHECKPOINT,
-        PH_COMMIT,
-        PH_COMPILE,
-        PH_DISPATCH,
-        PH_DRAIN,
-        PH_INIT,
-        PH_ON_CHUNK,
-        PH_RETUNE,
-        PH_SYNC,
-        maybe_span,
-    )
+    from shadow1_tpu.obs import boundary_hook, warm_up
     from shadow1_tpu.txn import (
         CapacityExceededError,
         OverflowGuard,
         SelfCheckError,
-        check_boundary_identity,
     )
 
     params = engine.params
     total = n_windows if n_windows is not None else engine.n_windows
     if every_windows is None:
         every_windows = max(total // 10, 1)
-    if st is None:
-        with maybe_span(profiler, PH_INIT):
-            st = engine.init_state()
     labels = ([dict(l) for l in labels] if labels else
               [{"exp": i + engine.exp_base, "seed": int(e.seed)}
                for i, e in enumerate(engine.exps)])
     engine.exp_ids = [l.get("exp", i) for i, l in enumerate(labels)]
-    try:
-        with maybe_span(profiler, PH_COMPILE):
-            jax.block_until_ready(engine.run(st, n_windows=0))
-    except Exception as e:
-        from shadow1_tpu import mem
-
-        # OOM taxonomy: this warmup is the compile — tag exhaustion here
-        # so the CLI's memory record reports the phase (mem.py).
-        if mem.is_oom(e):
-            e.shadow1_oom_phase = "compile"
-        raise
+    st = warm_up(engine, st, profiler)
 
     halt = params.on_overflow == "halt"
     retry = params.on_overflow == "retry"
@@ -361,12 +362,14 @@ def run_fleet(engine, st=None, n_windows=None, every_windows=None,
 
         controller = CapController(engine, _make_factory(),
                                    initial_state=st)
-    guard = (OverflowGuard(engine, make_engine=_make_factory(),
-                           mode="retry", controller=controller)
-             if retry else None)
+    guard = None
+    if retry:
+        guard = OverflowGuard(engine, make_engine=_make_factory(),
+                              mode="retry", controller=controller)
+        guard.bind(engine, st)
     hb = FleetHeartbeat(engine, stream=stream, initial_state=st,
                         emit_heartbeat=emit_heartbeat, emit_ring=emit_ring,
-                        guard=guard)
+                        guard=guard, profiler=profiler)
     hb.labels = labels
     recovery = hb.recovery
     if recovery_seed:
@@ -379,20 +382,19 @@ def run_fleet(engine, st=None, n_windows=None, every_windows=None,
         recovery["finished"] = [{"exp": int(g), "resumed": True}
                                 for g in recovery_seed.get("finished", [])]
     if guard is not None:
-        guard.bind(engine, st)
         guard.on_engine_swap = lambda eng_new: setattr(hb, "engine", eng_new)
-    prev_per_exp = engine.metrics_per_exp(st)
-    lineage = Lineage(ckpt_path, keep=ckpt_keep) if ckpt_path else None
-    last_save = time.perf_counter()
-    last_seq = [None]
+    # What a commit fetched of the chunk it accepted, for the heartbeat.
+    # ``hb.last_per_exp`` is the last committed boundary's: the halt
+    # check's baseline and a finalized lane's metrics.
+    fetched = None
     retry_seen = 0
 
-    def _record(rec: dict) -> None:
-        """An immediately-final record: stderr log line (the stream every
-        report tool reads) plus the caller's stdout hook."""
+    def _record(rec: dict, final: bool = True) -> None:
+        """A log line on stderr (the stream every report tool reads); an
+        immediately-``final`` record goes to the caller's stdout hook too."""
         if stream is not False:
             print(json.dumps(rec), file=stream or sys.stderr, flush=True)
-        if emit_record is not None:
+        if final and emit_record is not None:
             emit_record(rec)
 
     def _drain_retry_records(discarded: bool = False) -> None:
@@ -418,9 +420,7 @@ def run_fleet(engine, st=None, n_windows=None, every_windows=None,
             # Log-stream only (unlike quarantine/early-final records): a
             # retry is an audit event, not a per-lane result — the stdout
             # contract stays fleet_exp/.../fleet_summary.
-            if stream is not False:
-                print(json.dumps(rrec), file=stream or sys.stderr,
-                      flush=True)
+            _record(rrec, final=False)
         retry_seen = len(guard.resizes)
 
     def _repack(keep: list[int], st_from):
@@ -428,10 +428,12 @@ def run_fleet(engine, st=None, n_windows=None, every_windows=None,
         the engine at the CURRENT committed params, refresh the policies
         (stale-E caches dropped, counters/floors carried), re-baseline the
         heartbeat. Returns the repacked state."""
-        nonlocal engine, guard, controller, prev_per_exp
+        nonlocal engine, guard, controller
         st_new = select_lanes(st_from, keep)
         labels[:] = [labels[i] for i in keep]
-        new_eng = FleetEngine([engine.exps[i] for i in keep], params_live(),
+        # At the last COMMITTED params: grows from failed (quarantined)
+        # attempts are discarded with the tainted chunk.
+        new_eng = FleetEngine([engine.exps[i] for i in keep], engine.params,
                               [engine.max_rounds[i] for i in keep])
         new_eng.exp_base = engine.exp_base
         new_eng.exp_ids = [l.get("exp") for l in labels]
@@ -455,21 +457,14 @@ def run_fleet(engine, st=None, n_windows=None, every_windows=None,
                 lambda eng_new: setattr(hb, "engine", eng_new)
             hb.guard = guard
         hb.rebase(engine, st_new)
-        prev_per_exp = hb.last_per_exp  # rebase just fetched it
         return st_new
 
-    def params_live():
-        # The last COMMITTED params: grows from failed (quarantined)
-        # attempts are discarded with the tainted chunk.
-        return engine.params
-
-    def _quarantine(fail_lanes: list[int], reason: str, err, st_roll,
-                    w0: int, retries_discarded: bool):
-        """Slice deterministic failures out of the chunk-start state; the
-        quarantined lane checkpoint is written FIRST, then the survivors
-        repack (the survivors' own snapshot — with the shrunken ``lanes``
-        manifest — follows at this boundary's save). Raises ``err`` when
-        no lane survives, preserving the exit taxonomy.
+    def _quarantine(err, st_roll, retries_discarded: bool):
+        """Slice ``err.lanes`` (deterministic failures) out of the
+        chunk-start state; the quarantined lane checkpoint is written
+        FIRST, then the survivors repack (their own snapshot, with the
+        shrunken ``lanes`` manifest, follows at this boundary's save).
+        Raises ``err`` when no lane survives: the exit taxonomy is kept.
 
         ``retries_discarded``: grows from a guard.commit attempt that
         RAISED were rolled back with the tainted chunk (the outer engine
@@ -477,11 +472,11 @@ def run_fleet(engine, st=None, n_windows=None, every_windows=None,
         the same boundary (a halt/selfcheck quarantine after a successful
         retry) persist: the repack migrates ``st_roll`` onto the live
         caps below, so their records stay real."""
-        fail_lanes = sorted(set(fail_lanes))
-        # Flush grow audit records against the CURRENT labels before the
-        # repack shrinks them — stale local indices would remap onto the
-        # wrong experiment.
-        _drain_retry_records(discarded=retries_discarded)
+        fail_lanes = sorted(set(err.lanes))
+        reason = ("capacity" if isinstance(err, CapacityExceededError)
+                  else "selfcheck")
+        w0 = int(np.asarray(st_roll.win_start).max()) // engine.window
+        _drain_retry_records(discarded=retries_discarded)   # pre-repack
         survivors = engine.n_exp - len(fail_lanes)
         for i in fail_lanes:
             label = labels[i]
@@ -512,7 +507,7 @@ def run_fleet(engine, st=None, n_windows=None, every_windows=None,
         # bit-exact, tune/resize.py), so state shapes and engine caps
         # never diverge. The quarantined-lane checkpoints above stay at
         # the ORIGINAL caps: load_state cap-migrates on the solo side.
-        p = params_live()
+        p = engine.params
         if (int(np.asarray(st_roll.evbuf.kind).shape[-2]) != p.ev_cap
                 or int(np.asarray(st_roll.outbox.dst).shape[-2])
                 != p.outbox_cap):
@@ -523,146 +518,115 @@ def run_fleet(engine, st=None, n_windows=None, every_windows=None,
                                    outbox_cap=p.outbox_cap)
         return _repack(keep, st_roll)
 
-    chunks = chunk_log()
-    done = 0
-    while done < total and engine.n_exp > 0:
-        step = min(every_windows, total - done)
-        # Rollback point: jax states are immutable and run() never donates,
-        # so holding the reference is free until the commit drops it.
-        st0 = st if (guard is not None or quarantine) else None
-        w0 = int(np.asarray(st.win_start).max()) // engine.window
-        # What every span of this chunk carries: its first window and size.
-        ids = {"done": done, "windows": step}
-        with chunks.chunk(profiler, engine, st, **ids) as ch:
-            with maybe_span(profiler, PH_DISPATCH, **ids):
-                st_new = (OverflowGuard.run_guarded(engine, st, step)
-                          if guard is not None
-                          else engine.run(st, n_windows=step))
-            ch.watch(st_new)
-            if profiler is not None:
-                # Only under a PhaseProfiler: the span covers execution.
-                with maybe_span(profiler, PH_SYNC, **ids):
-                    jax.block_until_ready(st_new)
-        if guard is not None:
+    def commit(_engine, st0, st, done, step):
+        """``run_chunked``'s commit hook (OverflowGuard's surface): accept
+        the chunk, or quarantine its failing lanes from the chunk-START
+        state and replay the same chunk with the survivors, here, as
+        OverflowGuard replays a grown one — until a chunk commits or the
+        last lane's failure re-raises. Returns ``(engine, st)``."""
+        nonlocal engine, fetched
+        while True:
+            # Grows of a guard.commit that RAISES (ladder top, repeated
+            # overflow) were rolled back with the tainted chunk; grows it
+            # committed persist through a halt/selfcheck quarantine after.
+            discarded = True
             try:
-                with maybe_span(profiler, PH_COMMIT, **ids):
-                    engine, st_new = guard.commit(engine, st0, st_new, done,
-                                                  step)
-            except CapacityExceededError as err:
+                if guard is not None:
+                    engine, st = guard.commit(engine, st0, st, done, step)
+                    hb.engine = engine
+                discarded = False
+                with maybe_span(profiler, PH_DRAIN, done=done, windows=step):
+                    now = engine.metrics_per_exp(st)
+                if halt:
+                    _check_halt(engine, labels, now, hb.last_per_exp, done,
+                                step)
+                if selfcheck:
+                    _check_identity(labels, now)
+                fetched = now
+                return engine, st
+            except (CapacityExceededError, SelfCheckError) as err:
                 if not (quarantine and err.lanes):
                     raise
-                # Ladder-top / repeated-overflow exhaustion attributed to
-                # specific lanes: quarantine them from the chunk-start
-                # state and replay the chunk with the survivors (the
-                # raised commit rolled its grows back with the chunk).
-                st = _quarantine(err.lanes, "capacity", err, st0, w0,
-                                 retries_discarded=True)
-                continue
-            hb.engine = engine
-        with maybe_span(profiler, PH_DRAIN, **ids):
-            per_exp = engine.metrics_per_exp(st_new)
-        if halt:
-            try:
-                _check_halt(engine, labels, per_exp, prev_per_exp, done,
-                            step)
-            except CapacityExceededError as err:
-                if not (quarantine and err.lanes):
-                    raise
-                st = _quarantine(err.lanes, "capacity", err, st0, w0,
-                                 retries_discarded=False)
-                continue
-        if selfcheck:
-            violations: list[tuple[int, SelfCheckError]] = []
-            for e, m in enumerate(per_exp):
-                try:
-                    check_boundary_identity(
-                        m, where=(f"fleet experiment "
-                                  f"{labels[e].get('exp', e)}, chunk "
-                                  f"boundary, window "
-                                  f"{m.get('windows', 0)}"))
-                except SelfCheckError as err:
-                    if not quarantine:
-                        raise
-                    violations.append((e, err))
-            if violations:
-                st = _quarantine([e for e, _ in violations], "selfcheck",
-                                 violations[0][1], st0, w0,
-                                 retries_discarded=False)
-                continue
-        # ---- chunk COMMITTED -------------------------------------------
-        st = st_new
-        done += step
+                st0 = _quarantine(err, st0, retries_discarded=discarded)
+                st = OverflowGuard.run_guarded(engine, st0, step)
+
+    def beat(st, done):
+        """``on_chunk``'s first half: the retry audit, then the heartbeat
+        over what the commit fetched (where none ran, it fetches)."""
+        nonlocal fetched
         # One parseable fleet_retry record per committed grow+replay
         # (schema in docs/OBSERVABILITY.md) — heartbeat_report's recovery
         # section and the per-lane retry table read these.
         _drain_retry_records()
-        prev_per_exp = per_exp
-        with maybe_span(profiler, PH_ON_CHUNK, **ids):
-            hb(st, done, per_exp=per_exp)
-        sim_ns = int(np.asarray(st.win_start).max())
-        # Fault/preemption/hang injection (preempt.run_injection_hooks) —
-        # the same chunk-boundary contract as obs.run_with_heartbeat, so
-        # the supervisor, drain and watchdog paths are all testable
-        # fleet-shaped too. Inert without the env vars.
-        run_injection_hooks(sim_ns)
-        # ---- mid-sweep lane lifecycle ----------------------------------
-        if finalize and done < total and engine.n_exp > 1:
-            flags = type(engine).lane_done(st)
-            done_lanes = [i for i in range(engine.n_exp) if flags[i]]
-            # All-drained fleets just run out their remaining (no-op)
-            # windows like a solo run would — finalize only a strict
-            # subset, so the normal end-of-run path stays intact.
-            if done_lanes and len(done_lanes) < engine.n_exp:
-                for i in done_lanes:
-                    m = per_exp[i]
-                    rec = lane_record(engine, st, i, labels[i],
-                                      int(m.get("windows", done)), m=m)
-                    rec["finished_early"] = True
-                    rec["windows_configured"] = total
-                    recovery["finished"].append(rec)
-                    _record(rec)
-                keep = [i for i in range(engine.n_exp)
-                        if i not in done_lanes]
-                st = _repack(keep, st)
-        # ---- between-chunk retune (fleet --auto-caps) ------------------
-        if controller is not None and done < total and engine.n_exp > 0:
-            with maybe_span(profiler, PH_RETUNE, **ids):
-                new_engine, st = controller(engine, st)
-            if new_engine is not engine:
-                engine = new_engine
-                hb.engine = engine
-                if guard is not None:
-                    guard.engine = engine
-        # ---- snapshot / progress / drain -------------------------------
-        now = time.perf_counter()
-        draining = drain is not None and drain.requested
-        saved = False
-        if lineage is not None and engine.n_exp > 0 and (
-                done >= total or draining
-                or now - last_save > ckpt_every_s):
-            meta = {"win_start": sim_ns, "done_windows": done,
-                    "lanes": [l.get("exp") for l in labels]}
-            if recovery["quarantined"]:
-                meta["quarantined"] = [r["exp"] for r in
-                                       recovery["quarantined"]]
-            if recovery["finished"]:
-                meta["finished"] = [r["exp"] for r in recovery["finished"]]
-            if resume_meta:
-                meta.update(resume_meta)
-            with maybe_span(profiler, PH_CHECKPOINT, **ids):
-                last_seq[0] = lineage.save(st, meta)
-            last_save = now
-            saved = True
-        if ckpt_path:
-            write_json_atomic(ckpt_path + ".progress",
-                              {"done_windows": done, "total": total,
-                               "win_start": sim_ns, "seq": last_seq[0]})
-        crash_at = os.environ.get("SHADOW1_OBS_CRASH_AT_NS")
-        if saved and crash_at is not None and sim_ns == int(crash_at):
-            os._exit(41)
-        if draining and done < total:
-            raise PreemptedExit(st=st, signame=drain.signame,
-                                done_windows=done, win_start=sim_ns)
+        per_exp, fetched = fetched, None
+        hb(st, done, per_exp=per_exp)
+
+    def finalize_done(st):
+        """The mid-sweep lane lifecycle: drained lanes emit their final
+        record and leave; the survivors' repacked state."""
+        flags = type(engine).lane_done(st)
+        done_lanes = [i for i in range(engine.n_exp) if flags[i]]
+        # All-drained fleets just run out their remaining (no-op) windows
+        # like a solo run would — finalize only a strict subset, so the
+        # normal end-of-run path stays intact.
+        if not done_lanes or len(done_lanes) == engine.n_exp:
+            return st
+        for i in done_lanes:
+            m = hb.last_per_exp[i]
+            rec = lane_record(engine, st, i, labels[i], int(m["windows"]),
+                              m=m)
+            rec["finished_early"] = True
+            rec["windows_configured"] = started + total
+            recovery["finished"].append(rec)
+            _record(rec)
+        return _repack([i for i in range(engine.n_exp)
+                        if i not in done_lanes], st)
+
+    def retune(_engine, st):
+        """``run_chunked``'s between-chunk hook: the lane finalize, then
+        the ``--auto-caps`` step."""
+        nonlocal engine
+        if finalize:
+            st = finalize_done(st)
+        if controller is not None:
+            engine, st = controller(engine, st)
+            hb.engine = engine
+            if guard is not None:
+                guard.engine = engine
+        return engine, st
+
+    def manifest():
+        """The fleet's keys of a lineage manifest entry: the lanes of the
+        state the snapshot holds, the ledger, the caller's cursor."""
+        meta = {"lanes": [l.get("exp") for l in labels]}
+        if recovery["quarantined"]:
+            meta["quarantined"] = [r["exp"] for r in recovery["quarantined"]]
+        if recovery["finished"]:
+            meta["finished"] = [r["exp"] for r in recovery["finished"]]
+        if resume_meta:
+            meta.update(resume_meta)
+        return meta
+
+    # A state that has run is a snapshot's, and a snapshot precedes its
+    # boundary's retune: the lanes that boundary finalized leave before the
+    # first chunk, with the record the uninterrupted sweep gave them there.
+    started = hb.last.get("windows", 0)
+    if finalize and started and total:
+        st = finalize_done(st)
+    # A commit is what can refuse a chunk; with nothing to refuse one
+    # (the default ``drop`` policy, no selfcheck) there is none, no state
+    # is retained and the heartbeat makes the chunk's one fetch.
+    st = _ckpt.run_chunked(
+        engine, st, n_windows=total, chunk=every_windows,
+        on_chunk=boundary_hook(beat, total, ckpt_path, ckpt_every_s,
+                               ckpt_keep, drain, profiler, meta=manifest),
+        profiler=profiler,
+        retune=retune if finalize or controller is not None else None,
+        # OverflowGuard's surface; the guard itself is bound where it is made.
+        guard=types.SimpleNamespace(
+            bind=lambda *_: None, run_guarded=OverflowGuard.run_guarded,
+            commit=commit) if retry or halt or selfcheck else None,
+        drain=drain)
     return st, hb
 
 

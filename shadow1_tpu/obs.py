@@ -22,12 +22,16 @@ import os
 import sys
 import time
 
+import jax
+import numpy as np
+
 from shadow1_tpu.ckpt import run_chunked
 from shadow1_tpu.consts import SEC
 from shadow1_tpu.telemetry import (
     PH_CHECKPOINT,
     PH_COMPILE,
     PH_DRAIN,
+    PH_INIT,
     chunk_log,
     maybe_span,
     normalize,
@@ -249,6 +253,90 @@ class Heartbeat:
         return recs
 
 
+def warm_up(engine, st=None, profiler=None):
+    """``st`` (``engine.init_state()`` where None) with the program every
+    chunk reuses compiled: ``n_windows`` is a traced argument, so a
+    zero-window call builds it before any clock starts — the first
+    heartbeat's events/sec folds no compile time in. Both runners
+    (``run_with_heartbeat``, ``fleet.run.run_fleet``) start here."""
+    if st is None:
+        with maybe_span(profiler, PH_INIT):
+            st = engine.init_state()
+    with maybe_span(profiler, PH_COMPILE):
+        try:
+            jax.block_until_ready(engine.run(st, n_windows=0))
+        except Exception as e:
+            from shadow1_tpu import mem
+
+            # OOM taxonomy: an exhaustion here is a COMPILE/allocation
+            # failure, not a mid-run one — tag it so the CLI's memory
+            # record reports the phase truthfully (mem.py).
+            if mem.is_oom(e):
+                e.shadow1_oom_phase = "compile"
+            raise
+    return st
+
+
+def boundary_hook(beat, total, ckpt_path=None, ckpt_every_s=120.0,
+                  ckpt_keep=3, drain=None, profiler=None, meta=None):
+    """The ``on_chunk(st, done)`` both runners hand ``ckpt.run_chunked``:
+    ``beat(st, done)`` (the heartbeat), the injection hooks, and with a
+    ``ckpt_path`` the lineage snapshot and the ``.progress`` sidecar.
+
+    The snapshot is throttled to ``ckpt_every_s`` of wall, and forced at
+    the last chunk and by a pending ``drain`` request (the runner raises
+    PreemptedExit right after this hook). ``meta()`` adds the caller's keys
+    to the manifest entry (the fleet's ``lanes``, read when the snapshot is
+    taken: they are the lanes of the state it holds). ``win_start`` is the
+    max over a fleet's lanes, which a scalar's max is too."""
+    from shadow1_tpu.lineage import Lineage, write_json_atomic
+    from shadow1_tpu.preempt import run_injection_hooks
+
+    lineage = Lineage(ckpt_path, keep=ckpt_keep) if ckpt_path else None
+    last_save = time.perf_counter()
+    seq = None
+
+    def on_chunk(st, done):
+        nonlocal last_save, seq
+        beat(st, done)
+        # The absolute sim clock — monotonic across respawned processes,
+        # unlike the invocation-relative ``done``.
+        sim_ns = int(np.asarray(st.win_start).max())
+        # Fault/preemption/hang injection (tests, ci.sh, chaosprobe) — the
+        # chunk-boundary contract of both runners, with or without a
+        # checkpoint path; inert without the env vars.
+        run_injection_hooks(sim_ns)
+        if lineage is None:
+            return
+        now = time.perf_counter()
+        saved = (done >= total or now - last_save > ckpt_every_s
+                 or (drain is not None and drain.requested))
+        if saved:
+            entry = {"win_start": sim_ns, "done_windows": done}
+            if meta is not None:
+                entry.update(meta())
+            with maybe_span(profiler, PH_CHECKPOINT):
+                seq = lineage.save(st, entry)
+            last_save = now
+        # The progress sidecar is written at EVERY chunk boundary — it is
+        # the watchdog's liveness signal, so it must tick even between
+        # throttled saves. Atomic like save_state: a wedge mid-write must
+        # not leave a truncated sidecar that makes the supervisor abandon a
+        # perfectly resumable snapshot.
+        write_json_atomic(ckpt_path + ".progress",
+                          {"done_windows": done, "total": total,
+                           "win_start": sim_ns, "seq": seq})
+        # Fault injection (SURVEY §5 failure-detection analogue): die
+        # like a wedged device process at an exact sim time, once — a
+        # respawned resume starts past it. Gated on a save having
+        # happened; inert without the env var.
+        crash_at = os.environ.get("SHADOW1_OBS_CRASH_AT_NS")
+        if saved and crash_at is not None and sim_ns == int(crash_at):
+            os._exit(41)
+
+    return on_chunk
+
+
 def run_with_heartbeat(engine, st=None, n_windows=None, every_windows=None,
                        stream=None, ckpt_path=None, ckpt_every_s=120.0,
                        profiler=None, emit_heartbeat=True, emit_ring=True,
@@ -295,31 +383,10 @@ def run_with_heartbeat(engine, st=None, n_windows=None, every_windows=None,
     Returns (final_state, heartbeat) — heartbeat.records holds the stream,
     heartbeat.ring_records the drained per-window telemetry rows.
     """
-    import jax
-
-    from shadow1_tpu.telemetry import PH_INIT
-
     total = n_windows if n_windows is not None else engine.n_windows
     if every_windows is None:
         every_windows = max(total // 10, 1)
-    if st is None:
-        with maybe_span(profiler, PH_INIT):
-            st = engine.init_state()
-    # Compile before the clock starts: n_windows is a traced argument, so a
-    # zero-window call builds the exact program every chunk reuses — the
-    # first heartbeat's events/sec no longer folds compile time in.
-    with maybe_span(profiler, PH_COMPILE):
-        try:
-            jax.block_until_ready(engine.run(st, n_windows=0))
-        except Exception as e:
-            from shadow1_tpu import mem
-
-            # OOM taxonomy: an exhaustion here is a COMPILE/allocation
-            # failure, not a mid-run one — tag it so the CLI's memory
-            # record reports the phase truthfully (mem.py).
-            if mem.is_oom(e):
-                e.shadow1_oom_phase = "compile"
-            raise
+    st = warm_up(engine, st, profiler)
     hb = Heartbeat(engine, stream=stream, initial_state=st, profiler=profiler,
                    emit_heartbeat=emit_heartbeat, emit_ring=emit_ring,
                    guard=guard)
@@ -333,53 +400,8 @@ def run_with_heartbeat(engine, st=None, n_windows=None, every_windows=None,
         # Retry-driven cap grows swap engines too — heartbeat fill blocks
         # must report the caps of the engine that actually ran the chunk.
         guard.on_engine_swap = lambda eng_new: setattr(hb, "engine", eng_new)
-    if ckpt_path is None:
-        st = run_chunked(engine, st, n_windows=total, chunk=every_windows,
-                         on_chunk=hb, profiler=profiler, retune=retune,
-                         guard=guard, selfcheck=selfcheck, drain=drain)
-        return st, hb
-
-    from shadow1_tpu.lineage import Lineage, write_json_atomic
-    from shadow1_tpu.preempt import run_injection_hooks
-
-    lineage = Lineage(ckpt_path, keep=ckpt_keep)
-    last_save = time.perf_counter()
-    last_seq = [None]
-
-    def on_chunk(s, done):
-        nonlocal last_save
-        hb(s, done)
-        sim_ns = int(s.win_start)
-        # Fault/preemption/hang injection (tests, ci.sh, chaosprobe) —
-        # the shared chunk-boundary contract; inert without the env vars.
-        run_injection_hooks(sim_ns)
-        now = time.perf_counter()
-        draining = drain is not None and drain.requested
-        saved = False
-        if done >= total or now - last_save > ckpt_every_s or draining:
-            with maybe_span(profiler, PH_CHECKPOINT):
-                last_seq[0] = lineage.save(
-                    s, {"win_start": sim_ns, "done_windows": done})
-            last_save = now
-            saved = True
-        # The progress sidecar is written at EVERY chunk boundary — it is
-        # the watchdog's liveness signal, so it must tick even between
-        # throttled saves. win_start is the absolute sim clock — monotonic
-        # across respawned processes, unlike the invocation-relative
-        # ``done``. Atomic like save_state: a wedge mid-write must not
-        # leave a truncated sidecar that makes the supervisor abandon a
-        # perfectly resumable snapshot.
-        write_json_atomic(ckpt_path + ".progress",
-                          {"done_windows": done, "total": total,
-                           "win_start": sim_ns, "seq": last_seq[0]})
-        # Fault injection (SURVEY §5 failure-detection analogue): die
-        # like a wedged device process at an exact sim time, once — a
-        # respawned resume starts past it. Exercised by the supervisor
-        # test; inert without the env var.
-        crash_at = os.environ.get("SHADOW1_OBS_CRASH_AT_NS")
-        if saved and crash_at is not None and sim_ns == int(crash_at):
-            os._exit(41)
-
+    on_chunk = boundary_hook(hb, total, ckpt_path, ckpt_every_s, ckpt_keep,
+                             drain, profiler)
     st = run_chunked(engine, st, n_windows=total, chunk=every_windows,
                      on_chunk=on_chunk, profiler=profiler, retune=retune,
                      guard=guard, selfcheck=selfcheck, drain=drain)

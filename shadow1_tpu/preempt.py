@@ -5,10 +5,10 @@ capacity delivers a termination notice with a deadline, not a clean exit.
 Before this plane, a SIGTERM was indistinguishable from a crash — everything
 since the last throttled snapshot was thrown away and the supervisor charged
 a crash to its backoff accounting. Now the first SIGTERM/SIGINT *requests a
-drain*: the chunk runner (ckpt.run_chunked, via obs.run_with_heartbeat and
-fleet/run.py) finishes the in-flight chunk, commits it, forces a final
-snapshot, and exits with the dedicated :data:`consts.EXIT_PREEMPTED` code
-plus a parseable stdout record. The supervisor classifies that exit as
+drain*: the one chunk runner (ckpt.run_chunked, which obs.run_with_heartbeat
+and fleet.run.run_fleet both drive) finishes the in-flight chunk, commits it,
+forces a final snapshot (obs.boundary_hook), and exits with the dedicated
+:data:`consts.EXIT_PREEMPTED` code plus a parseable stdout record. The supervisor classifies that exit as
 clean-resume — no backoff, no crash accounting, checkpoint kept — mirroring
 the existing EXIT_CAPACITY taxonomy. Rerunning the same command resumes
 bit-identically (the preemption contract, docs/SEMANTICS.md).
@@ -66,10 +66,10 @@ class PreemptedExit(Exception):
 
 
 def run_injection_hooks(sim_ns: int) -> None:
-    """Chunk-boundary fault/preemption/hang injection, shared by the solo
-    and fleet runners (obs.run_with_heartbeat / fleet.run_fleet) so the
-    supervisor, drain and watchdog paths are testable in both shapes from
-    ONE contract. Inert without the env vars:
+    """Chunk-boundary fault/preemption/hang injection, run by the one
+    ``on_chunk`` (obs.boundary_hook) that the solo and the fleet runner hand
+    ckpt.run_chunked, so the supervisor, drain and watchdog paths are
+    testable in both shapes from ONE contract. Inert without the env vars:
 
     * ``SHADOW1_OBS_CRASH_PRE_SAVE_AT_NS`` — die before the checkpoint is
       written (the supervisor sees a zero-progress crash);
@@ -80,8 +80,8 @@ def run_injection_hooks(sim_ns: int) -> None:
       shape the watchdog must detect); the flag file makes it fire once so
       a respawn proceeds.
 
-    The post-save crash hook (``SHADOW1_OBS_CRASH_AT_NS``) stays in the
-    runners — it is gated on a save actually having happened."""
+    The post-save crash hook (``SHADOW1_OBS_CRASH_AT_NS``) stays in
+    obs.boundary_hook — it is gated on a save actually having happened."""
     crash_pre = os.environ.get("SHADOW1_OBS_CRASH_PRE_SAVE_AT_NS")
     if crash_pre is not None and sim_ns == int(crash_pre):
         os._exit(41)

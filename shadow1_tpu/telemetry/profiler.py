@@ -145,9 +145,11 @@ _ROW_SPANS = {PH_DISPATCH: "dispatch_ns", PH_ARGS: "args_ns",
 _BOUNDARY_SPANS = {PH_COMMIT: "commit_ns", PH_ON_CHUNK: "on_chunk_ns",
                    PH_DRAIN: "drain_ns", PH_CHECKPOINT: "checkpoint_ns",
                    PH_RETUNE: "retune_ns"}
-# Of those, the four that never contain one another (``on-chunk`` holds
-# ``drain`` and ``checkpoint`` in two of the three loops): with what is left
-# of the turnaround they split it, for the stall line's ``where``.
+# Of those, the four that never contain one another ON A ROW (``on-chunk``
+# holds ``drain`` and ``checkpoint``; a fleet's ``commit`` holds its ``drain``
+# fetch in the trace, and ``_TimedBoundary`` keeps that fetch out of the row's
+# ``commit_ns``): with what is left of the turnaround they split it, for the
+# stall line's ``where``.
 _BOUNDARY_LEAVES = (PH_COMMIT, PH_DRAIN, PH_CHECKPOINT, PH_RETUNE)
 
 
@@ -181,6 +183,28 @@ class _Timed:
         return out
 
 
+class _TimedBoundary(_Timed):
+    """A boundary span. One that lies inside an open ``commit`` (the fleet's
+    ``drain`` fetch: fleet/run.py) comes out of ``commit_ns``: on a row
+    ``commit`` is the commit's own time, so ``_BOUNDARY_LEAVES`` overlap
+    nowhere while the trace keeps the nesting."""
+
+    __slots__ = ("outer",)
+
+    def __enter__(self):
+        self.outer, _THREAD.timed = getattr(_THREAD, "timed", None), self
+        return super().__enter__()
+
+    def __exit__(self, *exc):
+        before = self.into.get(self.key, 0)
+        out = super().__exit__(*exc)
+        _THREAD.timed = self.outer
+        if self.outer is not None and self.outer.key == "commit_ns":
+            self.into["commit_ns"] = (self.into.get("commit_ns", 0)
+                                      - (self.into[self.key] - before))
+        return out
+
+
 def maybe_span(profiler: PhaseProfiler | None, name: str, **args):
     """``profiler.span(...)``, or the bare annotation where no PhaseProfiler
     is attached — call sites stay branchless, and every span is in any
@@ -194,7 +218,7 @@ def maybe_span(profiler: PhaseProfiler | None, name: str, **args):
         if ch is not None and ch.row is not None:
             return _Timed(cm, ch.row, _ROW_SPANS[name])
     elif name in _BOUNDARY_SPANS and _LOG.enabled:
-        return _Timed(cm, _boundary(), _BOUNDARY_SPANS[name])
+        return _TimedBoundary(cm, _boundary(), _BOUNDARY_SPANS[name])
     return cm
 
 

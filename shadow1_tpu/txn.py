@@ -117,6 +117,7 @@ class SelfCheckError(RuntimeError):
 
     IDENTITY = ("pkts_sent == pkts_delivered + pkts_lost + link_down_pkts "
                 "+ down_pkts + x2x_overflow (+ delivery share of ev_overflow)")
+    lanes = None    # a fleet's violating lanes (fleet/run._check_identity)
 
     def __init__(self, terms: dict, gap: int, where: str = ""):
         self.terms = {k: int(v) for k, v in terms.items()}

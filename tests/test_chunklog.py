@@ -1,6 +1,6 @@
 """The chunk log (telemetry/profiler.py ChunkLog): the third, always-on
-carrier of the chunk runner's spans — one row a chunk through all three chunk
-loops, readiness taken by the waiter thread, the host's health over a chunk,
+carrier of the chunk runner's spans — one row a chunk of the one chunk loop,
+whichever runner hands it hooks, readiness taken by the waiter thread, the host's health over a chunk,
 the ``stall`` line of a chunk far slower than the chunks it repeats, the
 heartbeat's ``chunk`` block and the final JSON's ``chunks`` block."""
 
@@ -223,6 +223,63 @@ def test_under_a_phase_profiler_wait_joins_the_chrome_trace_and_sync_stays(log):
     # The waiter's thread, not the loop's.
     loop_tid = {e["tid"] for e in prof.events if e["name"] == "dispatch"}
     assert {e["tid"] for e in waits}.isdisjoint(loop_tid)
+
+
+def test_a_quarantine_replays_inside_commit_and_its_boundary_has_one_on_chunk(
+        log, tmp_path):
+    """The fleet's recovery plane is the runner's commit hook: the chunk
+    whose lane fails is dispatched once by the loop, the quarantine and
+    the survivors' replay lie inside that chunk's one ``commit`` (as
+    OverflowGuard's grown replay does), and the boundary is handed to
+    ``on-chunk`` once, with the survivors."""
+    import dataclasses
+
+    from tests.test_fleet_recover import UNDER, mk
+
+    params = dataclasses.replace(UNDER, on_overflow="halt",
+                                 on_lane_fail="quarantine")
+    eng = FleetEngine([mk(5, loss=0.5), mk(6), mk(7, loss=0.5)], params)
+    prof = PhaseProfiler()
+    _st, hb = run_fleet(eng, n_windows=20, every_windows=5, stream=False,
+                        profiler=prof, quarantine_base=str(tmp_path / "lane"))
+    (q,) = hb.recovery["quarantined"]
+    done = q["window"]              # a fresh run: the loop's count is the window
+    of_chunk = [e for e in prof.events
+                if e.get("args", {}).get("done") == done]
+    names = [e["name"] for e in of_chunk]
+    for once in ("run-chunk", "dispatch", "sync", "commit", "on-chunk"):
+        assert names.count(once) == 1, (once, names)
+    (commit,) = (e for e in of_chunk if e["name"] == "commit")
+    drains = [e for e in of_chunk if e["name"] == "drain"]
+    # The refused attempt's fetch and the replay's, both inside the commit.
+    assert len(drains) == 2
+    for d in drains:
+        assert commit["ts"] <= d["ts"]
+        assert d["ts"] + d["dur"] <= commit["ts"] + commit["dur"] + 0.2
+    # Every other boundary committed at its first attempt.
+    assert prof.span_names().count("drain") == 4 + 1
+    assert [r["exps"] for r in (h["fleet"] for h in hb.records)] == (
+        [[0, 1, 2]] * (done // 5) + [[0, 2]] * (4 - done // 5))
+    # A replay opens no chunk of its own: one row a boundary, and the
+    # chunks after the quarantine are another engine's.
+    assert log.settle(5.0)
+    rows = log.rows()
+    assert [r["done"] for r in rows] == [0, 5, 10, 15]
+    # On a row the commit is less the fetches it holds: the boundary's
+    # parts sum to its turnaround (to the waiter's stamp, under a sync).
+    ours = [profiler.ChunkLog._parts(r) for r in rows
+            if "turnaround_ns" in r]        # the same engine's as before it
+    assert len(ours) == 2
+    for parts in ours:
+        assert {"commit", "drain", "turnaround"} <= set(parts)
+        assert parts["turnaround"] > -1e6, parts
+    held = next(r for r in rows if r["done"] == done + 5)
+    assert held["commit_ns"] + held["drain_ns"] == pytest.approx(
+        commit["dur"] * 1e3, rel=0.02)
+    engines = [r["engine"] for r in rows]
+    split = [r["done"] for r in rows].index(done) + 1
+    assert len(set(engines[:split])) == 1 and engines[0] == eng._chunk_log_no
+    assert all(n != engines[0] for n in engines[split:])
 
 
 def test_outside_a_chunk_loop_the_run_call_s_spans_are_bare_annotations(log):
@@ -453,6 +510,40 @@ def test_a_slow_span_of_the_boundary_before_a_stalled_chunk_is_named(
     # on-chunk began with the wait for the result (10 ms on this clock).
     assert (s["checkpoint_ms"], s["on_chunk_ms"]) == (5.0, 16.0)
     assert "drain_ms" not in s and s["turnaround_ms"] == 6.0
+
+
+def test_a_drain_inside_a_commit_is_one_part_of_the_turnaround_not_two(
+        log, clock, capsys):
+    """The fleet's commit hook holds its ``drain`` fetch (fleet/run.py): in
+    the trace the one span lies inside the other, on a row ``commit`` is
+    the commit's own time, so the parts still split the turnaround and the
+    slow fetch is named, not the commit around it."""
+    eng = FakeEngine(clock, late_ms=0.0, slow={13: (10.0, 90.0)})
+
+    def commit(engine, st0, st, done, step):
+        assert log.settle(5.0)
+        clock.pass_ms(3.0)
+        with maybe_span(None, PH_DRAIN, done=done):
+            clock.pass_ms(407.0 if eng.calls == 13 else 7.0)
+        return engine, st
+
+    guard = types.SimpleNamespace(
+        bind=lambda engine, st: None, commit=commit,
+        run_guarded=lambda engine, st, n: engine.run(st, n_windows=n))
+    for _ in range(6):
+        run_chunked(eng, state(0), n_windows=6, chunk=2, guard=guard,
+                    on_chunk=lambda st, done: clock.pass_ms(1.0))
+    assert log.settle(5.0)
+    (line,) = stall_lines(capsys)
+    assert (line["first_window"], line["where"]) == (2, "drain")
+    assert line["ms"] == {"args": 0.5, "call": 10.0, "wait": 90.0,
+                          "commit": 3.0, "drain": 407.0, "turnaround": 1.0}
+    assert line["median_of_ms"] == {"args": 0.5, "call": 10.0, "wait": 0.0,
+                                    "commit": 3.0, "drain": 7.0,
+                                    "turnaround": 1.0}
+    s = log.summary()
+    assert (s["commit_ms"], s["drain_ms"], s["turnaround_ms"]) == (
+        3.0, 7.0, 11.0)
 
 
 def test_a_loop_that_runs_ahead_of_the_device_is_not_a_run_of_stalls(log, clock, capsys):

@@ -380,6 +380,82 @@ def test_fleet_selfcheck_runs_per_experiment(fleet_run):
     assert len(hb.records) == 2  # one heartbeat per chunk
 
 
+def test_run_fleet_enters_the_one_chunk_runner_once(monkeypatch):
+    """The fleet runner holds no chunk loop: one call of run_fleet is one
+    call of ckpt.run_chunked, handed the fleet's hooks, and the module
+    dispatches, syncs and preempts nowhere of its own."""
+    import inspect
+
+    import shadow1_tpu.ckpt as ckpt
+    import shadow1_tpu.fleet.run as fleet_run_mod
+
+    calls = []
+    real = ckpt.run_chunked
+
+    def counting(engine, st=None, **kw):
+        calls.append(kw)
+        return real(engine, st, **kw)
+
+    monkeypatch.setattr(ckpt, "run_chunked", counting)
+    exps = [single_vertex_experiment(
+        n_hosts=8, seed=s, end_time=20 * MS, latency_ns=1 * MS, model="phold",
+        model_cfg={"mean_delay_ns": float(2 * MS), "init_events": 2})
+        for s in (5, 6)]
+    eng = FleetEngine(exps, EngineParams(ev_cap=32))
+    st, hb = fleet_run_mod.run_fleet(eng, n_windows=6, every_windows=2,
+                                     stream=False)
+    assert len(calls) == 1 and len(hb.records) == 3
+    (kw,) = calls
+    assert (kw["n_windows"], kw["chunk"]) == (6, 2) and callable(kw["on_chunk"])
+    # Nothing can refuse a chunk of this run and nothing retunes it.
+    assert kw["guard"] is None and kw["retune"] is None
+    assert int(np.asarray(st.metrics.windows).max()) == 6
+    src = inspect.getsource(fleet_run_mod)
+    for loop_only in ("while done", "chunks.chunk(", "PH_DISPATCH", "PH_SYNC",
+                      "raise PreemptedExit"):
+        assert loop_only not in src, loop_only
+
+
+def test_a_fleet_heartbeat_s_clock_is_read_after_the_chunk_s_fetch(monkeypatch):
+    """The run call returns before the chunk ends; the fetch is where the
+    loop waits for it. A heartbeat that makes the fetch itself (no commit
+    hook ran one) reads its clock after it, as one handed the commit's
+    does: ``wall_s`` and ``events_per_sec`` are of the chunk that ended."""
+    import types
+
+    import shadow1_tpu.fleet.run as fleet_run_mod
+
+    clock = [100.0]
+    monkeypatch.setattr(fleet_run_mod, "time", types.SimpleNamespace(
+        perf_counter=lambda: clock[0]))
+
+    class Eng:
+        n_exp = 2
+
+        def metrics_per_exp(self, st):
+            clock[0] += 2.0             # the chunk ends 2 s after its call
+            return [{"events": 30}, {"events": 50}]
+
+        def metrics_dict(self, st):
+            return {"events": 80, "windows": 5}
+
+        def drain_rings(self, st, start=0):
+            return []
+
+        def model_totals(self, st):
+            return [{}, {}]
+
+    st = types.SimpleNamespace(win_start=np.asarray([5, 5]))
+    for handed in (None, [{"events": 30}, {"events": 50}]):
+        clock[0] = 100.0
+        hb = fleet_run_mod.FleetHeartbeat(Eng(), stream=False)
+        if handed is not None:
+            clock[0] += 2.0             # the commit made the fetch
+        hb(st, 5, per_exp=handed)
+        (rec,) = hb.records
+        assert (rec["wall_s"], rec["events_per_sec"]) == (2.0, 40.0), handed
+
+
 # ---------------------------------------------------------------------------
 # records / report tooling
 # ---------------------------------------------------------------------------

@@ -33,6 +33,7 @@ import numpy as np
 import pytest
 
 import shadow1_tpu.txn as txn
+from shadow1_tpu import ckpt
 from shadow1_tpu.ckpt import load_state, snapshot_caps
 from shadow1_tpu.config.compiled import single_vertex_experiment
 from shadow1_tpu.consts import EXIT_CAPACITY, MS, EngineParams
@@ -401,6 +402,61 @@ def test_lane_finalize_early():
     _, summary = final_records(hb.engine, st, hb.labels, N, 1.0,
                                recovery=hb.recovery)
     assert summary["finished_early"] == [1]
+
+
+def test_lane_finalize_follows_the_boundary_s_snapshot(tmp_path):
+    """The one boundary order (docs/SEMANTICS.md "Fleet recovery contract"):
+    snapshot in on_chunk, finalize in retune after it. The snapshot taken at
+    the boundary a lane finalizes at still holds the lane, under the ``lanes``
+    of the state it holds; the next one is the first without it; and a sweep
+    resumed from the former (a drain or a crash at that boundary) owes the
+    boundary's retune: the lane leaves before the first chunk, with the
+    record the uninterrupted sweep gave it, field for field."""
+    exps = [mk(5, loss=0.5), mk(6, loss=0.5, stop=10 * MS)]
+    params = dataclasses.replace(UNDER, ev_cap=32, lane_finalize=1)
+    ck = str(tmp_path / "fleet.npz")
+    st, hb = run_fleet(FleetEngine(exps, params), n_windows=N, every_windows=5,
+                       stream=False, ckpt_path=ck, ckpt_every_s=0,
+                       ckpt_keep=8)
+    (rec,) = hb.recovery["finished"]
+    at = rec["windows"]
+    gens = {g["done_windows"]: g for g in Lineage(ck, keep=8).generations()}
+    assert sorted(gens) == [5, 10, 15, 20] and at in (5, 10, 15)
+    for done, g in gens.items():
+        if done <= at:
+            assert g["lanes"] == [0, 1] and "finished" not in g, done
+        else:
+            assert g["lanes"] == [0] and g["finished"] == [1], done
+    # Resume the two-lane snapshot of that boundary.
+    eng2 = FleetEngine(exps, params)
+    st2 = load_state(eng2.init_state(), gens[at]["file"])
+    emitted, ran = [], []
+    real = ckpt.run_chunked
+
+    def counting(engine, *a, **kw):
+        ran.append((engine.n_exp, len(emitted)))
+        return real(engine, *a, **kw)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ckpt, "run_chunked", counting)
+        st2, hb2 = run_fleet(eng2, st2, n_windows=N - at, every_windows=5,
+                             stream=False, emit_record=emitted.append)
+    # The record went out before any chunk ran, and the loop began E=1.
+    assert ran == [(1, 1)] and emitted == hb2.recovery["finished"]
+    assert emitted == [rec] and rec["windows_configured"] == N
+    assert [h["fleet"]["exps"] for h in hb2.records] == \
+        [[0]] * ((N - at) // 5)
+    assert stream(slice_experiment(st2, 0), eng2.window) == \
+        stream(slice_experiment(st, 0), eng2.window)
+    # A snapshot one boundary EARLIER holds no finished lane yet: nothing
+    # leaves at its start, and the lane leaves at ``at`` as it did.
+    if at > 5:
+        eng3 = FleetEngine(exps, params)
+        st3 = load_state(eng3.init_state(), gens[at - 5]["file"])
+        _st3, hb3 = run_fleet(eng3, st3, n_windows=N - at + 5,
+                              every_windows=5, stream=False)
+        assert hb3.recovery["finished"] == [rec]
+        assert hb3.records[0]["fleet"]["exps"] == [0, 1]
 
 
 def test_fleet_plan_subset():

@@ -2,7 +2,8 @@
 profiler's clock (telemetry/phases.py, telemetry/profiler.py): the join from
 a traced op's instruction name to its ``phase:`` scopes, attribution that
 sums to busy time, and ``shadow1:`` spans in any ``jax.profiler`` capture —
-from ``ckpt.run_chunked`` and from the fleet loop, profiler attached or not."""
+from ``ckpt.run_chunked``, bare and under the fleet runner's hooks, profiler
+attached or not."""
 
 import glob
 import gzip

@@ -9,6 +9,7 @@ nothing ever touched. tools/chaosprobe.py proves the same contract under
 randomized kills; these tests pin the mechanisms deterministically.
 """
 
+import dataclasses
 import io
 import json
 import os
@@ -20,7 +21,7 @@ import pytest
 import jax
 import numpy as np
 
-from shadow1_tpu.ckpt import load_state, run_chunked
+from shadow1_tpu.ckpt import load_state
 from shadow1_tpu.config.compiled import single_vertex_experiment
 from shadow1_tpu.consts import (
     EXIT_CAPACITY,
@@ -214,39 +215,85 @@ def test_lineage_fallback_fleet(tmp_path):
 # drain semantics in the chunk runner (in-process, no signals)
 # ---------------------------------------------------------------------------
 
-def test_run_chunked_drain_commits_inflight_chunk():
+def _solo_runner():
+    from shadow1_tpu.obs import run_with_heartbeat
+
+    return phold_engine(8), run_with_heartbeat, {}
+
+
+def _fleet_runner():
+    from shadow1_tpu.fleet.engine import FleetEngine
+    from shadow1_tpu.fleet.run import run_fleet
+
+    exp = phold_engine(8).exp
+    eng = FleetEngine([exp, dataclasses.replace(exp, seed=12)], EngineParams())
+    return eng, run_fleet, {"lanes": [0, 1]}
+
+
+def _latch_at(monkeypatch, drain, sim_ns, signame):
+    """Latch ``drain`` from inside on_chunk at sim time ``sim_ns``, where
+    the injection hooks deliver a real run's SIGTERM: after the heartbeat,
+    before the snapshot."""
+    import shadow1_tpu.preempt as preempt
+
+    def hooks(now_ns):
+        if now_ns == sim_ns:
+            drain.signame = signame
+
+    monkeypatch.setattr(preempt, "run_injection_hooks", hooks)
+
+
+RUNNERS = pytest.mark.parametrize("runner", [_solo_runner, _fleet_runner],
+                                  ids=["solo", "fleet"])
+
+
+@RUNNERS
+def test_run_chunked_drain_commits_inflight_chunk(runner, tmp_path,
+                                                  monkeypatch):
     """A drain requested mid-run stops AFTER the in-flight chunk commits:
     the carried state equals a straight run of exactly the committed
     windows — the when-work-is-lost half of the preemption contract.
     The latch is sampled BEFORE on_chunk at each boundary, so a request
     landing inside on_chunk (as the injection hooks do) is honored one
-    boundary later — never without the forced snapshot."""
-    eng = phold_engine(8)
+    boundary later — never without the forced snapshot. Solo and fleet
+    reach ckpt.run_chunked through the one boundary hook
+    (obs.boundary_hook): same snapshots, same sidecar, same exit."""
+    eng, run, meta = runner()
+    ck = str(tmp_path / "ck.npz")
     drain = DrainHandler()  # not installed: no real signals in-process
-
-    def on_chunk(st, done):
-        if done == 20:
-            drain.signame = "SIGTERM"  # latch mid-on_chunk
-
+    _latch_at(monkeypatch, drain, 20 * eng.window, "SIGTERM")
     with pytest.raises(PreemptedExit) as ei:
-        run_chunked(eng, n_windows=50, chunk=10, on_chunk=on_chunk,
-                    drain=drain)
+        run(eng, n_windows=50, every_windows=10, stream=False, ckpt_path=ck,
+            drain=drain)
     e = ei.value
     assert e.done_windows == 30 and e.signame == "SIGTERM"
     assert e.win_start == 30 * eng.window
     assert state_equal(e.st, eng.run(n_windows=30))
+    # Both boundaries that saw the latch forced their snapshot (the wall
+    # throttle alone would have saved neither), the newest holds the
+    # carried state, and the sidecar ticked with it.
+    gens = Lineage(ck).generations()
+    assert [g["done_windows"] for g in gens] == [20, 30]
+    assert [g["win_start"] for g in gens] == [20 * eng.window,
+                                              30 * eng.window]
+    assert all({k: g[k] for k in meta} == meta for g in gens)
+    assert state_equal(load_state(eng.init_state(), ck), e.st)
+    with open(ck + ".progress") as f:
+        assert json.load(f) == {"done_windows": 30, "total": 50,
+                                "win_start": 30 * eng.window,
+                                "seq": gens[-1]["seq"]}
 
 
-def test_drain_on_final_chunk_is_a_normal_exit():
-    eng = phold_engine(8)
+@RUNNERS
+def test_drain_on_final_chunk_is_a_normal_exit(runner, tmp_path,
+                                               monkeypatch):
+    eng, run, _meta = runner()
     drain = DrainHandler()
-
-    def on_chunk(st, done):
-        if done == 30:  # the last chunk: nothing left to preempt
-            drain.signame = "SIGINT"
-
-    st = run_chunked(eng, n_windows=30, chunk=10, on_chunk=on_chunk,
-                     drain=drain)
+    # The last chunk: nothing left to preempt.
+    _latch_at(monkeypatch, drain, 30 * eng.window, "SIGINT")
+    st, _hb = run(eng, n_windows=30, every_windows=10, stream=False,
+                  ckpt_path=str(tmp_path / "ck.npz"), drain=drain)
+    assert drain.requested
     assert state_equal(st, eng.run(n_windows=30))
 
 
