@@ -53,8 +53,8 @@ def load_graphml(path: str):
             raise ValueError(f"edge {a}-{b} missing latency attribute")
         p = float(data.get("packetloss", data.get("loss", 0.0)))
         i, j = index[a], index[b]
-        # Self-loop edges (Shadow convention) give the intra-PoP latency of
-        # hosts attached to the same vertex.
+        # Self-loop edges (Shadow convention) give the intra-PoP latency and
+        # loss of hosts attached to the same vertex.
         lat[i, j] = l_ns
         loss[i, j] = p
         if not directed:
@@ -82,11 +82,15 @@ def compile_paths(lat_e: np.ndarray, loss_e: np.ndarray,
     where present, else ``self_latency_ns``, else the minimum edge latency —
     it must stay positive, since the conservative window is min(lat_vv)
     (the reference computes runahead the same way, src/main/core/master.c).
+    Its loss is the self-loop's ``packetloss`` where the vertex has a
+    self-loop (both attributes of that edge are the same-vertex pair's, as
+    ``network.single_vertex: {latency, loss}`` always gave), else 0.
     """
     from scipy.sparse.csgraph import dijkstra
 
     v = lat_e.shape[0]
     self_lat = np.diag(lat_e).copy()          # self-loops (inf = absent)
+    self_loss = np.where(np.isfinite(self_lat), np.diag(loss_e), 0.0)
     lat_e = lat_e.copy()
     np.fill_diagonal(lat_e, np.inf)
     finite = lat_e[np.isfinite(lat_e)]
@@ -122,6 +126,6 @@ def compile_paths(lat_e: np.ndarray, loss_e: np.ndarray,
     lat_vv = np.rint(dist).astype(np.int64)
     np.fill_diagonal(lat_vv, np.rint(self_lat).astype(np.int64))
     loss_vv = (1.0 - rel).astype(np.float32)
-    np.fill_diagonal(loss_vv, 0.0)
+    np.fill_diagonal(loss_vv, self_loss.astype(np.float32))
     assert (lat_vv > 0).all(), "zero-latency path would break the window"
     return lat_vv, loss_vv

@@ -723,22 +723,26 @@ def tcp_rx(st, ctx, mask, p, now):
     nf = _notify(nf, sp, ds, N_SPACE, space=space)
 
     # Duplicate ACKs → fast retransmit (Go-Back-N rewind) at the threshold.
-    dup_a = a & ~new_ack & (ackno == snd_una) & outstanding & (length == 0) & ~is_syn & ~is_fin
-    dp = r.g("dupacks") + 1
-    r.s("dupacks", dp, dup_a)
-    frx = dup_a & (dp == pr.dupack_thresh) & ((snd_una - r.g("recover")) >= 0)
-    flight = snd_nxt - snd_una
-    ssth = jnp.maximum(flight // 2, 2 * pr.mss)
-    r.s("ssthresh", ssth, frx)
-    r.s("cwnd", ssth, frx)
-    r.s("recover", snd_nxt, frx)
-    r.s("snd_nxt", snd_una, frx)
-    r.s("ts_act", False, frx)
+    # A scope of its own (``phase:tcp_fast_rtx``, inside the pass's) so the
+    # attribution plane shows the episode as a row; the flush that resends
+    # is ``phase:tcp_flush``'s, below.
+    with jax.named_scope("phase:tcp_fast_rtx"):
+        dup_a = a & ~new_ack & (ackno == snd_una) & outstanding & (length == 0) & ~is_syn & ~is_fin
+        dp = r.g("dupacks") + 1
+        r.s("dupacks", dp, dup_a)
+        frx = dup_a & (dp == pr.dupack_thresh) & ((snd_una - r.g("recover")) >= 0)
+        flight = snd_nxt - snd_una
+        ssth = jnp.maximum(flight // 2, 2 * pr.mss)
+        r.s("ssthresh", ssth, frx)
+        r.s("cwnd", ssth, frx)
+        r.s("recover", snd_nxt, frx)
+        r.s("snd_nxt", snd_una, frx)
+        r.s("ts_act", False, frx)
 
-    st = st._replace(model=st.model._replace(tcp=r.d))
-    met = st.metrics
-    st = st._replace(metrics=met._replace(
-        tcp_fast_rtx=met.tcp_fast_rtx + frx.sum(dtype=jnp.int64)))
+        st = st._replace(model=st.model._replace(tcp=r.d))
+        met = st.metrics
+        st = st._replace(metrics=met._replace(
+            tcp_fast_rtx=met.tcp_fast_rtx + frx.sum(dtype=jnp.int64)))
     st = tcp_flush(st, ctx, new_ack | frx, ds, now)
 
     # ---- payload (in-order only: Go-Back-N receiver) and FIN
@@ -816,23 +820,26 @@ def on_tcp_timer(st, ctx, ev):
     r.s("timer_armed", True, future)
     fire = live & ~future
     outstanding = (r.g("snd_max") - r.g("snd_una")) > 0
-    rto_fire = fire & outstanding & _state_in(r.g("st"), _SENDABLE)
-    flight = r.g("snd_nxt") - r.g("snd_una")
-    r.s("ssthresh", jnp.maximum(flight // 2, 2 * pr.mss), rto_fire)
-    r.s("cwnd", pr.mss, rto_fire)
-    rto_n = jnp.minimum(r.g("rto") * 2, pr.rto_max)
-    r.s("rto", rto_n, rto_fire)
-    r.s("snd_nxt", r.g("snd_una"), rto_fire)
-    r.s("ts_act", False, rto_fire)
-    r.s("dupacks", 0, rto_fire)
-    r.s("recover", r.g("snd_una"), rto_fire)
-    r.s("rtx_t", now + rto_n, rto_fire)
-    r.s("timer_armed", True, rto_fire)
-    r.s("rtx_t", 0, fire & ~rto_fire)
-    st = st._replace(model=st.model._replace(tcp=r.d))
-    met = st.metrics
-    st = st._replace(metrics=met._replace(
-        tcp_rto=met.tcp_rto + rto_fire.sum(dtype=jnp.int64)))
+    # The timeout's rewind under a scope of its own (``phase:tcp_rto``,
+    # inside ``phase:h_timer``): a row of the attribution plane.
+    with jax.named_scope("phase:tcp_rto"):
+        rto_fire = fire & outstanding & _state_in(r.g("st"), _SENDABLE)
+        flight = r.g("snd_nxt") - r.g("snd_una")
+        r.s("ssthresh", jnp.maximum(flight // 2, 2 * pr.mss), rto_fire)
+        r.s("cwnd", pr.mss, rto_fire)
+        rto_n = jnp.minimum(r.g("rto") * 2, pr.rto_max)
+        r.s("rto", rto_n, rto_fire)
+        r.s("snd_nxt", r.g("snd_una"), rto_fire)
+        r.s("ts_act", False, rto_fire)
+        r.s("dupacks", 0, rto_fire)
+        r.s("recover", r.g("snd_una"), rto_fire)
+        r.s("rtx_t", now + rto_n, rto_fire)
+        r.s("timer_armed", True, rto_fire)
+        r.s("rtx_t", 0, fire & ~rto_fire)
+        st = st._replace(model=st.model._replace(tcp=r.d))
+        met = st.metrics
+        st = st._replace(metrics=met._replace(
+            tcp_rto=met.tcp_rto + rto_fire.sum(dtype=jnp.int64)))
     # One pending event per socket: re-push at whichever deadline applies.
     repush = future | rto_fire
     t_ev = jnp.where(future, deadline, now + rto_n)

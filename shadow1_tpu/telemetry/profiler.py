@@ -46,6 +46,7 @@ import time
 
 from shadow1_tpu.telemetry.registry import (
     CHUNK_CAP_TOTALS,
+    CHUNK_LOSS_TOTALS,
     CHUNK_TOTALS,
     REC_STALL,
 )
@@ -276,14 +277,19 @@ _TOTALS = CHUNK_TOTALS[:-1]
 # on every row that has any.
 _CAP_TOTALS = {"buckets": "compact_buckets"}
 assert tuple(_CAP_TOTALS) == CHUNK_CAP_TOTALS
+# Every total a row may carry, in the order ``_input_leaves`` reads them:
+# ``CHUNK_LOSS_TOTALS`` (the loss plane: what a chunk sent, lost, resent and
+# dropped out of order) are ``Metrics`` fields like ``_TOTALS``.
+_ROW_TOTALS = (*_TOTALS, *CHUNK_LOSS_TOTALS, *_CAP_TOTALS)
 
 
 def _input_leaves(st) -> tuple:
     """The scalars of an input state that the log reads: ``metrics.windows``,
-    the ``_TOTALS`` and the ``_CAP_TOTALS`` (None where the state has
-    none)."""
+    the ``_TOTALS``, the ``CHUNK_LOSS_TOTALS`` and the ``_CAP_TOTALS`` (None
+    where the state has none)."""
     m = getattr(st, "metrics", None)
-    return (*(getattr(m, k, None) for k in ("windows", *_TOTALS)),
+    return (*(getattr(m, k, None)
+              for k in ("windows", *_TOTALS, *CHUNK_LOSS_TOTALS)),
             *(getattr(st, leaf, None) for leaf in _CAP_TOTALS.values()))
 
 
@@ -296,7 +302,9 @@ def _host_count(engine) -> int | None:
 def work_between(row: dict, after: dict | None) -> dict | None:
     """What the chunk of ``row`` did — events, rounds (a lane's own, summed
     over lanes), ``active_hosts`` and ``elig_events`` (sums over its
-    windows), ``buckets`` (the compacted round loop's trips) where the
+    windows), the loss plane's ``pkts_sent``, ``pkts_lost``,
+    ``tcp_fast_rtx``, ``tcp_rto``, ``tcp_ooo_drops`` where both rows carry
+    them, ``buckets`` (the compacted round loop's trips) where the
     program counts them — where ``after`` is the row of the chunk that
     continued it: the same engine's, adjacent in ``seq``, starting on the
     window ``row`` ended on. Else None: a row's totals are of its chunk's
@@ -307,7 +315,7 @@ def work_between(row: dict, after: dict | None) -> dict | None:
             or after.get("first_window") != row["first_window"] + row["windows"]
             or any(k not in r for r in (row, after) for k in _TOTALS)):
         return None
-    return {k: after[k] - row[k] for k in (*_TOTALS, *_CAP_TOTALS)
+    return {k: after[k] - row[k] for k in _ROW_TOTALS
             if k in row and k in after}
 
 
@@ -403,7 +411,9 @@ class ChunkLog:
     A row: ``seq`` (chunks in the order they were opened), ``engine`` (a
     number of the engine object), ``done`` (the loop's count),
     ``first_window`` (the input state's ``metrics.windows``), ``windows``,
-    ``events``, ``rounds``, ``active_hosts``, ``elig_events``, and where a
+    ``events``, ``rounds``, ``active_hosts``, ``elig_events``, the loss
+    plane's ``pkts_sent``, ``pkts_lost``, ``tcp_fast_rtx``, ``tcp_rto``,
+    ``tcp_ooo_drops``, and where a
     ``compact_cap`` is in force ``buckets`` (the input
     state's running totals, summed over a fleet's lanes: what the chunk did
     is the NEXT row's less these, ``work_between``) and ``hosts`` (the
@@ -423,7 +433,7 @@ class ChunkLog:
     a chunk judged a stall; ``error`` where the result's readiness raised.
 
     Readiness is taken by ONE daemon thread, started with the first chunk,
-    asleep on its queue between chunks: handed a chunk, it reads the five
+    asleep on its queue between chunks: handed a chunk, it reads the ten
     scalars of the input state's metrics and gives them up, blocks
     on one scalar leaf of the result (never the state) under a ``wait``
     span, stamps ``ready_ns`` and gives the leaf up at once; it closes the
@@ -563,7 +573,7 @@ class ChunkLog:
         del leaves
         row["first_window"] = (None if values[0] is None
                                else int(np.max(values[0])))
-        for k, v in zip((*_TOTALS, *_CAP_TOTALS), values[1:]):
+        for k, v in zip(_ROW_TOTALS, values[1:]):
             if v is not None:
                 row[k] = int(np.sum(v))
 
@@ -707,7 +717,25 @@ class ChunkLog:
             out.update(
                 median_of_rounds=statistics.median(w["rounds"] for w in theirs),
                 median_of_events=statistics.median(w["events"] for w in theirs))
+        # What it resent (fast retransmits + RTOs) and lost, where its rows
+        # carry the loss plane: a chunk that recovered more than its twins
+        # ran more rounds for that.
+        resent = ChunkLog._retransmits(did)
+        if resent is not None:
+            out.update(retransmits=resent, pkts_lost=did["pkts_lost"])
+            have = [r for r in map(ChunkLog._retransmits, theirs)
+                    if r is not None]
+            if have:
+                out["median_of_retransmits"] = statistics.median(have)
         return out
+
+    @staticmethod
+    def _retransmits(did: dict) -> int | None:
+        """Fast retransmits + RTOs of a chunk's work, or None where its rows
+        carry no loss totals."""
+        if any(k not in did for k in CHUNK_LOSS_TOTALS):
+            return None
+        return did["tcp_fast_rtx"] + did["tcp_rto"]
 
     # -- readers -----------------------------------------------------------------
     def rows(self, wait_s: float = 1.0) -> list[dict]:
@@ -735,8 +763,8 @@ class ChunkLog:
         for key in ("turnaround_ns", *_BOUNDARY_SPANS.values()):
             if key in row:
                 out[_ms_key(key)] = _ms(row[key])
-        out.update({k: row[k] for k in CHUNK_TOTALS + CHUNK_CAP_TOTALS
-                    if k in row})
+        out.update({k: row[k] for k in (*CHUNK_TOTALS, *CHUNK_LOSS_TOTALS,
+                                        *_CAP_TOTALS) if k in row})
         return {**out, **row["health"]}
 
     def summary(self, wait_s: float = 1.0) -> dict:
@@ -776,8 +804,7 @@ class ChunkLog:
         # What those chunks did, of each that the next row continues.
         did = [w for w in map(work_between, rows, rows[1:]) if w is not None]
         if did:
-            out.update({k: sum(w[k] for w in did)
-                        for k in (*_TOTALS, *_CAP_TOTALS)
+            out.update({k: sum(w[k] for w in did) for k in _ROW_TOTALS
                         if all(k in w for w in did)})
         if "hosts" in rows[-1]:
             out["hosts"] = rows[-1]["hosts"]

@@ -249,17 +249,27 @@ CHUNK_BOUNDARY = ("commit_ms", "on_chunk_ms", "drain_ms", "checkpoint_ms",
 # CHUNK_CAP_TOTALS are running totals too, on a row only where the program
 # has a compact_cap in force: ``buckets`` is ``SimState.compact_buckets``,
 # the compacted round loop's trips (core/compact.py).
+# CHUNK_LOSS_TOTALS are the loss plane's running totals, read of the same
+# Metrics in the same pass (on every row of a state that has them: every
+# engine's): what a chunk sent, lost to a path's loss draw, and resent or
+# dropped for it (fast retransmits, RTOs, out-of-order segments a Go-Back-N
+# receiver dropped). A stall line prints the chunk's ``retransmits``
+# (fast retransmits + RTOs) and ``pkts_lost`` beside its rounds
+# (STALL_LOSS_WORK).
 CHUNK_TOTALS = ("events", "rounds", "active_hosts", "elig_events", "hosts")
 CHUNK_CAP_TOTALS = ("buckets",)
+CHUNK_LOSS_TOTALS = ("pkts_sent", "pkts_lost", "tcp_fast_rtx", "tcp_rto",
+                     "tcp_ooo_drops")
 STALL_WORK = ("rounds", "events", "median_of_rounds", "median_of_events")
+STALL_LOSS_WORK = ("retransmits", "pkts_lost", "median_of_retransmits")
 CHUNK_BLOCK = (("dispatch_ms", "wait_ms", "turnaround_ms") + CHUNK_TOTALS
-               + CHUNK_CAP_TOTALS + CHUNK_BOUNDARY)
+               + CHUNK_LOSS_TOTALS + CHUNK_CAP_TOTALS + CHUNK_BOUNDARY)
 CHUNK_HEALTH = ("cpu_s", "nivcsw", "nvcsw", "majflt", "inblock", "oublock",
                 "psi_cpu_us", "psi_io_us", "psi_mem_us", "load1")
 CHUNKS_BLOCK = ("count", "stalls", "rows", "windows", "dispatch_ms",
                 "args_ms", "call_ms", "wait_ms", "turnaround_ms",
                 "boundary_ms", "boundary_share") + CHUNK_TOTALS \
-    + CHUNK_CAP_TOTALS + CHUNK_BOUNDARY
+    + CHUNK_LOSS_TOTALS + CHUNK_CAP_TOTALS + CHUNK_BOUNDARY
 RECORD_TYPES = (REC_HEARTBEAT, REC_TRACKER, REC_RING, REC_RING_GAP,
                 REC_DIGEST, REC_FLEET_EXP, REC_FLEET_SUMMARY,
                 REC_FLEET_RETRY, REC_FLEET_QUARANTINE,

@@ -646,7 +646,8 @@ def test_the_cli_s_heartbeats_and_last_line_carry_the_documented_blocks(tmp_path
         # heartbeat of the chunk before (its drain inside it, or beside).
         want = REQUIRED_CHUNK | {"on_chunk_ms", "drain_ms"}
         if i == 0:
-            want = {"dispatch_ms", "wait_ms"} | set(registry.CHUNK_TOTALS)
+            want = ({"dispatch_ms", "wait_ms"} | set(registry.CHUNK_TOTALS)
+                    | set(registry.CHUNK_LOSS_TOTALS))
         assert want <= set(block) <= set(registry.CHUNK_BLOCK + registry.CHUNK_HEALTH)
         assert want == set(block) - set(registry.CHUNK_HEALTH)
         assert block["dispatch_ms"] > 0 and block["wait_ms"] >= 0
@@ -798,6 +799,101 @@ def test_the_stall_line_prints_the_chunk_s_rounds_and_events_beside_its_wall(
     assert [r["hosts"] for r in rows] == [16] * 8
     assert [r["rounds"] for r in rows] == [0, 20, 40, 60, 80, 100, 1100, 1120]
     assert log.summary()["rounds"] == 1120
+
+
+class LossyEngine(CountingEngine):
+    """A counting engine whose states keep the loss plane too: a call adds
+    ``loss`` (by the call's number; default 50 sent, 1 lost, nothing resent)
+    to every lane's ``pkts_sent``, ``pkts_lost``, ``tcp_fast_rtx``,
+    ``tcp_rto`` and ``tcp_ooo_drops``."""
+
+    def __init__(self, clock, loss=None, **kw):
+        super().__init__(clock, **kw)
+        self.loss = loss or {}
+
+    def run(self, st, n_windows=None):
+        add = self.loss.get(self.calls, (50, 1, 0, 0, 0))
+        out = super().run(st, n_windows)
+        for k, more in zip(registry.CHUNK_LOSS_TOTALS, add):
+            setattr(out.metrics, k,
+                    Leaf(np.asarray(getattr(st.metrics, k)) + more))
+        return out
+
+
+def lossy_zeros(lanes=2):
+    return counted(0, **{k: np.zeros(lanes, np.int64)
+                         for k in (*profiler._TOTALS, *registry.CHUNK_LOSS_TOTALS)})
+
+
+def test_a_row_carries_the_five_totals_of_the_loss_plane(log, clock):
+    """Read with the other totals of the chunk's INPUT state, summed over the
+    lanes: zeros on the first row, running totals after; the heartbeat's
+    block and the summary carry them, and two adjacent rows give the first
+    one's packets sent, lost, resent and dropped out of order."""
+    eng = LossyEngine(clock, loss={2: (400, 9, 2, 3, 11)})
+    eng.n_windows = 8
+    run_chunked(eng, lossy_zeros(), n_windows=8, chunk=2)
+    assert log.settle(5.0)
+    rows = log.rows()
+    assert len(rows) == 4
+    assert all(set(registry.CHUNK_LOSS_TOTALS) <= set(r) for r in rows)
+    assert [[r[k] for k in registry.CHUNK_LOSS_TOTALS] for r in rows] == [
+        [0, 0, 0, 0, 0], [100, 2, 0, 0, 0], [200, 4, 0, 0, 0],
+        [1000, 22, 4, 6, 22]]
+    did = profiler.work_between(rows[2], rows[3])
+    assert {k: did[k] for k in registry.CHUNK_LOSS_TOTALS} == {
+        "pkts_sent": 800, "pkts_lost": 18, "tcp_fast_rtx": 4, "tcp_rto": 6,
+        "tcp_ooo_drops": 22}
+    assert (did["rounds"], did["events"]) == (20, 200)
+    # The block of the chunk this thread ran last (a heartbeat's): the
+    # totals at that chunk's start; the summary: what the chunks that a
+    # kept row continues did, all but the last one's.
+    block = log.block()
+    assert {k: block[k] for k in registry.CHUNK_LOSS_TOTALS} == {
+        k: rows[3][k] for k in registry.CHUNK_LOSS_TOTALS}
+    assert set(block) <= set(registry.CHUNK_BLOCK + registry.CHUNK_HEALTH)
+    s = log.summary()
+    assert {k: s[k] for k in registry.CHUNK_LOSS_TOTALS} == {
+        k: rows[3][k] for k in registry.CHUNK_LOSS_TOTALS}
+    assert set(s) <= set(registry.CHUNKS_BLOCK)
+
+
+def test_rows_of_states_without_the_loss_plane_keep_their_work(log, clock):
+    """PR 43's fake states (events, rounds, hosts, no packet counters): the
+    rows, the work between two of them and the summary are what they were,
+    with none of the five."""
+    eng = CountingEngine(clock)
+    eng.n_windows = 6
+    run_chunked(eng, zeros(), n_windows=6, chunk=2)
+    assert log.settle(5.0)
+    rows = log.rows()
+    assert not any(set(r) & set(registry.CHUNK_LOSS_TOTALS) for r in rows)
+    did = profiler.work_between(rows[0], rows[1])
+    assert did == {"events": 200, "rounds": 20, "active_hosts": 6,
+                   "elig_events": 200}
+    assert not set(log.summary()) & set(registry.CHUNK_LOSS_TOTALS)
+
+
+def test_the_stall_line_prints_what_the_chunk_resent_beside_its_rounds(
+        log, clock, capsys):
+    """The chunk that took 60x its neighbours' wall also recovered from 40x
+    their losses: the line says how many episodes it started (fast
+    retransmits + RTOs, summed over the lanes), how many packets it lost,
+    and the median of the rows it was judged by. Rows without the loss plane
+    earn a line without those keys (the test above this file's other stall
+    tests: ``CountingEngine``)."""
+    eng = LossyEngine(clock, slow={5: (10.0, 1200.0)}, work={5: (500, 4000)},
+                      loss={5: (900, 40, 7, 13, 60), 2: (50, 1, 1, 0, 0)})
+    eng.n_windows = 16
+    run_chunked(eng, lossy_zeros(), n_windows=16, chunk=2)
+    assert log.settle(5.0)
+    (busy,) = stall_lines(capsys)
+    assert busy["first_window"] == 10
+    assert set(registry.STALL_WORK + registry.STALL_LOSS_WORK) <= set(busy)
+    assert (busy["rounds"], busy["retransmits"], busy["pkts_lost"]) == (
+        1000, 40, 80)
+    # Its neighbours: one started 2 episodes (both lanes), the others none.
+    assert busy["median_of_retransmits"] == 0
 
 
 def test_the_input_s_scalars_are_read_when_a_chunk_is_handed_over_not_when_it_is_ready(
