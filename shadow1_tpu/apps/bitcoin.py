@@ -198,7 +198,7 @@ def on_wakeup(st, ctx, ev, mask):
         snd_una, app_end = r.g("snd_una"), r.g("app_end")
         buffered = (app_end - snd_una) - (snd_una == 0).astype(jnp.int32)
         fits = (ctx.params.sndbuf - buffered) >= nbytes
-        mq_ok = ~r.g("mq_valid").all(axis=0)
+        mq_ok = T.mq_room(st.model.tcp, sock, ctx.params.msgq_cap)
         can = tx & fits & mq_ok
         retry = tx & ~can
         st, _acc = T.tcp_send(st, ctx, can, sock, nbytes, meta, ev.time)

@@ -90,7 +90,13 @@ import jax
 #      fleet: [E]), the compacted round loop's trips, present only where a
 #      compact_cap is in force (core/compact.py). A snapshot of a run
 #      without a cap is leaf for leaf a v15 one.
-CKPT_FORMAT = 16
+#  17: the message-boundary queue is one pool a host: the TCP dict's
+#      mq_valid / mq_end / mq_meta [MQ, S, H] leaves become mq_sock /
+#      mq_end / mq_meta [P, H] (tcp/tcp.py; P = EngineParams.mq_pool), and
+#      Metrics gains mq_max_fill / mq_overflow (the ring row their two
+#      columns). load_state's cap migration covers the pool as it covers
+#      ev_cap (tune/resize.resize_mq_pool). No older snapshot loads.
+CKPT_FORMAT = 17
 
 
 class CorruptCheckpointError(ValueError):
@@ -222,14 +228,17 @@ def load_state(template, path: str, migrate_caps: bool = True):
         # migrate the event buffer / outbox onto the template's caps before
         # the strict per-leaf validation below.
         st = jax.tree_util.tree_unflatten(treedef, leaves)
+        from shadow1_tpu.tune.resize import mq_pool_of, resize_state
+
         ev_cap = np.asarray(template.evbuf.kind).shape[-2]
         ob_cap = np.asarray(template.outbox.dst).shape[-2]
+        mq_pool = mq_pool_of(template)
         if (np.asarray(st.evbuf.kind).shape[-2] != ev_cap
-                or np.asarray(st.outbox.dst).shape[-2] != ob_cap):
-            from shadow1_tpu.tune.resize import resize_state
-
+                or np.asarray(st.outbox.dst).shape[-2] != ob_cap
+                or mq_pool_of(st) != mq_pool):
             try:
-                st = resize_state(st, ev_cap=ev_cap, outbox_cap=ob_cap)
+                st = resize_state(st, ev_cap=ev_cap, outbox_cap=ob_cap,
+                                  msgq_pool=mq_pool)
             except ValueError as e:
                 raise ValueError(
                     f"checkpoint {path} cannot migrate onto this engine's "

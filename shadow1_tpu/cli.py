@@ -1684,6 +1684,7 @@ def main(argv=None) -> int:
             "ev_cap": params.ev_cap,
             "outbox_cap": params.outbox_cap,
             "compact_cap": params.compact_cap,
+            "msgq_pool": params.mq_pool,
         },
         "metrics": {k: int(v) for k, v in metrics.items()},
     }

@@ -204,6 +204,15 @@ class EngineParams:
     sockets_per_host: int = 16
     # Per-socket in-flight message-boundary FIFO capacity.
     msgq_cap: int = 32
+    # Per-HOST pool of message-boundary slots that the host's sockets share
+    # (tcp/tcp.py: mq_sock / mq_end / mq_meta [P, H]). msgq_cap bounds what
+    # one socket may hold (the reference's rule); this bounds what the host
+    # holds at once, and is a capacity like ev_cap: a boundary that finds
+    # the pool full is dropped and counted (mq_overflow; parity needs 0),
+    # the high-water is the mq_max_fill gauge. 0 = derived (``mq_pool``):
+    # twice the larger of the two widths and at least 64, but never more
+    # than sockets_per_host * msgq_cap, which no host can exceed.
+    msgq_pool: int = 0
     # Max packets a single handler invocation may emit before it must yield
     # (schedules K_TX_RESUME at the same timestamp to continue).
     send_burst: int = 4
@@ -318,8 +327,20 @@ class EngineParams:
     rto_init: int = 1 * SEC
     dupack_thresh: int = 3
 
+    @property
+    def mq_pool(self) -> int:
+        """The message-boundary pool's slots a host (msgq_pool, derived
+        where 0)."""
+        s, q = self.sockets_per_host, self.msgq_cap
+        return self.msgq_pool or min(s * q, max(64, 2 * max(s, q)))
+
+    def cap(self, knob: str) -> int:
+        """A capacity knob's value in force (a derived one resolved)."""
+        return self.mq_pool if knob == "msgq_pool" else getattr(self, knob)
+
     def __post_init__(self):
         assert self.sockets_per_host <= 256, "sock ids are packed into 8 bits"
+        assert self.msgq_pool >= 0, self.msgq_pool
         assert self.metrics_ring >= 0, self.metrics_ring
         assert self.state_digest in (0, 1), self.state_digest
         assert self.link_telem in (0, 1), self.link_telem

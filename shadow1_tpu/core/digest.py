@@ -137,7 +137,7 @@ def digest_outbox(ob, hosts) -> jnp.ndarray:
 
 def digest_tcp(tcp: dict, hosts) -> jnp.ndarray:
     """Live sockets (st != TCP_FREE): every semantic field in canonical
-    order, plus the socket's valid message-boundary FIFO entries (summed
+    order, plus the socket's pending message boundaries (summed
     positionlessly — retirement order is ack-driven on both engines)."""
     from shadow1_tpu.core.events import tb_join
 
@@ -149,10 +149,15 @@ def digest_tcp(tcp: dict, hosts) -> jnp.ndarray:
     fields += [tb_join(tcp[f + "_hi"], tcp[f + "_lo"]) for f in TCP_FIELDS_I64]
     fields += [tcp[f] for f in TCP_FIELDS_BOOL]
     total = _masked_sum(_words(SEED_TCP, fields), live)
-    mq_mask = tcp["mq_valid"] & live[None, :, :]
+    # The host pool's slots [P, H], each keyed by the socket it names; a
+    # slot counts where that socket is live (a one-hot read of ``live`` at
+    # mq_sock, no gather: a free slot's −1 matches no socket).
+    mq_sock = tcp["mq_sock"]
+    mq_mask = ((mq_sock[:, None, :] == socks[None, :, :])
+               & live[None, :, :]).any(axis=1)
     mq_fields = [
-        jnp.broadcast_to(hosts[None, None, :], tcp["mq_valid"].shape),
-        jnp.broadcast_to(socks[None, :, :], tcp["mq_valid"].shape),
+        jnp.broadcast_to(hosts[None, :], mq_sock.shape),
+        mq_sock,
         tcp["mq_end"],
         tcp["mq_meta"],
     ]

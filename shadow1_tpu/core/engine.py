@@ -90,6 +90,11 @@ class Metrics(NamedTuple):
                                   # compact_cap), recorded compaction on OR
                                   # off so the knob can be sized before it
                                   # is enabled
+    mq_max_fill: jnp.ndarray      # busiest host's message boundaries in its
+                                  # pool (vs mq_pool; tcp/tcp.py), 0 without
+                                  # TCP
+    mq_overflow: jnp.ndarray      # boundaries dropped: full pool (parity
+                                  # needs 0, like ev_overflow)
     down_events: jnp.ndarray     # events discarded: host stopped (churn)
     down_pkts: jnp.ndarray       # packets dropped: destination host stopped
     nic_tx_drops: jnp.ndarray    # packets dropped: NIC uplink queue full
@@ -953,6 +958,15 @@ def window_phases(ctx: Ctx, handlers: dict, exchange=None, pre_window=None,
         st = fr.st
         ev_fill = evbuf_fill(st.evbuf)
         m = st.metrics
+        tcp = getattr(st.model, "tcp", None)
+        if tcp is not None:
+            # The boundary pool's fill, sampled with the event slots' (the
+            # pending boundaries at a window's end are engine-independent
+            # as the pending events are: the oracle mirrors the gauge).
+            from shadow1_tpu.tcp.tcp import mq_fill
+
+            m = m._replace(mq_max_fill=jnp.maximum(m.mq_max_fill,
+                                                   mq_fill(tcp)))
         st = st._replace(
             win_start=fr.win_end,
             metrics=m._replace(

@@ -32,6 +32,7 @@ from shadow1_tpu.consts import (
     packet_tb,
 )
 from shadow1_tpu.cpu_engine.rngcache import DrawCache
+from shadow1_tpu.txn import STATE_CAP_CHECKS
 
 
 class CpuEngine:
@@ -112,6 +113,10 @@ class CpuEngine:
             # gauges bit-exactly on overflow-free runs.
             "ev_max_fill": 0,
             "ob_max_fill": 0,
+            # The boundary pool (tcp/tcp.py): a host's Σ len(k.mq) at the
+            # same boundaries; the oracle has no pool to fill, so 0 drops.
+            "mq_max_fill": 0,
+            "mq_overflow": 0,
             # Wasted-work accounting (performance attribution plane):
             # running sums of the per-window boundary samples, mirroring
             # core/engine.window_phases ph_prepare/deliver_window. Same
@@ -143,7 +148,7 @@ class CpuEngine:
         # retry run against the oracle run at the final caps instead).
         self._halt_on_overflow = self.params.on_overflow == "halt"
         self._selfcheck = bool(self.params.selfcheck)
-        self._of_seen = {"ev_overflow": 0, "ob_overflow": 0}
+        self._of_seen = {c: 0 for c, _, _ in STATE_CAP_CHECKS}
         self._ev_dg = 0
         self._ev_word: dict[int, int] = {}  # gseq → element word
         self._ob_dg: dict[int, int] = {}    # window → send-word sum
@@ -401,6 +406,10 @@ class CpuEngine:
         fill = int(self.pending.max()) if self.pending.size else 0
         if fill > self.metrics["ev_max_fill"]:
             self.metrics["ev_max_fill"] = fill
+        mq_n = getattr(self.model, "mq_n", None)
+        if mq_n is not None:
+            self.metrics["mq_max_fill"] = max(self.metrics["mq_max_fill"],
+                                              int(mq_n.max()))
         if not self.digest_on and not self.work_on and not self.probe_on:
             n_skipped = (upto - self._next_boundary) // self.window + 1
             self._next_boundary += n_skipped * self.window
@@ -498,13 +507,11 @@ class CpuEngine:
             from shadow1_tpu.tune.ladder import next_step, recommend_cap
             from shadow1_tpu.txn import CapacityExceededError
 
-            for ctr, knob, gauge in (
-                    ("ev_overflow", "ev_cap", "ev_max_fill"),
-                    ("ob_overflow", "outbox_cap", "ob_max_fill")):
+            for ctr, knob, gauge in STATE_CAP_CHECKS:
                 fresh = self.metrics[ctr] - self._of_seen[ctr]
                 self._of_seen[ctr] = self.metrics[ctr]
                 if fresh > 0:
-                    cap = getattr(self.params, knob)
+                    cap = self.params.cap(knob)
                     peak = int(self.metrics.get(gauge, 0))
                     raise CapacityExceededError(
                         knob=knob, counter=ctr, cap=cap, overflow=fresh,

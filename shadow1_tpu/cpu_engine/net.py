@@ -154,6 +154,10 @@ class CpuNetModel:
         self.socks = [
             [CpuSock() for _ in range(self.pr.sockets_per_host)] for _ in range(h)
         ]
+        # Message boundaries a host holds over all its sockets, Σ len(k.mq):
+        # what the batched engines keep in one pool a host (tcp/tcp.py); the
+        # engine samples its max at window boundaries (mq_max_fill).
+        self.mq_n = np.zeros(h, np.int64)
         for k in ("tcp_fast_rtx", "tcp_rto", "tcp_ooo_drops"):
             eng.metrics[k] = 0
         name = eng.exp.model_cfg["app"]
@@ -358,8 +362,13 @@ class CpuNetModel:
     def listen(self, h, s):
         self.socks[h][s].st = TCP_LISTEN
 
+    def _init_conn(self, h, s, peer_host, peer_sock, state, rcv_nxt):
+        k = self.socks[h][s]
+        self.mq_n[h] -= len(k.mq)
+        k.init_conn(self.pr, peer_host, peer_sock, state, rcv_nxt)
+
     def connect(self, h, s, dst_host, dst_sock, now):
-        self.socks[h][s].init_conn(self.pr, dst_host, dst_sock, TCP_SYN_SENT, 0)
+        self._init_conn(h, s, dst_host, dst_sock, TCP_SYN_SENT, 0)
         self.flush(h, s, now)
 
     def tcp_send(self, h, s, nbytes, meta, now) -> int:
@@ -372,6 +381,7 @@ class CpuNetModel:
             k.app_end = seq_add(k.app_end, accepted)
             if accepted == nbytes and meta != 0 and len(k.mq) < pr.msgq_cap:
                 k.mq.append((k.app_end, meta))
+                self.mq_n[h] += 1
             self.flush(h, s, now)
         return accepted
 
@@ -449,7 +459,7 @@ class CpuNetModel:
                 None,
             )
             if not dup and child is not None:
-                socks[child].init_conn(pr, src, ss, TCP_SYN_RCVD, 1)
+                self._init_conn(h, child, src, ss, TCP_SYN_RCVD, 1)
                 socks[child].peer_wnd = wnd
                 self.flush(h, child, now)
             return
@@ -493,7 +503,9 @@ class CpuNetModel:
             if seq_lt(k.snd_nxt, ackno):
                 k.snd_nxt = ackno  # acked bytes were sent pre-rewind
             k.dupacks = 0
+            n_mq = len(k.mq)
             k.mq = [(e, m) for (e, m) in k.mq if seq_lt(ackno, e)]
+            self.mq_n[h] -= n_mq - len(k.mq)
             outstanding = seq_lt(ackno, snd_max0)
             k.rtx_t = (now + k.rto) if outstanding else 0
             if state == TCP_SYN_RCVD:

@@ -193,21 +193,19 @@ def _check_halt(engine, plan_labels, per_exp, prev_per_exp, done, step):
     """Per-experiment overflow halt: the first lane with fresh overflow
     raises a CapacityExceededError that names it (``lanes`` carries the
     local index for the quarantine policy)."""
-    from shadow1_tpu.txn import CapacityExceededError
+    from shadow1_tpu.txn import STATE_CAP_CHECKS, CapacityExceededError
     from shadow1_tpu.tune.ladder import recommend_cap
 
-    checks = (("ev_overflow", "ev_cap", "ev_max_fill"),
-              ("ob_overflow", "outbox_cap", "ob_max_fill"))
     for e, m in enumerate(per_exp):
         prev = prev_per_exp[e] if prev_per_exp else {}
-        for counter, knob, gauge in checks:
+        for counter, knob, gauge in STATE_CAP_CHECKS:
             fresh = int(m.get(counter, 0)) - int(prev.get(counter, 0))
             if fresh > 0:
                 label = plan_labels[e] if plan_labels else {"exp": e}
                 gv = int(m.get(gauge, 0))
                 raise CapacityExceededError(
                     knob=knob, counter=counter,
-                    cap=getattr(engine.params, knob), overflow=fresh,
+                    cap=engine.params.cap(knob), overflow=fresh,
                     window_range=(done, done + step),
                     recommended=recommend_cap(gv) if gv else None,
                     detail=(f" (fleet experiment {label.get('exp', e)}, "
@@ -256,7 +254,8 @@ def lane_record(engine, st, i: int, label: dict, windows: int,
         "window_ns": engine.window,
         "windows": windows,
         "caps": {"ev_cap": params.ev_cap, "outbox_cap": params.outbox_cap,
-                 "compact_cap": params.compact_cap},
+                 "compact_cap": params.compact_cap,
+                 "msgq_pool": params.mq_pool},
         "metrics": m,
         "model": model,
         "drops": {"total": sum(drops.values()), **drops},
@@ -668,7 +667,8 @@ def final_records(engine, st, labels, n_windows, wall, resumed=False,
         "events_per_exp": [int(m["events"]) for m in per_exp],
         "resumed": bool(resumed),
         "caps": {"ev_cap": params.ev_cap, "outbox_cap": params.outbox_cap,
-                 "compact_cap": params.compact_cap},
+                 "compact_cap": params.compact_cap,
+                 "msgq_pool": params.mq_pool},
         "metrics": agg,
     }
     if recovery:

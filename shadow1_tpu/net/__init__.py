@@ -76,7 +76,7 @@ def _app_module(name: str):
 def init(ctx, evbuf):
     pr = ctx.params
     nic = nic_init(ctx.n_hosts)
-    tcpd = T.tcp_init(ctx.n_hosts, pr.sockets_per_host, pr.msgq_cap, pr)
+    tcpd = T.tcp_init(ctx.n_hosts, pr.sockets_per_host, pr.mq_pool)
     app_mod = _app_module(ctx.model_cfg["app"])
     app, evbuf, over, tcpd = app_mod.init(ctx, evbuf, tcpd)
     return NetState(nic=nic, tcp=tcpd, app=app), evbuf, over

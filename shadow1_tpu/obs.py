@@ -176,11 +176,12 @@ class Heartbeat:
         fill = {}
         for gauge, cap_field in (("ev_max_fill", "ev_cap"),
                                  ("ob_max_fill", "outbox_cap"),
-                                 ("compact_max_fill", "compact_cap")):
+                                 ("compact_max_fill", "compact_cap"),
+                                 ("mq_max_fill", "msgq_pool")):
             if delta.pop(gauge, 0) or m.get(gauge):
                 fill[gauge] = m.get(gauge)
                 if params is not None:
-                    fill[cap_field] = getattr(params, cap_field)
+                    fill[cap_field] = params.cap(cap_field)
         if fill:
             rec["fill"] = fill
         # Exchange occupancy (sharded engine): how close the busiest

@@ -463,6 +463,7 @@ class ShardedEngine:
                 ev_max_fill=pmax_(st.metrics.ev_max_fill),
                 ob_max_fill=pmax_(st.metrics.ob_max_fill),
                 compact_max_fill=pmax_(st.metrics.compact_max_fill),
+                mq_max_fill=pmax_(st.metrics.mq_max_fill),
             ))
 
         def run(st: SimState, n_windows) -> SimState:

@@ -18,7 +18,12 @@ Deliberate model simplifications vs the reference (docs/SEMANTICS.md §tcp):
 * Immediate ACKs (no delayed-ACK timer).
 * Byte counts only — payload contents are never materialized (apps are
   models); message boundaries ride packets as (end_seq, meta) pairs, at
-  most one per segment.
+  most one per segment. A host's pending boundaries live in ONE pool
+  ``[P, H]`` (``mq_sock`` −1 = free slot, ``mq_end``, ``mq_meta``;
+  P = ``EngineParams.mq_pool``): a socket's queue is the slots that name it,
+  in no order (every use is a set operation). ``msgq_cap`` stays the
+  reference's per-socket bound (``len(k.mq) < msgq_cap``); a boundary that
+  finds the pool full is dropped and counted (``Metrics.mq_overflow``).
 
 Sequence space: u32 wrapping (i32 arrays, natural overflow). ISN = 0: SYN
 occupies seq 0, stream byte k is seq 1+k, FIN occupies the seq after the
@@ -106,7 +111,7 @@ _I64_SET = frozenset(_FIELDS_I64)
 _FIELDS_BOOL = ("timer_armed", "ts_act")
 
 
-def tcp_init(n_hosts: int, n_socks: int, mq_cap: int, params) -> dict:
+def tcp_init(n_hosts: int, n_socks: int, mq_pool: int) -> dict:
     zhi, zlo = tb_split(jnp.zeros((), jnp.int64))
     d = {}
     for f in _FIELDS_I32:
@@ -116,10 +121,28 @@ def tcp_init(n_hosts: int, n_socks: int, mq_cap: int, params) -> dict:
         d[f + "_lo"] = jnp.full((n_socks, n_hosts), zlo, jnp.int32)
     for f in _FIELDS_BOOL:
         d[f] = jnp.zeros((n_socks, n_hosts), bool)
-    d["mq_valid"] = jnp.zeros((mq_cap, n_socks, n_hosts), bool)
-    d["mq_end"] = jnp.zeros((mq_cap, n_socks, n_hosts), jnp.int32)
-    d["mq_meta"] = jnp.zeros((mq_cap, n_socks, n_hosts), jnp.int32)
+    d["mq_sock"] = jnp.full((mq_pool, n_hosts), -1, jnp.int32)
+    d["mq_end"] = jnp.zeros((mq_pool, n_hosts), jnp.int32)
+    d["mq_meta"] = jnp.zeros((mq_pool, n_hosts), jnp.int32)
     return d
+
+
+def mq_of(tcp: dict, sock) -> jnp.ndarray:
+    """bool [P, H]: the pool slots that hold a boundary of socket
+    ``sock[h]`` (a real socket id, 0..S-1, wherever the caller's mask
+    holds; free slots read −1 and match none)."""
+    return tcp["mq_sock"] == sock[None, :]
+
+
+def mq_room(tcp: dict, sock, msgq_cap: int) -> jnp.ndarray:
+    """bool [H]: socket ``sock[h]`` holds fewer than ``msgq_cap``
+    boundaries — the reference's ``len(k.mq) < msgq_cap``."""
+    return mq_of(tcp, sock).sum(axis=0, dtype=jnp.int32) < msgq_cap
+
+
+def mq_fill(tcp: dict) -> jnp.ndarray:
+    """The busiest host's boundaries in the pool (vs ``mq_pool``)."""
+    return (tcp["mq_sock"] >= 0).sum(axis=0, dtype=jnp.int64).max()
 
 
 class Sock:
@@ -242,6 +265,7 @@ from shadow1_tpu.core.engine import push_local_event as _push_local  # noqa: E40
 # Flush: packetize [snd_nxt, limit) — data, SYN, FIN — up to send_burst segs.
 # --------------------------------------------------------------------------
 _SENDABLE = TCP_SENDABLE_STATES
+_FAR = 2**31 - 1  # no boundary: farther than any segment reaches
 
 
 def _state_in(state, states):
@@ -300,7 +324,12 @@ def _tcp_flush(st, ctx, mask, sock, now):
     rcv_nxt = g("rcv_nxt")
     peer_host, peer_sock = g("peer_host"), g("peer_sock")
     rto = g64("rto")
-    mqv, mqe, mqm = g("mq_valid"), g("mq_end"), g("mq_meta")  # [MQ, H]
+    # The socket's boundaries as distances past the flush's first byte,
+    # [P, H], built ONCE a flush (u32 wrap: a lane's distance is this less
+    # its advance, exactly); a slot of another socket or none reads _FAR.
+    mq_d0 = jnp.where(mq_of(tcp, jnp.where(mask, sock, 0)),
+                      tcp["mq_end"] - nxt0[None, :], _FAR)
+    mqm = tcp["mq_meta"]
     is_synrcvd = state == TCP_SYN_RCVD
 
     # --- burst recurrence: cheap per-lane arithmetic, heavy ops deferred ---
@@ -342,18 +371,16 @@ def _tcp_flush(st, ctx, mask, sock, now):
         )
         # Message boundary riding this segment (truncating segmentation —
         # see tcp_send): min mq end in (nxt, nxt+len].
-        seg_hi = nxt + length
-        inrange = (
-            mqv & ((mqe - nxt[None, :]) > 0) & ((mqe - seg_hi[None, :]) <= 0)
-        )
-        has_m = seg_data & inrange.any(axis=0)
-        dist = jnp.where(inrange, mqe - nxt[None, :], jnp.int32(2**31 - 1))
+        rel = mq_d0 - (nxt - nxt0)[None, :]
+        inrange = (rel > 0) & (rel <= length[None, :])
+        dist = jnp.where(inrange, rel, _FAR)
         # Nearest boundary via min-reduce + equality one-hot (no argmin —
-        # core/dense.py). Ends are distinct while valid, so `near` is
-        # one-hot among inrange slots.
+        # core/dense.py). A socket's ends are distinct while valid, so
+        # `near` is one-hot among inrange slots.
         dmin = dist.min(axis=0)
+        has_m = seg_data & (dmin != _FAR)
         near = inrange & (dist == dmin[None, :])
-        mend = jnp.where(has_m, extract_col(near, mqe), 0)
+        mend = jnp.where(has_m, nxt + dmin, 0)
         mmeta = jnp.where(has_m, extract_col(near, mqm), 0)
         length = jnp.where(has_m, dmin, length)
         # NIC uplink reservation per lane — tx_stamp itself (pure [H]-vector
@@ -531,8 +558,8 @@ def _init_conn(r: Sock, ctx, mask, peer_host, peer_sock, state, rcv_nxt):
     r.s("recover", 0, mask)
     r.s("ts_act", False, mask)
     r.s("txr", 0, mask)
-    mq = jnp.where(mask[None, :], False, r.g("mq_valid"))
-    r.s("mq_valid", mq, mask)
+    r.d["mq_sock"] = jnp.where(
+        mq_of(r.d, r.sock) & mask[None, :], -1, r.d["mq_sock"])
 
 
 def tcp_connect(st, ctx, mask, sock, dst_host, dst_sock, now):
@@ -557,20 +584,24 @@ def tcp_send(st, ctx, mask, sock, nbytes, meta, now):
     r.s("app_end", new_end, accepted > 0)
     # Message boundary bookkeeping.
     want_meta = mask & (accepted > 0) & (accepted == nbytes) & (jnp.asarray(meta, jnp.int32) != 0)
-    mqv = r.g("mq_valid")                       # [MQ, H]
-    has_free, slot = first_true_idx(~mqv)
-    ok = want_meta & has_free
-    # Dense (slot, sock, host) one-hot write — no 3D scatter (core/dense.py).
-    sel = (
-        onehot_col(slot, mqv.shape[0])[:, None, :]
-        & onehot_col(r.sock, r.S, ok)[None, :, :]
-    )
-    r.d["mq_valid"] = r.d["mq_valid"] | sel
-    r.d["mq_end"] = jnp.where(sel, new_end[None, None, :], r.d["mq_end"])
+    # The reference's rule is per socket (len(k.mq) < msgq_cap); the slot
+    # is the host pool's first free one. A boundary that has the socket's
+    # leave and finds the pool full is dropped and counted, as a full
+    # ev_cap drops an event (mq_pool is a capacity: docs/SEMANTICS.md).
+    want_meta = want_meta & mq_room(r.d, r.sock, pr.msgq_cap)
+    has_free, slot = first_true_idx(r.d["mq_sock"] < 0)
+    sel = onehot_col(slot, r.d["mq_sock"].shape[0], want_meta & has_free)
+    r.d["mq_sock"] = jnp.where(
+        sel, jnp.asarray(r.sock, jnp.int32)[None, :], r.d["mq_sock"])
+    r.d["mq_end"] = jnp.where(sel, new_end[None, :], r.d["mq_end"])
     r.d["mq_meta"] = jnp.where(
-        sel, jnp.asarray(meta, jnp.int32)[None, None, :], r.d["mq_meta"]
+        sel, jnp.asarray(meta, jnp.int32)[None, :], r.d["mq_meta"]
     )
-    st = st._replace(model=st.model._replace(tcp=r.d))
+    met = st.metrics
+    st = st._replace(
+        model=st.model._replace(tcp=r.d),
+        metrics=met._replace(mq_overflow=met.mq_overflow
+                             + (want_meta & ~has_free).sum(dtype=jnp.int64)))
     st = tcp_flush(st, ctx, mask & (accepted > 0), sock, now)
     return st, accepted
 
@@ -699,8 +730,9 @@ def tcp_rx(st, ctx, mask, p, now):
     r.s("snd_nxt", ackno, new_ack & ((ackno - snd_nxt) > 0))
     r.s("dupacks", 0, new_ack)
     # Retire message boundaries the peer has fully acked.
-    keep = r.g("mq_valid") & ((r.g("mq_end") - ackno[None, :]) > 0)
-    r.s("mq_valid", keep, new_ack)
+    acked = (mq_of(r.d, ds) & new_ack[None, :]
+             & ((r.d["mq_end"] - ackno[None, :]) <= 0))
+    r.d["mq_sock"] = jnp.where(acked, -1, r.d["mq_sock"])
     # Restart (or clear) the retransmit deadline.
     outstanding = (snd_max - ackno) > 0
     r.s("rtx_t", jnp.where(outstanding, now + r.g("rto"), 0), new_ack)

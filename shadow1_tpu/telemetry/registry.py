@@ -54,6 +54,9 @@ METRIC_SPECS: dict[str, tuple[str, str]] = {
     "compact_max_fill": (GAUGE, "high-water window active-host count: demanded "
                                 "compaction-bucket lanes (vs compact_cap; "
                                 "per-shard block count under sharding)"),
+    "mq_max_fill": (GAUGE, "high-water window-end message boundaries a host "
+                           "holds in its pool (vs mq_pool)"),
+    "mq_overflow": (COUNTER, "message boundaries dropped: full host pool"),
     "down_events": (COUNTER, "events discarded: host stopped (churn)"),
     "down_pkts": (COUNTER, "packets dropped: destination host stopped"),
     "nic_tx_drops": (COUNTER, "packets dropped: NIC uplink queue full"),
@@ -345,6 +348,7 @@ MODEL_TOTALS: dict[str, str] = {
 DROP_SPECS: dict[str, str] = {
     "ev_overflow": "event buffer full",
     "ob_overflow": "outbox full",
+    "mq_overflow": "message-boundary pool full",
     "x2x_overflow": "all_to_all bucket full (sharded)",
     "nic_tx_drops": "NIC uplink queue full",
     "nic_rx_drops": "NIC downlink queue full",
@@ -366,7 +370,7 @@ DROP_FIELDS = tuple(DROP_SPECS)
 RING_COUNTERS = (
     "events", "rounds", "pkts_sent", "pkts_delivered", "pkts_lost",
     "ev_overflow", "ob_overflow", "x2x_overflow", "down_events", "down_pkts",
-    "link_down_pkts", "host_restarts",
+    "link_down_pkts", "host_restarts", "mq_overflow",
 )
 # Wasted-work accounting columns (performance attribution plane): per-window
 # DELTAS of the matching METRIC_SPECS counters, i.e. the window's boundary
@@ -387,6 +391,7 @@ RING_GAUGES = (
     "ev_max_fill",      # running high-water of evbuf_fill (vs ev_cap)
     "ob_max_fill",      # running high-water per-window outbox fill
     "compact_max_fill", # running high-water compaction-bucket demand
+    "mq_max_fill",      # running high-water boundary-pool fill (vs mq_pool)
     "x2x_max_fill",     # running high-water all_to_all bucket demand
 )
 # Determinism flight recorder (core/digest.py, EngineParams.state_digest):

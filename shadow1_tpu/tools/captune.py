@@ -10,7 +10,10 @@ measured peak occupancy of every bounded structure:
   per-window ``evbuf_fill`` plus the running ``*_max_fill`` gauges;
 * heartbeat JSONL (``type: "heartbeat"``): the ``fill`` block with the caps
   it was measured against;
-* the CLI's final stdout JSON (``{"metrics": ..., "caps": ...}``);
+* the CLI's final stdout JSON (``{"metrics": ..., "caps": ...}``), of any
+  engine: the CPU oracle's (``--engine cpu``) carries the same gauges, so
+  ``msgq_pool`` (the host's message-boundary pool, against ``mq_max_fill``)
+  and ``ev_cap`` can be sized from an oracle run with no accelerator;
 * ``tools/occprobe.py`` audit rows (``boundary_peak_occupancy``/``ev_cap``).
 
 It then prints, per knob, the measured peak, the configured cap (when the
@@ -38,6 +41,7 @@ import json
 import sys
 
 from shadow1_tpu.tune.ladder import HEADROOM, classify, recommend_cap
+from shadow1_tpu.txn import OVERFLOW_KNOBS
 
 # knob → (peak sources, cap key) in priority order. ``evbuf_fill`` (the
 # per-window series) and ``ev_max_fill`` (its running max) measure the same
@@ -46,6 +50,7 @@ _KNOBS = {
     "ev_cap": ("ev_max_fill", "evbuf_fill", "boundary_peak_occupancy"),
     "outbox_cap": ("ob_max_fill",),
     "compact_cap": ("compact_max_fill",),
+    "msgq_pool": ("mq_max_fill",),
     "x2x_cap": ("x2x_max_fill",),
 }
 
@@ -97,8 +102,7 @@ def peaks_from_records(recs: list[dict]) -> tuple[dict, dict, dict]:
     # cumulative counters of metrics/occprobe records. Accumulate each
     # channel separately and take the max, so any one of them suffices and
     # their redundancy never double-counts into a bogus total.
-    _CTRS = (("ev_overflow", "ev_cap"), ("ob_overflow", "outbox_cap"),
-             ("x2x_overflow", "x2x_cap"))
+    _CTRS = tuple(OVERFLOW_KNOBS.items())
     ring_sum = {k: 0 for _, k in _CTRS}
     hb_sum = {k: 0 for _, k in _CTRS}
     cum_max = {k: 0 for _, k in _CTRS}

@@ -293,11 +293,11 @@ class BatchSide(Side):
                         tb_join(t[f + "_hi"], t[f + "_lo"]))[s, hh])
                 d.update({f: bool(np.asarray(t[f])[s, hh])
                           for f in TCP_FIELDS_BOOL})
-                mqv = np.asarray(t["mq_valid"])[:, s, hh]
+                mine = np.nonzero(np.asarray(t["mq_sock"])[:, hh] == s)[0]
                 d["mq"] = sorted(
-                    (int(np.asarray(t["mq_end"])[q, s, hh]) & 0xFFFFFFFF,
-                     int(np.asarray(t["mq_meta"])[q, s, hh]) & 0xFFFFFFFF)
-                    for q in np.nonzero(mqv)[0]
+                    (int(np.asarray(t["mq_end"])[q, hh]) & 0xFFFFFFFF,
+                     int(np.asarray(t["mq_meta"])[q, hh]) & 0xFFFFFFFF)
+                    for q in mine
                 )
                 tcp[(int(hh), int(s))] = d
         return {"evbuf": ev, "rng": rng, "nic": nic, "tcp": tcp}

@@ -24,6 +24,12 @@ an engine compiled at the new static shape. Engines are cached per cap
 pair and caps are ladder-quantized (tune/ladder.py), so total recompiles
 are bounded by the ladder span, not the chunk count.
 
+``msgq_pool`` (the host's message-boundary pool, tcp/tcp.py) is GROWN the
+same way, from ``mq_max_fill`` and the ``mq_overflow`` backstop, and never
+shrunk: its default is derived from two widths the file states, a smaller
+pool is an offline choice (tools/captune.py), and a run without TCP has no
+pool (gauge 0: no decision).
+
 ``outbox_cap`` tuning is OFF by default: outbox space is a semantic knob
 for TCP (tcp_flush paces sends on ``outbox_space``; the CPU oracle honours
 the same bound), so resizing it mid-run changes the event stream. Enable
@@ -75,7 +81,7 @@ class CapController:
         self.policy = policy or CapPolicy()
         self._make_engine = make_engine
         self._engines = {self._key(engine.params): engine}
-        self._low_chunks = {"ev_cap": 0, "outbox_cap": 0}
+        self._low_chunks = {"ev_cap": 0, "outbox_cap": 0, "msgq_pool": 0}
         # Overflow backstop baselines (cumulative counters at last check).
         # A RESUMED state carries its pre-snapshot history in the cumulative
         # counters; baseline from it (``initial_state``) so a respawn does
@@ -88,19 +94,22 @@ class CapController:
                        if initial_state is not None else 0),
             "outbox_cap": (_total(initial_state.metrics.ob_overflow)
                            if initial_state is not None else 0),
+            "msgq_pool": (_total(initial_state.metrics.mq_overflow)
+                          if initial_state is not None else 0),
         }
         # Lossless floor: once a cap has overflowed, shrinking back to it
         # would just re-drop events — the shrink target ratchets above the
         # largest cap ever seen lossy (prevents grow/shrink oscillation on
         # workloads whose mid-window bursts hide from the window-end gauge).
         self._floor = {"ev_cap": self.policy.min_cap,
-                       "outbox_cap": self.policy.min_cap}
+                       "outbox_cap": self.policy.min_cap,
+                       "msgq_pool": self.policy.min_cap}
         self.resizes: list[dict] = []   # audit log (CLI output / tests)
         self._log = log
 
     @staticmethod
     def _key(params):
-        return (params.ev_cap, params.outbox_cap)
+        return (params.ev_cap, params.outbox_cap, params.mq_pool)
 
     def _engine_for(self, params):
         k = self._key(params)
@@ -185,14 +194,22 @@ class CapController:
             new_ob = self._overflow_grow("outbox_cap",
                                          _total(st.metrics.ob_overflow),
                                          params.outbox_cap, new_ob)
-        if (new_ev, new_ob) == (params.ev_cap, params.outbox_cap):
+        # The boundary pool only grows (module docstring): the decision's
+        # shrink side is cut off at the pool in force.
+        pool = params.mq_pool
+        mq_hw = _peak(st.metrics.mq_max_fill)
+        new_mq = max(pool, self._decide("msgq_pool", mq_hw, pool))
+        new_mq = self._overflow_grow(
+            "msgq_pool", _total(st.metrics.mq_overflow), pool, new_mq)
+        if (new_ev, new_ob, new_mq) == (params.ev_cap, params.outbox_cap,
+                                        pool):
             return engine, st
         from shadow1_tpu.tune.resize import resize_state
 
         host_st = jax.tree.map(np.asarray, st)
-        host_st = resize_state(host_st, ev_cap=new_ev, outbox_cap=new_ob)
+        host_st = resize_state(host_st, ev_cap=new_ev, outbox_cap=new_ob,
+                               msgq_pool=new_mq)
         new_params = _dc.replace(params, ev_cap=new_ev, outbox_cap=new_ob)
-        new_engine = self._engine_for(new_params)
         rec = {
             "windows_done": _peak(st.metrics.windows),
             "ev_cap": [params.ev_cap, new_ev],
@@ -200,6 +217,10 @@ class CapController:
             "ev_max_fill": ev_hw,
             "ob_max_fill": ob_hw,
         }
+        if new_mq != pool:
+            new_params = _dc.replace(new_params, msgq_pool=new_mq)
+            rec.update(msgq_pool=[pool, new_mq], mq_max_fill=mq_hw)
+        new_engine = self._engine_for(new_params)
         self.resizes.append(rec)
         if self._log is not None:
             self._log("auto-caps resize", **rec)

@@ -126,14 +126,56 @@ def resize_outbox(ob, new_cap: int):
     return ob._replace(**planes)
 
 
-def resize_state(st, ev_cap: int | None = None, outbox_cap: int | None = None):
-    """SimState → SimState with the event buffer / outbox migrated. Leaves
-    come back as numpy; callers re-place on device (engine.place_state).
-    Metrics, model state, cpu_busy and the telemetry ring are capacity-
-    independent and pass through untouched."""
+_MQ_FREE = {"mq_sock": -1, "mq_end": 0, "mq_meta": 0}  # tcp.tcp_init's fill
+
+
+def mq_pool_of(st) -> int | None:
+    """The message-boundary pool's slots a host in ``st`` (tcp/tcp.py), or
+    None where the model has no TCP."""
+    tcp = getattr(st.model, "tcp", None)
+    return None if tcp is None else np.asarray(tcp["mq_sock"]).shape[-2]
+
+
+def resize_mq_pool(tcp: dict, new_cap: int) -> dict:
+    """The TCP dict (numpy leaves) with its boundary pool ``[P, H]`` at
+    ``new_cap`` slots. A socket's queue is the SET of slots that name it
+    (no use reads a slot's position), so a grow appends free slots and a
+    shrink moves each host's occupied slots to the front, order kept, and
+    truncates; it refuses where a host holds more than the new pool."""
+    sock = np.asarray(tcp["mq_sock"])
+    cap, new_cap = sock.shape[-2], int(new_cap)
+    if new_cap == cap:
+        return tcp
+    planes = {f: np.asarray(tcp[f]) for f in _MQ_FREE}
+    if new_cap < cap:
+        occupied = sock >= 0
+        n_occ = int(occupied.sum(axis=-2).max())
+        if n_occ > new_cap:
+            raise ValueError(
+                f"cannot shrink msgq_pool {cap} -> {new_cap}: a host holds "
+                f"{n_occ} message boundaries"
+            )
+        order = np.argsort(~occupied, axis=-2, kind="stable")
+        planes = {f: np.take_along_axis(x, order, axis=-2)[..., :new_cap, :]
+                  for f, x in planes.items()}
+    else:
+        planes = {f: _pad_rows(x, new_cap - cap, _MQ_FREE[f])
+                  for f, x in planes.items()}
+    return {**tcp, **planes}
+
+
+def resize_state(st, ev_cap: int | None = None, outbox_cap: int | None = None,
+                 msgq_pool: int | None = None):
+    """SimState → SimState with the event buffer / outbox / message-boundary
+    pool migrated. Leaves come back as numpy; callers re-place on device
+    (engine.place_state). Metrics, the rest of the model state, cpu_busy and
+    the telemetry ring are capacity-independent and pass through untouched."""
     repl = {}
     if ev_cap is not None and int(ev_cap) != st.evbuf.kind.shape[-2]:
         repl["evbuf"] = resize_evbuf(st.evbuf, ev_cap)
     if outbox_cap is not None and int(outbox_cap) != st.outbox.dst.shape[-2]:
         repl["outbox"] = resize_outbox(st.outbox, outbox_cap)
+    if msgq_pool is not None and mq_pool_of(st) not in (None, int(msgq_pool)):
+        repl["model"] = st.model._replace(
+            tcp=resize_mq_pool(st.model.tcp, msgq_pool))
     return st._replace(**repl) if repl else st

@@ -52,6 +52,7 @@ from shadow1_tpu.consts import EXIT_CAPACITY  # noqa: F401
 OVERFLOW_KNOBS: dict[str, str] = {
     "ev_overflow": "ev_cap",
     "ob_overflow": "outbox_cap",
+    "mq_overflow": "msgq_pool",
     "x2x_overflow": "x2x_cap",
 }
 
@@ -59,8 +60,17 @@ OVERFLOW_KNOBS: dict[str, str] = {
 _KNOB_GAUGE = {
     "ev_cap": "ev_max_fill",
     "outbox_cap": "ob_max_fill",
+    "msgq_pool": "mq_max_fill",
     "x2x_cap": "x2x_max_fill",
 }
+
+# (overflow counter, knob, gauge) of the caps that are SHAPES of the state
+# (every engine has them; the exchange bucket is the sharded engine's own):
+# what the per-lane halt (fleet/run.py) and the oracle's boundary check
+# (cpu_engine/engine.py) walk. A knob's value in force is
+# ``EngineParams.cap(knob)`` (msgq_pool is derived where 0).
+STATE_CAP_CHECKS = tuple((c, k, _KNOB_GAUGE[k])
+                         for c, k in OVERFLOW_KNOBS.items() if k != "x2x_cap")
 
 
 class CapacityExceededError(RuntimeError):
@@ -305,7 +315,7 @@ class OverflowGuard:
     def _engine_for(self, params):
         if self._controller is not None:
             return self._controller.engine_for(params)
-        key = (params.ev_cap, params.outbox_cap)
+        key = (params.ev_cap, params.outbox_cap, params.mq_pool)
         eng = self._engines.get(key)
         if eng is None:
             if self._make_engine is None:
@@ -345,7 +355,7 @@ class OverflowGuard:
                                              "its guaranteed-fit cap)")
                 rec["x2x_cap"] = [old, engine._x2x_cap]
                 continue
-            cap = getattr(params, knob)
+            cap = params.cap(knob)
             new = next_step(cap)
             if new > self.max_cap:
                 raise self._error(
@@ -365,7 +375,8 @@ class OverflowGuard:
             engine = self._engine_for(new_params)
             host_st = jax.tree.map(np.asarray, st0)
             host_st = resize_state(host_st, ev_cap=new_params.ev_cap,
-                                   outbox_cap=new_params.outbox_cap)
+                                   outbox_cap=new_params.outbox_cap,
+                                   msgq_pool=new_params.mq_pool)
             st0 = engine.place_state(host_st)
         self.resizes.append(rec)
         if self.on_engine_swap is not None:
@@ -382,7 +393,7 @@ class OverflowGuard:
         counter = max(fresh, key=lambda c: fresh[c])
         knob = OVERFLOW_KNOBS[counter]
         cap = (getattr(engine, "_x2x_cap", 0) if knob == "x2x_cap"
-               else getattr(engine.params, knob))
+               else engine.params.cap(knob))
         # max over lanes == the scalar on solo engines (gauges are maxes).
         peak = int(np.asarray(getattr(st.metrics, _KNOB_GAUGE[knob], 0)).max())
         rec = max(next_step(cap), recommend_cap(peak) if peak else 0)
@@ -399,6 +410,8 @@ class OverflowGuard:
     def final_caps(self) -> dict:
         caps = {"ev_cap": self.engine.params.ev_cap,
                 "outbox_cap": self.engine.params.outbox_cap}
+        if self.engine.params.msgq_pool:    # grown (or set): not the derived
+            caps["msgq_pool"] = self.engine.params.msgq_pool
         x2x = getattr(self.engine, "_x2x_cap", None)
         if x2x:
             caps["x2x_cap"] = x2x

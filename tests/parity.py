@@ -22,7 +22,8 @@ from shadow1_tpu.consts import EngineParams
 # split staying symmetric between engines).
 PARITY_KEYS = [
     "events", "pkts_sent", "pkts_delivered", "pkts_lost",
-    "ev_overflow", "ob_overflow", "tcp_fast_rtx", "tcp_rto", "tcp_ooo_drops",
+    "ev_overflow", "ob_overflow", "mq_overflow", "mq_max_fill",
+    "tcp_fast_rtx", "tcp_rto", "tcp_ooo_drops",
     "pops_pkt", "pops_deliver", "pops_timer", "pops_txr", "pops_app",
 ]
 
@@ -64,9 +65,11 @@ def assert_parity(cm, cs, tm, ts, keys=("rx_bytes", "flows_done", "done_time"),
     first: parity is only defined for overflow-free runs (which packets
     drop on overflow is layout-defined — docs/SEMANTICS.md)."""
     hint = _HINT.format(a=sides[0], b=sides[1])
-    assert tm["ev_overflow"] == 0 and tm["ob_overflow"] == 0, (
+    assert (tm["ev_overflow"] == 0 and tm["ob_overflow"] == 0
+            and tm.get("mq_overflow", 0) == 0), (
         f"overflow run: parity undefined (ev={tm['ev_overflow']}, "
-        f"ob={tm['ob_overflow']}) — raise the caps" + hint
+        f"ob={tm['ob_overflow']}, mq={tm.get('mq_overflow')}) — raise the "
+        f"caps" + hint
     )
     assert tm["round_cap_hits"] == 0, (
         "round cap hit: windows truncated — raise max_rounds" + hint

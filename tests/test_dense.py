@@ -195,6 +195,40 @@ def test_tcp_round_has_no_gather(tcp_rounds, lanes):
         f"tcp/tcp.py, the first from {gathers[0]}")
 
 
+# The ``gather`` equations of the whole ``rounds`` phase, by model: none but
+# ``apps/tor.py``'s own (``_pick_weighted``, ``dir_ids[d_idx]``: PERF.md §7a8).
+ROUND_GATHERS = {"configs/rung1_filexfer.yaml": 0,
+                 "benchmarks/tests/rehearsal/configs/tor20.yaml": 11,
+                 "tests/rehearsal_bitcoin64/configs/bitcoin64.yaml": 0}
+
+
+@pytest.mark.parametrize("lanes", [0, 2], ids=["solo", "vmap2"])
+@pytest.mark.parametrize("config", sorted(ROUND_GATHERS),
+                         ids=["tor20", "filexfer", "bitcoin64"])
+def test_tcp_round_sweeps_no_plane_a_queue_a_socket_tall(config, lanes):
+    """The message boundaries are one pool a host, ``[P, H]`` (PR 48): no
+    equation of the round reads or writes an array ``[msgq_cap, sockets, H]``
+    (under ``vmap`` with the lanes in front), the pool's planes are there, and
+    the pool brought no ``gather``."""
+    from shadow1_tpu.tools.opcensus import iter_eqns
+
+    pr, h = _engine(config).params, _engine(config).exp.n_hosts
+    fn, fr = _rounds(config)
+    lead = (lanes,) if lanes else ()
+    if lanes:
+        fn = jax.vmap(fn)
+        fr = jax.tree_util.tree_map(lambda x: jnp.stack([x] * lanes), fr)
+    eqns = list(iter_eqns(jax.make_jaxpr(fn)(fr).jaxpr))
+    shapes = {tuple(v.aval.shape) for e in eqns
+              for v in (*e.invars, *e.outvars) if hasattr(v.aval, "shape")}
+    tall = lead + (pr.msgq_cap, pr.sockets_per_host, h)
+    assert tall not in shapes
+    assert not [s for s in shapes if s[-3:] == tall[-3:]]
+    assert lead + (pr.mq_pool, h) in shapes
+    assert sum(e.primitive.name == "gather" for e in eqns) \
+        == ROUND_GATHERS[config]
+
+
 @pytest.fixture(scope="module", params=["configs/serve_phold.yaml",
                                         "configs/rung2_tgen100.yaml"],
                 ids=["phold", "tgen100"])

@@ -74,8 +74,9 @@ def test_work_schema_and_ckpt_format():
     # (v10); the flow-probe ring leaf bumped it again (v11), the
     # link-telemetry accumulator leaf once more (v12), the deliver_ranks
     # Metrics leaf again (v13), the five runs_* leaves (v14),
-    # runs_window_end (v15), and the optional compact_buckets leaf (v16).
-    assert CKPT_FORMAT == 16
+    # runs_window_end (v15), the optional compact_buckets leaf (v16), and the
+    # message-boundary pool's leaves with its gauge and counter (v17).
+    assert CKPT_FORMAT == 17
 
 
 def test_stale_ckpt_format_rejected(tmp_path):

@@ -135,7 +135,7 @@ def test_the_lane_equals_the_reference_counter_for_counter(fleet, plan):
             "tcp_ooo_drops"} <= set(compared)
     assert all(a == b for a, b in compared.values()), compared
     assert have["total_streams_done"] >= 10 and have["events"] > 3000
-    assert all(have[k] == 0 for k in MUST_BE_ZERO)
+    assert all(have[k] == 0 for k in (*MUST_BE_ZERO, "mq_overflow"))
 
 
 def test_the_lane_equals_the_solo_engine_leaf_for_leaf(fleet, plan):
@@ -196,6 +196,55 @@ def test_a_lane_of_one_trip_a_window_equals_the_full_width_lane_rounds_too(
     assert m["compact_max_fill"] <= 32
     # One trip a window that had an event, none in the few that had none.
     assert N_WINDOWS - 6 <= int(st.compact_buckets[0]) < N_WINDOWS
+
+
+def _boundaries(tcp, lane=None):
+    """Per host, the pool's boundaries as a sorted list (socket, end, meta)."""
+    sock, end, meta = (np.asarray(tcp[k] if lane is None else tcp[k][lane])
+                       for k in ("mq_sock", "mq_end", "mq_meta"))
+    return [sorted(zip(sock[sock[:, h] >= 0, h].tolist(),
+                       end[sock[:, h] >= 0, h].tolist(),
+                       meta[sock[:, h] >= 0, h].tolist()))
+            for h in range(sock.shape[1])]
+
+
+def test_the_boundary_pool_moves_with_its_columns_under_a_cap(
+        fleet, full_width, plan):
+    """The pool's three planes keep H minor, so the column mover carries
+    them like any leaf: after 40 windows (boundaries pending on 9 hosts) and
+    after 60 the compacted lane's pool is the full-width lane's, slot for
+    slot, and the gauge with it."""
+    (eng, midway, st), (weng, _) = fleet, full_width
+    assert eng.params.mq_pool == 128 and weng.params.compact_cap == 0
+    want_mid = weng.run(n_windows=MIDWAY)
+    for got, want in ((midway, want_mid),
+                      (st, weng.run(want_mid, n_windows=N_WINDOWS - MIDWAY))):
+        for k in ("mq_sock", "mq_end", "mq_meta"):
+            assert np.array_equal(np.asarray(got.model.tcp[k]),
+                                  np.asarray(want.model.tcp[k])), k
+        m, w = fleet_metrics_per_exp(got)[0], fleet_metrics_per_exp(want)[0]
+        assert m["mq_max_fill"] == w["mq_max_fill"] > 0
+        assert m["mq_overflow"] == w["mq_overflow"] == 0
+    assert sum(bool(b) for b in _boundaries(midway.model.tcp, 0)) >= 3
+
+
+def test_the_boundary_pool_sharded_three_ways_is_the_solo_engine_s(plan):
+    """33 hosts on three devices of eleven: each shard holds its hosts'
+    columns of the pool; boundaries, gauge and counters are the solo
+    engine's."""
+    from shadow1_tpu.shard.engine import ShardedEngine
+
+    params = dataclasses.replace(plan.params, compact_cap=0)
+    solo = Engine(plan.exps[0], params)
+    want = solo.run(n_windows=MIDWAY)
+    sh = ShardedEngine(plan.exps[0], params, devices=jax.devices()[:3])
+    got = sh.run(n_windows=MIDWAY)
+    assert _boundaries(got.model.tcp) == _boundaries(want.model.tcp)
+    assert sum(bool(b) for b in _boundaries(want.model.tcp)) >= 3
+    m, w = ShardedEngine.metrics_dict(got), Engine.metrics_dict(want)
+    assert m["mq_max_fill"] == w["mq_max_fill"] > 0
+    assert all(m[k] == w[k] for k in ("events", "pkts_sent", "pkts_delivered",
+                                      "mq_overflow", "x2x_overflow"))
 
 
 # ---- (b3) what the compiled program of a fleet with a cap holds ------------------
@@ -493,8 +542,10 @@ def test_the_cell_s_files_state_what_the_issue_fixed():
 
 def test_rung_4_itself_builds_a_fleet_of_one_at_full_width():
     """The real file under the cell's seed, shapes only (no state is made):
-    1,271.6 MB in one lane, the message-queue planes [1, 64, 128, 10000];
-    its ``compact_cap`` 1,280 is in force — no warning, not one parameter
+    565.0 MB in one lane (1,271.6 while the message boundaries were two
+    planes [1, 64, 128, 10000] and a ``pred`` one: a pool [1, 256, 10000]
+    since PR 48, and the event payload plane is the largest leaf); its
+    ``compact_cap`` 1,280 is in force — no warning, not one parameter
     moved — so the lane's rounds run 1,280 of 10,000 columns a trip, and the
     state has one leaf more, the trips' count. (The name is PR 43's: until
     PR 44 the fleet dropped the cap and ran full width.)"""
@@ -520,10 +571,12 @@ def test_rung_4_itself_builds_a_fleet_of_one_at_full_width():
     leaves = jax.tree.leaves(st)
     sizes = sorted(((x.size * x.dtype.itemsize, x.shape) for x in leaves),
                    reverse=True)
-    assert len(leaves) == 131
-    assert sum(b for b, _ in sizes) == 1_271_600_372 + 8
-    assert sizes[0] == sizes[1] == (327_680_000, (1, 64, 128, 10000))
-    assert sizes[2] == (102_400_000, (1, 10, 256, 10000))
+    assert len(leaves) == 133           # Metrics.mq_max_fill, mq_overflow
+    assert sum(b for b, _ in sizes) == 565_040_396
+    assert sizes[0] == (102_400_000, (1, 10, 256, 10000))
+    assert {st.model.tcp[k].shape for k in ("mq_sock", "mq_end", "mq_meta")} \
+        == {(1, 256, 10000)}
+    assert not [shape for _, shape in sizes if shape[-3:-1] == (64, 128)]
 
 
 # ---- (e) the mover's yardstick: the round loop outside pops and passes ----------
