@@ -54,7 +54,12 @@ from shadow1_tpu.consts import (
     NP,
     TCP_LISTEN,
 )
-from shadow1_tpu.core.engine import any_host, lane_branch, push_local_event
+from shadow1_tpu.core.engine import (
+    any_host,
+    lane_branch,
+    pass_rows,
+    push_local_event,
+)
 from shadow1_tpu.core.events import push_local
 from shadow1_tpu.tcp import tcp as T
 
@@ -117,6 +122,10 @@ def init(ctx, evbuf, tcpd):
     return app, evbuf, n_over, tcpd
 
 
+def _peer_slots(ctx) -> int:
+    return ctx.model_cfg["peers"].shape[-1]
+
+
 def _push_msg(st, ctx, mask, sock, meta, nbytes, now):
     """Queue a protocol message send (admission-checked in OP_TX_MSG)."""
     return push_local_event(
@@ -146,6 +155,9 @@ def _mark_seen(app, mask, txid, now):
     return app, new
 
 
+# One INV a peer slot from ``_announce`` (a new transaction, created or
+# received), beside the dial's flush, the message's send and its retry.
+@pass_rows(lambda ctx: 5 + _peer_slots(ctx))
 def on_wakeup(st, ctx, ev, mask):
     op = ev.p[0]
     app = st.model.app
@@ -212,6 +224,7 @@ def on_wakeup(st, ctx, ev, mask):
         )
 
 
+@pass_rows(lambda ctx: 1 + _peer_slots(ctx))
 def on_notify(st, ctx, nf: T.Notif, now, mask):
     f = nf.flags
     sock = nf.sock

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 
 from shadow1_tpu import net
 from shadow1_tpu.consts import K_APP, N_DGRAM, NP
-from shadow1_tpu.core.engine import push_local_event
+from shadow1_tpu.core.engine import pass_rows, push_local_event
 from shadow1_tpu.core.events import push_local
 
 OP_TICK = 1
@@ -39,6 +39,7 @@ def init(ctx, evbuf, tcpd):
     return app, evbuf, over.sum(dtype=jnp.int64), tcpd
 
 
+@pass_rows(1)
 def on_wakeup(st, ctx, ev, mask):
     m = mask & (ev.p[0] == OP_TICK)
     app = st.model.app
@@ -54,6 +55,7 @@ def on_wakeup(st, ctx, ev, mask):
     return push_local_event(st, ctx, again, ev.time + app["interval"], K_APP, p0=OP_TICK)
 
 
+@pass_rows(0)
 def on_notify(st, ctx, nf, now, mask):
     app = dict(st.model.app)
     dg = mask & ((nf.flags & N_DGRAM) != 0)

@@ -30,6 +30,7 @@ from shadow1_tpu.consts import (
     NP,
     TCP_LISTEN,
 )
+from shadow1_tpu.core.engine import pass_rows
 from shadow1_tpu.core.events import push_local
 from shadow1_tpu.tcp import tcp as T
 
@@ -92,11 +93,13 @@ def _client_start(st, ctx, mask, now):
     return T.tcp_connect(st, ctx, mask, zero, app["server"], zero, now)
 
 
+@pass_rows(2)
 def on_wakeup(st, ctx, ev, mask):
     start = mask & (ev.p[0] == OP_START)
     return _client_start(st, ctx, start, ev.time)
 
 
+@pass_rows(8)
 def on_notify(st, ctx, nf: T.Notif, now, mask):
     app = st.model.app
     is_client = app["role"] == 1

@@ -39,7 +39,7 @@ from shadow1_tpu.consts import (
     R_APP,
     TCP_LISTEN,
 )
-from shadow1_tpu.core.engine import push_local_event
+from shadow1_tpu.core.engine import pass_rows, push_local_event
 from shadow1_tpu.core.events import push_local
 from shadow1_tpu.tcp import tcp as T
 
@@ -115,11 +115,13 @@ def _client_pump(st, ctx, mask, now):
     return T.tcp_close(st, ctx, done, one, now)
 
 
+@pass_rows(2)
 def on_wakeup(st, ctx, ev, mask):
     start = mask & (ev.p[0] == OP_START)
     return _start_stream(st, ctx, start, ev.time)
 
 
+@pass_rows(7)
 def on_notify(st, ctx, nf: T.Notif, now, mask):
     f = nf.flags
     is_client_sock = nf.sock == 1

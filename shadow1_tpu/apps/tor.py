@@ -86,7 +86,12 @@ from shadow1_tpu.core.dense import (
     read_sel,
     set_col,
 )
-from shadow1_tpu.core.engine import any_host, lane_branch, push_local_event
+from shadow1_tpu.core.engine import (
+    any_host,
+    lane_branch,
+    pass_rows,
+    push_local_event,
+)
 from shadow1_tpu.core.events import push_local
 from shadow1_tpu.consts import NP as NPCOLS
 from shadow1_tpu.tcp import tcp as T
@@ -424,6 +429,7 @@ def _relay_on_cell(st, ctx, m, sock, meta, now):
 
 
 # -- event handlers --------------------------------------------------------
+@pass_rows(11)
 def on_wakeup(st, ctx, ev, mask):
     with jax.named_scope("phase:tor_dir"):
         op = ev.p[0]
@@ -530,6 +536,7 @@ def on_wakeup(st, ctx, ev, mask):
                           lambda s: s, st)
 
 
+@pass_rows(22)
 def on_notify(st, ctx, nf: T.Notif, now, mask):
     with jax.named_scope("phase:tor_relay"):
         f = nf.flags

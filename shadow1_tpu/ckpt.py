@@ -96,7 +96,11 @@ import jax
 #      Metrics gains mq_max_fill / mq_overflow (the ring row their two
 #      columns). load_state's cap migration covers the pool as it covers
 #      ev_cap (tune/resize.resize_mq_pool). No older snapshot loads.
-CKPT_FORMAT = 17
+#  18: Metrics gains push_commit_trips / push_stage_max (the round's one
+#      commit of its staged pushes, core/events.push_commit; the ring row the
+#      gauge's column): two more i64 leaves in every snapshot. The stage
+#      itself is None between rounds and in no snapshot.
+CKPT_FORMAT = 18
 
 
 class CorruptCheckpointError(ValueError):

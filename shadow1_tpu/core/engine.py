@@ -42,11 +42,15 @@ from shadow1_tpu.core.events import (
     NEVER,
     EventBuf,
     Popped,
+    PushSites,
     any_eligible,
     deliver_batch,
     evbuf_init,
+    free_slots,
     pop_until,
     push_back,
+    push_commit,
+    stage_open,
 )
 from shadow1_tpu.core.outbox import Outbox, outbox_clear, outbox_init
 from shadow1_tpu.telemetry.profiler import PH_ARGS, PH_CALL, run_span
@@ -135,6 +139,15 @@ class Metrics(NamedTuple):
     # fill loop's trips * RB, summed over windows) — against windows * ev_cap
     # it says what a fill by slot would sweep. Batch-engine-only like fires_*.
     deliver_ranks: jnp.ndarray
+    # The round's one commit of its staged pushes (events.push_commit):
+    # trips the lane's own rounds needed, PUSH_RB ranks a trip (0 in a round
+    # that staged nothing; against ``rounds`` it says how often one trip did
+    # not do), and the most events one host staged in one round (against the
+    # rows a pass declares, ``pass_rows``: how far the proven bound is from
+    # use). 0 where the round pushes directly (one handler kind).
+    # Batch-engine-only like fires_*.
+    push_commit_trips: jnp.ndarray
+    push_stage_max: jnp.ndarray
     # Fault plane (shadow1_tpu/fault/): deterministic link outages and host
     # restarts (docs/SEMANTICS.md §"Fault plane").
     link_down_pkts: jnp.ndarray  # packets dropped: link outage window
@@ -434,6 +447,40 @@ def lane_branch(ctx: Ctx, fn):
     return branch
 
 
+def pass_rows(rows):
+    """Declare the push sites a handler pass traces: ``run_round`` sizes the
+    round's stage by the largest declaration and the trace fails, naming the
+    pass, where a pass traces one more (``events.PushSites``). A site is one
+    traced ``push_local`` / ``push_local_event``, whatever its mask; a
+    handler that declares nothing may push nothing. ``rows`` is a number,
+    or a function of the ctx where the experiment decides (Bitcoin announces
+    to each of a node's K peers: K sites); ``rows_of`` reads it."""
+    def declare(fn):
+        fn.push_rows = rows
+        return fn
+
+    return declare
+
+
+def rows_of(fn, ctx: Ctx) -> int:
+    """The push sites ``fn`` declares (``pass_rows``) under ``ctx``."""
+    rows = getattr(fn, "push_rows", 0)
+    return rows(ctx) if callable(rows) else rows
+
+
+def count_push_sites(st: SimState, ctx: Ctx, handlers: dict) -> dict:
+    """{pass: push sites one trace of it counts} for a round of ``handlers``
+    (shapes only, nothing runs): what ``pass_rows`` has to cover."""
+    PushSites.log = log = {}
+    try:
+        jax.eval_shape(
+            lambda s: run_round(s, ctx, handlers, s.win_start + ctx.window),
+            st)
+    finally:
+        PushSites.log = None
+    return log
+
+
 def run_round(st: SimState, ctx: Ctx, handlers: dict, win_end) -> SimState:
     """One inner round: per-host pop-min + the handler passes.
 
@@ -470,9 +517,28 @@ def run_round(st: SimState, ctx: Ctx, handlers: dict, win_end) -> SimState:
     is in them in nearly every round, and a conditional inside a running
     pass splits its fused writes of the event planes. PERF.md §6, PR 38.)
     ``fires_*`` counts the lane's own ``present``; ``runs_*`` counts what
-    the ``cond`` was handed."""
+    the ``cond`` was handed.
+
+    With more than one kind every guard is a ``conditional``, a wall no
+    fusion crosses, so each piece of a pass that pushed an event swept the
+    whole event plane on its own. Such a round stages its pushes as
+    [H]-vectors (``events.stage_open``: the field is None outside a round)
+    and writes them in ONE commit after the last pass, under
+    ``phase:push_commit`` and one more guard on ``any_host``. A host runs
+    one pass a round and a site pushes once a host, so the sites a pass
+    declares (``pass_rows``) bound what it stages; the passes share the
+    rows. With one kind (PHOLD) there is no conditional, the pass's pushes
+    fuse as they are, and the round pushes directly."""
+    items = sorted(handlers.items())
+    staged = len(items) > 1
     with jax.named_scope("phase:pop"):
         evbuf, ev = pop_until(st.evbuf, win_end)
+        if staged:
+            # A pop frees its slot: counted on the plane the pop reads.
+            evbuf = stage_open(
+                evbuf,
+                max([int(ctx.has_cpu)] + [rows_of(fn, ctx) for _, fn in items]),
+                free_slots(st.evbuf) + ev.mask.astype(jnp.int32))
     st = st._replace(evbuf=evbuf)
     m = st.metrics
     n_down = jnp.zeros((), jnp.int64)
@@ -487,9 +553,14 @@ def run_round(st: SimState, ctx: Ctx, handlers: dict, win_end) -> SimState:
         eff = jnp.maximum(ev.time, st.cpu_busy)
         defer = ev.mask & (eff >= win_end)
         run = ev.mask & ~defer
+        if staged:
+            # A deferred host runs no pass: its one row is any pass's first.
+            st.evbuf.stage.sites.enter("cpu_defer", 1)
         evbuf, over = push_back(
             st.evbuf, defer, eff, ev.tb, ev.kind, ev.p
         )
+        if staged:
+            evbuf.stage.sites.leave()
         st = st._replace(
             evbuf=evbuf,
             cpu_busy=jnp.where(run, eff + ctx.cpu_cost, st.cpu_busy),
@@ -509,13 +580,13 @@ def run_round(st: SimState, ctx: Ctx, handlers: dict, win_end) -> SimState:
             **pops,
         ),
     )
-    items = sorted(handlers.items())
     for kind, fn in items:
         scope = f"phase:h_{KIND_NAMES.get(kind, kind)}"
-        if len(items) == 1:
+        if not staged:
             with jax.named_scope(scope):
                 st = fn(st, ev)
         else:
+            fn = _counted_pass(fn, scope[len("phase:"):], rows_of(fn, ctx))
             present = (ev.mask & (ev.kind == kind)).any()
             runs = any_lane(ctx, present)
             if kind in KIND_METRIC_FIELDS:
@@ -528,7 +599,44 @@ def run_round(st: SimState, ctx: Ctx, handlers: dict, win_end) -> SimState:
             with jax.named_scope(scope):
                 st = jax.lax.cond(runs, lane_branch(ctx, fn),
                                   lambda s, _e: s, st, ev)
+    if staged:
+        with jax.named_scope("phase:push_commit"):
+            st = _commit_pushes(st, ctx)
     return st
+
+
+def _counted_pass(fn, name: str, rows: int):
+    """``fn`` with its push sites counted against the ``rows`` it declares."""
+    def run(st, ev):
+        sites = st.evbuf.stage.sites
+        sites.enter(name, rows)
+        st = fn(st, ev)
+        sites.leave()
+        return st
+
+    return run
+
+
+def _commit_pushes(st: SimState, ctx: Ctx) -> SimState:
+    """The round's one write of its staged events (``events.push_commit``)
+    and the stage's end: one more guard on ``any_host``, so a round in which
+    no host of any lane pushed (two in three of a Tor lane's) ranks no slot
+    and sweeps no plane."""
+    def commit(evbuf):
+        return push_commit(evbuf, lambda hit: any_lane(ctx, hit))
+
+    def skip(evbuf):
+        none = jnp.zeros((), jnp.int32)
+        return evbuf._replace(stage=None), none, none
+
+    evbuf, trips, n_max = jax.lax.cond(
+        any_host(ctx, st.evbuf.stage.cnt > 0), lane_branch(ctx, commit), skip,
+        st.evbuf)
+    m = st.metrics
+    return st._replace(evbuf=evbuf, metrics=m._replace(
+        push_commit_trips=m.push_commit_trips + trips.astype(jnp.int64),
+        push_stage_max=jnp.maximum(m.push_stage_max,
+                                   n_max.astype(jnp.int64))))
 
 
 def route_outbox(ctx: Ctx, ob: Outbox, links=None, win_start=None):

@@ -44,7 +44,8 @@ scatters, no per-slot argmin/cumsum in the round path (core/dense.py).
 
 from __future__ import annotations
 
-from typing import NamedTuple
+import sys
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -117,6 +118,10 @@ class EventBuf(NamedTuple):
     # rewrites leave it stale, exactly like t32).
     n_elig: jnp.ndarray    # i32 [H]
     u32: jnp.ndarray       # i32 scalar eligibility bound of n_elig
+    # A round's staged local pushes (``Stage``, below), or None: None between
+    # rounds, in every carry, snapshot and digest — no pytree leaf — and in a
+    # round whose pushes write the planes directly (``push_local``).
+    stage: Any = None
 
     def abs_time(self) -> jnp.ndarray:
         """i64 [C, H] absolute times (window-granularity readers only)."""
@@ -173,26 +178,13 @@ def push_local(buf: EventBuf, mask, time, kind, p) -> tuple[EventBuf, jnp.ndarra
 
     Returns (buf, overflow_mask). Overflowing events are dropped and must be
     surfaced as a metric — capacity is an experiment knob (SURVEY §7.3.2).
+    Where the round has a stage open (``stage_open``) the event is staged
+    and the planes are written at the round's one ``push_commit``: the same
+    slot, keys and counters either way.
     """
-    has_free, first = first_true(buf.kind == K_NONE)
-    ok = mask & has_free
-    w = first & ok[None, :]
-    time = jnp.asarray(time, jnp.int64)
-    thi, tlo = tb_split(time)
-    t32v = _t32_of(time, buf.epoch)
     hi, lo = tb_split(buf.self_ctr)
-    buf = buf._replace(
-        time_hi=jnp.where(w, thi[None, :], buf.time_hi),
-        time_lo=jnp.where(w, tlo[None, :], buf.time_lo),
-        t32=jnp.where(w, t32v[None, :], buf.t32),
-        tb_hi=jnp.where(w, hi[None, :], buf.tb_hi),
-        tb_lo=jnp.where(w, lo[None, :], buf.tb_lo),
-        kind=jnp.where(w, jnp.asarray(kind, jnp.int32)[None, :], buf.kind),
-        p=jnp.where(w[None], jnp.asarray(p, jnp.int32)[:, None, :], buf.p),
-        self_ctr=buf.self_ctr + ok.astype(jnp.int64),
-        n_elig=buf.n_elig + (ok & (t32v < buf.u32)).astype(jnp.int32),
-    )
-    return buf, mask & ~has_free
+    buf, ok, over = _push(buf, mask, time, hi, lo, kind, p)
+    return buf._replace(self_ctr=buf.self_ctr + ok.astype(jnp.int64)), over
 
 
 def push_back(buf: EventBuf, mask, time, tb, kind, p) -> tuple[EventBuf, jnp.ndarray]:
@@ -202,24 +194,238 @@ def push_back(buf: EventBuf, mask, time, tb, kind, p) -> tuple[EventBuf, jnp.nda
     past the window boundary (docs/SEMANTICS.md §cpu): the event re-enters
     at (eff_time, original tb), so its order among same-time events is
     preserved. Does not advance self_ctr."""
-    has_free, first = first_true(buf.kind == K_NONE)
-    ok = mask & has_free
-    w = first & ok[None, :]
+    hi, lo = tb_split(jnp.asarray(tb, jnp.int64))
+    buf, _ok, over = _push(buf, mask, time, hi, lo, kind, p)
+    return buf, over
+
+
+def _push(buf: EventBuf, mask, time, tb_hi, tb_lo, kind, p):
+    """One event per host where ``mask`` and a slot is free, under the given
+    tie-break words: (buf, ok, overflow_mask). Staged where a stage is open,
+    else written into the host's first free slot."""
     time = jnp.asarray(time, jnp.int64)
     thi, tlo = tb_split(time)
     t32v = _t32_of(time, buf.epoch)
-    hi, lo = tb_split(jnp.asarray(tb, jnp.int64))
+    kind = jnp.asarray(kind, jnp.int32)
+    p = jnp.asarray(p, jnp.int32)
+    if buf.stage is not None:
+        stage, ok, over = buf.stage.push(
+            mask, (thi, tlo, t32v, tb_hi, tb_lo, kind), p)
+        buf = buf._replace(stage=stage)
+    else:
+        has_free, first = first_true(buf.kind == K_NONE)
+        ok = mask & has_free
+        over = mask & ~has_free
+        w = first & ok[None, :]
+        buf = buf._replace(
+            time_hi=jnp.where(w, thi[None, :], buf.time_hi),
+            time_lo=jnp.where(w, tlo[None, :], buf.time_lo),
+            t32=jnp.where(w, t32v[None, :], buf.t32),
+            tb_hi=jnp.where(w, tb_hi[None, :], buf.tb_hi),
+            tb_lo=jnp.where(w, tb_lo[None, :], buf.tb_lo),
+            kind=jnp.where(w, kind[None, :], buf.kind),
+            p=jnp.where(w[None], p[:, None, :], buf.p),
+        )
+    return buf._replace(
+        n_elig=buf.n_elig + (ok & (t32v < buf.u32)).astype(jnp.int32)), ok, over
+
+
+# --------------------------------------------------------------------------
+# A round's local pushes, staged and committed once.
+#
+# A push writes ONE row a host, and as a select over the planes it reads and
+# writes all 16 * C * H words to do so. Straight-line pushes fuse into one
+# sweep, but a ``conditional`` is a fusion wall and a TCP round is built of
+# them (the pass guards, tcp_rx's accept and FIN blocks, the apps' guarded
+# blocks): five or six sweeps a round in the Tor cells (PERF.md §6, PR 49).
+# With a stage open a push site touches no plane: it writes its event into a
+# row of [H]-vectors, and ``push_commit`` writes the round's events into the
+# planes in one sweep after the last pass.
+#
+# It is the same buffer to the bit, layout included: a host's r-th successful
+# push of a round takes its then-first free slot, which is its r-th free slot
+# after the pop in ascending slot order — the slot the commit gives rank r.
+# ``self_ctr``, ``n_elig`` and the overflow mask are decided at the site from
+# [H] counts, exactly as the direct push decides them.
+# --------------------------------------------------------------------------
+
+# Ranks the commit writes a trip: RB compares and RB * 16 selects an element
+# of the planes. A Tor or tgen round stages at most one or two events on its
+# busiest host (a timer, a TX_RESUME, an app wakeup) and two rounds in three
+# stage none; a Bitcoin node that first sees a transaction announces it to
+# each of its K peers in one round (PERF.md §6, PR 49).
+# ``Metrics.push_commit_trips`` against ``rounds`` says how many rounds
+# needed a second trip.
+PUSH_RB = 4
+# A staged row: the host's rank + 1 (0 = the row holds nothing of this
+# host's), the six head planes' values, the payload.
+_ROW_FIELDS = 7 + NP
+
+
+class PushRowsError(Exception):
+    """A pass traced a push the stage has no row for (or from inside a
+    loop, where one site would run more than once a round)."""
+
+
+class PushSites:
+    """The trace-time count of push sites behind a round's stage.
+
+    A site pushes at most one event a host and a host executes one event —
+    one pass — a round, so the sites traced in a pass bound what a host can
+    stage there, and the passes share the rows. ``enter`` opens a pass (the
+    count restarts, since each trace of a pass traces its sites again),
+    ``take`` hands a site its row and refuses one past the rows the pass
+    declared (``core/engine.pass_rows``) or inside a loop's body.
+    ``PushSites.log``, where a dict, collects the sites each pass traced
+    (``core/engine.count_push_sites``)."""
+
+    log = None
+
+    def __init__(self, rows: int):
+        self.rows = rows
+        self.frame = None
+        self.leave()
+
+    def enter(self, name: str, limit: int):
+        """Open pass ``name`` of ``limit`` declared rows, from the frame
+        whose callees trace its sites."""
+        self.name, self.limit, self.n = name, min(limit, self.rows), 0
+        self.frame = sys._getframe(1)
+
+    def leave(self):
+        if PushSites.log is not None and self.frame is not None:
+            PushSites.log[self.name] = max(PushSites.log.get(self.name, 0),
+                                           self.n)
+        self.name, self.limit, self.n, self.frame = "round", self.rows, 0, None
+
+    def take(self) -> int:
+        # A loop's body is traced once and runs many times: a site inside
+        # one could push twice a round into its one row. jax traces the
+        # bodies of while_loop, fori_loop, scan and map from loops.py; the
+        # frames above the pass's own are the round loop's.
+        f = sys._getframe(1)
+        while f is not None and f is not self.frame:
+            if f.f_code.co_filename.endswith("control_flow/loops.py"):
+                raise PushRowsError(
+                    f"pass {self.name!r} pushes an event from inside a "
+                    "loop's body: a site must run once a round")
+            f = f.f_back
+        if self.n >= self.limit:
+            raise PushRowsError(
+                f"pass {self.name!r} traces more than the {self.limit} push "
+                "sites it declares (core/engine.pass_rows): count them "
+                "(core/engine.count_push_sites) and raise its rows")
+        self.n += 1
+        return self.n - 1
+
+
+@jax.tree_util.register_pytree_node_class
+class Stage:
+    """A round's staged pushes: ``free`` i32 [H] the host's free slots after
+    the pop, ``cnt`` i32 [H] the events it has staged, ``rows`` a tuple of
+    i32 [_ROW_FIELDS, H], one a push site of a pass (``PushSites``)."""
+
+    def __init__(self, free, cnt, rows, sites):
+        self.free, self.cnt, self.rows, self.sites = free, cnt, rows, sites
+
+    def tree_flatten(self):
+        return (self.free, self.cnt, self.rows), self.sites
+
+    @classmethod
+    def tree_unflatten(cls, sites, children):
+        return cls(*children, sites)
+
+    def push(self, mask, heads, p):
+        """Stage one event a host where ``mask`` and a slot is left:
+        (stage, ok, overflow_mask). O(fields * H): a masked write of the
+        site's own row."""
+        r = self.sites.take()
+        ok = mask & (self.cnt < self.free)
+        new = jnp.concatenate([jnp.stack([self.cnt + 1, *heads]), p])
+        rows = list(self.rows)
+        rows[r] = jnp.where(ok[None, :], new, rows[r])
+        stage = Stage(self.free, self.cnt + ok.astype(jnp.int32),
+                      tuple(rows), self.sites)
+        return stage, ok, mask & ~ok
+
+
+def free_slots(buf: EventBuf) -> jnp.ndarray:
+    """i32 [H]: the free slots each host has (one reduction over the ``kind``
+    plane, the plane ``pop_until``'s own first reduction reads)."""
+    return (buf.kind == K_NONE).sum(axis=0, dtype=jnp.int32)
+
+
+def stage_open(buf: EventBuf, rows: int, free) -> EventBuf:
+    """Open a round's stage of ``rows`` rows on a just-popped buffer whose
+    hosts have ``free`` slots left."""
+    h = buf.kind.shape[1]
+    zero = jnp.zeros((_ROW_FIELDS, h), jnp.int32)
+    return buf._replace(stage=Stage(
+        free, jnp.zeros(h, jnp.int32), (zero,) * rows, PushSites(rows)))
+
+
+def free_slot_rank(kind) -> jnp.ndarray:
+    """i32 [C, H]: a free slot's rank among its host's free slots in
+    ascending slot order, -1 on an occupied slot.
+
+    An exclusive prefix count down the slot axis, as a product with the
+    strictly lower triangle: 0/1 operands are exact in bfloat16 and the sums
+    (at most C) in float32, on any backend, and the TPU runs it on the MXU —
+    where ``cumsum`` down the sublane axis lowers to a slow sequence
+    (core/dense.py) and PUSH_RB successive first-free reductions read the
+    plane PUSH_RB times."""
+    cap = kind.shape[0]
+    free = kind == K_NONE
+    slot = jnp.arange(cap, dtype=jnp.int32)
+    below = (slot[None, :] < slot[:, None]).astype(jnp.bfloat16)
+    rank = jnp.dot(below, free.astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32)
+    return jnp.where(free, rank.astype(jnp.int32), -1)
+
+
+def push_commit(buf: EventBuf, any_lane=lambda hit: hit):
+    """Write the round's staged events into the planes and close the stage:
+    (buf, trips, n_max) — the trips THIS buffer needed and the most events
+    one of its hosts staged.
+
+    A host's rank-r event goes to its r-th free slot in ascending slot
+    order (``free_slot_rank``, taken once: a slot's rank does not move as
+    lower ranks fill), PUSH_RB ranks a trip: each rank's event by a select
+    over the rows, the write as dense compare-selects over the sixteen
+    planes — ``deliver_batch``'s fill body without its gather. No trip
+    where nothing was staged (the caller guards the ranking too:
+    ``core/engine._commit_pushes``). ``any_lane`` reduces the loop's
+    predicate over a fleet's lanes (``core/engine``): ``vmap`` of a loop
+    whose trip count differs by lane selects the whole carry every trip."""
+    stage = buf.stage
+    n = stage.cnt
+    n_max = n.max()
+    slot_rank = free_slot_rank(buf.kind)
+
+    def trip(carry):
+        i, heads, pay = carry
+        for j in range(PUSH_RB):
+            k = i * PUSH_RB + j
+            ev = jnp.zeros((_ROW_FIELDS - 1, n.shape[0]), jnp.int32)
+            for row in stage.rows:
+                ev = jnp.where((row[0] == k + 1)[None, :], row[1:], ev)
+            # A rank no host staged selects no slot: a free slot of that
+            # rank stays free.
+            sel = (slot_rank == k) & (n > k)[None, :]
+            heads = [jnp.where(sel, ev[f][None, :], x)
+                     for f, x in enumerate(heads)]
+            pay = jnp.where(sel[None], ev[6:][:, None, :], pay)
+        return i + 1, heads, pay
+
+    heads = [buf.time_hi, buf.time_lo, buf.t32, buf.tb_hi, buf.tb_lo,
+             buf.kind]
+    _, heads, pay = jax.lax.while_loop(
+        lambda c: any_lane(c[0] * PUSH_RB < n_max), trip,
+        (jnp.zeros((), jnp.int32), heads, buf.p))
     buf = buf._replace(
-        time_hi=jnp.where(w, thi[None, :], buf.time_hi),
-        time_lo=jnp.where(w, tlo[None, :], buf.time_lo),
-        t32=jnp.where(w, t32v[None, :], buf.t32),
-        tb_hi=jnp.where(w, hi[None, :], buf.tb_hi),
-        tb_lo=jnp.where(w, lo[None, :], buf.tb_lo),
-        kind=jnp.where(w, jnp.asarray(kind, jnp.int32)[None, :], buf.kind),
-        p=jnp.where(w[None], jnp.asarray(p, jnp.int32)[:, None, :], buf.p),
-        n_elig=buf.n_elig + (ok & (t32v < buf.u32)).astype(jnp.int32),
-    )
-    return buf, mask & ~has_free
+        time_hi=heads[0], time_lo=heads[1], t32=heads[2], tb_hi=heads[3],
+        tb_lo=heads[4], kind=heads[5], p=pay, stage=None)
+    return buf, (n_max + (PUSH_RB - 1)) // PUSH_RB, n_max
 
 
 # An ``until`` that no event is before, past-due ones (negative t32)

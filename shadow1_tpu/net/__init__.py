@@ -30,6 +30,7 @@ from shadow1_tpu.consts import (
     WIRE_OVERHEAD,
 )
 from shadow1_tpu.core.dense import payload
+from shadow1_tpu.core.engine import pass_rows, rows_of
 from shadow1_tpu.core.events import I64_MAX, push_local, tb_split
 from shadow1_tpu.core.outbox import outbox_append
 from shadow1_tpu.net.nic import (
@@ -228,6 +229,7 @@ def make_handlers(ctx):
     app_on_notify = app_mod.on_notify
     app_on_wakeup = app_mod.on_wakeup
 
+    @pass_rows(1)
     def on_pkt(st, ev):
         """K_PKT: packet reached the dst NIC — model the receive queue
         (drop-tail when the downlink queue bound is exceeded)."""
@@ -249,6 +251,7 @@ def make_handlers(ctx):
             ),
         )
 
+    @pass_rows(T.tcp_rx.push_rows + rows_of(app_on_notify, ctx))
     def on_deliver(st, ev):
         """K_PKT_DELIVER: the packet cleared the NIC — run TCP/UDP, then app."""
         m = ev.mask & (ev.kind == K_PKT_DELIVER)
@@ -262,12 +265,15 @@ def make_handlers(ctx):
         )
         return app_on_notify(st, ctx, nf, ev.time, nf.flags != 0)
 
+    @pass_rows(T.on_tcp_timer.push_rows)
     def on_timer(st, ev):
         return T.on_tcp_timer(st, ctx, ev)
 
+    @pass_rows(T.on_tx_resume.push_rows)
     def on_txr(st, ev):
         return T.on_tx_resume(st, ctx, ev)
 
+    @pass_rows(rows_of(app_on_wakeup, ctx))
     def on_app(st, ev):
         m = ev.mask & (ev.kind == K_APP)
         return app_on_wakeup(st, ctx, ev, m)

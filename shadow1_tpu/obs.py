@@ -177,10 +177,13 @@ class Heartbeat:
         for gauge, cap_field in (("ev_max_fill", "ev_cap"),
                                  ("ob_max_fill", "outbox_cap"),
                                  ("compact_max_fill", "compact_cap"),
-                                 ("mq_max_fill", "msgq_pool")):
+                                 ("mq_max_fill", "msgq_pool"),
+                                 # No knob: the rows a pass declares bound it
+                                 # (core/engine.pass_rows).
+                                 ("push_stage_max", None)):
             if delta.pop(gauge, 0) or m.get(gauge):
                 fill[gauge] = m.get(gauge)
-                if params is not None:
+                if params is not None and cap_field:
                     fill[cap_field] = params.cap(cap_field)
         if fill:
             rec["fill"] = fill

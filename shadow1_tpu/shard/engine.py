@@ -464,6 +464,7 @@ class ShardedEngine:
                 ob_max_fill=pmax_(st.metrics.ob_max_fill),
                 compact_max_fill=pmax_(st.metrics.compact_max_fill),
                 mq_max_fill=pmax_(st.metrics.mq_max_fill),
+                push_stage_max=pmax_(st.metrics.push_stage_max),
             ))
 
         def run(st: SimState, n_windows) -> SimState:

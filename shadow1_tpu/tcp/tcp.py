@@ -258,6 +258,7 @@ def _emit(st, ctx, r: Sock, mask, flags, seq, length, mend, mmeta, now):
     return st
 
 
+from shadow1_tpu.core.engine import pass_rows  # noqa: E402
 from shadow1_tpu.core.engine import push_local_event as _push_local  # noqa: E402
 
 
@@ -628,6 +629,10 @@ _CONN_STATES = TCP_CONN_STATES
 _RCV_STATES = TCP_RCV_STATES
 
 
+# Push sites (core/engine.pass_rows): a flush traces two, the retransmit
+# timer's event and TX_RESUME's. tcp_rx flushes in ``_accept`` and after the ACK
+# processing; the callers that go on into an app add the app's.
+@pass_rows(4)
 def tcp_rx(st, ctx, mask, p, now):
     """Process one arrived TCP segment per host where ``mask``.
 
@@ -832,6 +837,7 @@ def tcp_rx(st, ctx, mask, p, now):
 # --------------------------------------------------------------------------
 # Timer + TX-resume event handlers
 # --------------------------------------------------------------------------
+@pass_rows(3)
 def on_tcp_timer(st, ctx, ev):
     """K_TCP_TIMER: lazy single-event-per-socket retransmit timer.
 
@@ -879,6 +885,7 @@ def on_tcp_timer(st, ctx, ev):
     return tcp_flush(st, ctx, rto_fire, sock, now)
 
 
+@pass_rows(2)
 def on_tx_resume(st, ctx, ev):
     """K_TX_RESUME: continue a burst- or outbox-bounded flush."""
     m = ev.mask & (ev.kind == K_TX_RESUME)

@@ -47,6 +47,7 @@ import time
 from shadow1_tpu.telemetry.registry import (
     CHUNK_CAP_TOTALS,
     CHUNK_LOSS_TOTALS,
+    CHUNK_PUSH_TOTALS,
     CHUNK_TOTALS,
     REC_STALL,
 )
@@ -277,19 +278,22 @@ _TOTALS = CHUNK_TOTALS[:-1]
 # on every row that has any.
 _CAP_TOTALS = {"buckets": "compact_buckets"}
 assert tuple(_CAP_TOTALS) == CHUNK_CAP_TOTALS
-# Every total a row may carry, in the order ``_input_leaves`` reads them:
-# ``CHUNK_LOSS_TOTALS`` (the loss plane: what a chunk sent, lost, resent and
-# dropped out of order) are ``Metrics`` fields like ``_TOTALS``.
-_ROW_TOTALS = (*_TOTALS, *CHUNK_LOSS_TOTALS, *_CAP_TOTALS)
+# The ``Metrics`` fields among a row's totals: ``_TOTALS``, the loss plane's
+# (what a chunk sent, lost, resent and dropped out of order) and the push
+# commits' trips.
+_METRIC_TOTALS = (*_TOTALS, *CHUNK_LOSS_TOTALS, *CHUNK_PUSH_TOTALS)
+# Every total a row may carry, in the order ``_input_leaves`` reads them.
+_ROW_TOTALS = (*_METRIC_TOTALS, *_CAP_TOTALS)
+# ... and in the order a heartbeat's block lists them, ``hosts`` among them.
+_BLOCK_TOTALS = (*CHUNK_TOTALS, *_ROW_TOTALS[len(_TOTALS):])
 
 
 def _input_leaves(st) -> tuple:
     """The scalars of an input state that the log reads: ``metrics.windows``,
-    the ``_TOTALS``, the ``CHUNK_LOSS_TOTALS`` and the ``_CAP_TOTALS`` (None
-    where the state has none)."""
+    the ``_METRIC_TOTALS`` and the ``_CAP_TOTALS`` (None where the state has
+    none)."""
     m = getattr(st, "metrics", None)
-    return (*(getattr(m, k, None)
-              for k in ("windows", *_TOTALS, *CHUNK_LOSS_TOTALS)),
+    return (*(getattr(m, k, None) for k in ("windows", *_METRIC_TOTALS)),
             *(getattr(st, leaf, None) for leaf in _CAP_TOTALS.values()))
 
 
@@ -303,7 +307,7 @@ def work_between(row: dict, after: dict | None) -> dict | None:
     """What the chunk of ``row`` did — events, rounds (a lane's own, summed
     over lanes), ``active_hosts`` and ``elig_events`` (sums over its
     windows), the loss plane's ``pkts_sent``, ``pkts_lost``,
-    ``tcp_fast_rtx``, ``tcp_rto``, ``tcp_ooo_drops`` where both rows carry
+    ``tcp_fast_rtx``, ``tcp_rto``, ``tcp_ooo_drops``, ``push_commit_trips`` where both rows carry
     them, ``buckets`` (the compacted round loop's trips) where the
     program counts them — where ``after`` is the row of the chunk that
     continued it: the same engine's, adjacent in ``seq``, starting on the
@@ -413,7 +417,7 @@ class ChunkLog:
     ``first_window`` (the input state's ``metrics.windows``), ``windows``,
     ``events``, ``rounds``, ``active_hosts``, ``elig_events``, the loss
     plane's ``pkts_sent``, ``pkts_lost``, ``tcp_fast_rtx``, ``tcp_rto``,
-    ``tcp_ooo_drops``, and where a
+    ``tcp_ooo_drops``, the push commits' ``push_commit_trips``, and where a
     ``compact_cap`` is in force ``buckets`` (the input
     state's running totals, summed over a fleet's lanes: what the chunk did
     is the NEXT row's less these, ``work_between``) and ``hosts`` (the
@@ -763,8 +767,7 @@ class ChunkLog:
         for key in ("turnaround_ns", *_BOUNDARY_SPANS.values()):
             if key in row:
                 out[_ms_key(key)] = _ms(row[key])
-        out.update({k: row[k] for k in (*CHUNK_TOTALS, *CHUNK_LOSS_TOTALS,
-                                        *_CAP_TOTALS) if k in row})
+        out.update({k: row[k] for k in _BLOCK_TOTALS if k in row})
         return {**out, **row["health"]}
 
     def summary(self, wait_s: float = 1.0) -> dict:

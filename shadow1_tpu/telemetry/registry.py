@@ -85,6 +85,11 @@ METRIC_SPECS: dict[str, tuple[str, str]] = {
                                  "under sharding)"),
     "deliver_ranks": (COUNTER, "arriving ranks swept by the window-end merge "
                                "(deliver_batch's trips * RB; batch engines)"),
+    "push_commit_trips": (COUNTER, "trips of the round's commit of its staged "
+                                   "pushes (events.push_commit, PUSH_RB ranks "
+                                   "a trip; vs rounds; batch engines)"),
+    "push_stage_max": (GAUGE, "high-water events one host staged in one "
+                              "round (vs the rows its pass declares)"),
     "link_down_pkts": (COUNTER, "packets dropped: link outage window (fault plane)"),
     "host_restarts": (COUNTER, "host restart resets applied (fault plane churn)"),
     # Wasted-work accounting (performance attribution plane): per-window
@@ -133,7 +138,7 @@ LANE_PROGRAM_FIELDS = tuple(f[2] for f in KIND_METRIC_FIELDS.values()) + (
 # larger in a window of several. A comparison of a compacted run with a
 # full-width one leaves out these and ``SimState.compact_buckets`` (the
 # trips themselves, a leaf only the compacted state has) and nothing else.
-ROUND_PROGRAM_FIELDS = ("rounds",) + tuple(
+ROUND_PROGRAM_FIELDS = ("rounds", "push_commit_trips") + tuple(
     f for fs in KIND_METRIC_FIELDS.values() for f in fs[1:])
 
 # JSONL record types every consumer recognises (docs/OBSERVABILITY.md).
@@ -259,20 +264,25 @@ CHUNK_BOUNDARY = ("commit_ms", "on_chunk_ms", "drain_ms", "checkpoint_ms",
 # receiver dropped). A stall line prints the chunk's ``retransmits``
 # (fast retransmits + RTOs) and ``pkts_lost`` beside its rounds
 # (STALL_LOSS_WORK).
+# CHUNK_PUSH_TOTALS: the trips of the rounds' push commits, a Metrics field
+# read in the same pass: against the chunk's ``rounds`` it says how many of
+# them needed a second trip (core/events.push_commit).
 CHUNK_TOTALS = ("events", "rounds", "active_hosts", "elig_events", "hosts")
 CHUNK_CAP_TOTALS = ("buckets",)
 CHUNK_LOSS_TOTALS = ("pkts_sent", "pkts_lost", "tcp_fast_rtx", "tcp_rto",
                      "tcp_ooo_drops")
+CHUNK_PUSH_TOTALS = ("push_commit_trips",)
 STALL_WORK = ("rounds", "events", "median_of_rounds", "median_of_events")
 STALL_LOSS_WORK = ("retransmits", "pkts_lost", "median_of_retransmits")
 CHUNK_BLOCK = (("dispatch_ms", "wait_ms", "turnaround_ms") + CHUNK_TOTALS
-               + CHUNK_LOSS_TOTALS + CHUNK_CAP_TOTALS + CHUNK_BOUNDARY)
+               + CHUNK_LOSS_TOTALS + CHUNK_PUSH_TOTALS + CHUNK_CAP_TOTALS
+               + CHUNK_BOUNDARY)
 CHUNK_HEALTH = ("cpu_s", "nivcsw", "nvcsw", "majflt", "inblock", "oublock",
                 "psi_cpu_us", "psi_io_us", "psi_mem_us", "load1")
 CHUNKS_BLOCK = ("count", "stalls", "rows", "windows", "dispatch_ms",
                 "args_ms", "call_ms", "wait_ms", "turnaround_ms",
                 "boundary_ms", "boundary_share") + CHUNK_TOTALS \
-    + CHUNK_LOSS_TOTALS + CHUNK_CAP_TOTALS + CHUNK_BOUNDARY
+    + CHUNK_LOSS_TOTALS + CHUNK_PUSH_TOTALS + CHUNK_CAP_TOTALS + CHUNK_BOUNDARY
 RECORD_TYPES = (REC_HEARTBEAT, REC_TRACKER, REC_RING, REC_RING_GAP,
                 REC_DIGEST, REC_FLEET_EXP, REC_FLEET_SUMMARY,
                 REC_FLEET_RETRY, REC_FLEET_QUARANTINE,
@@ -392,6 +402,7 @@ RING_GAUGES = (
     "ob_max_fill",      # running high-water per-window outbox fill
     "compact_max_fill", # running high-water compaction-bucket demand
     "mq_max_fill",      # running high-water boundary-pool fill (vs mq_pool)
+    "push_stage_max",   # running high-water events a host staged in a round
     "x2x_max_fill",     # running high-water all_to_all bucket demand
 )
 # Determinism flight recorder (core/digest.py, EngineParams.state_digest):

@@ -92,7 +92,7 @@ def ring_record(ring: TelemetryRing, m0, m1, ev_fill,
     # RING_GAUGES order minus the trailing replicated x2x_max_fill.
     gauges = jnp.stack(
         [ev_fill, m1.ev_max_fill, m1.ob_max_fill, m1.compact_max_fill,
-         m1.mq_max_fill]
+         m1.mq_max_fill, m1.push_stage_max]
     )
     if telem_reduce is not None:
         counters, gauges = telem_reduce(counters, gauges)

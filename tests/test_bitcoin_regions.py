@@ -27,10 +27,15 @@ from shadow1_tpu.config.experiment import (
 from shadow1_tpu.consts import MS
 from shadow1_tpu.core.engine import Engine
 from shadow1_tpu.cpu_engine import CpuEngine
-from shadow1_tpu.fleet.engine import FleetEngine, fleet_metrics_per_exp
+from shadow1_tpu.core.events import PUSH_RB
+from shadow1_tpu.fleet.engine import (
+    FleetEngine,
+    fleet_metrics_per_exp,
+    slice_experiment,
+)
 from shadow1_tpu.fleet.expand import expand_sweep
 from shadow1_tpu.telemetry import phases
-from tests.parity import PARITY_KEYS, lane_metrics
+from tests.parity import PARITY_KEYS, lane_metrics, unlike_leaves
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 REHEARSAL = os.path.join(ROOT, "tests", "rehearsal_bitcoin_regions")
@@ -170,6 +175,9 @@ def test_a_lane_equals_its_solo_run_and_the_cpu_oracle(fleet, plan, lane):
         assert np.array_equal(got[k], summary[k]), k
         assert np.array_equal(got[k], np.asarray(cs[k])), k
     assert have == lane_metrics(Engine.metrics_dict(sst))
+    # ... leaf for leaf, the event planes slot for slot: the fleet's one
+    # commit loop runs each round to the lane that staged most (PR 49).
+    assert not unlike_leaves(slice_experiment(st, lane), sst)
     assert {k: have[k] for k in PARITY_KEYS} == {k: cm[k] for k in PARITY_KEYS}
     assert have["windows"] == N_WINDOWS
     assert have["ev_overflow"] == have["ob_overflow"] == 0
@@ -190,6 +198,21 @@ def test_a_lane_equals_the_cpp_reference_counter_for_counter(fleet, plan, lane):
                 if k not in ("wall_s", "events_per_sec", "n_threads")}
     assert len(compared) >= 14 and {"total_seen", "total_tx_rx"} <= set(compared)
     assert all(a == b for a, b in compared.values()), compared
+
+
+def test_the_lanes_need_different_commit_trips(fleet):
+    """A node that first sees a transaction announces it to its eight peers
+    in one round: more events staged than a trip writes, in rounds that
+    differ by lane, so the lanes' own trip counts differ — and each is its
+    solo run's (``test_a_lane_equals_its_solo_run_and_the_cpu_oracle``
+    compares every counter but ``runs_*`` and every leaf)."""
+    _, st = fleet
+    lanes = fleet_metrics_per_exp(st)
+    assert all(PUSH_RB < ln["push_stage_max"] <= 9 for ln in lanes)
+    trips = [ln["push_commit_trips"] for ln in lanes]
+    assert len(set(trips)) == len(trips) and min(trips) > 1000
+    # Rounds that staged nothing made no trip; some made two.
+    assert all(t < 2 * ln["rounds"] for t, ln in zip(trips, lanes))
 
 
 def test_two_lanes_end_on_different_counters(fleet):

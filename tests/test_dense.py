@@ -32,6 +32,7 @@ import numpy as np
 import pytest
 
 from shadow1_tpu.core.dense import extract_col, get_col, read_sel
+from tests.test_tor_fleet import _named_eqns
 
 C, H = 5, 7
 RANKS = {"CH": (), "LCH": (3,), "LLCH": (2, 3)}
@@ -227,6 +228,90 @@ def test_tcp_round_sweeps_no_plane_a_queue_a_socket_tall(config, lanes):
     assert lead + (pr.mq_pool, h) in shapes
     assert sum(e.primitive.name == "gather" for e in eqns) \
         == ROUND_GATHERS[config]
+
+
+def _round_eqns(config: str, lanes: int):
+    """The ``rounds`` phase's equations with their name stacks: the solo
+    engine's, or for ``lanes`` the program a fleet traces — the lanes' axis
+    named, so that every guard's predicate is reduced over it and stays a
+    conditional (a plain ``vmap`` of the solo program turns each into both
+    branches and a select of every leaf)."""
+    import dataclasses
+
+    from shadow1_tpu.core.engine import window_frame, window_phases
+
+    eng = _engine(config)
+    ctx = dataclasses.replace(eng.ctx, lane_axis="lane") if lanes else eng.ctx
+    make = eng._model.make_handlers
+    fn = dict(window_phases(ctx, make(ctx), None, eng._pre_window, make,
+                            None))["rounds"]
+    fr = window_frame(eng.init_state(), ctx)
+    if lanes:
+        fn = jax.vmap(fn, axis_name="lane")
+        fr = jax.tree_util.tree_map(lambda x: jnp.stack([x] * lanes), fr)
+    return list(_named_eqns(jax.make_jaxpr(fn)(fr).jaxpr))
+
+
+# An equation that only hands its operands to a body of its own.
+CONTAINERS = {"while", "cond", "pjit", "jit", "closed_call", "core_call"}
+PLANE_SWEEPS = {"tgen100": "configs/rung2_tgen100.yaml",
+                "filexfer": "configs/rung1_filexfer.yaml",
+                "bitcoin64": "tests/rehearsal_bitcoin64/configs/bitcoin64.yaml",
+                "tor20": "benchmarks/tests/rehearsal/configs/tor20.yaml"}
+
+
+@pytest.mark.parametrize("lanes", [0, 2], ids=["solo", "fleet2"])
+@pytest.mark.parametrize("config", PLANE_SWEEPS)
+def test_tcp_round_sweeps_the_event_payload_in_pop_and_commit_only(config,
+                                                                   lanes):
+    """A round's pushes are staged as [H]-vectors and written in one commit
+    (PR 49): in the ``rounds`` phase no equation outside ``phase:pop`` and
+    ``phase:push_commit`` reads or writes an array ``[NP, ev_cap, W]`` (the
+    loops and conditionals that only carry it apart), both scopes do, and
+    the passes are there to be looked at."""
+    from shadow1_tpu.consts import NP
+
+    cap = _engine(PLANE_SWEEPS[config]).params.ev_cap
+    sweeps, scopes, same = [], set(), {}
+    for e, stack in _round_eqns(PLANE_SWEEPS[config], lanes):
+        scopes.update(stack.split("/"))
+        name = e.primitive.name
+        if name in CONTAINERS:
+            continue
+        # ``vmap`` hands a conditional whose predicate differs by lane (the
+        # per-stream guards of tcp/ and the apps) each operand as
+        # ``select(the lane takes this branch, x, stop_gradient(x))``: the
+        # same words either way, which XLA folds. Not a sweep.
+        if name == "stop_gradient":
+            same[e.outvars[0]] = same.get(e.invars[0], e.invars[0])
+            continue
+        if name == "select_n" and len(
+                {same.get(v, v) for v in e.invars[1:]}) == 1:
+            same[e.outvars[0]] = same.get(e.invars[1], e.invars[1])
+            continue
+        if name == "broadcast_in_dim" and e.outvars[0].aval.dtype == bool:
+            continue
+        shapes = [tuple(v.aval.shape) for v in (*e.invars, *e.outvars)
+                  if hasattr(v.aval, "shape")]
+        if any(len(s) >= 3 and s[-3:-1] == (NP, cap) for s in shapes):
+            sweeps.append((name, stack))
+    inside = [s for _, s in sweeps
+              if "phase:pop" in s or "phase:push_commit" in s]
+    outside = [(p, s) for p, s in sweeps if s not in inside]
+    assert not outside, (f"{len(outside)} equations over the payload plane "
+                         f"outside pop and push_commit, the first {outside[0]}")
+    assert any("phase:pop" in s for s in inside)
+    assert any("phase:push_commit" in s for s in inside)
+    assert {"phase:h_deliver", "phase:h_timer", "phase:tcp_flush"} <= scopes
+
+
+@pytest.mark.parametrize("lanes", [0, 2], ids=["solo", "fleet2"])
+def test_phold_round_pushes_directly(lanes):
+    """One handler kind, no conditional: the pass's pushes fuse as they are,
+    the round opens no stage and traces no ``push_commit`` scope."""
+    eqns = _round_eqns("configs/serve_phold.yaml", lanes)
+    assert any("phase:h_phold" in stack for _, stack in eqns)
+    assert not [stack for _, stack in eqns if "push_commit" in stack]
 
 
 @pytest.fixture(scope="module", params=["configs/serve_phold.yaml",

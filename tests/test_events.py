@@ -7,12 +7,18 @@ import pytest
 
 from shadow1_tpu.consts import NP, K_PHOLD
 from shadow1_tpu.core.events import (
+    PUSH_RB,
     RB,
+    PushRowsError,
     deliver_batch,
     evbuf_init,
+    free_slots,
     pop_until,
+    push_back,
+    push_commit,
     push_local,
     rebase,
+    stage_open,
     tb_join,
     tb_split,
 )
@@ -405,3 +411,131 @@ def test_outbox_append_matches_list_model(lanes):
             ob = clear(ob)
             rows = [[[] for _ in range(h)] for _ in range(n)]
     assert full_seen > 10       # the masks did run past the cap
+
+
+# ---------------------------------------------------------------------------
+# A round's pushes staged and committed once (PR 49) against the same pushes
+# written one after another.
+# ---------------------------------------------------------------------------
+
+def _holed_buffer(rng, hosts, cap):
+    """A buffer whose hosts have 0, 1, 2 and many free slots, the holes at
+    random slots, rebased at epoch 1,000 with a bound 500 past it."""
+    buf = evbuf_init(hosts, cap)
+    free = np.array([0, 1, 2] + list(rng.integers(3, cap + 1, hosts - 3)))
+    occ = np.stack([rng.permutation(cap) >= f for f in free], axis=1)
+    t = rng.integers(900, 2000, (cap, hosts))
+    thi, tlo = tb_split(jnp.asarray(t, jnp.int64))
+    bhi, blo = tb_split(jnp.asarray(rng.integers(0, 1 << 40, (cap, hosts)),
+                                    jnp.int64))
+    buf = buf._replace(
+        time_hi=jnp.where(occ, thi, buf.time_hi),
+        time_lo=jnp.where(occ, tlo, buf.time_lo),
+        tb_hi=jnp.where(occ, bhi, 0), tb_lo=jnp.where(occ, blo, 0),
+        kind=jnp.where(occ, 3, 0).astype(jnp.int32),
+        p=jnp.asarray(rng.integers(0, 99, (NP, cap, hosts)) * occ, jnp.int32),
+        self_ctr=jnp.asarray(rng.integers(0, 50, hosts), jnp.int64))
+    return rebase(buf, 1000, 1500), free
+
+
+def _sites(rng, hosts, n, share):
+    """``n`` push sites: (mask, time, kind, payload) each, every fourth a
+    ``push_back`` (tb given)."""
+    out = []
+    for i in range(n):
+        site = dict(
+            mask=jnp.asarray(rng.random(hosts) < share),
+            time=jnp.asarray(rng.integers(950, 1800, hosts), jnp.int64),
+            kind=jnp.asarray(rng.integers(1, 6, hosts), jnp.int32),
+            p=jnp.asarray(rng.integers(-5, 99, (NP, hosts)), jnp.int32))
+        if i % 4 == 3:
+            site["tb"] = jnp.asarray(
+                (1 << 41) + rng.integers(0, 1 << 20, hosts), jnp.int64)
+        out.append(site)
+    return out
+
+
+def _push_all(buf, sites):
+    overs = []
+    for s in sites:
+        if "tb" in s:
+            buf, over = push_back(buf, s["mask"], s["time"], s["tb"],
+                                  s["kind"], s["p"])
+        else:
+            buf, over = push_local(buf, s["mask"], s["time"], s["kind"],
+                                   s["p"])
+        overs.append(over)
+    return buf, jnp.stack(overs)
+
+
+@pytest.mark.parametrize("n_sites", [3, 11])
+@pytest.mark.parametrize("cap", [8, 96])
+@pytest.mark.parametrize("lanes", [1, 4], ids=["solo", "vmap4"])
+def test_a_staged_round_equals_its_pushes_one_after_another(lanes, cap,
+                                                            n_sites):
+    """N sites staged and committed once leave the buffer N sequential
+    pushes leave, LEAF FOR LEAF — planes (so slot for slot), ``self_ctr``,
+    ``n_elig`` — and the same overflow mask a site, ``push_back`` among
+    them; hosts with 0, 1, 2 and many free slots, masks drawn per site;
+    four lanes under ``vmap`` that need different trip counts (one stages
+    nothing), the loop's predicate reduced over them."""
+    hosts = 9
+    rng = np.random.default_rng(1000 * lanes + 10 * cap + n_sites)
+    bufs, sites = [], []
+    for lane in range(lanes):
+        buf, free = _holed_buffer(rng, hosts, cap)
+        assert sorted(free)[:3] == [0, 1, 2] and free.max() > 2
+        bufs.append(buf)
+        sites.append(_sites(rng, hosts, n_sites,
+                            share=0.8 if lanes == 1 else lane / 3))
+
+    def direct(buf, ss):
+        return _push_all(buf, ss)
+
+    def staged(buf, ss, any_lane=lambda hit: hit):
+        buf, overs = _push_all(stage_open(buf, n_sites, free_slots(buf)), ss)
+        buf, trips, n_max = push_commit(buf, any_lane)
+        assert buf.stage is None
+        return buf, overs, trips, n_max
+
+    if lanes == 1:
+        want = jax.jit(direct)(bufs[0], sites[0])
+        got = jax.jit(staged)(bufs[0], sites[0])
+        trips, n_max = [int(got[2])], [int(got[3])]
+    else:
+        stack = lambda xs: jax.tree.map(lambda *x: jnp.stack(x), *xs)
+        want = jax.jit(jax.vmap(direct))(stack(bufs), stack(sites))
+        reduce = lambda hit: jax.lax.pmax(hit.astype(jnp.int32), "lane") > 0
+        got = jax.jit(jax.vmap(lambda b, s: staged(b, s, reduce),
+                               axis_name="lane"))(stack(bufs), stack(sites))
+        trips, n_max = got[2].tolist(), got[3].tolist()
+        assert n_max[0] == 0 and trips[0] == 0      # a lane that staged nothing
+        assert len(set(trips)) > 1
+    for a, b in zip(jax.tree.leaves(want[:2]), jax.tree.leaves(got[:2]),
+                    strict=True):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert bool(np.asarray(want[1]).any())          # some site overflowed
+    assert trips == [-(-n // PUSH_RB) for n in n_max]
+    if n_sites > PUSH_RB and cap > 8:
+        assert max(n_max) > PUSH_RB                 # more than one trip ran
+
+
+def test_a_site_past_the_rows_or_inside_a_loop_fails_the_trace():
+    """The stage holds a row a site: one push more than the rows it was
+    opened with is refused when traced, and so is a push from inside a
+    loop's body (a site there would run more than once a round)."""
+    buf, _ = _holed_buffer(np.random.default_rng(5), 9, 8)
+    (site,) = _sites(np.random.default_rng(6), 9, 1, 0.5)
+    two = stage_open(buf, 2, free_slots(buf))
+    two, _ = _push_all(two, [site, site])
+    with pytest.raises(PushRowsError, match="more than the 2 push sites"):
+        _push_all(two, [site])
+
+    def body(_, b):
+        return _push_all(b, [site])[0]
+
+    with pytest.raises(PushRowsError, match="inside a loop"):
+        jax.lax.fori_loop(0, 2, body, stage_open(buf, 2, free_slots(buf)))
+    # The same loop around a direct push is nobody's business.
+    jax.lax.fori_loop(0, 2, body, buf)

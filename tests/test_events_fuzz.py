@@ -5,7 +5,9 @@ Python heap model; every pop's (mask, time, kind, tb, payload) and the
 final buffer census must match exactly — on one plain buffer, as the solo
 engine calls the primitives, and on two stacked buffers under ``jax.vmap``,
 as FleetEngine does, the two lanes running different operation sequences
-against two heap models.
+against two heap models; with every push written into the planes directly
+(PHOLD's round) and with every push staged and committed (a TCP round,
+PR 49).
 
 This is the unstructured counterpart of tests/test_events.py: the
 structured tests pin the documented contracts; the fuzz sweep hunts the
@@ -165,10 +167,42 @@ def _deliver(buf, *a):
 
 
 N_DELIVER = 7   # deliver batches are padded to this (masked) width
+N_BURST = 6     # push sites of a "burst": one handler pass's worth
 
 
+def _burst(buf, mask, t, tb, kind, p):
+    """N_BURST pushes, one a row of the arguments, the third a push_back."""
+    overs = []
+    for j in range(N_BURST):
+        if j == 2:
+            buf, over = ev.push_back(buf, mask[j], t[j], tb[j], kind[j], p[j])
+        else:
+            buf, over = ev.push_local(buf, mask[j], t[j], kind[j], p[j])
+        overs.append(over)
+    return buf, jnp.stack(overs)
+
+
+def _staged(fn, rows):
+    """``fn``'s pushes staged and committed once (a round of the TCP
+    engines, core/engine.run_round) instead of written one by one."""
+    def run(buf, *a):
+        buf, extra = fn(ev.stage_open(buf, rows, ev.free_slots(buf)), *a)
+        return ev.push_commit(buf)[0], extra
+
+    return run
+
+
+PUSHERS = {
+    "direct": (ev.push_local, ev.push_back, _burst),
+    "staged": (_staged(ev.push_local, 1), _staged(ev.push_back, 1),
+               _staged(_burst, N_BURST)),
+}
+
+
+@pytest.mark.parametrize("pushes", PUSHERS)
 @pytest.mark.parametrize("lanes", [1, 2], ids=["solo", "vmap2"])
-def test_event_core_fuzz_vs_heap_model(lanes):
+def test_event_core_fuzz_vs_heap_model(lanes, pushes):
+    push_local, push_back, burst = PUSHERS[pushes]
     H, C = 6, 10
     until_bound = 10_000
     rngs = [np.random.default_rng(20260731 + i) for i in range(lanes)]
@@ -191,7 +225,7 @@ def test_event_core_fuzz_vs_heap_model(lanes):
     do_rebase([0] * lanes)
     for step in range(300):
         ops = [rng.choice(["push", "pop", "pop", "rebase", "deliver",
-                           "pushback"]) for rng in rngs]
+                           "pushback", "burst"]) for rng in rngs]
         for op in dict.fromkeys(ops):
             on = [i for i in range(lanes) if ops[i] == op]
             args = [None] * lanes
@@ -212,7 +246,31 @@ def test_event_core_fuzz_vs_heap_model(lanes):
                     args[i] = (jnp.asarray(mask), jnp.asarray(t, jnp.int64),
                                jnp.asarray(kind, jnp.int32),
                                jnp.asarray(p, jnp.int32))
-                over = bufs.apply(ev.push_local, args)
+                over = bufs.apply(push_local, args)
+                for i in on:
+                    assert np.asarray(over[i]).tolist() == want[i], (step, i)
+            elif op == "burst":
+                # What one handler pass does to a host's buffer: several
+                # pushes in a row, some masked, one with a given tie-break.
+                for i in on:
+                    rng = rngs[i]
+                    mask = rng.random((N_BURST, H)) < 0.5
+                    t = epoch[i] + rng.integers(0, 200, (N_BURST, H))
+                    tb = TB_PACKET_BASE + pkt_ctr[i] + np.arange(H)
+                    pkt_ctr[i] += H
+                    tb = np.broadcast_to(tb, (N_BURST, H))
+                    kind = rng.integers(1, 5, (N_BURST, H))
+                    p = rng.integers(0, 100, (N_BURST, NP, H))
+                    want[i] = [
+                        models[i].push_back(mask[j], t[j], tb[j], kind[j],
+                                            p[j]) if j == 2 else
+                        models[i].push_local(mask[j], t[j], kind[j], p[j])
+                        for j in range(N_BURST)]
+                    args[i] = (jnp.asarray(mask), jnp.asarray(t, jnp.int64),
+                               jnp.asarray(tb, jnp.int64),
+                               jnp.asarray(kind, jnp.int32),
+                               jnp.asarray(p, jnp.int32))
+                over = bufs.apply(burst, args)
                 for i in on:
                     assert np.asarray(over[i]).tolist() == want[i], (step, i)
             elif op == "pop":
@@ -248,7 +306,7 @@ def test_event_core_fuzz_vs_heap_model(lanes):
                                jnp.asarray(tb, jnp.int64),
                                jnp.asarray(kind, jnp.int32),
                                jnp.asarray(p, jnp.int32))
-                over = bufs.apply(ev.push_back, args)
+                over = bufs.apply(push_back, args)
                 for i in on:
                     assert np.asarray(over[i]).tolist() == want[i], (step, i)
             elif op == "rebase":

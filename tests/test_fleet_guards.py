@@ -46,12 +46,13 @@ from tests.test_tgen_parity import tgen_exp
 from tests.test_tor_fleet import doc20
 
 GUARDED = (core_engine, bitcoin, tor)
-# The TCP stack's passes (deliver, timer, tx-resume, app) and the window end
-# (``deliver_window``, PR 40): reduced over the lanes. Its two guards (passive
+# The TCP stack's passes (deliver, timer, tx-resume, app), the window end
+# (``deliver_window``, PR 40) and the round's push commit (``_commit_pushes``,
+# PR 49): reduced over the lanes. Its two guards (passive
 # open, FIN) and tgen's / filexfer's two (teardown) recur with every
 # connection: a ``case`` on the solo engine, the lane's own predicate
 # (selects) on a fleet. Then the app's bootstrap guards.
-PASSES, PER_STREAM = 4 + 1, 2
+PASSES, PER_STREAM = 4 + 1 + 1, 2
 
 
 def _filexfer_exps():

@@ -75,8 +75,9 @@ def test_work_schema_and_ckpt_format():
     # link-telemetry accumulator leaf once more (v12), the deliver_ranks
     # Metrics leaf again (v13), the five runs_* leaves (v14),
     # runs_window_end (v15), the optional compact_buckets leaf (v16), and the
-    # message-boundary pool's leaves with its gauge and counter (v17).
-    assert CKPT_FORMAT == 17
+    # message-boundary pool's leaves with its gauge and counter (v17), the
+    # push commit's counter and gauge (v18).
+    assert CKPT_FORMAT == 18
 
 
 def test_stale_ckpt_format_rejected(tmp_path):

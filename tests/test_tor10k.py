@@ -272,9 +272,15 @@ def _instructions(eng):
 
 def _round_loops(instrs):
     """The ``while`` instructions of the rounds phase outside the handler
-    passes, by how deep they sit: ``.../phase:rounds)/while`` is depth 1."""
+    passes and the round's push commit (its own loop of trips, PR 49: one
+    ``while`` under ``phase:push_commit``'s guard, inside the round loop),
+    by how deep they sit: ``.../phase:rounds)/while`` is depth 1."""
     tails = [op.split("phase:rounds)", 1)[1] for _, opc, op in instrs
              if opc == "while" and "phase:rounds)" in op and "phase:h_" not in op]
+    commits = [t for t in tails if "phase:push_commit" in t]
+    assert len(commits) == 1 and commits[0].endswith("/while") \
+        and "phase:push_commit/cond/" in commits[0]
+    tails.remove(commits[0])
     return sorted(t.count("while") for t in tails)
 
 
@@ -571,8 +577,10 @@ def test_rung_4_itself_builds_a_fleet_of_one_at_full_width():
     leaves = jax.tree.leaves(st)
     sizes = sorted(((x.size * x.dtype.itemsize, x.shape) for x in leaves),
                    reverse=True)
-    assert len(leaves) == 133           # Metrics.mq_max_fill, mq_overflow
-    assert sum(b for b, _ in sizes) == 565_040_396
+    # Metrics.mq_max_fill, mq_overflow (PR 48); push_commit_trips,
+    # push_stage_max (PR 49: the stage itself is no leaf between rounds).
+    assert len(leaves) == 135
+    assert sum(b for b, _ in sizes) == 565_040_396 + 16
     assert sizes[0] == (102_400_000, (1, 10, 256, 10000))
     assert {st.model.tcp[k].shape for k in ("mq_sock", "mq_end", "mq_meta")} \
         == {(1, 256, 10000)}

@@ -265,7 +265,9 @@ def test_every_handler_op_of_the_tor_module_is_under_an_onion_scope(lanes):
         tb = e.source_info.traceback
         fns = {f.function_name for f in (tb.frames if tb else ())
                if f.file_name.endswith("shadow1_tpu/apps/tor.py")}
-        if not fns - not_handlers:
+        # The round's push commit runs after the passes (PR 49): an equation
+        # there with a frame of this file is ``jnp.where``'s cached body.
+        if not fns - not_handlers or "phase:push_commit" in stack:
             continue
         seen += 1
         scopes = [s for s in phases.PHASE.findall(stack) if s in TOR_SCOPES]

@@ -486,6 +486,41 @@ def test_retransmits_per_kpkt_reads_the_traced_chunk_off_the_chunk_log(
     assert read(None, counters, {}) is None
 
 
+def test_push_commit_trips_per_round_reads_the_traced_chunk_off_the_chunk_log(
+        traced_rows, monkeypatch):
+    """PR 49's reader: the commits' trips a round over the traced stretch,
+    a lane's own summed over the lanes; under one where most rounds stage
+    nothing; nothing from the parent's rows, which lack the total."""
+    read = _reader("push_commit_trips_per_round")
+    counters = {"chunks": 1, "windows": 5, "rounds": 1, "lanes": 2}
+    m250, m255 = traced_rows.m250, traced_rows.m255
+
+    def delta(k):
+        return int(np.sum(getattr(m255, k)) - np.sum(getattr(m250, k)))
+
+    assert 0 < delta("push_commit_trips") < delta("rounds")
+    assert read(None, counters, {}) == pytest.approx(
+        delta("push_commit_trips") / delta("rounds"))
+    assert read(None, {"chunks": 0, "windows": 0}, {}) is None
+    log = chunk_log()
+    rows = log.rows()
+    assert all("push_commit_trips" in r for r in rows)
+    monkeypatch.setattr(log, "rows", lambda wait_s=1.0: [
+        {k: v for k, v in r.items() if k != "push_commit_trips"} for r in rows])
+    assert _reader("events_per_round")(None, counters, {}) is not None
+    assert read(None, counters, {}) is None
+    # The real manifest lists the cells whose rows the stretch is read off.
+    from benchmarks.harness import manifest as mf
+
+    (entry,) = [e for e in mf.load(ROOT)["per_layer"]
+                if e["name"] == "push_commit_trips_per_round"]
+    assert entry == {
+        "name": "push_commit_trips_per_round", "unit": "count",
+        "better": "lower", "source": "program_counter",
+        "layer": "window program", "moves": "events_per_s",
+        "workloads": ["tor1k.seeds8", "tor10k.join", "tor1k_regions.lossy3s"]}
+
+
 def test_timer_ms_per_round_reads_the_timer_pass_s_row_of_the_roll_up():
     read = _reader("timer_ms_per_round")
     rollup = {"handlers": 0.5, "h_deliver": 0.3, "h_timer": 0.04}
