@@ -100,7 +100,10 @@ import jax
 #      commit of its staged pushes, core/events.push_commit; the ring row the
 #      gauge's column): two more i64 leaves in every snapshot. The stage
 #      itself is None between rounds and in no snapshot.
-CKPT_FORMAT = 18
+#  19: Metrics gains route_rows (outbox rows the executed window ends'
+#      route_outbox looked up, core/engine.deliver_window): one more i64
+#      leaf in every snapshot. A running sum like runs_window_end.
+CKPT_FORMAT = 19
 
 
 class CorruptCheckpointError(ValueError):

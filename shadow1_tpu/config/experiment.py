@@ -21,8 +21,14 @@ Schema:
     hosts:                       # expanded in order into host ids 0..H-1
       - name: relay
         count: 8
-        vertex: 0                # attachment PoP (int id or GraphML node id,
-                                 # or "spread" = round-robin over vertices)
+        vertex: 0                # attachment PoP: an int id or a GraphML node
+                                 # id (the whole group on one vertex);
+                                 # "spread" = host i of the group on vertex
+                                 # i mod V, over ALL vertices in file order;
+                                 # {spread: {countrycode: X}} = the same deal
+                                 # over the vertices whose GraphML
+                                 # ``countrycode`` is X (Shadow's
+                                 # countrycodehint, without a draw)
         bandwidth_up: 100 Mbit   # "<num> <bit|Kbit|Mbit|Gbit>"/s
         bandwidth_down: 100 Mbit
     app:
@@ -195,13 +201,34 @@ def _expand_hosts(spec: list[dict]) -> list[HostGroup]:
     return groups
 
 
-def _vertex_assignment(groups, vertex_names, n_hosts) -> np.ndarray:
+def _vertices_with_code(group: str, spec: dict, codes) -> np.ndarray:
+    """The vertices, in file order, that a group's ``vertex: {spread:
+    {countrycode: X}}`` deals its hosts over."""
+    hint = spec.get("spread")
+    assert set(spec) == {"spread"} and isinstance(hint, dict) \
+        and set(hint) == {"countrycode"}, (
+            f"hosts[{group}].vertex: a mapping must be "
+            f"{{spread: {{countrycode: <code>}}}}, not {spec!r}")
+    code = str(hint["countrycode"])
+    where = np.flatnonzero([c == code for c in codes])
+    have = sorted({c for c in codes if c is not None})
+    assert where.size, (
+        f"hosts[{group}].vertex: no vertex has countrycode {code!r} "
+        f"(the topology's vertices carry {have or 'no countrycode'})")
+    return where
+
+
+def _vertex_assignment(groups, vertex_names, n_hosts, codes) -> np.ndarray:
     n_v = max(len(vertex_names), 1)
     name_idx = {str(n): i for i, n in enumerate(vertex_names)}
     hv = np.zeros(n_hosts, np.int32)
     for g in groups:
         if g.vertex_spec == "spread":
             hv[g.start:g.start + g.count] = np.arange(g.count) % n_v
+        elif isinstance(g.vertex_spec, dict):
+            among = _vertices_with_code(g.name, g.vertex_spec, codes)
+            hv[g.start:g.start + g.count] = among[
+                np.arange(g.count) % among.size]
         elif isinstance(g.vertex_spec, int):
             hv[g.start:g.start + g.count] = g.vertex_spec
         else:
@@ -538,12 +565,13 @@ def build_experiment(doc: dict, base_dir: str = ".") -> tuple[CompiledExperiment
         path = net["graphml"]
         if not os.path.isabs(path):
             path = os.path.join(base_dir, path)
-        names, lat_e, loss_e, directed, prefer_direct = load_graphml(path)
+        names, lat_e, loss_e, directed, prefer_direct, codes = \
+            load_graphml(path)
         lat_vv, loss_vv = compile_paths(lat_e, loss_e, directed=directed,
                                         prefer_direct=prefer_direct)
     else:
         sv = net.get("single_vertex", {})
-        names = ["v0"]
+        names, codes = ["v0"], [None]
         lat_vv = np.full((1, 1), parse_time_ns(sv.get("latency", "10 ms")), np.int64)
         loss_vv = np.full((1, 1), float(sv.get("loss", 0.0)), np.float32)
     # Per-packet path-latency jitter amplitude (± ns), uniform over all
@@ -557,7 +585,7 @@ def build_experiment(doc: dict, base_dir: str = ".") -> tuple[CompiledExperiment
     # -- hosts -------------------------------------------------------------
     groups = _expand_hosts(doc.get("hosts", [{"name": "host", "count": 1}]))
     h = sum(g.count for g in groups)
-    host_vertex = _vertex_assignment(groups, names, h)
+    host_vertex = _vertex_assignment(groups, names, h, codes)
     bw_up = np.zeros(h, np.int64)
     bw_dn = np.zeros(h, np.int64)
     stop_time = np.zeros(h, np.int64)

@@ -2,8 +2,7 @@
 
 The reference loads a GraphML network graph with igraph and answers
 latency/reliability queries with lazily-cached Dijkstra runs
-(src/main/routing/topology.c getLatency/getReliability). Published
-Shadow/Tor topology files therefore work here unchanged. We instead compile
+(src/main/routing/topology.c getLatency/getReliability). We instead compile
 the whole graph ONCE on the host into dense vertex-level tensors
 (SURVEY §7.1: exploit the vertex/host split — topologies have few network
 vertices with many attached hosts):
@@ -12,25 +11,57 @@ vertices with many attached hosts):
 * ``loss_vv`` — end-to-end loss probability along those same paths
   (1 - Π(1-loss_e), the reference's per-edge reliability product).
 
-Edge attributes honored (reference GraphML schema): ``latency`` (float,
-*milliseconds* — Shadow convention) or ``latency_ns``; ``packetloss``
-(probability). Vertices are the points of presence hosts attach to. The
-graph attribute ``preferdirectpaths`` (Shadow's; "True"/"False") makes an
-edge the path between its two ends even where a detour is shorter: a table
-of measured end-to-end latencies is then read as it stands.
+A published Shadow/Tor topology file loads here unchanged, and this is what
+is read of it (the reference's GraphML schema):
+
+* edge ``latency`` (float, *milliseconds* — Shadow convention) or
+  ``latency_ns``, and edge ``packetloss`` (probability; ``loss`` is taken for
+  it). A self-loop gives the latency and loss between two hosts of its vertex;
+* vertex ``countrycode``: what a host group's ``vertex: {spread:
+  {countrycode: X}}`` places by (config/experiment.py; Shadow's
+  ``countrycodehint``). Vertices are the points of presence hosts attach to;
+* graph ``preferdirectpaths`` (Shadow's; "True"/"False"): an edge is the
+  path between its two ends even where a detour is shorter, so a table of
+  measured end-to-end latencies is read as it stands.
+
+Every other attribute a file carries is IGNORED, and ``load_graphml`` says
+so once, by name: an edge's ``jitter`` (``network.jitter`` in the experiment
+file is the one jitter there is), a vertex's ``bandwidthup`` /
+``bandwidthdown`` (a host group's ``bandwidth_up`` / ``bandwidth_down``
+decide) and a vertex's ``packetloss``, ``ip``, ``citycode``, ``type`` and
+whatever else.
 """
 
 from __future__ import annotations
+
+import warnings
+from typing import NamedTuple
 
 import numpy as np
 
 from shadow1_tpu.consts import MS
 
+# What is read of a file, by the element that carries it (the docstring above).
+_EDGE_ATTRS = ("latency", "latency_ns", "packetloss", "loss")
+_VERTEX_ATTRS = ("countrycode",)
 
-def load_graphml(path: str):
-    """Returns (vertex_ids, lat_e, loss_e, directed, prefer_direct): node id
-    list (stable order), dense [V, V] edge matrices (np.inf / 0 where no
-    edge), and whether the graph sets ``preferdirectpaths``.
+
+class LoadedGraph(NamedTuple):
+    """``load_graphml``'s result: the node id list (stable order), dense
+    [V, V] edge matrices (np.inf / 0 where no edge), whether the graph is
+    directed, whether it sets ``preferdirectpaths``, and each vertex's
+    ``countrycode`` in the ids' order (None where a vertex has none)."""
+    vertex_ids: list
+    lat_e: np.ndarray
+    loss_e: np.ndarray
+    directed: bool
+    prefer_direct: bool
+    countrycodes: list
+
+
+def load_graphml(path: str) -> LoadedGraph:
+    """Read one GraphML topology (the module docstring says which
+    attributes), warning once of those it carries and nothing reads.
 
     Directed GraphML (Shadow's published Tor topologies use
     edgedefault="directed" with possibly asymmetric latencies) keeps each
@@ -40,6 +71,17 @@ def load_graphml(path: str):
     g = nx.read_graphml(path)
     directed = g.is_directed()
     nodes = list(g.nodes())
+    codes = [None if (c := g.nodes[n].get("countrycode")) is None else str(c)
+             for n in nodes]
+    ignored = sorted(
+        {f"vertex {k}" for _, d in g.nodes(data=True) for k in d
+         if k not in _VERTEX_ATTRS}
+        | {f"edge {k}" for _, _, d in g.edges(data=True) for k in d
+           if k not in _EDGE_ATTRS})
+    if ignored:
+        warnings.warn(f"{path}: attributes carried and not read: "
+                      f"{', '.join(ignored)} (config/topology.py says what is)",
+                      stacklevel=2)
     index = {n: i for i, n in enumerate(nodes)}
     v = len(nodes)
     lat = np.full((v, v), np.inf)
@@ -64,7 +106,7 @@ def load_graphml(path: str):
     if prefer not in ("true", "false"):
         raise ValueError(f"{path}: preferdirectpaths must be True or False, "
                          f"not {prefer!r}")
-    return nodes, lat, loss, directed, prefer == "true"
+    return LoadedGraph(nodes, lat, loss, directed, prefer == "true", codes)
 
 
 def compile_paths(lat_e: np.ndarray, loss_e: np.ndarray,

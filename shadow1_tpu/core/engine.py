@@ -139,6 +139,12 @@ class Metrics(NamedTuple):
     # fill loop's trips * RB, summed over windows) — against windows * ev_cap
     # it says what a fill by slot would sweep. Batch-engine-only like fires_*.
     deliver_ranks: jnp.ndarray
+    # Outbox rows the window ends' ``route_outbox`` looked up: ``outbox_cap
+    # × hosts`` in each window whose end the program ran (a skipped one adds
+    # 0), filled or not — against ``pkts_sent`` the share of the lookups
+    # that had a packet. The program's count like ``runs_window_end`` (one
+    # number in every lane of a fleet), summed over shards.
+    route_rows: jnp.ndarray
     # The round's one commit of its staged pushes (events.push_commit):
     # trips the lane's own rounds needed, PUSH_RB ranks a trip (0 in a round
     # that staged nothing; against ``rounds`` it says how often one trip did
@@ -817,7 +823,8 @@ def deliver_window(st: SimState, ctx: Ctx, exchange=None) -> SimState:
     counter, not a sort of the outbox's capacity (PERF.md §6, PR 40). A lane
     that sent nothing while another did runs it, as that identity
     (``run_round``'s contract, one axis up). ``runs_window_end`` counts the
-    windows the program ran it.
+    windows the program ran it, ``route_rows`` the outbox rows it looked up
+    in them (every row of the block, filled or not).
 
     The sharded engine keeps its window end unguarded: ``exchange`` is a
     collective that every shard must enter, and a predicate that differs by
@@ -832,8 +839,11 @@ def deliver_window(st: SimState, ctx: Ctx, exchange=None) -> SimState:
                           lane_branch(ctx, lambda s: _window_end(s, ctx)),
                           lambda s: s, st)
     m = st.metrics
+    ran = runs.astype(jnp.int64)
+    cap, h = st.outbox.dst.shape
     return st._replace(metrics=m._replace(
-        runs_window_end=m.runs_window_end + runs.astype(jnp.int64)))
+        runs_window_end=m.runs_window_end + ran,
+        route_rows=m.route_rows + ran * (cap * h)))
 
 
 def _window_end(st: SimState, ctx: Ctx, exchange=None) -> SimState:

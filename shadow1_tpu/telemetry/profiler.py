@@ -48,6 +48,7 @@ from shadow1_tpu.telemetry.registry import (
     CHUNK_CAP_TOTALS,
     CHUNK_LOSS_TOTALS,
     CHUNK_PUSH_TOTALS,
+    CHUNK_ROUTE_TOTALS,
     CHUNK_TOTALS,
     REC_STALL,
 )
@@ -279,9 +280,10 @@ _TOTALS = CHUNK_TOTALS[:-1]
 _CAP_TOTALS = {"buckets": "compact_buckets"}
 assert tuple(_CAP_TOTALS) == CHUNK_CAP_TOTALS
 # The ``Metrics`` fields among a row's totals: ``_TOTALS``, the loss plane's
-# (what a chunk sent, lost, resent and dropped out of order) and the push
-# commits' trips.
-_METRIC_TOTALS = (*_TOTALS, *CHUNK_LOSS_TOTALS, *CHUNK_PUSH_TOTALS)
+# (what a chunk sent, lost, resent and dropped out of order), the push
+# commits' trips and the window ends' route lookups.
+_METRIC_TOTALS = (*_TOTALS, *CHUNK_LOSS_TOTALS, *CHUNK_PUSH_TOTALS,
+                  *CHUNK_ROUTE_TOTALS)
 # Every total a row may carry, in the order ``_input_leaves`` reads them.
 _ROW_TOTALS = (*_METRIC_TOTALS, *_CAP_TOTALS)
 # ... and in the order a heartbeat's block lists them, ``hosts`` among them.
@@ -307,8 +309,9 @@ def work_between(row: dict, after: dict | None) -> dict | None:
     """What the chunk of ``row`` did — events, rounds (a lane's own, summed
     over lanes), ``active_hosts`` and ``elig_events`` (sums over its
     windows), the loss plane's ``pkts_sent``, ``pkts_lost``,
-    ``tcp_fast_rtx``, ``tcp_rto``, ``tcp_ooo_drops``, ``push_commit_trips`` where both rows carry
-    them, ``buckets`` (the compacted round loop's trips) where the
+    ``tcp_fast_rtx``, ``tcp_rto``, ``tcp_ooo_drops``, ``push_commit_trips``,
+    ``route_rows`` (the outbox rows its window ends looked up) where both
+    rows carry them, ``buckets`` (the compacted round loop's trips) where the
     program counts them — where ``after`` is the row of the chunk that
     continued it: the same engine's, adjacent in ``seq``, starting on the
     window ``row`` ended on. Else None: a row's totals are of its chunk's
@@ -417,7 +420,8 @@ class ChunkLog:
     ``first_window`` (the input state's ``metrics.windows``), ``windows``,
     ``events``, ``rounds``, ``active_hosts``, ``elig_events``, the loss
     plane's ``pkts_sent``, ``pkts_lost``, ``tcp_fast_rtx``, ``tcp_rto``,
-    ``tcp_ooo_drops``, the push commits' ``push_commit_trips``, and where a
+    ``tcp_ooo_drops``, the push commits' ``push_commit_trips``, the window
+    ends' ``route_rows``, and where a
     ``compact_cap`` is in force ``buckets`` (the input
     state's running totals, summed over a fleet's lanes: what the chunk did
     is the NEXT row's less these, ``work_between``) and ``hosts`` (the

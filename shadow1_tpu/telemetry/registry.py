@@ -85,6 +85,9 @@ METRIC_SPECS: dict[str, tuple[str, str]] = {
                                  "under sharding)"),
     "deliver_ranks": (COUNTER, "arriving ranks swept by the window-end merge "
                                "(deliver_batch's trips * RB; batch engines)"),
+    "route_rows": (COUNTER, "outbox rows the executed window ends' "
+                            "route_outbox looked up (outbox_cap x hosts a "
+                            "window end run; vs pkts_sent; batch engines)"),
     "push_commit_trips": (COUNTER, "trips of the round's commit of its staged "
                                    "pushes (events.push_commit, PUSH_RB ranks "
                                    "a trip; vs rounds; batch engines)"),
@@ -123,13 +126,14 @@ HOST_FIELDS = ("chunk_retries", "retry_windows_rerun")
 
 # Counters of the PROGRAM a lane rode in, not of the lane's simulation: the
 # guard predicate reduced over a fleet's lanes (core/engine.any_host): the
-# handler passes' (equal to fires_* on a solo engine) and the window end's.
+# handler passes' (equal to fires_* on a solo engine), the window end's, and
+# the outbox rows those window ends looked up.
 # On a fleet one number in every lane, and another number for the same lane
 # in a fleet of other lanes. A comparison of a fleet lane with its solo run
 # (or with the same lane in another fleet) leaves out exactly these and
 # nothing else.
 LANE_PROGRAM_FIELDS = tuple(f[2] for f in KIND_METRIC_FIELDS.values()) + (
-    "runs_window_end",)
+    "runs_window_end", "route_rows")
 
 # Counts the ROUND LOOP makes of itself: its iterations and the handler
 # passes they fired and ran. Where a compact_cap is in force a window's
@@ -267,22 +271,27 @@ CHUNK_BOUNDARY = ("commit_ms", "on_chunk_ms", "drain_ms", "checkpoint_ms",
 # CHUNK_PUSH_TOTALS: the trips of the rounds' push commits, a Metrics field
 # read in the same pass: against the chunk's ``rounds`` it says how many of
 # them needed a second trip (core/events.push_commit).
+# CHUNK_ROUTE_TOTALS: the outbox rows the executed window ends looked up, a
+# Metrics field read in the same pass: the chunk's ``pkts_sent`` against it
+# is the share of the route lookups that had a packet.
 CHUNK_TOTALS = ("events", "rounds", "active_hosts", "elig_events", "hosts")
 CHUNK_CAP_TOTALS = ("buckets",)
 CHUNK_LOSS_TOTALS = ("pkts_sent", "pkts_lost", "tcp_fast_rtx", "tcp_rto",
                      "tcp_ooo_drops")
 CHUNK_PUSH_TOTALS = ("push_commit_trips",)
+CHUNK_ROUTE_TOTALS = ("route_rows",)
 STALL_WORK = ("rounds", "events", "median_of_rounds", "median_of_events")
 STALL_LOSS_WORK = ("retransmits", "pkts_lost", "median_of_retransmits")
 CHUNK_BLOCK = (("dispatch_ms", "wait_ms", "turnaround_ms") + CHUNK_TOTALS
-               + CHUNK_LOSS_TOTALS + CHUNK_PUSH_TOTALS + CHUNK_CAP_TOTALS
-               + CHUNK_BOUNDARY)
+               + CHUNK_LOSS_TOTALS + CHUNK_PUSH_TOTALS + CHUNK_ROUTE_TOTALS
+               + CHUNK_CAP_TOTALS + CHUNK_BOUNDARY)
 CHUNK_HEALTH = ("cpu_s", "nivcsw", "nvcsw", "majflt", "inblock", "oublock",
                 "psi_cpu_us", "psi_io_us", "psi_mem_us", "load1")
 CHUNKS_BLOCK = ("count", "stalls", "rows", "windows", "dispatch_ms",
                 "args_ms", "call_ms", "wait_ms", "turnaround_ms",
                 "boundary_ms", "boundary_share") + CHUNK_TOTALS \
-    + CHUNK_LOSS_TOTALS + CHUNK_PUSH_TOTALS + CHUNK_CAP_TOTALS + CHUNK_BOUNDARY
+    + CHUNK_LOSS_TOTALS + CHUNK_PUSH_TOTALS + CHUNK_ROUTE_TOTALS \
+    + CHUNK_CAP_TOTALS + CHUNK_BOUNDARY
 RECORD_TYPES = (REC_HEARTBEAT, REC_TRACKER, REC_RING, REC_RING_GAP,
                 REC_DIGEST, REC_FLEET_EXP, REC_FLEET_SUMMARY,
                 REC_FLEET_RETRY, REC_FLEET_QUARANTINE,

@@ -648,7 +648,8 @@ def test_the_cli_s_heartbeats_and_last_line_carry_the_documented_blocks(tmp_path
         if i == 0:
             want = ({"dispatch_ms", "wait_ms"} | set(registry.CHUNK_TOTALS)
                     | set(registry.CHUNK_LOSS_TOTALS)
-                    | set(registry.CHUNK_PUSH_TOTALS))
+                    | set(registry.CHUNK_PUSH_TOTALS)
+                    | set(registry.CHUNK_ROUTE_TOTALS))
         assert want <= set(block) <= set(registry.CHUNK_BLOCK + registry.CHUNK_HEALTH)
         assert want == set(block) - set(registry.CHUNK_HEALTH)
         assert block["dispatch_ms"] > 0 and block["wait_ms"] >= 0

@@ -278,12 +278,12 @@ def test_a_snapshot_from_before_the_pool_is_refused_as_a_version_error(tmp_path)
     st = eng.init_state()
     path = str(tmp_path / "snap.npz")
     ckpt.save_state(st, path)
-    assert ckpt.CKPT_FORMAT == 18
+    assert ckpt.CKPT_FORMAT == 19
     with np.load(path) as d:
         arrs = {k: d[k].copy() for k in d.files}
     arrs["format"][0] = 16
     np.savez(path, **arrs)
-    with pytest.raises(ValueError, match="format v16.*reads v18"):
+    with pytest.raises(ValueError, match="format v16.*reads v19"):
         ckpt.load_state(st, path)
 
 

@@ -76,8 +76,8 @@ def test_work_schema_and_ckpt_format():
     # Metrics leaf again (v13), the five runs_* leaves (v14),
     # runs_window_end (v15), the optional compact_buckets leaf (v16), and the
     # message-boundary pool's leaves with its gauge and counter (v17), the
-    # push commit's counter and gauge (v18).
-    assert CKPT_FORMAT == 18
+    # push commit's counter and gauge (v18), route_rows (v19).
+    assert CKPT_FORMAT == 19
 
 
 def test_stale_ckpt_format_rejected(tmp_path):

@@ -578,9 +578,10 @@ def test_rung_4_itself_builds_a_fleet_of_one_at_full_width():
     sizes = sorted(((x.size * x.dtype.itemsize, x.shape) for x in leaves),
                    reverse=True)
     # Metrics.mq_max_fill, mq_overflow (PR 48); push_commit_trips,
-    # push_stage_max (PR 49: the stage itself is no leaf between rounds).
-    assert len(leaves) == 135
-    assert sum(b for b, _ in sizes) == 565_040_396 + 16
+    # push_stage_max (PR 49: the stage itself is no leaf between rounds);
+    # route_rows (PR 50).
+    assert len(leaves) == 136
+    assert sum(b for b, _ in sizes) == 565_040_396 + 24
     assert sizes[0] == (102_400_000, (1, 10, 256, 10000))
     assert {st.model.tcp[k].shape for k in ("mq_sock", "mq_end", "mq_meta")} \
         == {(1, 256, 10000)}

@@ -496,8 +496,10 @@ def _end_state(eng: Engine, seed: int, sent: bool):
 
 def _counted(st, runs):
     m = st.metrics
+    runs = jnp.asarray(runs, jnp.int64)
     return st._replace(metrics=m._replace(
-        runs_window_end=m.runs_window_end + jnp.asarray(runs, jnp.int64)))
+        runs_window_end=m.runs_window_end + runs,
+        route_rows=m.route_rows + runs * int(np.prod(st.outbox.dst.shape[-2:]))))
 
 
 @pytest.fixture(scope="module", params=[0, 1], ids=["links_off", "links_on"])
