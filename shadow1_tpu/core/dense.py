@@ -102,6 +102,47 @@ def extract_col(sel, arr):
     return jnp.where(s, arr, 0).sum(axis=-2, dtype=arr.dtype)
 
 
+def table_rows(table, row):
+    """``table[row[h], j]`` of a 64-bit ``[R, W]`` table → ``(lo, hi)``, its
+    two u32 half words as ``[W, H]`` planes: every host's row of the table,
+    without an index per host.
+
+    The one-hot read again, over the table's row axis, as a product: the
+    table cut into its eight byte planes (bf16 holds a byte exactly), times
+    ``onehot(row)``, accumulated in f32 — each sum has one non-zero term, a
+    byte, so the read is exact on any backend (``rng._log_tbl_read`` is the
+    precedent). The MXU does R · W · H multiply-adds a byte plane, which at
+    a few hundred rows is tens of µs where the same one-hot as compares and
+    selects is R · W · H VPU operations a half word (PERF.md §6, PR 51). A
+    traced table (a fleet lane's) is cut at run time, [R, W] operations; a
+    closed-over one folds."""
+    n_rows, width = table.shape
+    t = table.astype(jnp.uint64)
+    planes = jnp.stack([
+        ((t >> jnp.uint64(8 * i)) & jnp.uint64(0xFF)).astype(jnp.bfloat16).T
+        for i in range(8)]).reshape(8 * width, n_rows)      # rows (byte, j)
+    b = jnp.dot(
+        planes, onehot_col(row, n_rows).astype(jnp.bfloat16),
+        preferred_element_type=jnp.float32,
+    ).astype(jnp.uint32).reshape(8, width, -1)
+    return (b[0] | (b[1] << 8) | (b[2] << 16) | (b[3] << 24),
+            b[4] | (b[5] << 8) | (b[6] << 16) | (b[7] << 24))
+
+
+def pick_row(planes, idx):
+    """``plane[idx[c, h], h]`` for each ``[W, H]`` plane of ``planes`` and an
+    ``idx`` ``[C, H]`` → a ``[C, H]`` plane each (0 where ``idx`` is outside
+    the plane): ``get_col`` with a column of indices per host. One masked
+    sum per plane over the W axis, in the plane's own dtype (at most one
+    non-zero term: the plane's own integer);
+    XLA:TPU makes ONE fusion of the planes' sums and their shared compare,
+    and stores nothing ``[W, C, H]`` wide — a compare shared through a
+    stacked plane axis it stores (PERF.md §6, PR 51)."""
+    sel = idx[None] == jnp.arange(planes[0].shape[0], dtype=idx.dtype)[:, None, None]
+    return tuple(jnp.where(sel, p[:, None, :], 0).sum(axis=0, dtype=p.dtype)
+                 for p in planes)
+
+
 def first_true(m) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Per-host first True of a bool [C, H]: (any[H], onehot [C, H]).
 

@@ -38,6 +38,7 @@ from shadow1_tpu.consts import (
     EngineParams,
     packet_tb,
 )
+from shadow1_tpu.core.dense import pick_row, table_rows
 from shadow1_tpu.core.events import (
     NEVER,
     EventBuf,
@@ -306,23 +307,39 @@ class Ctx:
             )
 
 
-# route_outbox's two static bounds, on what it can see when it is traced.
+# route_outbox's three static bounds, on what it can see when it is traced.
 # A read of ``host_vertex`` or of a [V, V] path table with one index per
-# outbox row is an element-serial gather on the TPU, 7–13 ns a row and half
-# word whatever the outbox holds (PERF.md §6, PR 34 and PR 42); the same read
-# as compares and selects over the rows fuses into the arrival add and the
-# loss compare. What bounds the dense form is the size of the traced
-# program, not the chip's time:
+# outbox row is an element-serial gather on the TPU, 7–14 ns a row and half
+# word whatever the outbox holds (PERF.md §6, PRs 34, 42 and 50); the same
+# read as compares and selects over the rows fuses into the arrival add and
+# the loss compare. What bounds each dense form:
 # - a host → vertex map costs one compare and select per run and row, so it
 #   is dense up to this many runs (one per host group attached in order; the
 #   largest config under configs/ has 6) and a gather beyond (``vertex:
-#   spread`` has one run per host);
+#   spread`` has one run per host): the traced program's size, not the
+#   chip's time;
 MAX_VERTEX_RUNS = 32
-# - a path table costs V − 1 selects per row and V · (V − 1) more over [H]
-#   for the per-host rows, for each of up to three tables: 720 + 45 at 16
-#   vertices, four times that at 32. A GraphML topology with more vertices
-#   keeps ``table[vs, vd]``.
+# - a path table is read in two steps, the table's row per host ([V, H]: every
+#   slot of an outbox column has the column's source) and a pick over the
+#   destination's vertex per slot. As selects, the per-host step is
+#   V · (V − 1) of them over [H] and the pick V − 1 per row, for each of up
+#   to three tables: 720 + 45 equations at 16 vertices, four times that at
+#   32 — again the program's size;
 MAX_DENSE_VERTICES = 16
+# - past that the per-host step is ONE read of H table rows (a product of the
+#   table's byte planes with a one-hot of the hosts' vertices,
+#   core/dense.table_rows) and the pick a masked sum (core/dense.pick_row):
+#   a handful of equations whatever V, whose time grows with V — the pick
+#   does cap · H · V compare-selects where the gather pays cap · H indices,
+#   and the rows' byte planes are 32 · V · H bytes a table and lane. On the
+#   v5e, one table of two lanes of 5,000 hosts and 64 slots (PERF.md §6, PR
+#   51): 0.26 ms at 200 vertices, the planes held on the chip, against the
+#   gather's 15.2; 3.4 ms at 512 and 6.9 at 1,024 against 16.4–18.1, the
+#   planes by then 0.33 and 0.66 GB of HBM scratch. At twice that the
+#   scratch alone is past what a window end may ask for, and the link
+#   plane's own [V, V] bound is 1,024 too. A larger topology keeps
+#   ``table[vs, vd]``.
+MAX_ROW_VERTICES = 1024
 
 
 def _vertex_runs(host_vertex):
@@ -668,11 +685,16 @@ def route_outbox(ctx: Ctx, ob: Outbox, links=None, win_start=None):
     (``src`` is ``ctx.hosts`` in every slot, a contiguous range of ids);
     ``vd`` is ``vertex_of(dst)``: compares against the runs of
     ``host_vertex`` where they are few, the lookup where they are many
-    (``vertex: spread``); and a table of up to MAX_DENSE_VERTICES vertices
-    is read by two selects, over ``vs`` per host and over ``vd`` per slot,
-    the table's own integers bit for bit — a larger one by the lookup
-    ``table[vs, vd]``. Which form is traced depends on the map and the
-    tables' shape alone, the same on every engine.
+    (``vertex: spread``); and a path table is read in two steps, the
+    table's row per host and a pick over ``vd`` per slot, the table's own
+    integers bit for bit: both steps selects up to MAX_DENSE_VERTICES
+    vertices, then up to MAX_ROW_VERTICES the per-host step one read of H
+    table rows (``core/dense.table_rows``) and the pick a masked sum
+    (``core/dense.pick_row``) — only a larger table by the lookup
+    ``table[vs, vd]``. The rows are transients of the window end, [V, H] a
+    half word, never a leaf of the state or a constant of the program.
+    Which form is traced depends on the map and the tables' shape alone,
+    the same on every engine.
 
     With the link plane on (``links`` a LinkAccum, ``win_start`` the window
     start), every offered packet's edge contribution — counts, wire bytes,
@@ -717,8 +739,13 @@ def route_outbox(ctx: Ctx, ob: Outbox, links=None, win_start=None):
 
         def vv(table):
             with jax.named_scope("phase:route_path"):
-                if n_v > MAX_DENSE_VERTICES:
+                if n_v > MAX_ROW_VERTICES:
                     return table[vs, vd]
+                if n_v > MAX_DENSE_VERTICES:
+                    # The two steps below with no equation per vertex pair.
+                    lo, hi = pick_row(table_rows(table, vs_h), vd_ch)
+                    return flat((hi.astype(jnp.uint64) << jnp.uint64(32)
+                                 | lo.astype(jnp.uint64)).astype(table.dtype))
                 # table[vs, vd], the table's own integers: per host the row
                 # table[vs_h, :] by a select over the source vertex (a
                 # constant for a closed-over table, [H] a lane for the

@@ -8,8 +8,8 @@ of a GraphML and what it says of the rest, and a host group's placement by
 ``countrycode``; (c) what RUNS is the deployment in miniature
 (``tests/rehearsal_bitcoin_cities``: the 200-city file itself, two hosts a
 city, 16 transactions from 300 ms) as two lanes of the fleet engine for 100
-windows of 11 ms — both of ``route_outbox``'s per-row lookups traced, packets
-lost, RTOs and out-of-order drops live — held to the solo engine leaf for
+windows of 11 ms — ``host_vertex[dst]`` a lookup per outbox row, the path
+tables read per host row and picked per slot (PR 51), packets lost, RTOs and out-of-order drops live — held to the solo engine leaf for
 leaf, to the CPU oracle and to the C++ reference counter for counter, with
 ``Metrics.route_rows``; (d) the new per-layer reader; (e) the cell in
 miniature through the benchmark's own harness, and under ``wrong_seed``.
@@ -25,6 +25,7 @@ import types
 import warnings
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 import yaml
@@ -34,6 +35,7 @@ from shadow1_tpu.config.topology import load_graphml
 from shadow1_tpu.consts import MS
 from shadow1_tpu.core.engine import (
     MAX_DENSE_VERTICES,
+    MAX_ROW_VERTICES,
     MAX_VERTEX_RUNS,
     Engine,
     route_outbox,
@@ -53,6 +55,7 @@ from shadow1_tpu.telemetry.registry import (
 )
 from shadow1_tpu.tools import topogen
 from tests.parity import PARITY_KEYS, lane_metrics, unlike_leaves
+from tests.test_tor_fleet import _named_eqns
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 REHEARSAL = os.path.join(ROOT, "tests", "rehearsal_bitcoin_cities")
@@ -314,19 +317,33 @@ def test_the_miniature_is_on_the_city_graph_two_hosts_a_city(plan):
                and np.array_equal(e.lat_vv, exp.lat_vv) for e in plan.exps)
 
 
-def test_both_lookups_take_their_per_row_form(solo):
-    """Past ``MAX_VERTEX_RUNS`` runs ``host_vertex[dst]`` and past
-    ``MAX_DENSE_VERTICES`` vertices ``table[vs, vd]`` are traced with an
-    index per outbox row: the latency's and the loss threshold's."""
+@pytest.mark.parametrize("lanes", [0, 2], ids=["solo", "vmap2"])
+def test_only_host_vertex_of_dst_is_read_with_an_index_per_row(solo, lanes):
+    """Past ``MAX_VERTEX_RUNS`` runs ``host_vertex[dst]`` stays a lookup with
+    an index per outbox row, the only one ``route_outbox`` traces: on 200
+    vertices (past ``MAX_DENSE_VERTICES``, within ``MAX_ROW_VERTICES``) the
+    path tables are read per HOST row, as a product with a one-hot of the
+    hosts' vertices, and picked per slot by a masked sum — no ``gather``
+    under ``phase:route_path``, alone and under the fleet's ``vmap``."""
     eng, st = solo
     ctx = eng.ctx
     assert ctx.vertex_runs is None
-    assert ctx.lat_vv.shape[0] == 200 > MAX_DENSE_VERTICES
-    eqns = jax.make_jaxpr(lambda ob: route_outbox(ctx, ob))(st.outbox).eqns
-    gathers = [e for e in eqns if e.primitive.name == "gather"]
-    assert len(gathers) >= 3
-    rows = st.outbox.dst.size
-    assert all(e.outvars[0].aval.size == rows for e in gathers)
+    assert MAX_DENSE_VERTICES < ctx.lat_vv.shape[0] == 200 <= MAX_ROW_VERTICES
+    route, ob = (lambda o: route_outbox(ctx, o)), st.outbox
+    if lanes:
+        route = jax.vmap(route)
+        ob = jax.tree_util.tree_map(lambda x: jnp.stack([x] * lanes), ob)
+    eqns = list(_named_eqns(jax.make_jaxpr(route)(ob).jaxpr))
+    gathers = [(e, stack) for e, stack in eqns if e.primitive.name == "gather"]
+    assert len(gathers) == 1
+    (e, stack), = gathers
+    assert e.outvars[0].aval.size == ob.dst.size
+    assert "vertex_of" in {f.function_name
+                           for f in e.source_info.traceback.frames}
+    assert "phase:route_vertex" in stack and "phase:route_path" not in stack
+    # The guard can see the path reads: a product per table under the scope.
+    assert sum(e.primitive.name == "dot_general" and "phase:route_path" in stack
+               for e, stack in eqns) == 2
 
 
 @pytest.mark.parametrize("lane", range(len(SEEDS)))

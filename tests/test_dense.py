@@ -6,7 +6,9 @@ Values: the dense read — spelled out, and as ``get_col`` — must equal numpy'
 the state planes use (bool, i32, u32 with the top bit set, i64), at every
 rank callers pass ([C,H], [L,C,H], [L1,L2,C,H]), for columns below 0 and at
 or above C, eagerly, under ``jit`` and under ``vmap`` (what the fleet engine
-compiles).
+compiles). ``table_rows`` + ``pick_row`` (PR 51), the read of a 64-bit
+``[R, W]`` table at a row per host and a column per slot, must equal numpy's
+``table[row, idx]`` in both half words the same three ways.
 
 Shape of the program: in the ``rounds`` phase of a TCP model (filexfer, Tor,
 Bitcoin), solo and under ``vmap`` over two lanes, no ``gather`` equation has
@@ -16,7 +18,8 @@ TCP model (tgen, Tor, Bitcoin) the ``prepare`` phase holds no ``gather`` at
 all and ``route_outbox`` none in the ``deliver`` phase; nor on rung 1, whose
 two vertices each hold one run of hosts (its four lookups are compares and
 selects since PR 42), while a ``vertex: spread`` map of the same network
-keeps exactly one, ``host_vertex[dst]``. On the v5e such a gather is an
+keeps exactly one, ``host_vertex[dst]`` (on 200 vertices too, PR 51:
+``tests/test_bitcoin_cities.py``). On the v5e such a gather is an
 element-serial kCustom fusion, 7–13.5 ns an element (PERF.md §6, PR 26,
 PR 31, PR 33, PR 34 and PR 42).
 """
@@ -31,7 +34,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from shadow1_tpu.core.dense import extract_col, get_col, read_sel
+from shadow1_tpu.core.dense import (
+    extract_col,
+    get_col,
+    pick_row,
+    read_sel,
+    table_rows,
+)
 from tests.test_tor_fleet import _named_eqns
 
 C, H = 5, 7
@@ -106,6 +115,62 @@ def test_one_sel_serves_many_planes(dtype):
         arr = _plane(dtype, lead)
         np.testing.assert_array_equal(
             np.asarray(extract_col(sel, jnp.asarray(arr))), _gather_ref(arr, col))
+
+
+# ---------------------------------------------------------------------------
+# a table's rows per host, and a pick per slot (PR 51)
+# ---------------------------------------------------------------------------
+
+def _table(dtype: str, rows: int, width: int) -> np.ndarray:
+    """Every byte of the 64 bits in use, the top bit too; no two entries
+    alike."""
+    t = np.random.default_rng(rows * width).integers(
+        0, 2**64, (rows, width), dtype=np.uint64)
+    t[0, 0], t[-1, -1] = 0, 2**64 - 1
+    return t.astype(dtype)
+
+
+@pytest.mark.parametrize("mode", MODES)
+@pytest.mark.parametrize("shape", [(3, 3), (17, 17), (200, 200), (40, 9)],
+                         ids=lambda s: "x".join(map(str, s)))
+@pytest.mark.parametrize("dtype", ["int64", "uint64"])
+def test_table_rows_then_pick_row_equals_the_lookup(dtype, shape, mode):
+    """``pick_row(table_rows(table, row), idx)`` is ``table[row[h], idx[c,
+    h]]``, both half words, eagerly, under ``jit`` and under ``vmap`` with a
+    table and an ``idx`` a lane (as the fleet's thresholds are)."""
+    rows, width = shape
+    n_hosts, cap = 37, 5
+    r = np.random.default_rng(rows)
+    table = _table(dtype, rows, width)
+    row = r.integers(0, rows, n_hosts).astype(np.int32)
+    idx = r.integers(0, width, (cap, n_hosts)).astype(np.int32)
+
+    def read(t, i):
+        lo, hi = table_rows(t, jnp.asarray(row))
+        assert lo.shape == hi.shape == (width, n_hosts)
+        assert lo.dtype == hi.dtype == jnp.uint32
+        return pick_row((lo, hi), i)
+
+    if mode == "vmap":
+        tables = np.stack([table, table[::-1, ::-1]])
+        idxs = np.stack([idx, (idx + 1) % width])
+        lo, hi = jax.vmap(read)(jnp.asarray(tables), jnp.asarray(idxs))
+        want = np.stack([t[row[None, :], i] for t, i in zip(tables, idxs)])
+    else:
+        f = jax.jit(read) if mode == "jit" else read
+        lo, hi = f(jnp.asarray(table), jnp.asarray(idx))
+        want = table[row[None, :], idx]
+    got = (np.asarray(hi).astype(np.uint64) << np.uint64(32)
+           | np.asarray(lo).astype(np.uint64)).astype(dtype)
+    np.testing.assert_array_equal(got, want)
+    assert (np.asarray(hi) != 0).any() and (np.asarray(lo) >> 31).any()
+
+
+def test_pick_row_reads_zero_outside_the_plane():
+    plane = jnp.asarray(np.arange(1, 13, dtype=np.uint32).reshape(4, 3))
+    idx = jnp.asarray([[0, 3, 2], [-1, 4, 1]], jnp.int32)
+    got, = pick_row((plane,), idx)
+    np.testing.assert_array_equal(np.asarray(got), [[1, 11, 9], [0, 0, 6]])
 
 
 # ---------------------------------------------------------------------------
@@ -405,7 +470,12 @@ def test_route_gathers_by_network(route_nets, net, lanes):
     tables: where ``host_vertex`` is a few runs of hosts (rung 1) none of
     the reads is a lookup; where it is a run per host, ``host_vertex[dst]``
     stays one and is the only one — which also shows that the guard above
-    sees such reads."""
+    sees such reads. The path tables are no lookup at any number of vertices
+    up to ``MAX_ROW_VERTICES`` (selects up to ``MAX_DENSE_VERTICES``, then a
+    read per host row and a masked sum per slot, PR 51:
+    ``tests/test_window_ends.py`` holds each form to the gathered one and
+    counts ``table[vs, vd]`` past the bound, ``tests/test_bitcoin_cities.py``
+    counts on 200 vertices)."""
     from shadow1_tpu.core.engine import MAX_VERTEX_RUNS
 
     config, n_gathers = route_nets[net]

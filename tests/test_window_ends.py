@@ -12,11 +12,16 @@ time ties, full (time, tb) ties, unselected slots and a down host.
 table's one element broadcast over the outbox rows; under ``vmap`` each lane
 keeps its own threshold. With more vertices (PR 42) ``vs`` is ``host_vertex``
 broadcast down the slot axis, ``vd`` compares of ``dst`` against the runs of
-``host_vertex`` and ``table[vs, vd]`` two selects: held to the gathered form
-on two, three, six and seventeen vertices, on runs of unequal length and
-vertex ids that do not rise with host id, on a ``spread`` map and beyond
-MAX_DENSE_VERTICES (the two forms that stay lookups), for a shard's block of
-hosts, and under ``vmap`` with per-lane ``[V, V]`` thresholds.
+``host_vertex`` and ``table[vs, vd]`` two steps, the table's rows per host
+and a pick over ``vd`` per slot: held to the gathered form on two, three and
+six vertices (both steps selects), on seventeen and on two hundred (PR 51:
+past MAX_DENSE_VERTICES the per-host step is one read of H table rows,
+``core/dense.table_rows``, and the pick a masked sum), on runs of unequal
+length and vertex ids that do not rise with host id, on a ``spread`` map
+(``host_vertex[dst]`` stays a lookup), with latencies above 2**32 ns and
+thresholds whose two half words are both set, for a shard's block of hosts,
+and under ``vmap`` with per-lane ``[V, V]`` thresholds; one vertex past
+MAX_ROW_VERTICES ``table[vs, vd]`` is still what is traced.
 
 And the guard on the whole window end (PR 40): ``core/engine.deliver_window``
 runs its body only when some host (of some lane, on a fleet) sent this
@@ -53,6 +58,7 @@ from shadow1_tpu.consts import (
 )
 from shadow1_tpu.core.engine import (
     MAX_DENSE_VERTICES,
+    MAX_ROW_VERTICES,
     MAX_VERTEX_RUNS,
     Engine,
     FlatPackets,
@@ -74,6 +80,7 @@ from shadow1_tpu.telemetry.registry import LANE_PROGRAM_FIELDS
 from shadow1_tpu.tools.opcensus import _sub_jaxprs
 from tests.parity import lane_metrics, unlike_leaves
 from tests.test_net_parity import filexfer_exp
+from tests.test_tor_fleet import _named_eqns
 
 H, EV_CAP, OB_CAP = 6, 16, 8
 WIN = 10 * MS
@@ -322,10 +329,19 @@ NETS = {
     "V3_unordered": (_runs((2, 3), (0, 4), (1, 2), (0, 3)), True),
     # ``vertex: spread``: a run per host, more than MAX_VERTEX_RUNS
     "V3_spread": (np.arange(MAX_VERTEX_RUNS + 8, dtype=np.int32) % 3, False),
-    # more vertices than MAX_DENSE_VERTICES: table[vs, vd] stays a lookup
+    # one vertex more than MAX_DENSE_VERTICES: the per-host step is the read
+    # of H table rows
     "V17": (np.repeat(np.arange(MAX_DENSE_VERTICES + 1), 2).astype(np.int32),
             True),
+    # bitcoin5k_cities in miniature: 200 vertices, two hosts each, dealt
+    "V200_spread": (np.random.default_rng(200).permutation(
+        np.arange(400, dtype=np.int32) % 200), False),
+    # one vertex past MAX_ROW_VERTICES (few hosts: most vertices are empty)
+    "V_past_rows": (np.array([0, MAX_ROW_VERTICES, 512, 1, MAX_ROW_VERTICES,
+                              7], np.int32), True),
 }
+# The nets whose path tables are read per host row and picked per slot.
+ROW_NETS = ("V17", "V200_spread")
 
 
 def _ctx(net: str):
@@ -346,11 +362,19 @@ def _ctx(net: str):
     # words are read; the smallest, 7 ms, is the window.
     perm = r.permutation(v * v).reshape(v, v).astype(np.int64)
     lat = (1 + perm) * 7 * MS + (perm % 3 << 33)
-    return _phold(lat_vv=lat,
-                  loss_vv=(r.permutation(v * v).reshape(v, v)
-                           / (v * v)).astype(np.float32),
-                  jitter_vv=r.permutation(v * v).reshape(v, v) * 1000 + 1,
-                  host_vertex=host_vertex)
+    ctx = _phold(lat_vv=lat,
+                 loss_vv=(r.permutation(v * v).reshape(v, v)
+                          / (v * v)).astype(np.float32),
+                 # up to 4 ms, under every latency
+                 jitter_vv=(r.permutation(v * v).reshape(v, v)
+                            * (4 * MS // (v * v)) + 1),
+                 host_vertex=host_vertex)
+    if v <= MAX_DENSE_VERTICES:
+        return ctx
+    # A third of the thresholds with both half words set (such a path loses
+    # every packet): a high word read as 0, or another entry's, shows.
+    hi = jnp.asarray((perm % 3 == 1).astype(np.uint64) << np.uint64(32))
+    return dataclasses.replace(ctx, loss_thr_vv=ctx.loss_thr_vv | hi)
 
 
 def _outbox(seed: int, h: int = H) -> Outbox:
@@ -372,6 +396,14 @@ def _outbox(seed: int, h: int = H) -> Outbox:
     )
 
 
+def _path_lookups(ctx, ob) -> int:
+    """The ``gather`` equations ``route_outbox`` traces under
+    ``phase:route_path``: ``table[vs, vd]`` of a path table."""
+    jaxpr = jax.make_jaxpr(lambda o: route_outbox(ctx, o))(ob).jaxpr
+    return sum(e.primitive.name == "gather" and "phase:route_path" in stack
+               for e, stack in _named_eqns(jaxpr))
+
+
 @pytest.mark.parametrize("net", NETS)
 def test_route_outbox_equals_gathered_form(net):
     ctx = _ctx(net)
@@ -386,6 +418,10 @@ def test_route_outbox_equals_gathered_form(net):
             np.repeat(verts, np.diff(starts + (len(host_vertex),))),
             host_vertex)
     ob = _outbox(11, ctx.n_hosts)
+    # Latency, jitter and threshold: a lookup each past MAX_ROW_VERTICES,
+    # none below it.
+    assert (v > MAX_ROW_VERTICES) == (net == "V_past_rows")
+    assert _path_lookups(ctx, ob) == (3 if net == "V_past_rows" else 0)
     got = jax.jit(lambda o: route_outbox(ctx, o))(ob)
     want = jax.jit(lambda o: _route_outbox_gathered(ctx, o))(ob)
     _assert_trees_equal(got, want)
@@ -401,14 +437,15 @@ def test_route_outbox_equals_gathered_form(net):
         assert flight.min() < 12 * MS and flight.max() > 35 * MS  # by path
     else:
         # Many paths of the network were taken, each at its own latency.
-        assert len(set((flight // MS).tolist())) > v
+        assert len(set((flight // MS).tolist())) > min(v, ctx.n_hosts)
 
 
 @pytest.mark.parametrize("block", [0, 1], ids=["lo", "hi"])
-@pytest.mark.parametrize("net", ["V6_runs6", "V3_spread"])
+@pytest.mark.parametrize("net", ["V6_runs6", "V3_spread", *ROW_NETS])
 def test_route_outbox_of_a_shard_block_equals_gathered_form(net, block):
     """As the sharded engine calls it: ``hosts`` a traced block of the
-    global ids, ``host_vertex`` the whole map, destinations global."""
+    global ids, ``host_vertex`` the whole map, destinations global. On the
+    ROW_NETS the table rows read are the block's hosts'."""
     ctx = _ctx(net)
     n = ctx.n_hosts // 2
     ob = _outbox(31 + block, n)
@@ -458,10 +495,11 @@ def test_route_outbox_one_vertex_fleet_lanes_keep_their_thresholds():
     assert 0 < n_lost[0] / n_sent[0] < 0.25 < 0.4 < n_lost[1] / n_sent[1] < 0.8
 
 
-@pytest.mark.parametrize("net", ["V2", "V6_runs6", "V3_spread"])
+@pytest.mark.parametrize("net", ["V2", "V6_runs6", "V3_spread", *ROW_NETS])
 def test_route_outbox_fleet_lanes_keep_their_path_thresholds(net):
     """The same with V > 1: a traced, batched [V, V] table read by selects
-    (or, on the spread map, through the gathered vd)."""
+    (on the spread map through the gathered vd), on the ROW_NETS cut into
+    its byte planes lane by lane."""
     ctx = _ctx(net)
     v = ctx.lat_vv.shape[0]
     # Lane 0 loses only on paths out of vertex 0, lane 1 only on the others.
